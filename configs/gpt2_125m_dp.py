@@ -1,4 +1,11 @@
-"""GPT-2 125M, pure data parallelism (BASELINE config 2: v5e-8)."""
+"""GPT-2 125M, pure data parallelism (BASELINE config 2: v5e-8).
+
+An EIGHT-chip config: ``global_batch_size = 64`` in one pass is 8 rows a
+chip on a v5e-8.  On ONE 16 GB v5e a 64-row pass does not fit (the compiler
+refuses it at 24.9 GB, and 32 rows at 16.6 GB); run it there with
+``--config.num_minibatches=4`` (16 rows a pass, 12.2 GB — what
+``chip_smoke.py`` does).
+"""
 
 from ml_collections import ConfigDict
 
@@ -9,9 +16,9 @@ def get_config():
     c = ConfigDict()
     c.simulate_cpu_devices = 0
     c.model = "gpt2_125m"
-    # round-3 tuned defaults: 0.4344 MFU on v5e-1 (SWEEP_r03.json,
-    # docs/05_performance.md) — flash 512x512 tiles, attention residuals
-    # saved by the proj_attn remat policy, layers unrolled
+    # flash 512x512 tiles, attention residuals saved by the proj_attn remat
+    # policy, layers unrolled: the recipe earlier rounds tuned; its
+    # throughput is not measured on the current machine (PERF.md)
     c.model_overrides = model_overrides(
         attn_impl="flash", remat_policy="proj_attn", scan_layers=False
     )

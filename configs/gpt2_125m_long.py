@@ -7,17 +7,12 @@ from ever materializing — at seq 8192 x vocab 50304 they would be ~0.8 GB
 bf16 per batch row.  For longer-still contexts shard the token axis
 instead (``attn_impl="ring"`` + a ``seq`` mesh axis — docs/04).
 
-Measured on v5e-1 (round 5, SWEEP_r05/r05b): batch 16 x 8192 with 8
-accumulation minibatches and UNROLLED layers trains at 48.1k
-tokens/sec/chip, **MFU 0.4023** — the per-pass shape (2 rows) keeps the
-unrolled compile inside budget (the round-4 "batch 8 crashes" was the
-8-row single-pass trace), and the round-5 batch ladder carries the rest.
-The scan ladder tops out at 0.3797 (batch 32, 8 minibatches; batch 16/4: 0.3783).  Longer contexts, same recipe at one row
-per pass: 16k = 29.4k tok/s (MFU 0.3814), 32k = 17.0k tok/s (MFU 0.3769)
-— attention's FLOPs share grows with seq while flash runs below matmul
-peak, so MFU declines gently; throughput per token-window is the metric
-that matters at fixed global tokens.  Round-4 record for reference:
-batch 4 x 8192 scan, 44.5k tok/s, MFU 0.372.
+The shape (batch 16 x 8192 as 8 accumulation passes of 2 rows, UNROLLED
+layers) was tuned in earlier rounds on a machine that no longer exists; its
+throughput is not measured on the current machine (PERF.md).  What is known
+here: the streamed GQA kernel at seq 8192 compiles for a v5e
+(tests/test_chip_compile.py).  Longer contexts use the same recipe at one
+row per pass.
 """
 
 from ml_collections import ConfigDict
@@ -34,8 +29,8 @@ def get_config():
         attn_impl="flash",  # auto-selects the streamed kernels at this length
         remat_policy="proj_attn",
         loss_chunk=1024,
-        # unrolled beats scan by ~6% here too; per-pass 2 rows keeps the
-        # 8k unrolled trace inside the remote-compile budget
+        # unrolled layers; per-pass 2 rows keeps the 8k unrolled program
+        # small enough to compile quickly
         scan_layers=False,
     )
     c.mesh = ConfigDict(dict(data=-1, model=1, pipe=1, seq=1))

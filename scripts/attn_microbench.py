@@ -50,7 +50,7 @@ def main():
             for _ in range(n):
                 out = step(q, k, v)
             jax.block_until_ready(out)
-            # device->host read: block_until_ready can lie on some transports
+            # end the timed region on a value the host has actually read
             float(jnp.sum(out[0].astype(jnp.float32)))
             dt = (time.perf_counter() - t0) / n
             print(

@@ -49,6 +49,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 
+from tpu_parallel.utils.profiling import run_identity
+
 REQUIRED_KINDS = ("crash", "stall", "flap")  # the storm must contain each
 
 
@@ -400,10 +402,7 @@ def run_soak(model, params, cfg, prompts, refs, *, seed, n_replicas,
 
     record = {
         "bench": "chaos_soak",
-        "model": getattr(cfg, "_name", None) or (
-            "gpt2_125m" if jax.default_backend() == "tpu" else "tiny"
-        ),
-        "backend": jax.default_backend(),
+        **run_identity(cfg),
         "seed": seed,
         "replicas": n_replicas,
         "router": router,
@@ -496,17 +495,22 @@ def main():
                          "in small storms")
     ap.add_argument("--record", type=str, default="",
                     help="write the soak record to this JSON file")
+    ap.add_argument("--model", choices=("tiny", "gpt2_125m"),
+                    default="tiny",
+                    help="the served model: the tiny test config (the CPU "
+                         "gate) or full-width GPT-2 125M (a chip)")
     args = ap.parse_args()
 
     from tpu_parallel.models import GPTLM, gpt2_125m, tiny_test
 
-    on_tpu = jax.default_backend() == "tpu"
+    # the caller names the model; the backend never picks it
+    real = args.model == "gpt2_125m"
     cfg = (
         gpt2_125m(dropout_rate=0.0, remat=False)
-        if on_tpu
+        if real
         else tiny_test(remat=False)
     )
-    new_tokens = args.new or (32 if on_tpu else 8)
+    new_tokens = args.new or (32 if real else 8)
     model = GPTLM(cfg)
     rnd = random.Random(args.seed)
     lo, hi = 3, min(16, cfg.seq_len - new_tokens - 2)

@@ -99,6 +99,15 @@ import urllib.request
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
+# A CPU protocol gate (journal, signals, HTTP/SSE, recovery — tier-1 runs it
+# through scripts/check_all.py), not a chip benchmark: the parent computes the
+# generate() oracle with JAX AND starts serve children that import JAX, and a
+# chip belongs to one process.  So the platform is set EXPLICITLY — here for
+# this process before anything imports jax, below for every child — and every
+# record is stamped with it.
+BACKEND = "cpu"
+os.environ["JAX_PLATFORMS"] = BACKEND
+
 DEFAULT_NEW_TOKENS = 8
 SOAK_NEW_TOKENS = 20  # long enough that a seeded kill lands mid-stream
 READY_TIMEOUT = 300.0  # cold jax import + compile on a 1-core box
@@ -170,8 +179,7 @@ def spawn_daemon(args, journal, ready_file, extra=()):
         "--grace", str(args.grace), "--fsync-batch", str(args.fsync_batch),
         *extra,
     ]
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = dict(os.environ, JAX_PLATFORMS=BACKEND)
     return subprocess.Popen(cmd, env=env)
 
 
@@ -229,10 +237,9 @@ def greedy_references(schedule, cfg_overrides=None):
 def serve(args):
     import jax
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(REPO_ROOT, ".pytest_xla_cache"),
-    )
+    from tpu_parallel.runtime import enable_compilation_cache
+
+    enable_compilation_cache()
     from tpu_parallel.cluster import Frontend, FrontendConfig
     from tpu_parallel.daemon import (
         DaemonConfig,
@@ -728,7 +735,7 @@ def run_disk_trial(args, seed, refs, degraded_leg=True):
 
 def run_disk_soak(args):
     """The seeded media-corruption acceptance soak (>= 3 seeds)."""
-    record = {"bench": "daemon_disk_faults", "trials": []}
+    record = {"bench": "daemon_disk_faults", "backend": BACKEND, "trials": []}
     problems = []
     refs_cache = {}
     for trial in range(args.trials):
@@ -1081,7 +1088,7 @@ def run_kv_disk_soak(args):
     import importlib.util
     import types
 
-    record = {"bench": "kv_disk", "trials": []}
+    record = {"bench": "kv_disk", "backend": BACKEND, "trials": []}
     problems = []
     # 20 timed samples per leg: p95 is the second-worst sample, so one
     # scheduler hiccup cannot decide the warm-vs-cold verdict
@@ -1147,7 +1154,7 @@ def run_soak(args):
     """The seeded kill-9 / restart / drain acceptance soak."""
     from tpu_parallel.daemon import load_state
 
-    record = {"bench": "daemon_soak", "trials": []}
+    record = {"bench": "daemon_soak", "backend": BACKEND, "trials": []}
     problems = []
     refs_cache = {}
     for trial in range(args.trials):

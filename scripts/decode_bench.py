@@ -1,8 +1,11 @@
-"""Serving throughput: tokens/sec for KV-cache decoding on the available chip.
+"""Serving throughput: tokens/sec for KV-cache decoding.
 
 Prints one JSON line per (batch, new_tokens) point.  Not part of the driver
 contract — perf evidence for the generation path (prefill + lax.scan decode,
 last-position lm_head, int8-cache variant, speculative draft-verify).
+``--model`` names what is served (the tiny test config by default,
+``gpt2_125m`` for a chip) — the backend never picks it — and every record
+names where it ran (``platform``, ``device_kind``, ``device_count``).
 
 Usage:
   python scripts/decode_bench.py [--reps N] [--warmup N]
@@ -10,6 +13,7 @@ Usage:
   python scripts/decode_bench.py --spec [--draft-k K1,K2,...] [combos ...]
   python scripts/decode_bench.py --engine [--fused-tick T1,T2,...] [combos]
   python scripts/decode_bench.py beam [batch prompt new num_beams]
+  (every form takes --model tiny|gpt2_125m)
 
 ``--engine`` measures the SERVING ENGINE's decode hot loop across the
 ``--fused-tick`` sweep (decode_steps_per_tick 1,4,8,16 by default): T=1
@@ -49,28 +53,31 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
+from tpu_parallel.utils.profiling import run_identity
 
-def _build(kv_dtype="bf16"):
+
+def _build(model_name, kv_dtype="bf16", **tiny_overrides):
+    """The model the caller named: full-width GPT-2 125M, or the tiny test
+    config with the workload's own window/positional overrides."""
     from tpu_parallel.models import GPTLM, gpt2_125m, tiny_test
 
-    on_tpu = jax.default_backend() == "tpu"
-    cfg = (
-        gpt2_125m(
+    if model_name == "gpt2_125m":
+        cfg = gpt2_125m(
             dropout_rate=0.0, remat=False, scan_layers=True,
             kv_cache_dtype=kv_dtype,
         )
-        if on_tpu
-        else tiny_test(kv_cache_dtype=kv_dtype)
-    )
-    return GPTLM(cfg), cfg, on_tpu
+    else:
+        cfg = tiny_test(kv_cache_dtype=kv_dtype, **tiny_overrides)
+    return GPTLM(cfg), cfg
 
 
-def run_one(batch, prompt_len, new_tokens, kv_dtype="bf16", reps=3, warmup=1):
+def run_one(batch, prompt_len, new_tokens, kv_dtype="bf16", reps=3, warmup=1,
+            model_name="tiny"):
     from tpu_parallel.models.generate import generate
 
-    model, cfg, on_tpu = _build(kv_dtype)
-    # clamp BOTH knobs to the model's window (the CPU tiny model has
-    # seq_len 32, far below the TPU defaults)
+    model, cfg = _build(model_name, kv_dtype)
+    # clamp BOTH knobs to the model's window (the tiny model has seq_len
+    # 32, far below the default combos)
     new_tokens = min(new_tokens, cfg.seq_len // 2)
     prompt_len = max(1, min(prompt_len, cfg.seq_len - new_tokens))
     prompt = jax.random.randint(
@@ -81,9 +88,8 @@ def run_one(batch, prompt_len, new_tokens, kv_dtype="bf16", reps=3, warmup=1):
     ]
 
     def timed(n_new):
-        # warmup (compile + extra reps), then time; finish with a
-        # device->host read — block_until_ready can lie on some transports
-        # (the same pitfall scripts/attn_microbench.py documents)
+        # warmup (compile + extra reps), then time; finish on a
+        # device->host read, so the region ends on a value the host holds
         for _ in range(max(warmup, 1)):
             out = generate(model, params, prompt, max_new_tokens=n_new)
         jax.device_get(out[0, -1])
@@ -102,7 +108,7 @@ def run_one(batch, prompt_len, new_tokens, kv_dtype="bf16", reps=3, warmup=1):
         prompt=prompt_len,
         new_tokens=new_tokens,
         kv_cache=kv_dtype,
-        model="gpt2_125m" if on_tpu else "tiny",
+        **run_identity(cfg),
         e2e_tokens_per_sec=round(batch * new_tokens / dt_full, 1),
         decode_tokens_per_sec=round(batch * (new_tokens - 1) / decode_dt, 1),
         decode_ms_per_step=round(decode_dt / (new_tokens - 1) * 1000, 3),
@@ -111,7 +117,7 @@ def run_one(batch, prompt_len, new_tokens, kv_dtype="bf16", reps=3, warmup=1):
 
 
 def run_spec(batch, prompt_len, new_tokens, kv_dtype="bf16", ks=(2, 4, 8),
-             reps=3, warmup=1):
+             reps=3, warmup=1, model_name="tiny"):
     """Speculative vs per-token host-loop decode on a repetitive prompt;
     one JSON line per point.  Parity-asserted: every variant must produce
     the same greedy tokens."""
@@ -120,28 +126,20 @@ def run_spec(batch, prompt_len, new_tokens, kv_dtype="bf16", ks=(2, 4, 8),
     from tpu_parallel.models.generate import generate
     from tpu_parallel.serving.spec_decode import generate_speculative
 
-    from tpu_parallel.models import GPTLM, tiny_test
-
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        model, cfg, _ = _build(kv_dtype)
-    else:
-        # CPU stand-in tuned for the workload under test: a longer window
-        # than the 32-token test default (cycles need decode length to
-        # form and amortize) and the RoPE/RMSNorm variant, whose untrained
-        # greedy continuations actually lock onto the prompt's repetition
-        # (the learned-positions tiny model wanders chaotically — ~0.35
-        # acceptance vs ~0.8 here — which starves any drafter)
-        cfg = tiny_test(
-            seq_len=256, positional="rope", norm="rmsnorm",
-            kv_cache_dtype=kv_dtype,
-        )
-        model = GPTLM(cfg)
+    # the tiny stand-in is tuned for the workload under test: a longer
+    # window than the 32-token test default (cycles need decode length to
+    # form and amortize) and the RoPE/RMSNorm variant, whose untrained
+    # greedy continuations actually lock onto the prompt's repetition (the
+    # learned-positions tiny model wanders chaotically — ~0.35 acceptance
+    # vs ~0.8 here — which starves any drafter)
+    model, cfg = _build(
+        model_name, kv_dtype, seq_len=256, positional="rope", norm="rmsnorm"
+    )
     new_tokens = min(new_tokens, cfg.seq_len // 2)
     prompt_len = max(1, min(prompt_len, cfg.seq_len - new_tokens))
     # repetitive prompt: a short random pattern tiled to length — the
     # prompt-lookup drafter's home turf (greedy continuations of a cycle)
-    period = 16 if on_tpu else 4
+    period = 16 if model_name == "gpt2_125m" else 4
     pattern = jax.random.randint(
         jax.random.PRNGKey(0), (batch, period), 0, cfg.vocab_size
     )
@@ -187,7 +185,7 @@ def run_spec(batch, prompt_len, new_tokens, kv_dtype="bf16", ks=(2, 4, 8),
         prompt=prompt_len,
         new_tokens=new_tokens,
         kv_cache=kv_dtype,
-        model="gpt2_125m" if on_tpu else "tiny_rope_256",
+        **run_identity(cfg),
         pattern_period=period,
         scan_decode_tokens_per_sec=round(
             batch * (new_tokens - 1) / max(dt_scan - dt_pre, 1e-9), 1
@@ -220,7 +218,7 @@ def run_spec(batch, prompt_len, new_tokens, kv_dtype="bf16", ks=(2, 4, 8),
 
 def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
                ticks=(1, 4, 8, 16), reps=3, warmup=1, chunk=0,
-               overlap=False):
+               overlap=False, model_name="tiny"):
     """ENGINE-mode decode throughput: the ServingEngine's decode hot loop
     across the ``--fused-tick`` sweep — T=1 is the per-step tick (one
     host dispatch + sync per token, the DECODE_r06 348-tok/s-at-batch-1
@@ -238,20 +236,13 @@ def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
     ``host_overlap_ratio`` shows the measured launch-ahead fraction."""
     import numpy as np
 
-    from tpu_parallel.models import GPTLM, tiny_test
     from tpu_parallel.models.generate import generate
     from tpu_parallel.serving import Request, SchedulerConfig, ServingEngine
 
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu:
-        model, cfg, _ = _build(kv_dtype)
-    else:
-        # CPU stand-in with a real decode window: seq 256 gives the
-        # cache-read side enough weight that the int8-native read's
-        # bandwidth story is visible (the 32-token test default is all
-        # fixed overhead)
-        cfg = tiny_test(seq_len=256, kv_cache_dtype=kv_dtype)
-        model = GPTLM(cfg)
+    # the tiny stand-in gets a real decode window: seq 256 gives the
+    # cache-read side enough weight that the int8-native read's bandwidth
+    # story is visible (the 32-token test default is all fixed overhead)
+    model, cfg = _build(model_name, kv_dtype, seq_len=256)
     new_tokens = min(new_tokens, cfg.seq_len // 2)
     prompt_len = max(1, min(prompt_len, cfg.seq_len - new_tokens))
     prompt = jax.random.randint(
@@ -307,7 +298,7 @@ def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
             prompt=prompt_len,
             new_tokens=new_tokens,
             kv_cache=kv_dtype,
-            model="gpt2_125m" if on_tpu else "tiny_256",
+            **run_identity(cfg),
             decode_steps_per_tick=steps,
             prefill_chunk_tokens=chunk or None,
             unified_tick=eng.unified_tick,
@@ -332,12 +323,13 @@ def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
         )), flush=True)
 
 
-def run_beam(batch=2, prompt_len=512, new_tokens=128, num_beams=4):
+def run_beam(batch=2, prompt_len=512, new_tokens=128, num_beams=4,
+             model_name="tiny"):
     """Lazy vs eager beam search vs the aligned-greedy floor at the same
     effective rows (batch * num_beams) — one JSON line per variant."""
     from tpu_parallel.models.generate import generate, generate_beam
 
-    model, cfg, on_tpu = _build()
+    model, cfg = _build(model_name)
     new_tokens = min(new_tokens, cfg.seq_len // 2)
     prompt_len = max(1, min(prompt_len, cfg.seq_len - new_tokens))
     prompt = jax.random.randint(
@@ -373,18 +365,20 @@ def run_beam(batch=2, prompt_len=512, new_tokens=128, num_beams=4):
         results[f"beam_{name}_vs_greedy_per_row"] = round(dt / dt_greedy, 3)
     results.update(
         batch=batch, num_beams=num_beams, rows=rows, prompt=prompt_len,
-        new_tokens=new_tokens, model="gpt2_125m" if on_tpu else "tiny",
+        new_tokens=new_tokens, **run_identity(cfg),
     )
     print(json.dumps(results), flush=True)
 
 
 def main():
-    if len(sys.argv) > 1 and sys.argv[1] == "beam":
-        run_beam(*(int(a) for a in sys.argv[2:]))
-        return
     ap = argparse.ArgumentParser()
     ap.add_argument("combos", nargs="*",
-                    help="batch,prompt,new[,kv_cache_dtype] points")
+                    help="batch,prompt,new[,kv_cache_dtype] points (after "
+                         "a leading 'beam': batch prompt new num_beams)")
+    ap.add_argument("--model", choices=("tiny", "gpt2_125m"),
+                    default="tiny",
+                    help="the model served: the tiny test config or "
+                         "full-width GPT-2 125M (a chip)")
     ap.add_argument("--reps", type=int, default=3,
                     help="timed repetitions per point")
     ap.add_argument("--warmup", type=int, default=1,
@@ -410,6 +404,9 @@ def main():
                          "launch/collect pipeline (records "
                          "host_overlap_ratio)")
     args = ap.parse_args()
+    if args.combos[:1] == ["beam"]:
+        run_beam(*(int(a) for a in args.combos[1:]), model_name=args.model)
+        return
 
     combos = []
     for arg in args.combos:
@@ -442,14 +439,15 @@ def main():
         try:
             if args.spec:
                 record = run_spec(*combo, ks=ks, reps=args.reps,
-                                  warmup=args.warmup)
+                                  warmup=args.warmup, model_name=args.model)
             elif args.engine:
                 run_engine(*combo, ticks=fused_ticks, reps=args.reps,
                            warmup=args.warmup, chunk=args.chunk,
-                           overlap=args.overlap)
+                           overlap=args.overlap, model_name=args.model)
                 continue  # run_engine prints one record per T itself
             else:
-                record = run_one(*combo, reps=args.reps, warmup=args.warmup)
+                record = run_one(*combo, reps=args.reps, warmup=args.warmup,
+                                 model_name=args.model)
             print(json.dumps(record), flush=True)
         except Exception as e:  # OOM etc — report and continue
             print(

@@ -74,6 +74,15 @@ import urllib.request
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
 
+# A CPU protocol gate (journal, signals, HTTP/SSE, recovery — tier-1 runs it
+# through scripts/check_all.py), not a chip benchmark: the parent computes the
+# generate() oracle with JAX AND starts serve children that import JAX, and a
+# chip belongs to one process.  So the platform is set EXPLICITLY — here for
+# this process before anything imports jax, below for every child — and every
+# record is stamped with it.
+BACKEND = "cpu"
+os.environ["JAX_PLATFORMS"] = BACKEND
+
 DEFAULT_NEW_TOKENS = 8
 # long enough that a seeded kill lands mid-stream, while prompt +
 # budget stays inside the tiny_test model's seq_len of 32
@@ -180,8 +189,7 @@ class Peer:
         ]
         if self.trace_log:
             cmd += ["--trace-log", self.trace_log]
-        env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = dict(os.environ, JAX_PLATFORMS=BACKEND)
         self.proc = subprocess.Popen(cmd, env=env)
         return self
 
@@ -209,8 +217,7 @@ def spawn_router(tmpdir, peer_addrs, warm_blocks=64, roles=None,
         cmd += ["--roles", ",".join(roles)]
     if trace_log:
         cmd += ["--trace-log", trace_log]
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = dict(os.environ, JAX_PLATFORMS=BACKEND)
     return subprocess.Popen(cmd, env=env), ready
 
 
@@ -291,10 +298,9 @@ def serve(args):
     ``/v1/kv/export`` has hot chains to ship."""
     import jax
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(REPO_ROOT, ".pytest_xla_cache"),
-    )
+    from tpu_parallel.runtime import enable_compilation_cache
+
+    enable_compilation_cache()
     from tpu_parallel.cluster import Frontend, FrontendConfig
     from tpu_parallel.daemon import (
         DaemonConfig,
@@ -1397,6 +1403,7 @@ def run_disagg(args):
         )
     record = {
         "bench": "fleet_disagg",
+        "backend": BACKEND,
         "seed": seed,
         "config": {
             "daemons": 3,
@@ -1678,7 +1685,7 @@ def run_trial(args, seed):
 
 def run_soak(args):
     """The seeded host-kill acceptance soak (>= 3 seeds)."""
-    record = {"bench": "fleet_soak", "trials": []}
+    record = {"bench": "fleet_soak", "backend": BACKEND, "trials": []}
     problems = []
     total_handoffs = 0
     for trial in range(args.trials):

@@ -1,9 +1,10 @@
 """Profile one training-step workload and print where the time goes.
 
-Runs a few steps of the bench config under ``jax.profiler.trace``, then
-parses the captured ``.xplane.pb`` with ``tensorboard_plugin_profile`` and
-prints the top ops by self time — the evidence needed to close the MFU gap
-(BASELINE.md north star) instead of guessing at configs.
+Runs a few steps of the bench config (GPT-2 125M, on a TPU — it fails
+without one) under ``jax.profiler.trace``, then reads the captured
+``.xplane.pb`` with ``jax.profiler.ProfileData`` and prints the top ops by
+device time — the evidence needed to close the MFU gap (BASELINE.md north
+star) instead of guessing at configs.
 
 Usage:
     python scripts/profile_step.py [batch] [remat] [attn] [chunk] [scan] [k=v...]
@@ -19,9 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def run_and_trace(batch, remat, attn, chunk, logdir, scan=None, extra=None):
-    import jax
-
-    from tpu_parallel.runtime import MeshConfig
+    from tpu_parallel.runtime import MeshConfig, require_tpu
     from tpu_parallel.train_lib import Trainer, TrainerConfig
     from tpu_parallel.utils.profiling import sync, trace
 
@@ -34,9 +33,9 @@ def run_and_trace(batch, remat, attn, chunk, logdir, scan=None, extra=None):
         overrides.update(remat=True, remat_policy=remat)
     else:
         overrides.update(remat=remat in ("1", "full"))
-    on_tpu = jax.devices()[0].platform == "tpu"
+    print(f"device: {require_tpu()}")
     config = TrainerConfig(
-        model="gpt2_125m" if on_tpu else "tiny",
+        model="gpt2_125m",
         model_overrides=overrides,
         mesh=MeshConfig(data=-1),
         global_batch_size=batch,
@@ -59,38 +58,25 @@ def run_and_trace(batch, remat, attn, chunk, logdir, scan=None, extra=None):
 
 
 def summarize(logdir, top=30):
-    """Aggregate per-op device time from the newest xplane.pb.
+    """Aggregate per-op device time from the newest xplane.pb."""
+    from jax.profiler import ProfileData
 
-    Parses the trace with a locally-compiled mirror of the XSpace proto
-    (scripts/xplane.proto) — the image's tensorboard_plugin_profile build
-    can't read xplane files, protoc can.
-    """
-    import subprocess
-    import tempfile
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    with tempfile.TemporaryDirectory() as tmp:
-        subprocess.run(
-            ["protoc", f"--python_out={tmp}", "--proto_path", here, "xplane.proto"],
-            check=True,
-        )
-        sys.path.insert(0, tmp)
-        os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-        import xplane_pb2  # noqa: E402
-
-        xplanes = sorted(
-            glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True),
-            key=os.path.getmtime,
-        )
-        if not xplanes:
-            print("no xplane.pb captured", file=sys.stderr)
-            return
-        space = xplane_pb2.XSpace()
-        with open(xplanes[-1], "rb") as f:
-            space.ParseFromString(f.read())
+    xplanes = sorted(
+        glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True),
+        key=os.path.getmtime,
+    )
+    if not xplanes:
+        print("no xplane.pb captured", file=sys.stderr)
+        return
+    planes = list(ProfileData.from_file(xplanes[-1]).planes)
+    print(f"trace: {xplanes[-1]} ({os.path.getsize(xplanes[-1]) / 1e6:.1f} MB)")
+    for plane in planes:
+        lines = [(line.name, len(list(line.events))) for line in plane.lines]
+        print(f"plane {plane.name!r}: {sum(n for _, n in lines)} events on "
+              f"{len(lines)} lines {lines[:8]}")
 
     printed = False
-    for plane in space.planes:
+    for plane in planes:
         is_device = plane.name.startswith("/device:") or "TPU" in plane.name
         if not is_device:
             continue
@@ -98,24 +84,23 @@ def summarize(logdir, top=30):
         print(f"\n=== plane: {plane.name} ===")
         totals = {}
         for line in plane.lines:
-            # only the per-op schedule lines: device planes also carry
-            # "XLA Modules" / "Steps" lines whose whole-step spans would
-            # double-count every op into the totals
-            if "Modules" in line.name or "Steps" in line.name:
+            # only the synchronous per-op schedule: a TPU plane also carries
+            # "Steps" / "XLA Modules" (whole-step spans) and "Async XLA Ops"
+            # (copies in flight UNDER the compute), which would double-count
+            if line.name != "XLA Ops":
                 continue
             for ev in line.events:
-                name = plane.event_metadata[ev.metadata_id].name
-                totals[name] = totals.get(name, 0) + ev.duration_ps
+                totals[ev.name] = totals.get(ev.name, 0) + ev.duration_ns
         if not totals:
+            print("(no 'XLA Ops' events on this plane)")
             continue
         grand = sum(totals.values())
         print(f"{'time%':>7}  {'ms':>9}  op")
-        for name, ps in sorted(totals.items(), key=lambda kv: -kv[1])[:top]:
-            print(f"{ps / grand * 100:7.2f}  {ps / 1e9:9.3f}  {name[:90]}")
-        print(f"total attributed: {grand / 1e9:.3f} ms across {len(totals)} ops")
+        for name, ns in sorted(totals.items(), key=lambda kv: -kv[1])[:top]:
+            print(f"{ns / grand * 100:7.2f}  {ns / 1e6:9.3f}  {name[:90]}")
+        print(f"total attributed: {grand / 1e6:.3f} ms across {len(totals)} ops")
     if not printed:
-        # CPU traces carry no per-op device lines — list what was captured
-        names = ", ".join(p.name for p in space.planes)
+        names = ", ".join(p.name for p in planes)
         print(f"no device plane with op events (planes: {names})")
 
 

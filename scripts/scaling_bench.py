@@ -62,7 +62,7 @@ def main():
             "(unset SCALING_BENCH_REAL to simulate on CPU)"
         )
 
-    from tpu_parallel.runtime import MeshConfig, make_mesh
+    from tpu_parallel.runtime import MeshConfig, device_record, make_mesh
     from tpu_parallel.train_lib import Trainer, TrainerConfig
     from tpu_parallel.utils.profiling import sync
 
@@ -141,6 +141,7 @@ def main():
         return dict(
             strategy=strategy,
             simulated=jax.devices()[0].platform == "cpu",
+            **device_record(),
             n_chips=n,
             step_time_ms=round(dt * 1e3, 3),
             tokens_per_sec=round(batch * seq_len / dt, 1),

@@ -106,7 +106,9 @@ JSON at exit; ``--metrics-out`` writes the LAST point's metric-registry
 snapshot as Prometheus text exposition (docs/11_observability.md).
 
 Defaults exercise 32 requests at rates 8 and 0 (0 = all-at-once) on the
-CPU tiny model (gpt2_125m on TPU).
+tiny test model; ``--model gpt2_125m`` serves the full-width model (for a
+chip).  The caller picks the model, never the backend, and every record
+line names where it ran (``platform``, ``device_kind``, ``device_count``).
 
 ``--prompt-dist`` switches to the prefix-shared workload: every prompt
 starts with the same ``--prefix-len`` system header followed by a random
@@ -141,6 +143,8 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
+
+from tpu_parallel.utils.profiling import run_identity
 
 
 def make_prompts(cfg, *, n_requests, prompt_min, prompt_max, prefix_len,
@@ -502,10 +506,7 @@ def run_point(model, params, cfg, prompts, *, rate, n_slots, new_tokens,
     lengths = [len(p) for p in prompts]
     return eng, {
         "bench": "serve",
-        "model": getattr(cfg, "_name", None) or (
-            "gpt2_125m" if jax.default_backend() == "tpu" else "tiny"
-        ),
-        "backend": jax.default_backend(),
+        **run_identity(cfg),
         "prefill_mode": label,
         "n_requests": n_requests,
         "arrival_mode": "poisson" if rate > 0 else "burst",
@@ -706,10 +707,7 @@ def run_cluster_point(model, params, cfg, prompts, *, rate, n_replicas,
     )
     return fe, {
         "bench": "serve_cluster",
-        "model": getattr(cfg, "_name", None) or (
-            "gpt2_125m" if jax.default_backend() == "tpu" else "tiny"
-        ),
-        "backend": jax.default_backend(),
+        **run_identity(cfg),
         "router": s["router"],
         "replicas": n_replicas,
         "fault": bool(fault_plans),
@@ -959,10 +957,7 @@ def run_swap_bench(model, params, cfg, schedule, *, n_replicas, n_slots,
 
     record = {
         "bench": "serve_swap",
-        "model": getattr(cfg, "_name", None) or (
-            "gpt2_125m" if jax.default_backend() == "tpu" else "tiny"
-        ),
-        "backend": jax.default_backend(),
+        **run_identity(cfg),
         "seed": seed,
         "replicas": n_replicas,
         "router": router,
@@ -1236,10 +1231,7 @@ def run_autopilot_bench(model, params, cfg, *, n_replicas=2, max_replicas=4,
     s1 = fe1.summary()
     record = {
         "bench": "serve_autopilot",
-        "model": getattr(cfg, "_name", None) or (
-            "gpt2_125m" if jax.default_backend() == "tpu" else "tiny"
-        ),
-        "backend": jax.default_backend(),
+        **run_identity(cfg),
         "seed": seed,
         "replicas": n_replicas,
         "max_replicas": max_replicas,
@@ -1384,10 +1376,7 @@ def run_capacity_probe(model, params, cfg, *, seed, logger):
     paged_tps = burst_tok_s(True)
     record = {
         "bench": "serve_paged_capacity",
-        "model": getattr(cfg, "_name", None) or (
-            "gpt2_125m" if jax.default_backend() == "tpu" else "tiny"
-        ),
-        "backend": jax.default_backend(),
+        **run_identity(cfg),
         "seq_len": seq_len,
         "kv_block_tokens": bt,
         "kv_pool_blocks": n_blocks,
@@ -1447,13 +1436,13 @@ def run_kv_hierarchy_bench(model, params, cfg, *, seed, logger,
     )
     from tpu_parallel.serving import Request, SchedulerConfig, ServingEngine
 
-    if jax.default_backend() != "tpu" and cfg.seq_len < 128:
+    if cfg.seq_len < 128:
         # the hierarchy's TTFT claim needs prefill COMPUTE to save — on
         # the toy test config a prefill call is pure dispatch overhead
         # and any win hides inside one log-histogram bucket.  The bench
         # builds its own small-but-real model (d_model 192, seq_len 128:
         # ~10s on CPU), exactly like the capacity probe owns its pool
-        # geometry; on TPU the passed gpt2_125m is already real.
+        # geometry; a passed gpt2_125m is already real.
         from tpu_parallel.models import GPTLM, tiny_test
 
         cfg = tiny_test(
@@ -1638,10 +1627,7 @@ def run_kv_hierarchy_bench(model, params, cfg, *, seed, logger,
 
     record = {
         "bench": "serve_kv_hierarchy",
-        "model": getattr(cfg, "_name", None) or (
-            "gpt2_125m" if jax.default_backend() == "tpu" else "tiny"
-        ),
-        "backend": jax.default_backend(),
+        **run_identity(cfg),
         "seed": seed,
         "workload": {
             "n_requests": n_requests,
@@ -1737,9 +1723,7 @@ def run_kv_disk_bench(model, params, cfg, *, seed, logger,
     import shutil
     import tempfile
 
-    if model is None or (
-        jax.default_backend() != "tpu" and cfg.seq_len < 128
-    ):
+    if model is None or cfg.seq_len < 128:
         # same reasoning as run_kv_hierarchy_bench: the hit a disk
         # restore saves is prefill COMPUTE, so the toy 32-token config
         # would measure nothing but dispatch
@@ -1837,10 +1821,7 @@ def run_kv_disk_bench(model, params, cfg, *, seed, logger,
     )
     record = {
         "bench": "serve_kv_disk",
-        "model": getattr(cfg, "_name", None) or (
-            "gpt2_125m" if jax.default_backend() == "tpu" else "tiny"
-        ),
-        "backend": jax.default_backend(),
+        **run_identity(cfg),
         "seed": seed,
         "workload": {
             "n_requests": n_requests,
@@ -2029,8 +2010,7 @@ def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
         )
     record = {
         "bench": "serve_unified",
-        "backend": jax.default_backend(),
-        "model": getattr(cfg, "_name", None) or "tiny_128",
+        **run_identity(cfg),
         "seq_len": cfg.seq_len,
         "n_requests": n_requests,
         "n_slots": 4,
@@ -2355,30 +2335,36 @@ def main():
                          "Prometheus text exposition")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", type=str, default="serve_bench")
+    ap.add_argument("--model", choices=("tiny", "gpt2_125m"),
+                    default="tiny",
+                    help="the served model: the 0.1M-parameter test config "
+                         "(the CPU gates) or full-width GPT-2 125M (a chip)")
     args = ap.parse_args()
 
     from tpu_parallel.models import GPTLM, gpt2_125m, tiny_test
     from tpu_parallel.utils.logging_utils import MetricLogger
 
-    on_tpu = jax.default_backend() == "tpu"
+    # the caller names the model (--model); the backend never picks it, and
+    # every record says where it ran (run_identity)
+    real = args.model == "gpt2_125m"
     cfg = (
         gpt2_125m(dropout_rate=0.0, remat=False)
-        if on_tpu
+        if real
         else tiny_test(remat=False)
     )
-    new_tokens = args.new or (64 if on_tpu else 8)
+    new_tokens = args.new or (64 if real else 8)
     if args.prompt_dist:
-        prefix_len = args.prefix_len or (128 if on_tpu else 8)
+        prefix_len = args.prefix_len or (128 if real else 8)
         prompt_min = args.prompt_min or 1
         prompt_max = args.prompt_max or (
-            min(384, cfg.seq_len - new_tokens - prefix_len) if on_tpu
+            min(384, cfg.seq_len - new_tokens - prefix_len) if real
             else cfg.seq_len - new_tokens - prefix_len - 3
         )
     else:
         prefix_len = 0
-        prompt_min = args.prompt_min or (128 if on_tpu else 3)
+        prompt_min = args.prompt_min or (128 if real else 3)
         prompt_max = args.prompt_max or (
-            min(512, cfg.seq_len - new_tokens) if on_tpu
+            min(512, cfg.seq_len - new_tokens) if real
             else cfg.seq_len - new_tokens - 2
         )
     model = GPTLM(cfg)
@@ -2388,7 +2374,7 @@ def main():
     )["params"]
     if args.prompt_zipf:
         zipf_s, zipf_tenants = parse_zipf(args.prompt_zipf)
-        zp_len = args.prefix_len or (128 if on_tpu else 8)
+        zp_len = args.prefix_len or (128 if real else 8)
         zp_max = max(1, prompt_max - zp_len + prefix_len)
         if zp_max < prompt_min:
             raise SystemExit(

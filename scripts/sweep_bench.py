@@ -1,7 +1,8 @@
-"""Perf sweep for GPT-2 125M on the available chip: batch x remat x attn.
+"""Perf sweep for GPT-2 125M on a TPU: batch x remat x attn.
 
-Prints one JSON line per config with tokens/sec/chip and MFU; used to pick
-bench.py defaults.  Not part of the driver contract — a tuning tool.
+Prints one JSON line per config with tokens/sec/chip and MFU (and the device
+it ran on); used to pick bench.py defaults.  Fails without a TPU.  Not part
+of the driver contract — a tuning tool.
 """
 
 import json
@@ -21,11 +22,7 @@ def run_one(
     from tpu_parallel.core import compute as compute_metrics
     from tpu_parallel.runtime import MeshConfig
     from tpu_parallel.train_lib import Trainer, TrainerConfig
-    from tpu_parallel.utils.profiling import (
-        peak_flops,
-        sync,
-        transformer_flops_per_token,
-    )
+    from tpu_parallel.utils.profiling import mfu, sync
 
     overrides = dict(
         dropout_rate=0.0, attn_impl=attn_impl, scan_layers=scan_layers,
@@ -59,22 +56,23 @@ def run_one(
     sync((state, metrics))
     dt = time.perf_counter() - t0
 
-    device = jax.devices()[0]
-    tokens_per_sec = batch * trainer.model_config.seq_len * steps / dt
-    flops_per_token = transformer_flops_per_token(trainer.model_config)
-    peak = peak_flops(device) or 197e12
-    mfu = tokens_per_sec * flops_per_token / peak / jax.device_count()
+    tokens_per_sec_chip = (
+        batch * trainer.model_config.seq_len * steps / dt / jax.device_count()
+    )
     return dict(
         batch=batch,
         remat=remat,
         attn=attn_impl,
-        tokens_per_sec_chip=round(tokens_per_sec / jax.device_count(), 1),
-        mfu=round(mfu, 4),
+        tokens_per_sec_chip=round(tokens_per_sec_chip, 1),
+        mfu=round(mfu(tokens_per_sec_chip, trainer.model_config), 4),
         final_loss=round(compute_metrics(metrics)["loss"], 3),
     )
 
 
 def main():
+    from tpu_parallel.runtime import require_tpu
+
+    device = require_tpu()
     combos = []
     for arg in sys.argv[1:]:
         parts = arg.split(",")
@@ -102,6 +100,7 @@ def main():
                 extra=extra,
             )
             result["minib"], result["scan"], result["chunk"] = minib, scan, chunk
+            result.update(device)
             if extra:
                 result["extra"] = extra
             print(json.dumps(result), flush=True)

@@ -1,9 +1,8 @@
 #!/bin/bash
-# Round-4 sweep plan (VERDICT #2): finish the batch>=24 region the round-3
-# HTTP 500s truncated, measure loss_chunk where it was built to matter, and
-# diagnose the ~25ms layer-scan overhead by varying ONLY the remat policy
-# under scan.  One process, combos serialized (single TPU claim; shared
-# compile cache).  Appends JSON lines to SWEEP_r04.json.
+# Round-4 sweep plan: the batch>=24 region, loss_chunk where it was built to
+# matter, and the ~25ms layer-scan overhead diagnosed by varying ONLY the
+# remat policy under scan.  One process, combos serialized (a chip belongs to
+# one process; shared compile cache).  Appends JSON lines to SWEEP_r04.json.
 #
 # combo format: batch,remat,attn,minib,scan,chunk[,k=v...]
 set -u

@@ -23,19 +23,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# Persistent XLA compile cache: the round box has ONE cpu core, and the
-# suite's wall time is dominated by XLA compiles of near-identical tiny
-# trainers re-traced per test file.  The disk cache is keyed by HLO hash,
-# so identical programs compile once — across files AND across runs (a
-# re-run of the unchanged suite skips nearly every compile).  Kept under
-# the repo (gitignored) so it survives between gate runs.
-_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    ".pytest_xla_cache",
-)
-jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+# Persistent XLA compile cache: the suite's wall time is dominated by XLA
+# compiles of near-identical tiny trainers re-traced per test file.  The disk
+# cache is keyed by HLO hash, so identical programs compile once — across
+# files AND across runs.  enable_compilation_cache() is the repo's one place
+# that picks the directory (JAX_COMPILATION_CACHE_DIR, else .xla_cache/).
+from tpu_parallel.runtime import enable_compilation_cache
+
+enable_compilation_cache()
 
 from tpu_parallel.runtime import MeshConfig, make_mesh
 

@@ -1,0 +1,214 @@
+"""What can be proven about the chip path without a chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``).  The flash kernels
+of the main path are compiled for a ``v5e:2x2`` topology at real widths with
+``interpret=False`` — interpret mode, which every other kernel test uses,
+cannot see a tile the hardware refuses or a kernel that outgrows VMEM.  A
+compile that passes is not a chip run: ``chip_smoke.py`` is that.
+
+Also here: the rules this repo holds about the device — one compile cache
+placeable from outside, peak FLOP/s only for known devices, and measurement
+entry points that fail without a TPU instead of falling back to the CPU.
+"""
+
+import functools
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tpu_parallel.ops.flash_attention import (
+    flash_attention,
+    flash_chunk_attention,
+)
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """A sharding on one chip of a described v5e:2x2, with the persistent
+    compile cache off: an executable built for a described chip is written
+    to the cache but cannot be read back without one, so later runs would
+    warn and recompile."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as exc:  # noqa: BLE001 — no libtpu, or it cannot describe
+        pytest.skip(f"cannot describe a v5e topology here: {exc!r}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _bidirectional(q, k, v):
+    from tpu_parallel.models.layers import bidirectional_flash_attention
+
+    return bidirectional_flash_attention(q, k, v, block_q=512, block_k=512)
+
+
+# name -> (fn(q, k, v, *extra), q shape, kv shape, extra int32 operand shapes)
+# Shapes are [batch, seq, heads, head_dim], bf16.  Every case compiles
+# forward AND backward (fwd, dq, dkv kernels — about a second a case here;
+# the first one pays libtpu's start-up).
+KERNEL_CASES = {
+    "gpt2_125m_512x512": (
+        functools.partial(
+            flash_attention, block_q=512, block_k=512, interpret=False
+        ),
+        (16, 1024, 12, 64), (16, 1024, 12, 64), (),
+    ),
+    "gpt2_125m_128x128": (
+        functools.partial(flash_attention, interpret=False),
+        (16, 1024, 12, 64), (16, 1024, 12, 64), (),
+    ),
+    "gqa_16q_4kv_seq8192_streamed": (
+        functools.partial(
+            flash_attention, block_q=512, block_k=512, interpret=False
+        ),
+        (1, 8192, 16, 128), (1, 8192, 4, 128), (),
+    ),
+    "packed_segment_ids": (
+        lambda q, k, v, seg: flash_attention(
+            q, k, v, segment_ids=seg, block_q=512, block_k=512,
+            interpret=False,
+        ),
+        (4, 1024, 12, 64), (4, 1024, 12, 64), ((4, 1024),),
+    ),
+    "window_256": (
+        functools.partial(
+            flash_attention, block_q=512, block_k=512, window=256,
+            interpret=False,
+        ),
+        (4, 1024, 12, 64), (4, 1024, 12, 64), (),
+    ),
+    # the ring partials: (out, lse), the lse cotangent included
+    "noncausal_chunk_with_lse": (
+        functools.partial(
+            flash_chunk_attention, causal=False, block_q=512, block_k=512,
+            interpret=False,
+        ),
+        (4, 512, 12, 64), (4, 512, 12, 64), (),
+    ),
+    "causal_chunk_with_lse": (
+        functools.partial(
+            flash_chunk_attention, causal=True, block_q=512, block_k=512,
+            interpret=False,
+        ),
+        (4, 512, 12, 64), (4, 512, 12, 64), (),
+    ),
+    # layers.py's encoder path takes no ``interpret``: the test steers
+    # ``jax.default_backend`` instead (below)
+    "bidirectional_bert_base_seq512": (
+        _bidirectional, (8, 512, 12, 64), (8, 512, 12, 64), (),
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_flash_kernel_compiles_for_v5e(case, v5e_chip, monkeypatch):
+    fn, q_shape, kv_shape, extra = KERNEL_CASES[case]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(q, k, v, *ints):
+        outs = jax.tree_util.tree_leaves(fn(q, k, v, *ints))
+        return sum(o.astype(jnp.float32).sum() for o in outs)
+
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=v5e_chip)
+    kv = jax.ShapeDtypeStruct(kv_shape, jnp.bfloat16, sharding=v5e_chip)
+    ints = [
+        jax.ShapeDtypeStruct(s, jnp.int32, sharding=v5e_chip) for s in extra
+    ]
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv, *ints)
+    assert lowered.as_text().count("tpu_custom_call") == 3, (
+        "expected the fwd, dq and dkv kernels as custom calls (not interpreted)"
+    )
+    lowered.compile()  # raises what the chip's compiler would raise
+
+
+def test_compile_cache_honours_the_environment(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX already uses it and the code
+    sets no directory of its own."""
+    from tpu_parallel.runtime import enable_compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compilation_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_fixed_inside_the_checkout(monkeypatch):
+    from tpu_parallel.runtime import COMPILE_CACHE_DIR, enable_compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        assert enable_compilation_cache() == COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == COMPILE_CACHE_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert COMPILE_CACHE_DIR == os.path.join(REPO, ".xla_cache")
+    with open(os.path.join(REPO, ".gitignore")) as fh:
+        assert ".xla_cache/" in fh.read().split()
+
+
+def test_peak_flops_is_exact_and_refuses_unknown_devices():
+    from types import SimpleNamespace
+
+    from tpu_parallel.utils.profiling import mfu, peak_flops
+
+    v5e = SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+    assert peak_flops(v5e) == 197e12
+    # no substring catch-all: a kind the table does not hold is an error
+    for kind in ("TPU v5 lite (new)", "tpu v5 lite", "TPU v7", "cpu"):
+        with pytest.raises(ValueError, match="no peak FLOPs/s"):
+            peak_flops(SimpleNamespace(device_kind=kind, platform="tpu"))
+    from tpu_parallel.models import gpt2_125m
+
+    with pytest.raises(ValueError, match="no peak FLOPs/s"):
+        mfu(1e5, gpt2_125m(), jax.devices()[0])  # the CPU has no entry either
+
+
+def test_chip_smoke_fails_without_a_tpu():
+    """The contract, literally: under ``JAX_PLATFORMS=cpu`` the script exits
+    non-zero and prints no result — at the device check, before any model is
+    built, so this is quick."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")], env=env,
+        cwd=REPO, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        timeout=120,
+    )
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    assert not [l for l in proc.stdout.splitlines() if l.startswith("{")]
+    assert "no TPU" in proc.stderr
+
+
+def test_bench_fails_without_a_tpu(capsys):
+    """bench.py is one process that measures on a TPU or fails: no CPU
+    fallback, no retry, no older result (same check as above, in-process)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "bench_under_test", os.path.join(REPO, "bench.py")
+    )
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    with pytest.raises(RuntimeError, match="no TPU"):
+        bench.main()
+    assert capsys.readouterr().out == ""

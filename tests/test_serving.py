@@ -1720,10 +1720,6 @@ def test_spec_engine_wall_clock_with_oracle(rng):
     assert dt_spec < dt_plain
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="jax.shard_map unavailable (the repo's sharded paths need it)",
-)
 def test_engine_sharded_tp_matches_static(mesh_data4_model2, rng):
     """TP serving through the engine: mesh-sharded weights, head-sharded
     cache pool, greedy tokens identical to generate_sharded on the same

@@ -21,21 +21,11 @@ Metrics = Dict[str, Tuple[jax.Array, jax.Array]]
 def vma_of(x) -> Tuple[str, ...]:
     """The mesh axes ``x`` is varying over (empty outside shard_map).
 
-    Single home for the version-sensitive vma introspection — works on
-    traced arrays and on ``jax.eval_shape`` results.
+    Works on traced arrays and on ``jax.eval_shape`` results.
     """
     # sorted: .vma is a frozenset, and hash-randomized iteration order would
     # vary the axes tuples baked into jaxprs run-to-run (compile-cache poison)
-    return tuple(sorted(getattr(jax.typeof(x), "vma", ()) or ()))
-
-
-def _cast_varying(x: jax.Array, axis_names: Sequence[str]) -> jax.Array:
-    # lax.pcast supersedes the deprecated lax.pvary; keep the fallback while
-    # the pinned jax still ships both
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, tuple(axis_names), to="varying")
-    return lax.pvary(x, tuple(axis_names))
+    return tuple(sorted(jax.typeof(x).vma))
 
 
 def pvary_missing(x: jax.Array, axis_names: Sequence[str]) -> jax.Array:
@@ -49,11 +39,9 @@ def pvary_missing(x: jax.Array, axis_names: Sequence[str]) -> jax.Array:
     the sum simply multiplies by the axis size exactly as it did with the
     checker off.  Outside shard_map (no vma tracking) this is a no-op.
     """
-    vma = getattr(jax.typeof(x), "vma", None)
-    if vma is None:
-        return x
+    vma = jax.typeof(x).vma
     missing = tuple(a for a in axis_names if a not in vma)
-    return _cast_varying(x, missing) if missing else x
+    return lax.pcast(x, missing, to="varying") if missing else x
 
 
 def metric(value: jax.Array, count: Union[int, jax.Array] = 1) -> Tuple[jax.Array, jax.Array]:

@@ -112,17 +112,16 @@ class TransformerConfig:
     # — unlike scan_unroll, which unrolls the loop but keeps one carry
     # round-trip per block.  Param layout changes to [n_layers/g] stacks of
     # g named blocks ("block0".."block{g-1}"); g=1 keeps the historical
-    # layout.  Must divide n_layers.  Measured round 5 (SWEEP_r05.json):
-    # FLAT at 125M (0.3876/0.3865/0.3859/0.3867/0.384 MFU at g=1/2/3/4/6)
-    # — which falsified the carry-round-trip theory of the scan tax; the
-    # bisect then located it in the backward (fwd +6.6%, bwd +15.7% vs
-    # unrolled).  The knob stays for other depths/hardware.
+    # layout.  Must divide n_layers.  An earlier round found throughput
+    # FLAT in g at 125M (g=1..6) — which falsified the carry-round-trip
+    # theory of the scan tax; a bisect then located it in the backward.
+    # Not measured on the current machine (PERF.md); the knob stays for
+    # other depths/hardware.
     scan_group: int = 1
     # lax.scan's _split_transpose: lowers the layer scan's BACKWARD as two
     # loops (residual regeneration + gradient accumulation) that XLA can
-    # overlap.  The measured scan tax lives in the backward (fwd +6.6%,
-    # bwd +15.7% vs unrolled at 125M/batch16 — round-5 bisect), which is
-    # exactly the pass this targets.
+    # overlap.  The scan tax an earlier round bisected lives in the
+    # backward, which is exactly the pass this targets.
     scan_split_transpose: bool = False
     fsdp: bool = False  # shard big params over the data axis (ZeRO-3)
     fsdp_min_size: int = 2**18

@@ -22,7 +22,7 @@ Two kernel variants share the masking/band geometry:
 - **resident** (seq <= ``STREAM_SEQ_THRESHOLD``): one (batch, head) row's
   whole K/V lives in VMEM; the K loop runs inside the kernel and skips
   out-of-band blocks entirely.  This is the measured-fastest path at the
-  bench config (512x512 tiles, seq 1024 — SWEEP_r03.json).
+  bench config (512x512 tiles, seq 1024).
 - **streamed** (longer seq): the K/V walk is a grid dimension; VMEM holds one
   [block_k, d] tile plus fp32 online-softmax scratch carried across grid
   steps, so residency is O(block) and seq 8k-32k fits v5e VMEM.  Out-of-band
@@ -34,8 +34,13 @@ Packed sequences: ``segment_ids`` [batch, seq] adds a same-segment condition
 to the causal mask in all kernels (each query can always see itself, so no
 row is ever fully masked).
 
-Falls back to the jnp reference implementation off-TPU (CPU tests run the
-kernels in interpret mode explicitly).
+There is no jnp fallback by backend: off-TPU the SAME kernels run in Pallas
+interpret mode (``interpret=None`` resolves from ``jax.default_backend()`` —
+what the CPU tests exercise), and on a TPU they compile to ``tpu_custom_call``s.
+A program that must be on the chip proves it by finding that custom call in
+its lowered text (``chip_smoke.py`` does).  The one dense escape hatch is by
+SHAPE, not backend: a sequence no tile divides warns and takes the O(seq^2)
+``causal_attention`` path.
 """
 
 from __future__ import annotations
@@ -49,12 +54,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU too (used for interpret-mode tests)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu  # importable on CPU too
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128

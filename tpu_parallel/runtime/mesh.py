@@ -22,9 +22,12 @@ Axis convention (outermost -> innermost):
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
+
+logger = logging.getLogger("tpu_parallel")
 
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
@@ -111,7 +114,12 @@ def make_mesh(
                 devices=devices,
                 allow_split_physical_axes=allow_split_physical_axes,
             )
-        except (ValueError, AssertionError, NotImplementedError):
+        except (ValueError, AssertionError, NotImplementedError) as exc:
+            # loud, not silent: a plain reshape ignores the ICI topology
+            logger.warning(
+                "create_device_mesh%s failed (%r); falling back to a plain "
+                "reshape of the device list", shape, exc,
+            )
             dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, AXIS_ORDER)
 

@@ -457,18 +457,17 @@ class Trainer:
                     last["tokens_per_sec"] = tokens_per_step * step / max(
                         time.perf_counter() - t_start, 1e-9
                     )
-                # mfu's FLOPs model is the decoder-only transformer; an
-                # encoder-decoder number from it would be fiction
-                util = (
-                    None
-                    if self.is_seq2seq
-                    else mfu(
-                        last["tokens_per_sec"] / jax.device_count(),
+                # mfu's FLOPs model is the decoder-only transformer (an
+                # encoder-decoder number from it would be fiction), and a
+                # CPU run has no device utilization to report; any OTHER
+                # device must be in the peak table (mfu raises)
+                device = self.mesh.devices.flat[0]
+                if not self.is_seq2seq and device.platform != "cpu":
+                    last["mfu"] = mfu(
+                        last["tokens_per_sec"] / self.mesh.size,
                         self.model_config,
+                        device,
                     )
-                )
-                if util is not None:  # None off-TPU (no known peak FLOPs)
-                    last["mfu"] = util
                 self._publish_gauges(last)
                 if log_fn is not None:
                     log_fn(step, last)
@@ -622,7 +621,7 @@ class Trainer:
                         self.state, metrics, batch
                     )
                     jax.block_until_ready(new_state)
-                except Exception as exc:  # noqa: BLE001 — device/transport failure
+                except Exception as exc:  # noqa: BLE001 — device/runtime failure
                     if step_span is not None:
                         # close at the failure, not at export time — an
                         # unfinished span would render as one giant

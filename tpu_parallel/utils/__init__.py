@@ -2,6 +2,7 @@ from tpu_parallel.utils.logging_utils import MetricLogger, print_exception
 from tpu_parallel.utils.profiling import (
     mfu,
     peak_flops,
+    run_identity,
     sync,
     timeit,
     trace,
@@ -13,6 +14,7 @@ __all__ = [
     "print_exception",
     "mfu",
     "peak_flops",
+    "run_identity",
     "sync",
     "timeit",
     "trace",

@@ -11,33 +11,55 @@ from __future__ import annotations
 
 import contextlib
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable
 
 import jax
 
-# Dense bf16 peak FLOPs/s per chip.  Matching is SUBSTRING-in-device_kind,
-# so more-specific kinds must precede their prefixes ("tpu v4i" before
-# "tpu v4", "tpu v5p" before "tpu v5") — dicts iterate in insertion order.
+# Dense bf16 peak FLOPs/s per chip, keyed by the EXACT ``device_kind`` string
+# JAX reports (the strings are what ``jax.experimental.topologies`` describes
+# for v4 / v5e / v5p / v6e with the installed libtpu).  Figures: Google Cloud
+# TPU documentation, the "TPU v4" / "TPU v5e" / "TPU v5p" / "TPU v6e" system
+# architecture pages.  A kind that is not here is an error on a measurement
+# path, never a default.
 PEAK_FLOPS_BY_KIND = {
-    "tpu v5 lite": 197e12,
-    "tpu v5litepod": 197e12,
-    "tpu v5p": 459e12,
-    "tpu v5": 197e12,
-    "tpu v4i": 138e12,
-    "tpu v4": 275e12,
-    "tpu v6 lite": 918e12,
-    "tpu v6": 918e12,
+    "TPU v4": 275e12,  # one device per chip (megacore)
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5": 459e12,  # v5p
+    "TPU v6 lite": 918e12,  # v6e
 }
 
 
-def peak_flops(device=None) -> Optional[float]:
-    """Peak bf16 FLOPs/s for ``device`` (None if unknown, e.g. CPU)."""
+def peak_flops(device=None) -> float:
+    """Peak bf16 FLOPs/s for ``device``; raises on an unknown ``device_kind``."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, val in PEAK_FLOPS_BY_KIND.items():
-        if key in kind:
-            return val
-    return None
+    try:
+        return PEAK_FLOPS_BY_KIND[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOPs/s on record for device_kind={device.device_kind!r} "
+            f"(platform {device.platform!r}); known: {sorted(PEAK_FLOPS_BY_KIND)} "
+            "— add the published figure with its source, do not assume one"
+        ) from None
+
+
+def model_label(cfg) -> str:
+    """The architecture by its numbers — how benchmark records name what they
+    ran, so the label follows the config and nothing else (not the backend,
+    not a default)."""
+    return (
+        f"d{cfg.d_model}_l{cfg.n_layers}_h{cfg.n_heads}"
+        f"_v{cfg.vocab_size}_s{cfg.seq_len}"
+    )
+
+
+def run_identity(cfg) -> dict:
+    """What every benchmark record says about what ran where: the model by
+    its shape, and the device as JAX reports it (``backend`` repeats the
+    platform under the key older records used)."""
+    from tpu_parallel.runtime import device_record
+
+    device = device_record()
+    return {"model": model_label(cfg), "backend": device["platform"], **device}
 
 
 def transformer_flops_per_token(cfg) -> float:
@@ -76,11 +98,12 @@ def transformer_flops_per_token(cfg) -> float:
     return 6 * matmul_params + attn
 
 
-def mfu(tokens_per_sec_per_chip: float, cfg, device=None) -> Optional[float]:
-    peak = peak_flops(device)
-    if peak is None:
-        return None
-    return tokens_per_sec_per_chip * transformer_flops_per_token(cfg) / peak
+def mfu(tokens_per_sec_per_chip: float, cfg, device=None) -> float:
+    return (
+        tokens_per_sec_per_chip
+        * transformer_flops_per_token(cfg)
+        / peak_flops(device)
+    )
 
 
 @contextlib.contextmanager
@@ -97,10 +120,9 @@ def sync(out) -> None:
     """Force completion of every array in the pytree ``out``.
 
     ``block_until_ready`` (all shards, all leaves) plus a device->host fetch
-    of one element: on some transports (e.g. tunneled single-chip setups)
-    ``block_until_ready`` can return before execution finishes; reading a
-    value back cannot.  The fetch only runs on fully-addressable arrays
-    (eager indexing of a multi-host global array would raise); on a pod,
+    of one element, so a timed region ends on a value the host has actually
+    read.  The fetch only runs on fully-addressable arrays (eager indexing
+    of a multi-host global array would raise); on a pod,
     ``block_until_ready`` alone is the barrier.
     """
     out = jax.block_until_ready(out)

@@ -19,8 +19,8 @@ text-exposition snapshot of the trainer's metric registry (MFU,
 tokens/sec, loss gauges).
 """
 
-import os
 import sys
+import types
 
 from absl import app, flags, logging
 from ml_collections import config_flags
@@ -38,52 +38,72 @@ _METRICS_OUT = flags.DEFINE_string(
 )
 
 
+# config fields that steer the run around the Trainer (name -> default);
+# everything else in a config file is a TrainerConfig field
+RUN_FIELDS = dict(
+    simulate_cpu_devices=0,
+    checkpoint_dir="",
+    checkpoint_every=100,
+    data_path="",
+    # "flat": contiguous seq_len windows; "packed": EOS-delimited documents
+    # packed whole into rows with segment_ids (in-kernel attention masking)
+    data_format="flat",
+    eos_id=50256,  # GPT-2's <|endoftext|>
+    eval_steps=0,
+    # >0: evaluate on the held-out split every N steps during fit;
+    # keep_best then also snapshots the lowest-eval-loss state to
+    # {checkpoint_dir}/best
+    eval_every=0,
+    keep_best=False,
+)
+
+
+def build_trainer(cd, tracer=None):
+    """``(Trainer, run options)`` from a config file's ConfigDict — the one
+    construction path, shared with ``chip_smoke.py`` so the smoke drives the
+    object this CLI drives."""
+    from tpu_parallel.train_lib import Trainer, TrainerConfig
+
+    trainer_cd = dict(cd)
+    run = types.SimpleNamespace(
+        **{k: trainer_cd.pop(k, default) for k, default in RUN_FIELDS.items()}
+    )
+    # fraction of the token stream held out for eval (never trained on);
+    # defaults on whenever eval is requested over a real dataset
+    run.eval_fraction = trainer_cd.pop(
+        "eval_fraction", 0.1 if (run.eval_steps or run.eval_every) else 0.0
+    )
+    config = TrainerConfig.from_config_dict(trainer_cd)
+    return Trainer(config, tracer=tracer), run
+
+
 def main(argv):
     del argv
     cd = _CONFIG.value
-    from tpu_parallel.runtime import initialize, process_info, simulate_cpu_devices
-    from tpu_parallel.train_lib import Trainer, TrainerConfig
+    from tpu_parallel.runtime import (
+        enable_compilation_cache,
+        initialize,
+        process_info,
+        simulate_cpu_devices,
+    )
 
     # Distributed bootstrap first: jax.distributed.initialize must run before
     # the first backend touch (simulate_cpu_devices initializes the backend to
     # validate its post-condition).
     initialize()
-    from tpu_parallel.runtime import enable_compilation_cache
-
-    # no-op on remote-compile transports / with TPU_PARALLEL_NO_COMPILE_CACHE=1
     enable_compilation_cache()
     sim = cd.get("simulate_cpu_devices", 0)
     if sim:
         simulate_cpu_devices(sim)
     logging.info("topology: %s", process_info())
 
-    trainer_cd = dict(cd)
-    trainer_cd.pop("simulate_cpu_devices", None)
-    checkpoint_dir = trainer_cd.pop("checkpoint_dir", "")
-    checkpoint_every = trainer_cd.pop("checkpoint_every", 100)
-    data_path = trainer_cd.pop("data_path", "")
-    # "flat": contiguous seq_len windows; "packed": EOS-delimited documents
-    # packed whole into rows with segment_ids (in-kernel attention masking)
-    data_format = trainer_cd.pop("data_format", "flat")
-    eos_id = trainer_cd.pop("eos_id", 50256)  # GPT-2's <|endoftext|>
-    eval_steps = trainer_cd.pop("eval_steps", 0)
-    # >0: evaluate on the held-out split every N steps during fit;
-    # keep_best then also snapshots the lowest-eval-loss state to
-    # {checkpoint_dir}/best
-    eval_every = trainer_cd.pop("eval_every", 0)
-    keep_best = trainer_cd.pop("keep_best", False)
-    # fraction of the token stream held out for eval (never trained on);
-    # defaults on whenever eval is requested over a real dataset
-    eval_fraction = trainer_cd.pop(
-        "eval_fraction", 0.1 if (eval_steps or eval_every) else 0.0
-    )
-    config = TrainerConfig.from_config_dict(trainer_cd)
     tracer = None
     if _TRACE_OUT.value:
         from tpu_parallel.obs import Tracer
 
         tracer = Tracer()
-    trainer = Trainer(config, tracer=tracer)
+    trainer, run = build_trainer(cd, tracer)
+    config = trainer.config
     logging.info(
         "model=%s params=%.1fM mesh=%s",
         config.model,
@@ -92,32 +112,34 @@ def main(argv):
     )
 
     data_loader = None
-    if data_path:
+    if run.data_path:
         from tpu_parallel.data import DataLoader, PackedDataset, TokenDataset
 
-        paths = data_path.split(",") if "," in data_path else data_path
-        if data_format == "packed":
+        paths = (
+            run.data_path.split(",") if "," in run.data_path else run.data_path
+        )
+        if run.data_format == "packed":
             if isinstance(paths, list):
                 raise NotImplementedError(
                     "packed datasets read a single .bin stream "
                     "(concatenate shards at prepare time)"
                 )
             dataset = PackedDataset(
-                paths, trainer.model_config.seq_len, eos_id=eos_id
+                paths, trainer.model_config.seq_len, eos_id=run.eos_id
             )
-        elif data_format == "flat":
+        elif run.data_format == "flat":
             dataset = TokenDataset(paths, trainer.model_config.seq_len)
         else:
-            raise ValueError(f"data_format={data_format!r} (flat | packed)")
+            raise ValueError(f"data_format={run.data_format!r} (flat | packed)")
         data_loader = DataLoader(
             dataset,
             trainer.mesh,
             config.global_batch_size,
             seed=config.seed,
-            holdout_fraction=eval_fraction,
+            holdout_fraction=run.eval_fraction,
             batch_spec=trainer.batch_spec,
         )
-        if eval_steps:
+        if run.eval_steps:
             # fail fast: an eval split smaller than one batch (or
             # eval_fraction=0) should abort before training, not after it
             data_loader.eval_view()
@@ -126,21 +148,21 @@ def main(argv):
         parts = " ".join(f"{k}={v:.4f}" for k, v in sorted(metrics.items()))
         logging.info("step %d: %s", step, parts)
 
-    if (eval_every or keep_best) and not checkpoint_dir:
+    if (run.eval_every or run.keep_best) and not run.checkpoint_dir:
         raise ValueError(
             "eval_every/keep_best run inside the fault-tolerant fit loop — "
             "set checkpoint_dir too"
         )
-    if checkpoint_dir:
+    if run.checkpoint_dir:
         # fault-tolerant path: auto-resume + periodic saves + exact data replay
         final = trainer.fit(
-            checkpoint_dir,
+            run.checkpoint_dir,
             data_loader=data_loader,
-            checkpoint_every=checkpoint_every,
+            checkpoint_every=run.checkpoint_every,
             log_fn=log_fn,
-            eval_every=eval_every,
-            eval_steps=eval_steps or 10,
-            keep_best=keep_best,
+            eval_every=run.eval_every,
+            eval_steps=run.eval_steps or 10,
+            keep_best=run.keep_best,
         )
     else:
         final = trainer.train(
@@ -149,10 +171,10 @@ def main(argv):
             log_fn=log_fn,
         )
     logging.info("final: %s", final)
-    if eval_steps:
+    if run.eval_steps:
         # held-out split: windows the train loader can never sample
         eval_iter = iter(data_loader.eval_view()) if data_loader else None
-        ev = trainer.evaluate(batch_iter=eval_iter, steps=eval_steps)
+        ev = trainer.evaluate(batch_iter=eval_iter, steps=run.eval_steps)
         logging.info("eval: %s", ev)
     if tracer is not None:
         from tpu_parallel.obs import write_chrome_trace
