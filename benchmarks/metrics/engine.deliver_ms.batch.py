@@ -1,0 +1,9 @@
+"""Serving engine: `engine.deliver_ms.batch` (ms), from program_counter; should move `serve_out_tok_s`."""
+
+from lib import readers
+
+META = {"name": "engine.deliver_ms.batch", "layer": "Serving engine", "unit": "ms", "source": "program_counter", "moves": "serve_out_tok_s"}
+
+
+def read(run):
+    return readers.counter(run, "tick_deliver_ms_mean")

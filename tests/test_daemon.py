@@ -1037,3 +1037,204 @@ def test_degraded_mode_typed_rejects_drains_and_exits_clean(
     # damage, and the accepted request's submit record is durable
     state = load_state(str(path))
     assert "t0" in state.dedupe
+
+
+# -- the pump's phase clock and the lock, observed ---------------------------
+
+
+class _SignalClock(FakeClock):
+    """A FakeClock that says when somebody read it."""
+
+    def __init__(self):
+        super().__init__()
+        self.read = threading.Semaphore(0)
+
+    def __call__(self) -> float:
+        self.read.release()
+        return self.t
+
+
+def _hist(daemon, name, **labels):
+    (row,) = [
+        r for r in daemon.registry.snapshot()["histograms"]
+        if r["name"] == name and r["labels"] == labels
+    ]
+    return row
+
+
+def test_submit_waits_for_the_pumps_lock_and_says_how_long(env, tmp_path):
+    """A submit issued while another thread holds the daemon's lock
+    records a `lock_wait` at least as long as the hold, apart from what
+    it held itself."""
+    _, _, _, prompts, _ = env
+    clock = _SignalClock()
+    d = _daemon(env, tmp_path / "j.jsonl", clock=clock)
+    got = []
+    d._lock.acquire()  # the pump, mid-tick
+    try:
+        t = threading.Thread(target=lambda: got.append(d.submit(
+            Request(prompt=prompts[0], max_new_tokens=4, request_id="r0")
+        )))
+        while clock.read.acquire(blocking=False):
+            pass  # reads made so far are not the submit's
+        t.start()
+        assert clock.read.acquire(timeout=30)  # it read the clock: waiting
+        clock.t += 5.0  # ... through all of the hold
+    finally:
+        d._lock.release()
+    t.join(timeout=30)
+    assert not t.is_alive() and got[0]["status"] != REJECTED
+    wait = _hist(d, "daemon_call_seconds", call="submit", phase="lock_wait")
+    held = _hist(d, "daemon_call_seconds", call="submit", phase="held")
+    assert wait["count"] == held["count"] == 1
+    assert wait["sum"] >= 5.0 and held["sum"] == 0.0
+    # the snapshot submit returns is taken under the same hold: no
+    # `result` call of its own
+    assert _hist(
+        d, "daemon_call_seconds", call="result", phase="lock_wait"
+    )["count"] == 0
+    d.result("r0")
+    d.cancel("r0")
+    d.subscribe("r0")
+    for call in ("result", "cancel", "subscribe"):
+        assert _hist(
+            d, "daemon_call_seconds", call=call, phase="held"
+        )["count"] == 1
+
+
+def test_pump_tick_observes_its_phases_and_its_own_lock_wait(env, tmp_path):
+    _, _, _, prompts, _ = env
+    clock = _SignalClock()
+    d = _daemon(env, tmp_path / "j.jsonl", clock=clock)
+    d.submit(Request(prompt=prompts[0], max_new_tokens=4, request_id="r0"))
+    done = []
+    d._lock.acquire()  # a handler thread, mid-submit
+    try:
+        t = threading.Thread(target=lambda: done.append(d.tick()))
+        while clock.read.acquire(blocking=False):
+            pass
+        t.start()
+        assert clock.read.acquire(timeout=30)
+        clock.t += 2.0
+    finally:
+        d._lock.release()
+    t.join(timeout=30)
+    assert not t.is_alive() and done
+    for _ in range(4):
+        d.tick()
+    for name in ("lock_wait", "step", "journal", "fsync", "housekeeping"):
+        assert _hist(
+            d, "daemon_tick_phase_seconds", phase=name
+        )["count"] == 5, name
+    waited = _hist(d, "daemon_tick_phase_seconds", phase="lock_wait")
+    assert waited["sum"] == 2.0  # the one contended tick, nothing else
+
+
+def test_phase_annotations_are_leaves_on_the_pump_thread(
+    env, tmp_path, monkeypatch
+):
+    """Over 20 ticks with traffic, what reaches the profiler under
+    `engine.tick.` / `daemon.tick.` is a sequence of leaves (never one
+    inside another), all from the pump's thread, none from a handler
+    thread's submit, and no enclosing span shares the prefixes."""
+    from tpu_parallel.obs import phases as phase_module
+
+    _, _, _, prompts, _ = env
+    seen, open_now, nested = [], [], []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            if open_now:
+                nested.append((list(open_now), self.name))
+            open_now.append(self.name)
+            seen.append((self.name, threading.get_ident()))
+
+        def __exit__(self, *exc):
+            open_now.remove(self.name)
+
+    monkeypatch.setattr(phase_module, "TraceAnnotation", Recorder)
+    d = _daemon(env, tmp_path / "j.jsonl")
+    pump = threading.get_ident()
+
+    def handler():
+        for i, p in enumerate(prompts):
+            d.submit(Request(prompt=p, max_new_tokens=6, request_id=f"h{i}"))
+            d.result(f"h{i}")
+
+    t = threading.Thread(target=handler)
+    t.start()
+    t.join(timeout=60)
+    assert not t.is_alive() and not seen  # calls annotate nothing
+    for _ in range(20):
+        d.tick()
+    assert all(d.result(f"h{i}")["status"] == "finished" for i in range(4))
+    names = {name for name, _ in seen}
+    assert names == {
+        "engine.tick.schedule", "engine.tick.prefill",
+        "engine.tick.dispatch", "engine.tick.device_wait",
+        "engine.tick.deliver", "engine.tick.record",
+        "daemon.tick.lock_wait", "daemon.tick.journal",
+        "daemon.tick.fsync", "daemon.tick.housekeeping",
+    }
+    assert not nested and not open_now
+    assert {ident for _, ident in seen} == {pump}
+    assert sum(n == "daemon.tick.journal" for n, _ in seen) == 20
+
+
+def test_tracez_returns_every_span_once_and_the_tracer_stays_small(
+    env, tmp_path
+):
+    """Tracing on for a long-lived daemon: what the spool has written is
+    released from the tracer, and `/v1/tracez` still has each span once."""
+    from tpu_parallel.obs import SpanSpool, Tracer
+
+    cfg, model, params, prompts, _ = env
+    clock = FakeClock()
+    tracer = Tracer(clock=clock)
+
+    def frontend_factory(clk):
+        engine = ServingEngine(
+            model, params, n_slots=2, clock=clk, tracer=tracer,
+            scheduler=SchedulerConfig(max_prefills_per_tick=2),
+        )
+        return Frontend(
+            [engine], router="least", config=FrontendConfig(restart=None),
+            clock=clk, registry=MetricRegistry(), tracer=tracer,
+        )
+
+    spool = SpanSpool(str(tmp_path / "spans.jsonl"), "daemon:test")
+    d = ServingDaemon(
+        frontend_factory, str(tmp_path / "j.jsonl"), clock=clock,
+        config=DaemonConfig(fsync_batch=4), span_spool=spool,
+    )
+    resident = 0
+    for i in range(60):
+        if i % 10 == 0:
+            d.submit(Request(
+                prompt=prompts[i % 4], max_new_tokens=6,
+                request_id=f"r{i}",
+            ))
+            d.subscribe(f"r{i}")
+        clock.t += 0.01
+        d.tick()
+        resident = max(resident, len(tracer.spans) + len(tracer.instants))
+    assert resident <= 8  # only what is still open waits in the tracer
+    assert tracer.dropped == 0
+    records = [
+        r for r in d.trace_payload()["records"] if r["kind"] == "span"
+    ]
+    ticks = [r for r in records if r["name"] == "tick"]
+    assert len(ticks) == 60
+    assert len({r["attrs"]["tick"] for r in ticks}) == 60  # each once
+    for name in ("journal", "fsync", "housekeeping", "step", "lock_wait"):
+        pump = [r for r in records if r["name"] == f"tick.{name}"]
+        assert len(pump) == 60 and {r["track"] for r in pump} == {"daemon"}
+    waits = [r for r in records if r["name"] == "lock_wait"]
+    assert sorted(r["attrs"]["call"] for r in waits) == (
+        ["submit"] * 6 + ["subscribe"] * 6
+    )
+    assert all("async_id" in r for r in waits)
+    spool.close()

@@ -20,11 +20,13 @@ from tpu_parallel.obs import (
     HistogramWindow,
     MetricRegistry,
     PercentileWindow,
+    SpanSpool,
     Tracer,
     chrome_trace_events,
     parse_prometheus_text,
     prometheus_lines,
     prometheus_text,
+    read_span_log,
     validate_snapshot,
     write_chrome_trace,
 )
@@ -438,22 +440,6 @@ def test_prometheus_label_escaping_roundtrips_through_parser():
         assert reparsed["labels"] == s["labels"]
 
 
-def test_jsonl_exporter_rebases_registry_onto_metric_logger(tmp_path):
-    from tpu_parallel.obs import export_snapshot_jsonl
-    from tpu_parallel.utils.logging_utils import MetricLogger
-
-    r = MetricRegistry()
-    r.counter("serving_finished_total").inc(7)
-    logger = MetricLogger(logdir=str(tmp_path), name="snap")
-    export_snapshot_jsonl(r, logger, point="burst-8")
-    logger.close()
-    (line,) = open(tmp_path / "snap.jsonl").read().splitlines()
-    record = json.loads(line)
-    assert record["kind"] == "registry_snapshot"
-    assert record["point"] == "burst-8"
-    assert validate_snapshot(record["metrics"]) == []
-
-
 # -- MetricLogger scalar coercion (satellite regression) -------------------
 
 
@@ -628,3 +614,95 @@ def test_disabled_tracer_overhead_under_two_percent():
         f"null-tracer overhead {per_tick_overhead * 1e6:.2f}us is "
         f"{ratio:.2%} of a {tick_s * 1e3:.2f}ms tick"
     )
+
+
+# -- the phase clock and the tracer's bounded window -------------------------
+
+
+def test_phase_writes_three_ways_from_one_pair_of_reads(monkeypatch):
+    """One `phase` block: two clock reads; the sink, the tracer span and
+    the profiler annotation all carry that one interval."""
+    from tpu_parallel.obs import phase, phases
+
+    entered = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.name))
+
+    monkeypatch.setattr(phases, "TraceAnnotation", Recorder)
+    clock = FakeClock()
+    tr = Tracer(clock=clock)
+    sunk = []
+    with phase(lambda n, s: sunk.append((n, s)), tr, "scheduler", "deliver",
+               clock, annotation="engine.tick.deliver") as ph:
+        assert entered == [("enter", "engine.tick.deliver")]
+    assert clock.t == 2.0  # two reads, no more
+    assert (ph.start, ph.end) == (1.0, 2.0)
+    assert sunk == [("deliver", 1.0)]
+    assert entered[-1] == ("exit", "engine.tick.deliver")
+    (span,) = tr.spans
+    assert (span.name, span.track, span.start, span.end) == (
+        "tick.deliver", "scheduler", 1.0, 2.0
+    )
+    # tracing off and no annotation: still the sink, nothing else
+    with phase(lambda n, s: sunk.append((n, s)), NULL_TRACER, "daemon",
+               "step", clock):
+        pass
+    assert sunk[-1] == ("step", 1.0) and clock.t == 4.0
+    assert len(entered) == 2 and len(tr.spans) == 1
+
+
+def test_spool_drain_keeps_the_tracer_small_over_10k_ticks(tmp_path):
+    """A tracer drained by a spool holds a tick's worth, not the 10 k
+    ticks' spans, and the log has every span exactly once."""
+    clock = FakeClock()
+    tr = Tracer(clock=clock)
+    spool = SpanSpool(str(tmp_path / "spans.jsonl"), "daemon:test",
+                      max_bytes=1 << 30)
+    resident = 0
+    queue = None
+    for tick in range(10_000):
+        if tick % 100 == 0:
+            queue = tr.start_async(
+                "queue", "scheduler", async_id=f"q{tick}", n=tick
+            )
+        with tr.span("tick", track="scheduler", tick=tick):
+            tr.record("tick.deliver", "scheduler", clock.t, clock.t, n=tick)
+        if tick % 100 == 50:
+            queue.finish()  # open across 50 drains, then written once
+        tr.instant("finish", track="slot 0", n=tick)
+        spool.drain(tr)
+        resident = max(resident, len(tr.spans) + len(tr.instants))
+    assert resident == 0 and tr.dropped == 0  # all handed to the spool
+    spool.close()
+    records, skipped = read_span_log(spool.path)
+    assert skipped == {"garbage": 0, "crc": 0}
+    for name, count in (("tick", 10_000), ("tick.deliver", 10_000),
+                        ("queue", 100)):
+        got = [r["attrs"].get("tick", r["attrs"].get("n"))
+               for r in records if r.get("name") == name
+               and r["kind"] == "span"]
+        assert len(got) == len(set(got)) == count, name
+    assert sum(r["kind"] == "instant" for r in records) == 10_000
+
+
+def test_tracer_without_a_spool_is_capped_and_counts_what_it_drops():
+    tr = Tracer(clock=FakeClock())
+    tr.max_resident = 100
+    held = tr.span("tick", track="scheduler", tick=-1)  # open, then dropped
+    for i in range(1000):
+        tr.record("decode", "slot 0", i, i + 1, i=i)
+        tr.instant("finish", track="slot 0", i=i)
+        assert len(tr.spans) <= 100 and len(tr.instants) <= 100
+    assert tr.dropped == (1001 - len(tr.spans)) + (1000 - len(tr.instants))
+    assert tr.spans[-1].attrs["i"] == 999  # the newest stay
+    held.finish()  # a dropped span is still its holder's to close
+    assert held.end is not None and held not in tr.spans
+    assert NULL_TRACER.release(1, 1) is None

@@ -2018,3 +2018,228 @@ def test_nan_sentinel_spec_verify_path(rng, spec_steps):
     assert eng.integrity_trips == 1
     assert eng.pool.n_free == eng.pool.n_slots
     assert not eng.has_work()
+
+
+# -- the phase clock ---------------------------------------------------------
+
+PHASES = ("schedule", "prefill", "dispatch", "device_wait", "deliver", "record")
+OLD_SUMMARY_KEYS = {
+    "cancelled", "decode_ticks", "expired", "finished", "host_dispatches",
+    "host_ms_per_tick_p50", "host_ms_per_tick_p95", "host_overlap_ratio",
+    "integrity_trips", "itl_ms_p50", "itl_ms_p95", "kv_block_cow_copies",
+    "kv_blocks_free", "kv_blocks_in_use", "kv_bytes_per_active_token",
+    "kv_host_blocks_in_use", "kv_host_breaker_state",
+    "kv_host_breaker_trips", "kv_host_evictions", "kv_host_offloads",
+    "kv_host_restore_failures", "kv_host_restored_blocks",
+    "kv_integrity_failures", "overlapped_dispatches", "prefill_calls",
+    "prefill_chunks", "prefills", "prefix_entries", "prefix_entry_bytes",
+    "prefix_evictions", "prefix_hit_rate", "prefix_hits", "prefix_misses",
+    "prefix_shared_blocks", "queue_depth_max", "queue_depth_mean",
+    "rejected", "slot_occupancy_mean", "spec_acceptance_rate",
+    "spec_wasted_positions", "ticks", "tokens_accepted", "tokens_drafted",
+    "tokens_out", "tokens_per_decode_tick", "tokens_per_dispatch_mean",
+    "tokens_per_sec", "ttft_ms_p50", "ttft_ms_p95",
+    "unified_tick_tokens_mean",
+}
+NEW_SUMMARY_KEYS = {
+    "busy_ticks", "busy_tick_ms_mean", "decode_only_tick_ms_mean",
+    "prefill_tick_ms_mean", "host_exposed_share",
+    *(f"tick_{name}_ms_mean" for name in PHASES + ("between",)),
+}
+
+
+class _SetClock:
+    """A clock that moves only when told to (so all of a tick's time falls
+    inside the code the test makes slow) and counts its reads."""
+
+    def __init__(self):
+        self.t = 0.0
+        self.reads = 0
+
+    def __call__(self):
+        self.reads += 1
+        return self.t
+
+
+def _slow(obj, attr, clock, seconds):
+    """Make ``obj.attr`` take ``seconds`` on ``clock``."""
+    inner = getattr(obj, attr)
+
+    def slowed(*args, **kwargs):
+        clock.t += seconds
+        return inner(*args, **kwargs)
+
+    setattr(obj, attr, slowed)
+
+
+def _phase_hists(eng):
+    sums, counts = {}, {}
+    busy_sum, busy_count = 0.0, 0
+    for row in eng.registry.snapshot()["histograms"]:
+        if row["name"] == "serving_tick_phase_seconds":
+            sums[row["labels"]["phase"]] = row["sum"]
+            counts[row["labels"]["phase"]] = row["count"]
+        elif row["name"] == "serving_busy_tick_seconds":
+            busy_sum += row["sum"]
+            busy_count += row["count"]
+    return sums, counts, busy_sum, busy_count
+
+
+@pytest.mark.parametrize("kind", ["fused", "unified", "per_step"])
+def test_phases_partition_busy_ticks_and_skip_idle_ones(rng, kind):
+    """The six in-tick phases add up to the busy tick's wall time and
+    `between` to the gaps between consecutive busy ticks; idle ticks and
+    the sleep before a burst enter no histogram; a busy tick reads the
+    clock at most 12 times beside its per-token stamps."""
+    cfg, model, prompt, params = _build(rng, n_rows=2, prompt_len=7)
+    clock = _SetClock()
+    knobs = dict(
+        fused={}, unified=dict(prefill_chunk_tokens=4),
+        per_step=dict(decode_steps_per_tick=1),
+    )[kind]
+    eng = ServingEngine(model, params, n_slots=2, clock=clock, **knobs)
+    cost = dict(schedule=0.002, prefill=0.003, dispatch=0.005,
+                device_wait=0.1, deliver=0.001)
+    _slow(eng.scheduler, "schedule", clock, cost["schedule"])
+    _slow(eng, "_admit_batch", clock, cost["prefill"])
+    _slow(eng, "_launch_decode", clock, cost["dispatch"])
+    _slow(eng, "_sync_payload", clock, cost["device_wait"])
+
+    def on_token(event):
+        clock.t += cost["deliver"]
+
+    def submit(row):
+        eng.add_request(_req(prompt[row], 20, on_token=on_token))
+
+    submit(0)
+    submit(1)
+    ticks, tokens = 0, 0
+    while eng.has_work():
+        before = clock.reads
+        events = eng.step()
+        ticks += 1
+        tokens += len(events)
+        if all(ev.index > 0 for ev in events):  # no first token: steady
+            assert clock.reads - before - len(events) <= 12
+        clock.t += 0.5  # whatever the engine's owner does between ticks
+    assert ticks >= 3
+    if kind == "unified":
+        assert eng.metrics.summary()["unified_tick_tokens_mean"] is not None
+
+    sums, counts, busy_sum, busy_count = _phase_hists(eng)
+    assert busy_count == ticks
+    assert {counts[name] for name in PHASES} == {ticks}
+    for name in ("schedule", "dispatch", "device_wait"):
+        assert sums[name] == pytest.approx(cost[name] * ticks)
+    # a first token is delivered by the prefill that made it (in the
+    # unified tick: by the collect of the tick that activated it)
+    first = 0 if kind == "unified" else 2
+    assert sums["prefill"] == pytest.approx(
+        cost["prefill"] * ticks + cost["deliver"] * first
+    )
+    assert sums["deliver"] == pytest.approx(
+        cost["deliver"] * (tokens - first)
+    )
+    assert sum(sums[name] for name in PHASES) == pytest.approx(busy_sum)
+    assert counts["between"] == ticks - 1
+    assert sums["between"] == pytest.approx(0.5 * (ticks - 1))
+
+    # idle ticks, then the sleep before the next burst: observed nowhere
+    clock.t += 100.0
+    for _ in range(3):
+        eng.step()
+    clock.t += 100.0
+    assert _phase_hists(eng) == (sums, counts, busy_sum, busy_count)
+    submit(0)
+    eng.step()
+    sums2, counts2, _, busy_count2 = _phase_hists(eng)
+    assert busy_count2 == ticks + 1
+    assert counts2["between"] == ticks - 1  # the tick before was idle
+    assert sums2["between"] == pytest.approx(sums["between"])
+
+    s = eng.metrics.summary()
+    assert s["busy_ticks"] == ticks + 1 < s["ticks"]
+    exposed = sum(
+        s[f"tick_{n}_ms_mean"] for n in ("schedule", "deliver", "record",
+                                         "between")
+    )
+    everything = sum(s[f"tick_{n}_ms_mean"] for n in PHASES + ("between",))
+    assert s["host_exposed_share"] == pytest.approx(
+        100.0 * exposed / everything, abs=1e-3
+    )
+
+
+def test_summary_keeps_every_old_key_and_has_the_phase_clock(rng):
+    cfg, model, prompt, params = _build(rng, n_rows=1)
+    eng = ServingEngine(model, params, n_slots=1)
+    empty = eng.metrics.summary()
+    assert set(empty) == OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS
+    assert empty["busy_ticks"] == 0
+    assert all(empty[k] is None for k in NEW_SUMMARY_KEYS - {"busy_ticks"})
+    eng.add_request(_req(prompt[0], 12))
+    eng.run()
+    s = eng.metrics.summary()
+    assert set(s) == OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS
+    assert s["busy_ticks"] == s["decode_ticks"] >= 2
+    # the one admission made the first tick a prefill tick
+    assert s["prefill_tick_ms_mean"] > 0 and s["decode_only_tick_ms_mean"] > 0
+    assert 0.0 < s["host_exposed_share"] < 100.0
+    assert s["tick_between_ms_mean"] is not None
+
+
+def test_pipelined_ticks_stay_out_of_the_phase_histograms(rng):
+    """`run(overlap=True)` collects tick N with tick N+1 queued on the
+    device: such ticks count in `overlapped_dispatches` and in no phase
+    series, so `host_exposed_share` never reads hidden time as exposed."""
+    cfg, model, prompt, params = _build(rng, n_rows=1)
+    eng = ServingEngine(model, params, n_slots=1)
+    eng.add_request(_req(prompt[0], 24))
+    eng.run(overlap=True)
+    s = eng.metrics.summary()
+    assert s["overlapped_dispatches"] >= 2
+    assert s["busy_ticks"] < s["decode_ticks"]
+    assert s["busy_ticks"] <= s["decode_ticks"] - s["overlapped_dispatches"]
+
+
+def test_tick_span_carries_its_phases(rng):
+    """One click on a `tick` span answers where the tick went; the phase
+    spans nest inside it on the scheduler track under their own names."""
+    from tpu_parallel.obs import Tracer
+
+    cfg, model, prompt, params = _build(rng, n_rows=1)
+    clock = [0.0]
+
+    def fake_clock():
+        clock[0] += 0.25
+        return clock[0]
+
+    tracer = Tracer(clock=fake_clock)
+    eng = ServingEngine(
+        model, params, n_slots=1, clock=fake_clock, tracer=tracer,
+    )
+    eng.add_request(_req(prompt[0], 10))
+    eng.run()
+    ticks = [s for s in tracer.spans if s.name == "tick"]
+    assert len(ticks) >= 2
+    for tick in ticks:
+        assert {f"{n}_ms" for n in PHASES} <= set(tick.attrs)
+        inside = [
+            s for s in tracer.spans
+            if s.track == "scheduler" and s.name.startswith("tick.")
+            and s.name != "tick.between"
+            and tick.start <= s.start and s.end <= tick.end
+        ]
+        assert {s.name for s in inside} == {f"tick.{n}" for n in PHASES}
+        # leaves: sequential, never overlapping one another
+        inside.sort(key=lambda s: s.start)
+        assert all(a.end <= b.start for a, b in zip(inside, inside[1:]))
+    # the decode dispatch's window holds exactly the wait for the device
+    decode = [s for s in tracer.spans if s.name == "decode_tick"]
+    waits = [s for s in tracer.spans if s.name == "tick.device_wait"]
+    assert len(decode) == len(waits) == len(ticks)
+    for d, w in zip(decode, waits):
+        assert d.start < w.start and d.end == w.end
+    # no request-level span name was taken by a phase
+    assert not [s for s in tracer.spans
+                if s.name in PHASES and s.track == "scheduler"]
+    assert [s for s in tracer.spans if s.name == "tick.between"]

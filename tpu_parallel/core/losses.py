@@ -28,10 +28,15 @@ def token_cross_entropy(logits: jax.Array, targets: jax.Array) -> jax.Array:
     side fp32 cast would only double the [B, S, vocab] HBM footprint, the
     dominant buffer at GPT-2 vocab sizes).  The upcast here fuses into the
     log-softmax reductions on TPU, so no fp32 logits tensor materializes.
+
+    Runs under ``jax.named_scope("cross_entropy")``: XLA's fusion names
+    change with every compile, the scope in their metadata does not
+    (docs/05_performance.md).
     """
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits.astype(jnp.float32), targets
-    )
+    with jax.named_scope("cross_entropy"):
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits.astype(jnp.float32), targets
+        )
 
 
 def vocab_parallel_argmax(logits: jax.Array, axis_name: str) -> jax.Array:
@@ -125,9 +130,10 @@ def make_lm_loss(fold_axes: AxisNames = "data") -> Callable:
             if batch.loss_mask is not None
             else jnp.ones_like(loss, jnp.float32)
         )
-        loss = loss * mask
-        n_tok = mask.sum()
-        correct = ((logits.argmax(-1) == batch.targets) * mask).sum()
+        with jax.named_scope("cross_entropy"):
+            loss = loss * mask
+            n_tok = mask.sum()
+            correct = ((logits.argmax(-1) == batch.targets) * mask).sum()
         metrics: Metrics = {
             "loss": (loss.sum(), n_tok),
             "accuracy": (correct.astype(jnp.float32), n_tok),

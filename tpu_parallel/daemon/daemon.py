@@ -36,6 +36,7 @@ rides lock-free subscriber queues fed from inside the tick.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import queue
@@ -57,6 +58,7 @@ from tpu_parallel.daemon.journal import (
     load_state,
 )
 from tpu_parallel.daemon.wallclock import WallClock
+from tpu_parallel.obs.phases import DAEMON_PREFIX, phase
 from tpu_parallel.obs.spool import read_span_log
 from tpu_parallel.obs.tracer import NULL_TRACER, TraceContext
 from tpu_parallel.serving.request import (
@@ -70,6 +72,12 @@ from tpu_parallel.serving.request import (
 )
 
 DAEMON_TRACK = "daemon"  # tracer track for signals/recovery/shutdown
+# the pump tick's phases (daemon_tick_phase_seconds{phase=...}); `step`
+# holds the engine's own phases, the others are leaves
+TICK_PHASES = ("lock_wait", "step", "journal", "fsync", "housekeeping")
+# the public calls whose wait for / hold of the daemon's lock is
+# observed (daemon_call_seconds{call=..., phase=lock_wait|held})
+LOCKED_CALLS = ("submit", "subscribe", "result", "cancel")
 
 # exit codes (the signal contract; docs/13_daemon.md)
 EXIT_CLEAN = 0  # drained: every accepted request terminal, journal clean
@@ -256,6 +264,23 @@ class ServingDaemon:
             "daemon_degraded_rejects_total"
         )
         self._m_kv_peer_exports = r.counter("daemon_kv_peer_exports_total")
+        # the pump's phase clock (tick()) and, for every public call
+        # that takes the daemon's lock, how long it waited for the lock
+        # and how long it held it: `POST /v1/submit` taking seconds
+        # under load reads as submit/lock_wait here
+        self._m_tick_phase = {
+            name: r.histogram("daemon_tick_phase_seconds", phase=name)
+            for name in TICK_PHASES
+        }
+        self._m_call = {
+            call: (
+                r.histogram(
+                    "daemon_call_seconds", call=call, phase="lock_wait"
+                ),
+                r.histogram("daemon_call_seconds", call=call, phase="held"),
+            )
+            for call in LOCKED_CALLS
+        }
         # observed swap/autopilot decisions flow through the frontend's
         # journal hook into REC_DECISION records
         self.frontend.set_journal(self._frontend_note)
@@ -498,6 +523,51 @@ class ServingDaemon:
             ) == old:
                 del self._dedupe[gone.dedupe_token]
 
+    # -- the lock, observed -------------------------------------------------
+
+    @contextlib.contextmanager
+    def _locked(self, call: str):
+        """Take the daemon's lock for one public call and observe how
+        long the call WAITED for it and how long it HELD it
+        (``daemon_call_seconds``).  Yields the wait as ``(start, end)``
+        on the daemon's clock, for a caller that also records it as a
+        span.  Runs on handler threads: histograms only, never a
+        ``daemon.tick.*`` annotation (those are the pump thread's)."""
+        waited, held = self._m_call[call]
+        t0 = self.clock()
+        self._lock.acquire()
+        t1 = self.clock()
+        try:
+            yield t0, t1
+        finally:
+            t2 = self.clock()
+            self._lock.release()
+            waited.observe(t1 - t0)
+            held.observe(t2 - t1)
+
+    def _lock_wait_span(self, call: str, request_id: str, wait) -> None:
+        """The wait as a ``lock_wait`` span on the daemon track (async:
+        handler threads wait side by side); with the request bound to a
+        fleet trace it joins that trace."""
+        if self.tracer.enabled:
+            self.tracer.record(
+                "lock_wait", DAEMON_TRACK, wait[0], wait[1],
+                async_id=f"{call}:{request_id}",
+                request_id=request_id, call=call,
+            )
+
+    def _tick_phase(self, name: str, leaf: bool = True) -> phase:
+        """One phase of the pump's tick (:mod:`tpu_parallel.obs.phases`):
+        histogram, ``daemon`` track span when tracing, and - for a leaf -
+        the ``daemon.tick.<name>`` profiler annotation."""
+        return phase(
+            self._observe_tick_phase, self.tracer, DAEMON_TRACK, name,
+            self.clock, annotation=DAEMON_PREFIX + name if leaf else None,
+        )
+
+    def _observe_tick_phase(self, name: str, seconds: float) -> None:
+        self._m_tick_phase[name].observe(seconds)
+
     # -- admission ---------------------------------------------------------
 
     def submit(
@@ -523,13 +593,13 @@ class ServingDaemon:
             ROLE_DECODE,
         )
 
-        with self._lock:
+        with self._locked("submit") as wait:
             dedupe_token = dedupe_token or request.dedupe_token
             if dedupe_token and dedupe_token in self._dedupe:
                 self._m_dedupe_hits.inc()
                 # a SNAPSHOT, like result(): the live record mutates
                 # under the tick while the HTTP thread serializes this
-                return self.result(self._dedupe[dedupe_token])
+                return self._snapshot(self._dedupe[dedupe_token])
             record = {
                 "request_id": request.request_id,
                 "status": QUEUED,
@@ -567,6 +637,7 @@ class ServingDaemon:
                 # bind BEFORE frontend.submit so the queue span the
                 # admission records already carries the fleet trace id
                 self.tracer.bind_trace(request.request_id, trace)
+            self._lock_wait_span("submit", request.request_id, wait)
             dr = _DaemonRequest(record, dedupe_token)
             request.on_token = self._make_on_token(dr)
             now = self.clock()
@@ -628,31 +699,36 @@ class ServingDaemon:
             self._register(dr)
             self._open_count += 1
             self._m_accepted.inc()
-            return self.result(request.request_id)
+            return self._snapshot(request.request_id)
 
     def cancel(self, request_id: str, reason: str = "cancelled") -> bool:
-        with self._lock:
+        with self._locked("cancel"):
             return self.frontend.cancel(request_id, reason=reason)
 
     def result(self, request_id: str) -> Optional[Dict]:
-        with self._lock:
-            dr = self._requests.get(request_id)
-            if dr is None:
-                return None
-            rec = dict(dr.record)
-            rec["tokens"] = list(rec["tokens"])
-            return rec
+        with self._locked("result"):
+            return self._snapshot(request_id)
+
+    def _snapshot(self, request_id: str) -> Optional[Dict]:
+        """A copy of the request's record; the caller holds the lock."""
+        dr = self._requests.get(request_id)
+        if dr is None:
+            return None
+        rec = dict(dr.record)
+        rec["tokens"] = list(rec["tokens"])
+        return rec
 
     def subscribe(self, request_id: str):
         """Stream attachment: returns ``(snapshot, q)`` — the tokens
         already delivered plus a queue of future :class:`StreamEvent`s
         (``q`` is None when the request is already terminal; the
         snapshot record tells the subscriber how it ended)."""
-        with self._lock:
+        with self._locked("subscribe") as wait:
+            self._lock_wait_span("subscribe", request_id, wait)
             dr = self._requests.get(request_id)
             if dr is None:
                 return None, None
-            snapshot = self.result(request_id)
+            snapshot = self._snapshot(request_id)
             if dr.out is None:  # terminal
                 return snapshot, None
             q: queue.Queue = queue.Queue()
@@ -809,30 +885,38 @@ class ServingDaemon:
     def tick(self) -> List[StreamEvent]:
         """One daemon tick: a frontend step, then the tick's journal
         batch (tokens + terminals) and ONE batched fsync window."""
-        with self._lock:
-            events = self.frontend.step()
-            self._flush_dirty()
-            self._sync()
-            self._enforce_retention()
-            ci = self.config.compact_interval_records
-            if (
-                ci
-                and not self._dirty
-                and self._degraded_reason is None
-                and self.journal.records_since_rotate >= ci
-            ):
-                self._compact()
-            self.ticks += 1
-            self._m_ticks.inc()
-            self.registry.gauge("daemon_open_requests").set(
-                self._open_count
-            )
-            self.registry.gauge("daemon_draining").set(
-                1.0 if self._draining else 0.0
-            )
-            self.registry.gauge("daemon_degraded").set(
-                0.0 if self._degraded_reason is None else 1.0
-            )
+        with self._tick_phase("lock_wait"):
+            self._lock.acquire()
+        try:
+            with self._tick_phase("step", leaf=False):
+                events = self.frontend.step()
+            with self._tick_phase("journal"):
+                self._flush_dirty()
+            with self._tick_phase("fsync"):
+                self._sync()
+            with self._tick_phase("housekeeping"):
+                self._enforce_retention()
+                ci = self.config.compact_interval_records
+                if (
+                    ci
+                    and not self._dirty
+                    and self._degraded_reason is None
+                    and self.journal.records_since_rotate >= ci
+                ):
+                    self._compact()
+                self.ticks += 1
+                self._m_ticks.inc()
+                self.registry.gauge("daemon_open_requests").set(
+                    self._open_count
+                )
+                self.registry.gauge("daemon_draining").set(
+                    1.0 if self._draining else 0.0
+                )
+                self.registry.gauge("daemon_degraded").set(
+                    0.0 if self._degraded_reason is None else 1.0
+                )
+        finally:
+            self._lock.release()
         # span IO happens OUTSIDE the daemon lock: a slow (or
         # fault-injected) spool write must not stall admission
         self._drain_spool()

@@ -335,16 +335,20 @@ def make_ce_fn(config: GPTConfig):
         bound (mesh path), plain CE on full logits otherwise."""
         from tpu_parallel.parallel.tp import axis_size_or_none
 
-        logits = head.apply({"params": lm_params}, h)
-        if axis_size_or_none(config.model_axis) is not None:
-            ce, pred = vocab_parallel_cross_entropy(
-                logits, targets, config.model_axis
-            )
-        else:
-            ce = token_cross_entropy(logits, targets)
-            pred = logits.argmax(-1)
-        loss_sum = (ce * mask).sum()
-        correct = ((pred == targets) * mask).sum()
+        # stable names for the step's second bottleneck: this head is
+        # unnamed (applied outside the model), so it gets no module scope
+        with jax.named_scope("lm_head"):
+            logits = head.apply({"params": lm_params}, h)
+        with jax.named_scope("cross_entropy"):
+            if axis_size_or_none(config.model_axis) is not None:
+                ce, pred = vocab_parallel_cross_entropy(
+                    logits, targets, config.model_axis
+                )
+            else:
+                ce = token_cross_entropy(logits, targets)
+                pred = logits.argmax(-1)
+            loss_sum = (ce * mask).sum()
+            correct = ((pred == targets) * mask).sum()
         return loss_sum, correct
 
     def chunked_ce(lm_params, h, targets, mask):

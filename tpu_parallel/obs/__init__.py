@@ -1,5 +1,6 @@
 """Unified telemetry: labeled metric registry, request-lifecycle span
-tracer, and pluggable exporters (Chrome trace / Prometheus text / JSONL).
+tracer, the pump loops' phase clock, and pluggable exporters (Chrome
+trace / Prometheus text).
 
 Shared by the serving engine and the trainer (docs/11_observability.md):
 ``MetricRegistry`` is the one store every counter/gauge/histogram lives
@@ -15,13 +16,13 @@ single Perfetto timeline with flow arrows across the wire crossings.
 
 from tpu_parallel.obs.exporters import (
     chrome_trace_events,
-    export_snapshot_jsonl,
     parse_prometheus_text,
     prometheus_lines,
     prometheus_text,
     write_chrome_trace,
     write_prometheus,
 )
+from tpu_parallel.obs.phases import phase
 from tpu_parallel.obs.registry import (
     Counter,
     Gauge,
@@ -75,5 +76,5 @@ __all__ = [
     "prometheus_text",
     "parse_prometheus_text",
     "write_prometheus",
-    "export_snapshot_jsonl",
+    "phase",
 ]

@@ -1,7 +1,7 @@
-"""Pluggable telemetry exporters: Chrome trace-event JSON (Perfetto),
-Prometheus text exposition, and the shared JSONL sink.
+"""Pluggable telemetry exporters: Chrome trace-event JSON (Perfetto) and
+Prometheus text exposition.
 
-All three read the SAME two sources — a :class:`~tpu_parallel.obs.tracer.
+Both read the SAME two sources — a :class:`~tpu_parallel.obs.tracer.
 Tracer`'s span list and a :class:`~tpu_parallel.obs.registry.
 MetricRegistry` snapshot — so adding an exporter never means adding
 instrumentation.
@@ -274,18 +274,3 @@ def write_prometheus(source: Union[MetricRegistry, Dict], path: str) -> str:
     with open(path, "w") as fh:
         fh.write(prometheus_text(source))
     return path
-
-
-# -- JSONL sink (the MetricLogger file every subsystem already writes) -----
-
-
-def export_snapshot_jsonl(registry: MetricRegistry, logger, **extra) -> Dict:
-    """Append one full registry snapshot to a
-    :class:`~tpu_parallel.utils.logging_utils.MetricLogger` JSONL sink
-    (process-0-gated by the logger) — the existing machine-readable
-    stream, rebased onto the registry instead of ad-hoc dicts.  Returns
-    the record written."""
-    record = {"kind": "registry_snapshot", **extra,
-              "metrics": registry.snapshot()}
-    logger.log_record(record)
-    return record

@@ -6,7 +6,7 @@ its TTFT/ITL/queue-depth percentiles from histograms registered here (the
 PR-1 unbounded-window deques are gone), the trainer publishes MFU and
 throughput gauges into the same registry, and the exporters
 (:mod:`tpu_parallel.obs.exporters`) serialize one :meth:`snapshot` in
-Prometheus text / JSONL form — the instrument API is the only write path,
+Prometheus text form — the instrument API is the only write path,
 so every consumer sees the same numbers.
 
 Design constraints, in order:
@@ -288,7 +288,7 @@ class MetricRegistry:
 
     def snapshot(self) -> Dict[str, list]:
         """JSON-serializable dump of every instrument: the one structure
-        the exporters (Prometheus text, JSONL sink) and the serve_bench
+        the exporter (Prometheus text) and the serve_bench
         ``--smoke`` schema gate consume."""
         out: Dict[str, list] = {"counters": [], "gauges": [], "histograms": []}
         for name, by_label in sorted(self._instruments.items()):

@@ -67,8 +67,6 @@ class SpanSpool:
         self.max_bytes = max_bytes
         self.rotations = 0
         self.dropped = 0  # records lost to rotation, lifetime
-        self._span_cursor = 0
-        self._instant_cursor = 0
         self._pending: List = []  # seen but not yet finished spans
         self._fh = None
         self._bytes = 0
@@ -108,9 +106,10 @@ class SpanSpool:
 
     def drain(self, tracer) -> int:
         """Append every span finished (and instant recorded) since the
-        last drain.  Returns the record count written.  Unfinished spans
-        are parked and re-checked next drain — span lists are
-        append-only, so two cursors cover them."""
+        last drain, then RELEASE them from the tracer: the log has them,
+        and a long-lived process must not keep every span it ever made.
+        Returns the record count written.  Unfinished spans are parked
+        here and re-checked next drain."""
         written = 0
         still_open: List = []
         for span in self._pending:
@@ -120,22 +119,25 @@ class SpanSpool:
                 self._write(self._record_of_span(span))
                 written += 1
         self._pending = still_open
-        spans = tracer.spans
-        while self._span_cursor < len(spans):
-            span = spans[self._span_cursor]
-            self._span_cursor += 1
-            if span.end is None:
-                self._pending.append(span)
-            else:
-                self._write(self._record_of_span(span))
+        # copies: what another thread records meanwhile lands past them
+        # and waits for the next drain
+        n_spans = n_instants = 0
+        try:
+            for span in tracer.spans[:]:
+                n_spans += 1
+                if span.end is None:
+                    self._pending.append(span)
+                else:
+                    self._write(self._record_of_span(span))
+                    written += 1
+            for ev in tracer.instants[:]:
+                n_instants += 1
+                self._write(self._record_of_instant(ev))
                 written += 1
-        instants = tracer.instants
-        while self._instant_cursor < len(instants):
-            self._write(self._record_of_instant(
-                instants[self._instant_cursor]
-            ))
-            self._instant_cursor += 1
-            written += 1
+        finally:
+            # also when a write failed: the log is loss-tolerant, a
+            # record written twice would not be
+            tracer.release(n_spans, n_instants)
         if written:
             self._fh.flush()
             if self._bytes > self.max_bytes:

@@ -183,28 +183,39 @@ NULL_SPAN = _NullSpan()
 
 
 class Tracer:
-    """Append-only span/instant recorder.
+    """Span/instant recorder over a BOUNDED window.
 
     ``span``/``start`` open a complete span (``span`` reads better under
     ``with``; they are the same call), ``start_async`` an overlap-safe
     async span, ``record`` retro-records an interval measured by the
     caller (the engine's batched prefill fans one device call out into
-    per-slot spans sharing the measured window), ``instant`` drops a
-    zero-duration marker.
+    per-slot spans sharing the measured window; with ``async_id`` it is
+    an async span, for intervals of several threads on one track),
+    ``instant`` drops a zero-duration marker.
 
     **Trace binding**: ``bind_trace(request_id, ctx)`` makes every
     subsequent span/instant whose attrs carry that ``request_id`` (or
     ``rid``) a child of ``ctx`` — stamped with the trace id, a fresh
     span id, and ``ctx.span_id`` as parent — until ``release_trace``.
     The lookup costs one falsy dict check when nothing is bound.
+
+    **Bounded**: ``spans`` / ``instants`` hold what no consumer has taken
+    yet.  A :class:`~tpu_parallel.obs.spool.SpanSpool` releases what it
+    has written (:meth:`release`), so a daemon that traces for days holds
+    a tick's worth; without a spool each list is capped at
+    ``max_resident`` — past it the oldest quarter is dropped and counted
+    in ``dropped``.  A dropped span that is still open stays valid for
+    its holder; it just is no longer listed.
     """
 
     enabled = True
+    max_resident = 1 << 17  # per list; ~50 MB of spans at the worst
 
     def __init__(self, clock: Callable[[], float] = time.monotonic):
         self.clock = clock
         self.spans: List[Span] = []
         self.instants: List[Dict] = []
+        self.dropped = 0  # spans + instants lost to the cap, lifetime
         self._bindings: Dict[str, TraceContext] = {}
         # per-tracer span-id mint: a process nonce + a counter keeps ids
         # unique across the fleet without a uuid4 per span
@@ -238,12 +249,28 @@ class Tracer:
                 span.span_id = self.next_span_id()
         return span
 
+    # -- the bounded window -----------------------------------------------
+
+    def _keep(self, items: List, item) -> None:
+        items.append(item)
+        if len(items) > self.max_resident:
+            drop = max(1, self.max_resident // 4)
+            del items[:drop]
+            self.dropped += drop
+
+    def release(self, n_spans: int, n_instants: int) -> None:
+        """Forget the oldest ``n_spans`` spans and ``n_instants``
+        instants: their consumer has them.  Safe beside a recording
+        thread — what was appended meanwhile sits past the cut."""
+        del self.spans[:n_spans]
+        del self.instants[:n_instants]
+
     # -- recording --------------------------------------------------------
 
     def start(self, name: str, track: str = "main", **attrs) -> Span:
         span = Span(self, name, track, attrs, self.clock())
         self._stamp(span)
-        self.spans.append(span)
+        self._keep(self.spans, span)
         return span
 
     span = start
@@ -253,15 +280,15 @@ class Tracer:
         span = Span(self, name, track, attrs, self.clock(),
                     async_id=async_id)
         self._stamp(span)
-        self.spans.append(span)
+        self._keep(self.spans, span)
         return span
 
     def record(self, name: str, track: str, start: float, end: float,
-               **attrs) -> Span:
-        span = Span(self, name, track, attrs, start)
+               async_id: Optional[str] = None, **attrs) -> Span:
+        span = Span(self, name, track, attrs, start, async_id=async_id)
         span.end = end
         self._stamp(span)
-        self.spans.append(span)
+        self._keep(self.spans, span)
         return span
 
     def instant(self, name: str, track: str = "main", **attrs) -> None:
@@ -273,7 +300,7 @@ class Tracer:
             if ctx is not None:
                 ev["trace_id"] = ctx.trace_id
                 ev["parent_id"] = ctx.span_id
-        self.instants.append(ev)
+        self._keep(self.instants, ev)
 
     def tracks(self) -> List[str]:
         """Every track touched, ``scheduler`` and ``trainer`` first, the
@@ -324,10 +351,13 @@ class NullTracer:
         return NULL_SPAN
 
     def record(self, name: str, track: str, start: float, end: float,
-               **attrs) -> _NullSpan:
+               async_id: Optional[str] = None, **attrs) -> _NullSpan:
         return NULL_SPAN
 
     def instant(self, name: str, track: str = "main", **attrs) -> None:
+        pass
+
+    def release(self, n_spans: int, n_instants: int) -> None:
         pass
 
     def tracks(self) -> List[str]:

@@ -139,6 +139,7 @@ from tpu_parallel.models.generate import (
     prefill_step,
     verify_step,
 )
+from tpu_parallel.obs.phases import ENGINE_PREFIX, SPAN_PREFIX, phase
 from tpu_parallel.obs.registry import MetricRegistry
 from tpu_parallel.obs.tracer import NULL_TRACER, Tracer
 from tpu_parallel.serving.cache_pool import (
@@ -1041,15 +1042,18 @@ class _PendingTick:
     not be read until this tick's collect."""
 
     __slots__ = (
-        "kind", "start", "t0", "tick_span", "events", "admitted",
+        "kind", "start", "t0", "t1", "tick_span", "events", "admitted",
         "chunks_advanced", "chunk_tokens", "chunk_spans", "active_tokens",
-        "entering", "finals", "payload", "overlapped",
+        "entering", "finals", "payload", "overlapped", "phases", "between",
     )
 
-    def __init__(self, start: float):
+    def __init__(self):
         self.kind = "idle"
-        self.start = start
+        self.start = 0.0  # launch entry: the first phase's start
+        # the decode dispatch's window on the engine's clock, from the
+        # phase reads: enqueued at t0, synced at t1
         self.t0 = 0.0
+        self.t1 = 0.0
         self.tick_span = None
         self.events: List[StreamEvent] = []
         self.admitted: List[RequestOutput] = []
@@ -1061,6 +1065,14 @@ class _PendingTick:
         self.finals: List[tuple] = []
         self.payload = None
         self.overlapped = False
+        # the phase clock: seconds by leaf phase (a phase entered twice
+        # in one tick adds up), and the gap since the previous busy
+        # tick's collect (None: there was none, or it was not busy)
+        self.phases: Dict[str, float] = {}
+        self.between: Optional[float] = None
+
+    def add_phase(self, name: str, seconds: float) -> None:
+        self.phases[name] = self.phases.get(name, 0.0) + seconds
 
 
 class ServingEngine:
@@ -1175,10 +1187,17 @@ class ServingEngine:
     - ``registry``: the :class:`~tpu_parallel.obs.registry.MetricRegistry`
       backing every counter/gauge/histogram (``ServingMetrics`` owns one
       by default; pass a shared registry to co-locate serving + trainer
-      series for one Prometheus/JSONL export).  Per-tick the engine
+      series for one Prometheus export).  Per-tick the engine
       publishes queue depth, occupancy, and a stall-cause counter
       (``queue_empty`` / ``prefill`` / ``spec_verify`` / ``none``); the
       scheduler adds the queue-age gauge.
+    - the phase clock, always on: every tick is cut into leaf phases
+      (``schedule`` / ``prefill`` / ``dispatch`` / ``device_wait`` /
+      ``deliver`` / ``record``, and ``between`` two busy ticks), each
+      written by :class:`~tpu_parallel.obs.phases.phase` to
+      ``serving_tick_phase_seconds``, to a ``tick.<phase>`` span when
+      tracing, and to an ``engine.tick.<phase>`` profiler annotation —
+      on ``clock``, which a tracer should share.
     """
 
     def __init__(
@@ -1259,6 +1278,12 @@ class ServingEngine:
                 scheduler, clock=clock, registry=self.registry
             )
         self._queue_spans: Dict[str, object] = {}
+        # the phase clock's memory across ticks: the newest launched
+        # tick (a collect that finds another one in flight beside its
+        # own was pipelined), and the end of the last busy sequential
+        # tick's collect — what `between` is measured from
+        self._newest_tick: Optional[_PendingTick] = None
+        self._busy_end: Optional[float] = None
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
 
         if prefill_buckets == "auto":
@@ -1792,45 +1817,70 @@ class ServingEngine:
         Between launch and collect every donated buffer belongs to the
         device: nothing here may read device results (the launch-body
         sync gate in ``scripts/check_host_sync.py``)."""
-        now = self.clock()
-        p = _PendingTick(now)
+        p = self._newest_tick = _PendingTick()
         p.overlapped = ahead
         if self.tracer.enabled:
             p.tick_span = self.tracer.span(
                 "tick", track="scheduler", tick=self.metrics.ticks
             )
-        self._expire_queue(now, p.events)
         unified = self._unified and self._fused_steps > 1
-        if not unified:
-            # per-phase: chunked prefills first, one extend dispatch per
-            # slot — a chunk finishing this tick decodes this tick
-            p.chunks_advanced = len(self._chunking)
-            for slot in sorted(self._chunking):
-                p.events.extend(self._advance_chunk(slot))
-        bucket_key = (
-            self._admission_key
-            if (self._buckets is not None or self._chunk_tokens is not None)
-            else None
-        )
-        p.admitted = self.scheduler.schedule(
-            self.pool.n_free, now, bucket_key=bucket_key,
-            can_admit=self._block_gate() if self._paged else None,
-        )
-        p.events.extend(self._admit_batch(p.admitted))
+        # per-phase: chunked prefills run BEFORE scheduling, one extend
+        # dispatch per slot — a chunk finishing this tick decodes this
+        # tick (so such a tick enters `schedule` and `prefill` twice)
+        chunks_first = not unified and bool(self._chunking)
+        with self._phase(p, "schedule") as first:
+            now = p.start = first.start
+            if self._busy_end is not None:
+                p.between, self._busy_end = now - self._busy_end, None
+            self._expire_queue(now, p.events)
+            if not chunks_first:
+                p.admitted = self._schedule(now)
+        if chunks_first:
+            with self._phase(p, "prefill"):
+                p.chunks_advanced = len(self._chunking)
+                for slot in sorted(self._chunking):
+                    p.events.extend(self._advance_chunk(slot))
+            with self._phase(p, "schedule"):
+                p.admitted = self._schedule(now)
+        with self._phase(p, "prefill"):
+            p.events.extend(self._admit_batch(p.admitted))
         if unified:
             # chunk slots (newly started ones included) ride THIS tick's
             # unified dispatch — same chunk-per-tick cadence as the
             # per-phase engine, minus its per-slot dispatches
             p.chunks_advanced = len(self._chunking)
-        self._launch_decode(p)
-        # active tokens RESIDENT during this tick's decode = slots'
-        # written depths + chunked prefills' post-advance offsets,
-        # captured BEFORE delivery retires finished slots — the capacity
-        # denominator behind kv_bytes_per_active_token
-        p.active_tokens = int(self._pos[self._active].sum()) + sum(
-            st.offset for st in self._chunking.values()
-        ) + sum(plen for (_, _, plen) in p.finals)
+        with self._phase(p, "dispatch") as dispatch:
+            self._launch_decode(p)
+            # active tokens RESIDENT during this tick's decode = slots'
+            # written depths + chunked prefills' post-advance offsets,
+            # captured BEFORE delivery retires finished slots — the
+            # capacity denominator behind kv_bytes_per_active_token
+            p.active_tokens = int(self._pos[self._active].sum()) + sum(
+                st.offset for st in self._chunking.values()
+            ) + sum(plen for (_, _, plen) in p.finals)
+        p.t0 = dispatch.end
         return p
+
+    def _phase(self, p: _PendingTick, name: str) -> phase:
+        """One leaf phase of tick ``p`` (:mod:`tpu_parallel.obs.phases`):
+        its seconds on the engine's clock go to ``p`` (observed at the
+        tick's end if it was busy), a span to the ``scheduler`` track
+        when tracing, and ``engine.tick.<name>`` to the profiler."""
+        return phase(
+            p.add_phase, self.tracer, "scheduler", name, self.clock,
+            annotation=ENGINE_PREFIX + name,
+        )
+
+    def _schedule(self, now: float) -> List[RequestOutput]:
+        bucket_key = (
+            self._admission_key
+            if (self._buckets is not None or self._chunk_tokens is not None)
+            else None
+        )
+        return self.scheduler.schedule(
+            self.pool.n_free, now, bucket_key=bucket_key,
+            can_admit=self._block_gate() if self._paged else None,
+        )
 
     def _expire_queue(self, now: float, events: List[StreamEvent]) -> None:
         for out in self.scheduler.expire(now):
@@ -1863,7 +1913,6 @@ class ServingEngine:
         )
         if not self._active.any() and not unified_chunks:
             return
-        p.t0 = self.tracer.now()
         p.entering = tuple(int(s) for s in np.nonzero(self._active)[0])
         if self._spec_fused:
             self._launch_spec_fused(p, unified_chunks)
@@ -1884,52 +1933,59 @@ class ServingEngine:
         ``run(overlap=True)`` all of it runs while the NEXT tick's
         device dispatch is already computing."""
         events = p.events
-        if p.kind == "fused":
-            events.extend(self._collect_fused(p))
-        elif p.kind == "unified":
-            events.extend(self._collect_unified(p))
-        elif p.kind == "spec_fused":
-            events.extend(self._collect_spec_fused(p))
-        elif p.kind == "spec":
-            events.extend(self._collect_spec_step(p))
-        elif p.kind == "step":
-            events.extend(self._collect_per_step(p))
         decoded = p.kind != "idle"
+        if decoded:
+            with self._phase(p, "device_wait") as wait:
+                p.payload = self._sync_payload(p)
+            p.t1 = wait.end
+            with self._phase(p, "deliver"):
+                if p.kind == "fused":
+                    events.extend(self._collect_fused(p))
+                elif p.kind == "unified":
+                    events.extend(self._collect_unified(p))
+                elif p.kind == "spec_fused":
+                    events.extend(self._collect_spec_fused(p))
+                elif p.kind == "spec":
+                    events.extend(self._collect_spec_step(p))
+                else:
+                    events.extend(self._collect_per_step(p))
         admitted = p.admitted
         chunks_advanced = p.chunks_advanced
         active_tokens = p.active_tokens
-        if self._prefix is not None:
-            entry_bytes = None
-            if self._radix is not None:
-                entry_bytes = self._radix.device_bytes
-            elif self._paged:
-                entry_bytes = self.pool.bytes_per_block * sum(
-                    len(blocks) for blocks, _ in self._prefix.values()
+        with self._phase(p, "record") as record:
+            if self._prefix is not None:
+                entry_bytes = None
+                if self._radix is not None:
+                    entry_bytes = self._radix.device_bytes
+                elif self._paged:
+                    entry_bytes = self.pool.bytes_per_block * sum(
+                        len(blocks) for blocks, _ in self._prefix.values()
+                    )
+                self.metrics.sync_prefix_cache(
+                    self._prefix, entry_bytes=entry_bytes
                 )
-            self.metrics.sync_prefix_cache(
-                self._prefix, entry_bytes=entry_bytes
-            )
-            if self._radix is not None:
-                self.metrics.sync_host_tier(self._radix)
-                if self._radix.disk is not None:
-                    self.metrics.sync_disk_tier(self._radix)
-        if self._paged:
-            self.metrics.sync_block_pool(
-                self.pool, active_tokens=active_tokens
-            )
-        # stall attribution, most-specific first: any prefill work this
-        # tick stalled the pool's decode; a speculative tick spent its
-        # decode slot verifying; an undecoded tick with nothing admitted
-        # was starved by an empty queue; else a clean decode tick
-        if admitted or chunks_advanced:
-            stall = STALL_PREFILL
-        elif decoded and self._spec_width > 0:
-            stall = STALL_SPEC_VERIFY
-        elif not decoded:
-            stall = STALL_QUEUE_EMPTY
-        else:
-            stall = STALL_NONE
-        end = self.clock()
+                if self._radix is not None:
+                    self.metrics.sync_host_tier(self._radix)
+                    if self._radix.disk is not None:
+                        self.metrics.sync_disk_tier(self._radix)
+            if self._paged:
+                self.metrics.sync_block_pool(
+                    self.pool, active_tokens=active_tokens
+                )
+            # stall attribution, most-specific first: any prefill work
+            # this tick stalled the pool's decode; a speculative tick
+            # spent its decode slot verifying; an undecoded tick with
+            # nothing admitted was starved by an empty queue; else a
+            # clean decode tick
+            if admitted or chunks_advanced:
+                stall = STALL_PREFILL
+            elif decoded and self._spec_width > 0:
+                stall = STALL_SPEC_VERIFY
+            elif not decoded:
+                stall = STALL_QUEUE_EMPTY
+            else:
+                stall = STALL_NONE
+        end = record.end
         self.metrics.record_tick(
             now=end,
             queue_depth=self.scheduler.depth,
@@ -1943,14 +1999,41 @@ class ServingEngine:
         )
         if p.overlapped:
             self.metrics.record_overlap()
+        # a tick is pipelined when it was launched ahead, or when the
+        # next one was: either way part of it ran with device work
+        # queued, so its phases say nothing about host-exposed time
+        pipelined = p.overlapped or self._newest_tick is not p
+        if decoded and not pipelined:
+            self.metrics.record_busy_tick(
+                end - p.start, p.phases, prefill=stall == STALL_PREFILL,
+                between=p.between,
+            )
+            self._busy_end = end
+            if p.between is not None and self.tracer.enabled:
+                self.tracer.record(
+                    SPAN_PREFIX + "between", "scheduler",
+                    p.start - p.between, p.start,
+                )
         if p.tick_span is not None:
             p.tick_span.finish(
                 stall=stall,
                 queue_depth=self.scheduler.depth,
                 admitted=len(admitted),
                 decoded=decoded,
+                **{f"{k}_ms": 1e3 * v for k, v in p.phases.items()},
             )
         return events
+
+    def _sync_payload(self, p: _PendingTick):
+        """The tick's ONE device sync: the launch's result handles (all
+        of one dispatch) become host arrays; a host-side entry (the spec
+        tick's draft lengths) passes through."""
+        if p.kind == "step":
+            return np.asarray(p.payload)
+        return tuple(
+            x if x is None else np.asarray(x)  # host-sync: once a tick
+            for x in p.payload
+        )
 
     def has_work(self) -> bool:
         return (
@@ -2944,10 +3027,10 @@ class ServingEngine:
         p.payload = nxt
 
     def _collect_per_step(self, p: _PendingTick) -> List[StreamEvent]:
-        nxt = np.asarray(p.payload)  # ONE sync; t1 is real device time
+        nxt = p.payload  # synced by collect(); t1 is real device time
         events = []
         trace = self.tracer.enabled
-        t1 = self.tracer.now()
+        t1 = p.t1
         if trace:
             self.tracer.record("decode_tick", "scheduler", p.t0, t1)
         # every slot's current token was just written into the cache;
@@ -3244,10 +3327,9 @@ class ServingEngine:
         streaming granularity becomes per-tick (at most
         ``decode_steps_per_tick`` tokens per event flush)."""
         block, counts = p.payload
-        block, counts = np.asarray(block), np.asarray(counts)
         self._check_progress(p, counts)
         trace = self.tracer.enabled
-        t1 = self.tracer.now()
+        t1 = p.t1
         if trace:
             self.tracer.record(
                 "decode_tick", "scheduler", p.t0, t1,
@@ -3285,12 +3367,9 @@ class ServingEngine:
         first (the per-phase engine's chunk-advance-then-decode order),
         then the decode block."""
         act_emit, block, counts = p.payload
-        act_emit, block, counts = (
-            np.asarray(act_emit), np.asarray(block), np.asarray(counts),
-        )
         events: List[StreamEvent] = []
         trace = self.tracer.enabled
-        t1 = self.tracer.now()
+        t1 = p.t1
         self._collect_chunks(p, t1)
         for slot, out, plen in p.finals:
             events.append(
@@ -3396,10 +3475,9 @@ class ServingEngine:
         mid-block EOS/length finish)."""
         k = self._spec_width
         block, accepted, dlen = p.payload
-        block, accepted = np.asarray(block), np.asarray(accepted)
         events = []
         trace = self.tracer.enabled
-        t1 = self.tracer.now()
+        t1 = p.t1
         if trace:
             self.tracer.record(
                 "verify_tick", "scheduler", p.t0, t1, width=k
@@ -3508,15 +3586,9 @@ class ServingEngine:
         mirrors stay exact."""
         k = self._spec_width
         act_emit, blocks, counts, drafted, accepted = p.payload
-        if act_emit is not None:
-            act_emit = np.asarray(act_emit)
-        blocks, counts, drafted, accepted = (
-            np.asarray(blocks), np.asarray(counts), np.asarray(drafted),
-            np.asarray(accepted),
-        )
         events: List[StreamEvent] = []
         trace = self.tracer.enabled
-        t1 = self.tracer.now()
+        t1 = p.t1
         self._collect_chunks(p, t1)
         for slot, out, plen in p.finals:
             events.append(

@@ -8,7 +8,7 @@ trainer uses), (2) an end-of-run :meth:`ServingMetrics.summary` dict
 (the record ``scripts/serve_bench.py`` emits next to the ``DECODE_r*``
 decode-bench lines), and (3) the registry itself
 (:class:`~tpu_parallel.obs.registry.MetricRegistry`), which any exporter
-— Prometheus text, JSONL snapshot — can serialize at any moment.
+— Prometheus text — can serialize at any moment.
 
 The PR-1 sliding-window deques are gone: latency/depth distributions live
 in the registry's LOG-BUCKETED histograms, so a long-lived engine's
@@ -39,6 +39,16 @@ STALL_CAUSES = (
     STALL_QUEUE_EMPTY, STALL_PREFILL, STALL_SPEC_VERIFY, STALL_NONE
 )
 
+# the engine tick's leaf phases (serving_tick_phase_seconds{phase=...};
+# docs/11_observability.md has the table).  In the first four and in
+# `between` the engine has NOTHING queued on the device, in `prefill` and
+# `device_wait` it has; `dispatch` straddles (uploads, then the enqueue).
+TICK_PHASES = (
+    "schedule", "prefill", "dispatch", "device_wait", "deliver", "record",
+    "between",
+)
+HOST_EXPOSED_PHASES = ("schedule", "deliver", "record", "between")
+
 
 def percentile(values: Sequence[float], p: float) -> Optional[float]:
     """Linear-interpolated percentile (``p`` clamped into [0, 100]); None
@@ -62,18 +72,14 @@ class ServingMetrics:
 
     Pass ``registry`` to share one store across subsystems (engine +
     trainer + exporters); by default each instance owns a fresh one.
-    ``max_samples`` is kept for call-site compatibility but unused — the
-    log-bucketed histograms are bounded by construction, not by a window.
     """
 
     def __init__(
         self,
         logger: Optional[MetricLogger] = None,
         log_every: int = 0,
-        max_samples: int = 100_000,
         registry: Optional[MetricRegistry] = None,
     ):
-        del max_samples  # windowing replaced by bounded log-bucketing
         self.logger = logger
         self.log_every = log_every
         self.registry = registry if registry is not None else MetricRegistry()
@@ -205,6 +211,19 @@ class ServingMetrics:
             "serving_tokens_per_dispatch"
         )
         self._host_ms_per_tick = r.histogram("serving_host_ms_per_tick")
+        # the phase clock: where a BUSY tick's wall time went, phase by
+        # phase (idle ticks and pipelined ticks are observed in neither
+        # series — see record_busy_tick), and the tick's whole wall time
+        # from launch entry to collect exit, split by whether it carried
+        # prefill work.  Sums are exact: summary() reports sum / ticks.
+        self._tick_phase = {
+            name: r.histogram("serving_tick_phase_seconds", phase=name)
+            for name in TICK_PHASES
+        }
+        self._busy_tick = {
+            flag: r.histogram("serving_busy_tick_seconds", prefill=flag)
+            for flag in ("0", "1")
+        }
         # the unified ragged tick + double-buffered launch/collect
         # pipeline: tokens (prompt chunk tokens consumed + tokens
         # generated) each unified dispatch advanced, and how many
@@ -371,6 +390,28 @@ class ServingMetrics:
                     "tokens_per_sec": float(self.throughput() or 0.0),
                 },
             )
+
+    def record_busy_tick(
+        self,
+        seconds: float,
+        phases: Dict[str, float],
+        prefill: bool,
+        between: Optional[float] = None,
+    ) -> None:
+        """One BUSY, sequential tick (it dispatched decode work and no
+        other tick was in flight beside it): its wall time from launch
+        entry to collect exit, the seconds each leaf phase took, and
+        ``between`` — the gap since the previous busy tick's collect,
+        None unless that tick was busy and sequential too, so an idle
+        sleep never enters.  Idle ticks are left out (they would pull
+        every mean toward the cost of doing nothing); so are pipelined
+        ticks (``launch(ahead=True)``), whose deliver / record run with
+        device work queued and would read as host-exposed time."""
+        self._busy_tick["1" if prefill else "0"].observe(seconds)
+        for name, dt in phases.items():
+            self._tick_phase[name].observe(dt)
+        if between is not None:
+            self._tick_phase["between"].observe(between)
 
     def record_finished(self, out) -> None:
         """Fold one retired RequestOutput's latencies in."""
@@ -559,8 +600,15 @@ class ServingMetrics:
             m = h.mean()
             return None if m is None else round(m, digits)
 
+        def per_tick_ms(total, ticks):
+            return round(1000.0 * total / ticks, 4) if ticks else None
+
         probes = self.prefix_hits + self.prefix_misses
         qd_max = self._queue_depth.max
+        decode_only, with_prefill = self._busy_tick["0"], self._busy_tick["1"]
+        busy_ticks = decode_only.count + with_prefill.count
+        phase_s = {n: h.sum for n, h in self._tick_phase.items()}
+        all_phases_s = sum(phase_s.values())
         out = {
             "ticks": self.ticks,
             "decode_ticks": self.decode_ticks,
@@ -648,6 +696,34 @@ class ServingMetrics:
                 None
                 if self._host_ms_per_tick.percentile(95) is None
                 else round(self._host_ms_per_tick.percentile(95), 3)
+            ),
+            # the phase clock, over the busy sequential ticks: exact
+            # (sum / ticks), not bucket midpoints
+            "busy_ticks": busy_ticks,
+            "busy_tick_ms_mean": per_tick_ms(
+                decode_only.sum + with_prefill.sum, busy_ticks
+            ),
+            "decode_only_tick_ms_mean": per_tick_ms(
+                decode_only.sum, decode_only.count
+            ),
+            "prefill_tick_ms_mean": per_tick_ms(
+                with_prefill.sum, with_prefill.count
+            ),
+            **{
+                f"tick_{name}_ms_mean": per_tick_ms(phase_s[name], busy_ticks)
+                for name in TICK_PHASES
+            },
+            # the share of those ticks' time in which the engine had
+            # nothing queued on the device, by the host's clock alone;
+            # `dispatch` straddles and is left out of the numerator
+            "host_exposed_share": (
+                round(
+                    100.0 * sum(phase_s[n] for n in HOST_EXPOSED_PHASES)
+                    / all_phases_s,
+                    4,
+                )
+                if all_phases_s > 0
+                else None
             ),
             "tokens_per_sec": (
                 round(self.throughput(), 1)
