@@ -40,7 +40,7 @@ def main():
     peak = peak_flops(jax.devices()[0])
     n_chips = device["device_count"]
     # The shape earlier rounds tuned on a v5e (not measured on the current
-    # machine — PERF.md): flash 512x512 tiles, "proj_attn" remat, layers
+    # machine — PERF.md): flash kernels (tiles from the shape), "proj_attn" remat, layers
     # unrolled, 256 rows a chip accumulated over 16 passes of 16 rows.  16
     # rows a pass is also what fits: the compiler refuses 32 (16.6 GB of the
     # chip's 15.75 GB) and 64 (24.9 GB) for this step (CHANGES.md, PR 22).

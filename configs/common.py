@@ -15,8 +15,10 @@ def model_overrides(**kw) -> ConfigDict:
     base = dict(
         # attention: "xla" | "flash" | "ring" | "ulysses"
         attn_impl="xla",
-        flash_block_q=512,
-        flash_block_k=512,
+        # flash tile sizes: placeholders (None = derived from the shape,
+        # ops.flash_attention.flash_plan); an int is used for both passes
+        flash_block_q=config_dict.placeholder(int),
+        flash_block_k=config_dict.placeholder(int),
         # sliding-window attention (0 = full causal)
         attn_window=0,
         # remat: "full" | "proj" | "proj_attn" | "dots" (remat=False to disable)

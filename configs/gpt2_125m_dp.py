@@ -16,9 +16,9 @@ def get_config():
     c = ConfigDict()
     c.simulate_cpu_devices = 0
     c.model = "gpt2_125m"
-    # flash 512x512 tiles, attention residuals saved by the proj_attn remat
-    # policy, layers unrolled: the recipe earlier rounds tuned; its
-    # throughput is not measured on the current machine (PERF.md)
+    # flash kernels with tiles derived from the shape, attention residuals
+    # saved by the proj_attn remat policy, layers unrolled: the recipe of the
+    # benchmark's train cell (PERF.md section 4; its numbers in section 5)
     c.model_overrides = model_overrides(
         attn_impl="flash", remat_policy="proj_attn", scan_layers=False
     )

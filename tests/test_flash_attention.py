@@ -571,7 +571,7 @@ def test_remat_policy_sees_kernel_outputs(rng):
     """The finalize-pattern contract: the fwd kernels' out/lse are ordinary
     named jaxpr values, so a save_only_these_names("attn") remat policy
     keeps them and the backward graph contains NO forward-kernel re-run —
-    3 pallas calls (fwd + dq + dkv), not 4.  Guards against re-hiding the
+    2 pallas calls (fwd + the one resident backward), not 3.  Guards against re-hiding the
     forward inside the custom_vjp or dropping the checkpoint_name calls,
     for both the self-attention path and the chunk (ring/encoder) path."""
     from tpu_parallel.ops.flash_attention import flash_chunk_attention
@@ -596,5 +596,303 @@ def test_remat_policy_sees_kernel_outputs(rng):
             f = jax.checkpoint(block, policy=pol, prevent_cse=True)
             text = str(jax.make_jaxpr(jax.grad(f))(q, k, v))
             counts[pname] = text.count("pallas_call")
-        assert counts["saved"] == 3, (name, counts)
-        assert counts["unsaved"] == 4, (name, counts)
+        assert counts["saved"] == 2, (name, counts)
+        assert counts["unsaved"] == 3, (name, counts)
+
+
+# --- the resident tile walk: every tile once, masked only at the band's edges --
+
+
+def _dense_ref(q, k, v, *, causal=True, window=0, q_offset=0, seg_q=None,
+               seg_k=None):
+    """Dense fp32 attention on [b, s, h, d] with the kernels' band
+    (``lo <= q_pos - k_pos <= hi``), GQA by expansion, segments, and the
+    empty-partial contract: a row with no visible key is ``out = 0``,
+    ``lse = NEG_INF`` and a constant.  Returns (out, lse [b, h, s], empty)."""
+    from tpu_parallel.ops.flash_attention import NEG_INF
+
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+    scores = scores / jnp.sqrt(q.shape[-1]).astype(jnp.float32)
+    rel = (q_offset + jnp.arange(q.shape[1]))[:, None] - jnp.arange(k.shape[1])[None]
+    mask = jnp.ones(rel.shape, bool)
+    if causal:
+        mask &= rel >= 0
+    if window:
+        mask &= rel < window
+        if not causal:
+            mask &= -rel < window
+    mask = jnp.broadcast_to(mask, scores.shape)
+    if seg_q is not None:
+        mask &= (seg_q[:, :, None] == seg_k[:, None, :])[:, None]
+    empty = ~mask.any(-1)
+    masked = jnp.where(mask, scores, -1e30)
+    lse = jnp.where(empty, NEG_INF, jax.nn.logsumexp(masked, axis=-1))
+    p = jnp.where(mask, jnp.exp(masked - lse[..., None]), 0.0)
+    p = jnp.where(empty[..., None], 0.0, p)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v), lse, empty
+
+
+# (seq, block_q, block_k): rows of 1, 2, 4 and 8 tiles, and block_q != block_k
+_WALKS = [(8, 8, 8), (16, 8, 8), (32, 8, 8), (64, 8, 8), (32, 16, 8)]
+
+
+_HEADS = {"mha": (2, 2), "gqa2": (4, 2), "gqa4": (4, 1)}
+_WINDOWS = {"full": 0, "win<tile": 5, "win>tile": 20}
+
+
+def _walk_is_run(hn, packed, wn, walk):
+    """The full product on the rows of 1 and 2 tiles and the rectangular
+    walk.  Interpret mode traces every tile body of the static walk (group x
+    tiles of them in the backward), so the long rows are thinned where they
+    add no new band: the 4-tile row takes its windows with MHA only, the
+    8-tile row is MHA on three band / segment combinations."""
+    if walk == (64, 8, 8):
+        return hn == "mha" and (packed, wn) in {
+            (False, "full"), (False, "win<tile"), (True, "win>tile")
+        }
+    if walk == (32, 8, 8):
+        return hn == "mha" or wn == "full"
+    return True
+
+
+_WALK_CASES = [
+    pytest.param(*_HEADS[hn], packed, _WINDOWS[wn], *walk,
+                 id=f"{hn}-{'packed' if packed else 'dense'}-{wn}-{walk[0]}-{walk[1]}x{walk[2]}")
+    for hn in _HEADS for packed in (False, True) for wn in _WINDOWS
+    for walk in _WALKS if _walk_is_run(hn, packed, wn, walk)
+]
+
+
+@pytest.mark.parametrize("h,h_kv,segments,window,seq,block_q,block_k", _WALK_CASES)
+def test_resident_walk_matches_reference(rng, h, h_kv, segments, window, seq,
+                                         block_q, block_k):
+    """Forward and all three gradients of the resident kernels against the
+    dense reference, over rows with zero, one and several unmasked tiles,
+    the diagonal tile, windows inside one tile and across tiles, packed
+    segments and GQA groups."""
+    b, d = 1, 8
+    ks = jax.random.split(jax.random.fold_in(rng, seq * 7 + window), 4)
+    q = jax.random.normal(ks[0], (b, seq, h, d))
+    k, v = (jax.random.normal(kk, (b, seq, h_kv, d)) for kk in ks[1:3])
+    w = jax.random.normal(ks[3], (b, seq, h, d))
+    seg = _packed_segments(jax.random.PRNGKey(seq), b, seq) if segments else None
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, segment_ids=seg, window=window, block_q=block_q,
+            block_k=block_k, interpret=True, stream=False,
+        )
+
+    def ref(q, k, v):
+        return _dense_ref(q, k, v, window=window, seg_q=seg, seg_k=seg)[0]
+
+    out, vjp = jax.vjp(flash, q, k, v)
+    out_ref, vjp_ref = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(out_ref), rtol=2e-3, atol=2e-3
+    )
+    for a, bb, name in zip(vjp(w), vjp_ref(w), "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(bb), rtol=5e-3, atol=5e-3,
+            err_msg=f"d{name}",
+        )
+
+
+@pytest.mark.parametrize(
+    "window,q_offset,segments,tile",
+    [(12, 16, False, 16), (20, 40, False, 8), (12, -16, False, 16),
+     (0, 0, True, 16), (24, 32, True, 16)],
+    ids=["off16", "off40-some-tiles-skipped", "ahead16", "foreign-segments",
+         "off32-packed"],
+)
+def test_chunk_guard_rows_without_a_visible_key(rng, window, q_offset, segments,
+                                                tile):
+    """The only users of the ``lse <= NEG_INF / 2`` guard: non-causal chunks
+    whose window (at a static ``q_offset``) or whose segments leave rows
+    with NO visible key.  With a nonzero cotangent on ``out`` AND on ``lse``
+    for every row, empty rows must come back as empty partials and add
+    nothing to any gradient."""
+    from tpu_parallel.ops.flash_attention import NEG_INF, flash_chunk_attention
+
+    b, s, h, h_kv, d = 2, 32, 4, 2, 8
+    ks = jax.random.split(jax.random.fold_in(rng, 100 + q_offset), 5)
+    q = jax.random.normal(ks[0], (b, s, h, d))
+    k, v = (jax.random.normal(kk, (b, s, h_kv, d)) for kk in ks[1:3])
+    w_out = jax.random.normal(ks[3], (b, s, h, d))
+    w_lse = jax.random.normal(ks[4], (b, h, s))
+    seg_q = seg_k = None
+    if segments:
+        # the kv chunk holds segments 0-1, the q chunk 1-2: segment 2's
+        # queries match nothing
+        seg_k = (jnp.arange(s)[None] >= s // 2).astype(jnp.int32) + jnp.zeros((b, 1), jnp.int32)
+        seg_q = seg_k + 1
+    kw = dict(causal=False, window=window, q_offset=q_offset)
+
+    def flash(q, k, v):
+        return flash_chunk_attention(
+            q, k, v, block_q=tile, block_k=tile, interpret=True,
+            segment_ids_q=seg_q, segment_ids_kv=seg_k, **kw,
+        )
+
+    (out, lse), vjp = jax.vjp(flash, q, k, v)
+    (ref_out, ref_lse, empty), vjp_ref = jax.vjp(
+        lambda *a: _dense_ref(*a, seg_q=seg_q, seg_k=seg_k, **kw), q, k, v
+    )
+    assert bool(empty.any()) and not bool(empty.all()), "the case must mix both"
+    seen = ~np.asarray(empty)
+    np.testing.assert_allclose(
+        np.asarray(lse)[seen], np.asarray(ref_lse)[seen], rtol=2e-3, atol=2e-3
+    )
+    assert (np.asarray(lse)[~seen] <= NEG_INF / 2).all()
+    seen_o = seen.transpose(0, 2, 1)[..., None]  # [b, s, h, 1]
+    np.testing.assert_allclose(
+        np.asarray(out) * seen_o, np.asarray(ref_out) * seen_o, rtol=2e-3, atol=2e-3
+    )
+    # dout != 0 and dlse != 0 on every row, the empty ones included
+    g_flash = vjp((w_out, w_lse))
+    g_ref = vjp_ref((w_out, w_lse, np.zeros(empty.shape, jax.dtypes.float0)))
+    for a, bb, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(bb), rtol=5e-3, atol=5e-3,
+            err_msg=f"d{name}",
+        )
+
+
+_BANDS = [
+    # causal, window, q_offset, block_q, block_k, n_q, n_k
+    (True, 0, 0, 8, 8, 4, 4),
+    (True, 0, 0, 16, 8, 2, 4),
+    (True, 5, 0, 8, 8, 4, 4),
+    (True, 20, 0, 8, 8, 8, 8),
+    (True, 20, 0, 8, 16, 8, 4),
+    (False, 0, 0, 8, 8, 3, 3),
+    (False, 12, 0, 8, 8, 4, 4),
+    (False, 12, 16, 8, 8, 4, 4),
+    (False, 20, -24, 8, 8, 4, 4),
+    (False, 7, 40, 8, 8, 4, 4),
+]
+
+
+@pytest.mark.parametrize("causal,window,q_offset,block_q,block_k,n_q,n_k", _BANDS)
+def test_tile_kind_is_the_band_mask_summarised(causal, window, q_offset, block_q,
+                                               block_k, n_q, n_k):
+    """``_tile_kind`` (what the static walk emits), ``_band_mask`` (what a
+    masked tile applies, in both orientations) and the streamed kernels'
+    block ranges are one band: SKIP = nothing visible, INTERIOR = everything
+    visible, MASKED = the rest."""
+    import importlib
+
+    # the module: ``tpu_parallel.ops`` re-exports the function under its name
+    fa = importlib.import_module("tpu_parallel.ops.flash_attention")
+    for qi in range(n_q):
+        first, last = fa._stream_k_range(
+            qi, block_q, block_k, causal, window, n_k, q_offset
+        )
+        for ki in range(n_k):
+            mask = fa._band_mask(
+                qi, ki, (block_q, block_k), block_q, block_k, causal, window,
+                q_offset,
+            )
+            mask = np.ones((block_q, block_k), bool) if mask is None else np.asarray(mask)
+            mask_t = fa._band_mask(
+                qi, ki, (block_k, block_q), block_q, block_k, causal, window,
+                q_offset, transposed=True,
+            )
+            if mask_t is not None:
+                np.testing.assert_array_equal(np.asarray(mask_t), mask.T)
+            want = (
+                fa.SKIP if not mask.any()
+                else fa.INTERIOR if mask.all() else fa.MASKED
+            )
+            kind = fa._tile_kind(qi, ki, block_q, block_k, causal, window, q_offset)
+            assert kind == want, (qi, ki)
+            if kind != fa.SKIP:  # the streamed range may only be wider
+                assert int(first) <= ki <= int(last), (qi, ki, first, last)
+
+
+_PLAN_SHAPES = [
+    (seq, d, group)
+    for seq in (64, 128, 256, 512, 1024, 2048, 4096, 8192, 32768)
+    for d in (64, 128)
+    for group in (1, 4)
+]
+
+
+@pytest.mark.parametrize("seq,head_dim,group", _PLAN_SHAPES)
+def test_derived_tiles_divide_the_row(seq, head_dim, group):
+    """Every shape of the table gets tiles that divide it, a walk short
+    enough to unroll or the streamed kernels, and an explicit tile wins."""
+    from tpu_parallel.ops.flash_attention import (
+        MAX_STATIC_TILES, STREAM_SEQ_THRESHOLD, flash_plan,
+    )
+
+    plan = flash_plan(seq, head_dim, group)
+    for name, bodies, rows in (("fwd", 1, seq), ("bwd", group, group * seq)):
+        p = plan[name]
+        assert seq % p["block_q"] == 0 and seq % p["block_k"] == 0
+        assert p["block_q"] % p["block_k"] == 0
+        if p["variant"] == "resident":
+            assert rows <= STREAM_SEQ_THRESHOLD
+            assert bodies * p["tiles_computed"] <= MAX_STATIC_TILES
+        assert 0 < p["tiles_masked"] <= p["tiles_computed"]
+    assert plan["fused_bwd"] == (plan["bwd"]["variant"] == "resident")
+    explicit = flash_plan(seq, head_dim, group, block_q=64, block_k=32)
+    for name in ("fwd", "bwd"):
+        assert (explicit[name]["block_q"], explicit[name]["block_k"]) == (64, 32)
+
+
+def test_plan_of_the_train_cell_is_one_backward_pass_and_a_masked_diagonal():
+    """gpt2_125m's attention, 1024 x 64, group 1: both passes resident, ONE
+    backward kernel, and the only masked tiles are the ones the diagonal
+    crosses (seq / tile of them)."""
+    from tpu_parallel.ops.flash_attention import flash_plan
+
+    plan = flash_plan(1024, 64, 1, jnp.bfloat16)
+    assert plan["fused_bwd"] is True
+    assert plan["fwd"] == {
+        "block_q": 512, "block_k": 512, "variant": "resident",
+        "tiles_computed": 3, "tiles_masked": 1024 // 512,
+    }
+    assert plan["bwd"] == {
+        "block_q": 256, "block_k": 256, "variant": "resident",
+        "tiles_computed": 10, "tiles_masked": 1024 // 256,
+    }
+    assert flash_plan(1000, 64, 1) is None  # no multiple of 128 divides it
+
+
+def test_derived_tiles_run_the_same_numbers(rng):
+    """block_q / block_k None (the default) = tiles from the shape: same
+    output and gradients as the reference, different tiles per pass."""
+    q, k, v = _make_qkv(rng, b=1, s=256, h=2, d=16)
+    out = flash_attention(q, k, v, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_ref_bshd(q, k, v)), rtol=2e-3, atol=2e-3
+    )
+    g = jax.grad(lambda *a: (flash_attention(*a, interpret=True) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *a: (_ref_bshd(*a) ** 2).sum(), argnums=(0, 1, 2))(q, k, v)
+    for a, bb in zip(g, g_ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(bb), rtol=5e-3, atol=5e-3)
+
+
+def test_trainer_says_its_flash_plan_once(rng):
+    """The counter that says the change engaged: ``Trainer.flash_plan`` at
+    build, and a ``flash_plan`` instant on the tracer when tracing is on."""
+    from tpu_parallel.obs.tracer import Tracer
+    from tpu_parallel.runtime import MeshConfig
+    from tpu_parallel.train_lib import Trainer, TrainerConfig
+
+    config = TrainerConfig(
+        model="tiny", model_overrides=dict(attn_impl="flash"),
+        mesh=MeshConfig(data=8), global_batch_size=8, steps=1, log_every=10,
+    )
+    tracer = Tracer()
+    trainer = Trainer(config, tracer=tracer)
+    assert trainer.flash_plan["fused_bwd"] is True
+    trainer.init()
+    trainer.train(steps=1)
+    (instant,) = [i for i in tracer.instants if i["name"] == "flash_plan"]
+    assert instant["attrs"]["fused_bwd"] is True
+    assert instant["attrs"]["bwd_variant"] == "resident"
+    assert instant["attrs"]["fwd_tiles_masked"] >= 1

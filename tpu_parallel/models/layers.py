@@ -126,10 +126,11 @@ class TransformerConfig:
     fsdp: bool = False  # shard big params over the data axis (ZeRO-3)
     fsdp_min_size: int = 2**18
     attn_impl: str = "xla"  # "xla" | "flash" | "ring" | "ulysses"
-    # flash kernel tile sizes; 512x512 measured fastest on v5e at seq 1024
-    # (scripts/attn_microbench.py: 10.5ms vs 17.2ms fwd+bwd at 128x128)
-    flash_block_q: int = 512
-    flash_block_k: int = 512
+    # flash kernel tile sizes; None derives the forward's and the backward's
+    # tiles from the shape (ops.flash_attention.flash_plan, whose table is
+    # the v5e sweep in PERF.md section 6, PR 27); an int is used for both
+    flash_block_q: Optional[int] = None
+    flash_block_k: Optional[int] = None
     # sliding-window attention: 0 = full causal; >0 = each query sees only
     # the last `attn_window` positions (Mistral-style).  Applies to every
     # attention impl: xla, flash (whole out-of-window key blocks skipped

@@ -5,11 +5,10 @@ SURVEY.md §5 long-context row); this kernel serves the transformer configs and
 the ≥40% MFU target: O(seq) memory instead of O(seq^2), fp32 online softmax,
 bf16 MXU matmuls, block sizes aligned to the 128-lane MXU.
 
-Layout convention: [batch, heads, seq, head_dim] inside the kernels (the
-public API accepts [batch, seq, heads, head_dim] and transposes).  The causal
-structure is exploited twice: key blocks beyond the query block are skipped
-(not masked — skipped), and the backward kernels iterate only the triangle
-they need.
+Layout convention: [batch, heads, seq, head_dim] operands (the public API
+accepts [batch, seq, heads, head_dim] and transposes).  The causal structure
+is exploited twice: tiles beyond the band are skipped (not masked — skipped),
+and only the tiles the diagonal (or a window edge) crosses are masked at all.
 
 Grouped-query attention is native: K/V may carry ``n_kv < n_heads`` heads and
 are NEVER expanded — the BlockSpec index maps route each query head to its
@@ -17,22 +16,34 @@ K/V head's blocks, so GQA pays 1/group of MHA's K/V HBM traffic (the whole
 point of GQA; a pre-kernel ``jnp.repeat`` would materialize full-MHA K/V
 because Pallas operands are real buffers, not fusible broadcasts).
 
-Two kernel variants share the masking/band geometry:
+Two kernel variants share the band geometry (``_band_bounds``):
 
-- **resident** (seq <= ``STREAM_SEQ_THRESHOLD``): one (batch, head) row's
-  whole K/V lives in VMEM; the K loop runs inside the kernel and skips
-  out-of-band blocks entirely.  This is the measured-fastest path at the
-  bench config (512x512 tiles, seq 1024).
-- **streamed** (longer seq): the K/V walk is a grid dimension; VMEM holds one
-  [block_k, d] tile plus fp32 online-softmax scratch carried across grid
-  steps, so residency is O(block) and seq 8k-32k fits v5e VMEM.  Out-of-band
-  grid steps clamp their index map to the previous block — Pallas skips the
-  DMA when the mapped block is unchanged — so causal still halves the
-  traffic, not just the FLOPs.
+- **resident** (``group * seq`` and ``seq_kv`` <= ``STREAM_SEQ_THRESHOLD``):
+  one grid step holds a whole (batch, head) row in VMEM and walks the tiles
+  of the band in a loop that is static at trace time, so each tile is
+  classified before it is emitted (``_tile_kind``): tiles outside the band
+  emit nothing, tiles strictly inside it emit no iota, compare or select,
+  and only the tiles an edge of the band crosses are masked.  Score tiles
+  are computed TRANSPOSED (``[block_k, block_q]``, keys on sublanes, queries
+  on lanes): the running max / sum, ``lse`` and ``delta`` are lane-dense
+  ``[1, block_q]`` rows and the output accumulator is ``[head_dim, block_q]``.
+  The backward is ONE kernel per (batch, kv head) row: from one score tile,
+  one ``exp`` and one ``dp`` it forms ``dv``, ``dk`` and ``dq`` (5 matmuls);
+  ``dq`` accumulates in an fp32 VMEM scratch and is written once.
+- **streamed** (longer rows, or more tiles than a static walk should
+  unroll): the K/V walk is a grid dimension; VMEM holds one [block_k, d]
+  tile plus fp32 online-softmax scratch carried across grid steps, so
+  residency is O(block) and seq 8k-32k fits v5e VMEM.  Out-of-band grid
+  steps clamp their index map to the previous block — Pallas skips the DMA
+  when the mapped block is unchanged — so causal still halves the traffic,
+  not just the FLOPs.  Its backward is the dq / dkv pair.
+
+``flash_plan`` maps the shape to the tiles of each pass and says which
+variant runs; chip numbers for both are in PERF.md (section 6, PR 27).
 
 Packed sequences: ``segment_ids`` [batch, seq] adds a same-segment condition
-to the causal mask in all kernels (each query can always see itself, so no
-row is ever fully masked).
+to every tile in all kernels (each query can always see itself, so no row
+of a causal self-attention is ever fully masked).
 
 There is no jnp fallback by backend: off-TPU the SAME kernels run in Pallas
 interpret mode (``interpret=None`` resolves from ``jax.default_backend()`` —
@@ -56,13 +67,20 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu  # importable on CPU too
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
-# above this K/V length the streamed kernels take over (resident K/V at
-# 4096 x 64 x bf16 is ~0.5MB/operand — comfortable; 16k+ overflows v5e VMEM
-# once pipelining double-buffers the operands)
+# above this row length (K/V, or the group's queries) the streamed kernels
+# take over (a resident row at 4096 x 64 x bf16 is 1MB/operand in VMEM with
+# its lanes padded — comfortable; 16k+ overflows v5e VMEM once pipelining
+# double-buffers the operands)
 STREAM_SEQ_THRESHOLD = 4096
+# the resident kernels unroll their tile walk at trace time: past this many
+# tile bodies in one kernel the streamed kernels (a grid, not an unroll) run
+MAX_STATIC_TILES = 160
+# a derived tile never cuts a row into more pieces than this
+MAX_TILES_PER_SIDE = 16
 NEG_INF = -1e30
+# dot_general dimension numbers: a @ b.T and a.T @ b, no transpose op
+_NT = (((1,), (1,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
 
 
 def reference_attention(
@@ -110,33 +128,80 @@ def _window_first_k_block(qi, block_q: int, block_k: int, window: int,
     return jnp.maximum(0, q_offset + qi * block_q - window + 1) // block_k
 
 
+def _band_bounds(causal: bool, window: int):
+    """The band, once: key ``k`` is visible to query ``q`` iff
+    ``lo <= q_pos - k_pos <= hi`` (``None`` = unbounded).  Causal bounds it
+    below at 0; a window bounds it above at ``window - 1`` and, when not
+    causal (encoder local attention), symmetrically below."""
+    lo = 0 if causal else (-(window - 1) if window else None)
+    hi = window - 1 if window else None
+    return lo, hi
+
+
 def _band_mask(qi, ki, shape, block_q: int, block_k: int, causal: bool,
-               window: int, q_offset: int = 0):
-    """Causal and/or sliding-window mask for one [block_q, block_k] score
-    tile, or None when neither applies — the ONE definition all kernels
-    (fwd, dq, dkv; resident and streamed) share, so forward and backward can
-    never desynchronize on the band geometry.
+               window: int, q_offset: int = 0, transposed: bool = False):
+    """Causal and/or sliding-window mask for one score tile, or None when
+    neither applies — the ONE definition all kernels (forward and backward,
+    resident and streamed) share, so forward and backward can never
+    desynchronize on the band geometry.  ``shape`` is ``[block_q, block_k]``,
+    or ``[block_k, block_q]`` with ``transposed`` (the resident kernels).
 
     ``q_offset`` (static) shifts query positions relative to key positions:
     in ring attention the q chunk starts ``j * local_seq`` tokens after the
     K/V chunk it is attending, so the sliding-window band between them is
     the same geometry translated by that constant.
+
+    ``q_pos - k_pos`` is a loop-invariant iota difference plus one scalar
+    per tile, so a masked tile costs a compare and a select per bound.
     """
-    if not (causal or window):
+    lo, hi = _band_bounds(causal, window)
+    if lo is None and hi is None:
         return None
-    q_pos = q_offset + qi * block_q + lax.broadcasted_iota(jnp.int32, shape, 0)
-    k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32, shape, 1)
+    q_axis, k_axis = (1, 0) if transposed else (0, 1)
+    rel = lax.broadcasted_iota(jnp.int32, shape, q_axis) - lax.broadcasted_iota(
+        jnp.int32, shape, k_axis
+    )
+    shift = q_offset + qi * block_q - ki * block_k
     mask = None
-    if causal:
-        mask = q_pos >= k_pos
-    if window:
-        # causal: one-sided band (keys at most window-1 behind the query);
-        # non-causal (encoder local attention): symmetric |q - k| < window
-        near = q_pos - k_pos < window
-        if not causal:
-            near = jnp.logical_and(near, k_pos - q_pos < window)
+    if lo is not None:
+        mask = rel >= lo - shift
+    if hi is not None:
+        near = rel <= hi - shift
         mask = near if mask is None else jnp.logical_and(mask, near)
     return mask
+
+
+SKIP, MASKED, INTERIOR = "skip", "masked", "interior"
+
+
+def _tile_kind(qi: int, ki: int, block_q: int, block_k: int, causal: bool,
+               window: int, q_offset: int = 0) -> str:
+    """Where tile ``(qi, ki)`` lies against the band, on Python ints (the
+    resident kernels walk their tiles at trace time): ``SKIP`` if no key of
+    it is visible to any query of it, ``INTERIOR`` if every key is visible
+    to every query (no mask needed), ``MASKED`` if an edge of the band
+    crosses it.  Interval arithmetic on ``q_pos - k_pos`` against
+    :func:`_band_bounds` — the same inequality :func:`_band_mask` evaluates
+    per element."""
+    lo, hi = _band_bounds(causal, window)
+    q_lo = q_offset + qi * block_q
+    d_min = q_lo - ((ki + 1) * block_k - 1)
+    d_max = q_lo + block_q - 1 - ki * block_k
+    if (lo is not None and d_max < lo) or (hi is not None and d_min > hi):
+        return SKIP
+    if (lo is None or d_min >= lo) and (hi is None or d_max <= hi):
+        return INTERIOR
+    return MASKED
+
+
+def _count_tiles(n_q: int, n_k: int, block_q: int, block_k: int, causal: bool,
+                 window: int, q_offset: int = 0):
+    """(tiles computed, tiles masked) of one row's walk."""
+    kinds = [
+        _tile_kind(qi, ki, block_q, block_k, causal, window, q_offset)
+        for qi in range(n_q) for ki in range(n_k)
+    ]
+    return len(kinds) - kinds.count(SKIP), kinds.count(MASKED)
 
 
 def _stream_k_range(qi, block_q, block_k, causal, window, num_ki, q_offset=0):
@@ -188,8 +253,128 @@ def _stream_q_range(ki, block_q, block_k, causal, window, num_qi, q_offset=0):
     return first, last
 
 
-def _use_stream(s_kv: int, stream: Optional[bool]) -> bool:
-    return s_kv > STREAM_SEQ_THRESHOLD if stream is None else bool(stream)
+def _use_stream(rows: int, tiles: int, stream: Optional[bool]) -> bool:
+    """Streamed kernels for a row too long to hold in VMEM, or a walk of more
+    tile bodies than a kernel should unroll; an explicit ``stream`` wins."""
+    if stream is not None:
+        return bool(stream)
+    return rows > STREAM_SEQ_THRESHOLD or tiles > MAX_STATIC_TILES
+
+
+def _rows_can_be_empty(causal: bool, q_offset: int) -> bool:
+    """Static: can a query row have no visible key?  Not in causal
+    self-attention (a row sees itself, in any segment and any window); in a
+    non-causal or offset chunk a window or a segment can miss every key."""
+    return not causal or q_offset != 0
+
+
+def _derive_tile(seq: int, preferred: int) -> Optional[int]:
+    """Largest tile <= ``preferred`` that is a multiple of 128 and divides
+    ``seq`` (grown again while it would cut the row into more than
+    ``MAX_TILES_PER_SIDE`` pieces); a row shorter than 128 is one tile.
+    None when nothing divides: the caller takes the dense path, loudly."""
+    if seq < 128:
+        return seq
+    tile = preferred
+    while tile > 128 and seq % tile:
+        tile //= 2
+    if seq % tile:
+        return None
+    while seq // tile > MAX_TILES_PER_SIDE and seq % (2 * tile) == 0:
+        tile *= 2
+    return tile
+
+
+def _preferred_tiles(head_dim: int) -> Tuple[int, int]:
+    """(forward, backward) square tile of the resident kernels, from the
+    sweep on a TPU v5e recorded in PERF.md (section 6, PR 27; bf16, seq 1024
+    and 2048, tiles 128-1024 each way): the forward takes 512 below head
+    width 128 (its running max and sum are per-tile work that larger tiles
+    amortise) and 128 from 128 up (the MXU is full at any tile), the
+    backward 256 at both."""
+    return (512 if head_dim < 128 else 128), 256
+
+
+def flash_plan(
+    seq: int,
+    head_dim: int,
+    group: int = 1,
+    dtype=jnp.bfloat16,
+    *,
+    seq_kv: Optional[int] = None,
+    causal: bool = True,
+    window: int = 0,
+    q_offset: int = 0,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    stream: Optional[bool] = None,
+) -> Optional[dict]:
+    """What the kernels will do for this shape: the tile of each pass, which
+    variant runs it, and how many tiles of one row's walk are computed and
+    how many of those are masked.  ``None`` when no tile divides the row.
+
+    Derived tiles (``block_q`` / ``block_k`` None) are
+    :func:`_preferred_tiles` cut to what divides the row; an explicit tile is
+    used for both passes.  ``dtype`` is part of the key and moves nothing
+    yet: the table was measured in bf16 and fp32 operands take the same
+    tiles.
+    """
+    del dtype
+    seq_kv = seq if seq_kv is None else seq_kv
+    fwd_pref, bwd_pref = _preferred_tiles(head_dim)
+    plan = {}
+    for name, pref, rows, bodies in (
+        ("fwd", fwd_pref, max(seq, seq_kv), 1),
+        ("bwd", bwd_pref, max(seq_kv, group * seq), group),
+    ):
+        streamed = _use_stream(rows, 0, stream)
+        if streamed:
+            # the streamed kernels mask and re-fetch per tile: the larger
+            # tile they always ran with (no cell measures them yet)
+            pref = 512
+        bq = _derive_tile(seq, pref) if block_q is None else min(block_q, seq)
+        bk = _derive_tile(seq_kv, pref) if block_k is None else min(block_k, seq_kv)
+        if bq is None or bk is None or seq % bq or seq_kv % bk:
+            return None
+        computed, masked = _count_tiles(
+            seq // bq, seq_kv // bk, bq, bk, causal, window, q_offset
+        )
+        streamed = _use_stream(rows, bodies * computed, stream)
+        plan[name] = {
+            "block_q": bq,
+            "block_k": bk,
+            "variant": "streamed" if streamed else "resident",
+            "tiles_computed": computed,
+            # the streamed kernels mask every tile they compute
+            "tiles_masked": computed if streamed and (causal or window) else masked,
+        }
+    plan["fused_bwd"] = plan["bwd"]["variant"] == "resident"
+    return plan
+
+
+def _padded_bytes(rows: int, cols: int, dtype) -> int:
+    """VMEM bytes of a [rows, cols] block: lanes pad to 128, sublanes to a
+    32-byte word's worth of rows."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // itemsize)
+    return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
+
+
+def _resident_params(interpret: bool, block_bytes: int, scratch_bytes: int,
+                     block_q: int, block_k: int):
+    """Compiler parameters of a resident kernel: rows are independent, and
+    the VMEM limit follows the blocks (double-buffered), the scratch and a
+    handful of live fp32 score tiles instead of the 16 MiB default that a
+    4096-long row outgrows."""
+    if interpret:
+        return {}
+    need = 2 * block_bytes + scratch_bytes + 12 * block_q * block_k * 4
+    limit = min(max(need * 5 // 4, 32 << 20), 100 << 20)
+    return {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=limit
+        )
+    }
 
 
 def _stream_kv_map(kv_row, block_q, block_k, causal, window, num_ki, q_offset):
@@ -237,60 +422,86 @@ def _finalize_rows(acc, m, l, o_ref, lse_ref, causal):
         )
 
 
+def _tile_mask(kind, qi, ki, seg_q, seg_k, block_q, block_k, causal, window,
+               q_offset):
+    """Mask of one TRANSPOSED ``[block_k, block_q]`` tile of a resident
+    kernel, or None: the band only where an edge crosses the tile, the
+    same-segment test on every tile of a packed batch."""
+    mask = None
+    if kind == MASKED:
+        mask = _band_mask(qi, ki, (block_k, block_q), block_q, block_k, causal,
+                          window, q_offset, transposed=True)
+    if seg_q is not None:
+        same = seg_k == seg_q  # [bk, 1] == [1, bq]
+        mask = same if mask is None else jnp.logical_and(mask, same)
+    return mask
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref, *rest, block_q, block_k, scale, has_segments,
-    causal=True, window=0, q_offset=0,
+    causal=True, window=0, q_offset=0, can_be_empty=False,
 ):
+    """Resident forward: one grid step is one (batch, head) row.  The tile
+    walk is static; per q tile the online softmax runs on transposed score
+    tiles ``s_t = k q^T`` so its state is lane-dense (``m``, ``l``:
+    ``[1, block_q]``; ``acc_t``: ``[head_dim, block_q]``)."""
     if has_segments:
         # separate q- and k-side segment refs: for self-attention both view
-        # the same array; ring chunks pass the local chunk's ids vs the
-        # rotating chunk's ids
+        # the same ids; ring chunks pass the local chunk's ids (as rows,
+        # [n_q, 1, block_q]) vs the rotating chunk's ([s_kv, 1])
         seg_q_ref, seg_k_ref, o_ref, lse_ref = rest
     else:
         o_ref, lse_ref = rest
-    qi = pl.program_id(1)
-    # keep MXU operands in the input dtype (bf16 on TPU: full MXU rate) and
-    # accumulate fp32 via preferred_element_type; fp32 operands would run
-    # the systolic array at a fraction of peak
-    q = (q_ref[0] * jnp.asarray(scale, q_ref.dtype)).astype(q_ref.dtype)
-    if has_segments:
-        seg_q = seg_q_ref[0]  # [bq, 1] — block qi via the index map
-    # band range from the ONE shared helper (causal: blocks <= qi; full
-    # mode: every block, or the symmetric window band for encoders)
-    first_k_block, last_k_block = _stream_k_range(
-        qi, block_q, block_k, causal, window,
-        k_ref.shape[1] // block_k, q_offset,
-    )
-    num_k_blocks = last_k_block + 1
-
-    def body(ki, carry):
-        acc, m_prev, l_prev = carry
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [bq, bk]
-        mask = _band_mask(qi, ki, s.shape, block_q, block_k, causal, window,
-                          q_offset)
-        if has_segments:
-            seg_k = seg_k_ref[0, pl.ds(ki * block_k, block_k), :]  # [bk, 1]
-            same = seg_q == seg_k.T
-            mask = same if mask is None else jnp.logical_and(mask, same)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + jnp.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        return acc, m_new, l_new
-
     d = q_ref.shape[-1]
-    acc = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc, m, l = lax.fori_loop(first_k_block, num_k_blocks, body, (acc, m0, l0))
-    _finalize_rows(acc, m, l, o_ref, lse_ref, causal)
+    for qi in range(q_ref.shape[1] // block_q):
+        rows = slice(qi * block_q, (qi + 1) * block_q)
+        # keep MXU operands in the input dtype (bf16 on TPU: full MXU rate)
+        # and accumulate fp32 via preferred_element_type; fp32 operands
+        # would run the systolic array at a fraction of peak
+        q = (q_ref[0, rows, :] * jnp.asarray(scale, q_ref.dtype)).astype(q_ref.dtype)
+        seg_q = seg_q_ref[0, qi] if has_segments else None  # [1, bq]
+        acc_t = jnp.zeros((d, block_q), jnp.float32)
+        m = jnp.full((1, block_q), NEG_INF, jnp.float32)
+        l = jnp.zeros((1, block_q), jnp.float32)
+        for ki in range(k_ref.shape[1] // block_k):
+            kind = _tile_kind(qi, ki, block_q, block_k, causal, window, q_offset)
+            if kind == SKIP:
+                continue
+            cols = slice(ki * block_k, (ki + 1) * block_k)
+            k = k_ref[0, cols, :]
+            v = v_ref[0, cols, :]
+            s_t = lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+            mask = _tile_mask(
+                kind, qi, ki, seg_q, seg_k_ref[0, cols, :] if has_segments else None,
+                block_q, block_k, causal, window, q_offset,
+            )
+            if mask is not None:
+                s_t = jnp.where(mask, s_t, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s_t, axis=0, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p_t = jnp.exp(s_t - m_new)
+            l = l * alpha + jnp.sum(p_t, axis=0, keepdims=True)
+            acc_t = acc_t * alpha + lax.dot_general(
+                v, p_t.astype(v.dtype), _TN, preferred_element_type=jnp.float32
+            )  # v^T p^T: [d, bq]
+            m = m_new
+        # Causal self-attention rows always see themselves (l > 0); an
+        # offset-window or cross-segment chunk can leave rows with NO visible
+        # key — those emit the empty-partial contract (out = 0, lse =
+        # NEG_INF) instead of 0/0 = nan.  The guard is static.
+        if can_be_empty:
+            empty = l <= 0.0
+            safe_l = jnp.where(empty, 1.0, l)
+            out_t = jnp.where(empty, 0.0, acc_t / safe_l)
+            lse = jnp.where(empty, NEG_INF, m + jnp.log(safe_l))
+        else:
+            out_t = acc_t / l
+            lse = m + jnp.log(l)
+        o_ref[0, rows, :] = out_t.T.astype(o_ref.dtype)
+        # log-sum-exp per query, needed by the backward pass; stored as
+        # lane-dense rows [bh, n_q, 1, block_q] ([bh, s, 1] would pad every
+        # value to a 128-lane word in VMEM and in HBM)
+        lse_ref[0, qi] = lse
 
 
 def _fwd_kernel_stream(
@@ -380,11 +591,11 @@ def _flash_fwd(
         window=window,
         q_offset=q_offset,
     )
-    out_shape = [
-        _sds((bh, s, d), q.dtype, qf),
-        _sds((bh, s, 1), jnp.float32, qf),
-    ]
-    if _use_stream(s_kv, stream):
+    n_q = s // block_q
+    tiles, _ = _count_tiles(
+        n_q, s_kv // block_k, block_q, block_k, causal, window, q_offset
+    )
+    if _use_stream(max(s, s_kv), tiles, stream):
         num_ki = s_kv // block_k
         kv_map = _stream_kv_map(
             kv_row, block_q, block_k, causal, window, num_ki, q_offset
@@ -418,7 +629,10 @@ def _flash_fwd(
                 pl.BlockSpec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_, qi, 0)),
             ],
-            out_shape=out_shape,
+            out_shape=[
+                _sds((bh, s, d), q.dtype, qf),
+                _sds((bh, s, 1), jnp.float32, qf),
+            ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, d), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
@@ -428,32 +642,43 @@ def _flash_fwd(
         )(*args)
         return out.reshape(b, h, s, d), lse.reshape(b, h, s)
 
+    # resident: one grid step per (batch, head) row; consecutive heads of a
+    # GQA group map to the same K/V block, which Pallas does not fetch twice
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh_, qi: (bh_, qi, 0)),
-        pl.BlockSpec((1, s_kv, d), lambda bh_, qi: (kv_row(bh_), 0, 0)),
-        pl.BlockSpec((1, s_kv, d), lambda bh_, qi: (kv_row(bh_), 0, 0)),
+        pl.BlockSpec((1, s, d), lambda bh_: (bh_, 0, 0)),
+        pl.BlockSpec((1, s_kv, d), lambda bh_: (kv_row(bh_), 0, 0)),
+        pl.BlockSpec((1, s_kv, d), lambda bh_: (kv_row(bh_), 0, 0)),
     ]
     args = [qf, kf, vf]
+    block_bytes = 2 * _padded_bytes(s, d, q.dtype) + 2 * _padded_bytes(
+        s_kv, d, q.dtype
+    )
     if seg_q is not None:
-        # all H heads of batch row b read the same blocks: the q side one
-        # [block_q, 1] tile per grid step, the k side its full [S_kv, 1] lane
+        # all H heads of batch row b read the same ids: the q side as
+        # lane-dense rows, one per q tile, the k side as a [S_kv, 1] column
         in_specs.append(
-            pl.BlockSpec((1, block_q, 1), lambda bh_, qi: (bh_ // h, qi, 0))
+            pl.BlockSpec((1, n_q, 1, block_q), lambda bh_: (bh_ // h, 0, 0, 0))
         )
-        in_specs.append(
-            pl.BlockSpec((1, s_kv, 1), lambda bh_, qi: (bh_ // h, 0, 0))
-        )
-        args += [seg_q, seg_k]
+        in_specs.append(pl.BlockSpec((1, s_kv, 1), lambda bh_: (bh_ // h, 0, 0)))
+        args += [seg_q.reshape(b, n_q, 1, block_q), seg_k]
+        block_bytes += _padded_bytes(s_kv, 1, jnp.int32)
     out, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, **kernel_kwargs),
-        grid=(bh, s // block_q),
+        functools.partial(
+            _fwd_kernel, can_be_empty=_rows_can_be_empty(causal, q_offset),
+            **kernel_kwargs,
+        ),
+        grid=(bh,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh_, qi: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh_, qi: (bh_, qi, 0)),
+            pl.BlockSpec((1, s, d), lambda bh_: (bh_, 0, 0)),
+            pl.BlockSpec((1, n_q, 1, block_q), lambda bh_: (bh_, 0, 0, 0)),
         ],
-        out_shape=out_shape,
+        out_shape=[
+            _sds((bh, s, d), q.dtype, qf),
+            _sds((bh, n_q, 1, block_q), jnp.float32, qf),
+        ],
         interpret=interpret,
+        **_resident_params(interpret, block_bytes, 0, block_q, block_k),
     )(*args)
     return out.reshape(b, h, s, d), lse.reshape(b, h, s)
 
@@ -461,52 +686,87 @@ def _flash_fwd(
 # --- backward kernels ---------------------------------------------------------
 
 
-def _bwd_dq_kernel(
+def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    block_q, block_k, scale, has_segments, causal=True, window=0, q_offset=0,
+    block_q, block_k, scale, seq_len, group, has_segments, causal=True,
+    window=0, q_offset=0, can_be_empty=False,
 ):
+    """Resident backward, ONE pass: grid (b*h_kv,), one step per K/V head's
+    row.  Each tile of the band is visited once and from one transposed score
+    tile ``s_t = k q^T``, one ``p_t = exp(s_t - lse)`` and one ``dp_t = v
+    do^T`` come ``dv += p_t do``, ``dk += ds_t q`` and ``dq_t += k^T ds_t``
+    (5 matmuls; ``lse`` / ``delta`` are lane-dense ``[1, block_q]`` rows).
+    ``dk`` / ``dv`` of a key tile accumulate as values over its q tiles;
+    ``dq`` accumulates transposed in the fp32 scratch ``dq_acc``
+    ``[group * n_q, head_dim, block_q]`` and is written once at the end.
+
+    Under GQA (group > 1) the q/do/lse/delta operands arrive reshaped to
+    ``[b*h_kv, group*seq, ...]`` and the walk repeats per query head of the
+    group, summing into the same ``dk`` / ``dv`` — the reduction over the
+    group happens here, not via an expanded K/V."""
     if has_segments:
-        seg_q_ref, seg_k_ref, dq_ref = rest
+        seg_q_ref, seg_k_ref, dq_ref, dk_ref, dv_ref, dq_acc = rest
     else:
-        (dq_ref,) = rest
-    qi = pl.program_id(1)
-    q = (q_ref[0] * jnp.asarray(scale, q_ref.dtype)).astype(q_ref.dtype)
-    do = do_ref[0]  # [bq, D]
-    lse = lse_ref[0]  # [bq, 1]
-    delta = delta_ref[0]  # [bq, 1]
-    if has_segments:
-        seg_q = seg_q_ref[0]  # [bq, 1] — block qi via the index map
-    first_k_block, last_k_block = _stream_k_range(
-        qi, block_q, block_k, causal, window,
-        k_ref.shape[1] // block_k, q_offset,
-    )
-    num_k_blocks = last_k_block + 1
-
-    def body(ki, dq):
-        k = k_ref[0, pl.ds(ki * block_k, block_k), :]
-        v = v_ref[0, pl.ds(ki * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        mask = _band_mask(qi, ki, s.shape, block_q, block_k, causal, window,
-                          q_offset)
-        if has_segments:
-            seg_k = seg_k_ref[0, pl.ds(ki * block_k, block_k), :]
-            same = seg_q == seg_k.T
-            mask = same if mask is None else jnp.logical_and(mask, same)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        # empty rows (lse == NEG_INF, only in offset-window chunk mode)
-        # must contribute zero: exp(s - lse) would be exp(0) = 1 on
-        # their masked entries
-        p = jnp.where(lse <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(k.dtype)
-        return dq + jnp.dot(ds, k, preferred_element_type=jnp.float32)
-
-    d = q_ref.shape[-1]
-    dq = lax.fori_loop(
-        first_k_block, num_k_blocks, body, jnp.zeros((block_q, d), jnp.float32)
-    )
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
+        dq_ref, dk_ref, dv_ref, dq_acc = rest
+    d = k_ref.shape[-1]
+    n_q = seq_len // block_q
+    started = set()  # dq tiles that hold a partial sum already (static)
+    for ki in range(k_ref.shape[1] // block_k):
+        cols = slice(ki * block_k, (ki + 1) * block_k)
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        k_t = k.T  # [d, bk], once per key tile
+        seg_k = seg_k_ref[0, cols, :] if has_segments else None
+        dk = jnp.zeros((block_k, d), jnp.float32)
+        dv = jnp.zeros((block_k, d), jnp.float32)
+        for g in range(group):  # one walk per query head in the group
+            for qi in range(n_q):
+                kind = _tile_kind(qi, ki, block_q, block_k, causal, window,
+                                  q_offset)
+                if kind == SKIP:
+                    continue
+                t = g * n_q + qi
+                rows = slice(t * block_q, (t + 1) * block_q)
+                q = (
+                    q_ref[0, rows, :] * jnp.asarray(scale, q_ref.dtype)
+                ).astype(q_ref.dtype)
+                do = do_ref[0, rows, :]
+                lse = lse_ref[0, t]  # [1, bq]
+                s_t = lax.dot_general(
+                    k, q, _NT, preferred_element_type=jnp.float32
+                )  # [bk, bq]
+                mask = _tile_mask(
+                    kind, qi, ki, seg_q_ref[0, qi] if has_segments else None,
+                    seg_k, block_q, block_k, causal, window, q_offset,
+                )
+                if mask is not None:
+                    s_t = jnp.where(mask, s_t, NEG_INF)
+                p_t = jnp.exp(s_t - lse)
+                if can_be_empty and mask is not None:
+                    # empty rows (lse == NEG_INF) must contribute zero:
+                    # exp(s - lse) is exp(0) = 1 on their masked entries.
+                    # A row of an unmasked tile sees keys, so is not empty.
+                    p_t = jnp.where(lse <= NEG_INF / 2, 0.0, p_t)
+                dv = dv + jnp.dot(
+                    p_t.astype(do.dtype), do, preferred_element_type=jnp.float32
+                )
+                dp_t = lax.dot_general(
+                    v, do, _NT, preferred_element_type=jnp.float32
+                )
+                ds_t = (p_t * (dp_t - delta_ref[0, t])).astype(q.dtype)
+                dk = dk + jnp.dot(ds_t, q, preferred_element_type=jnp.float32)
+                dq_t = jnp.dot(k_t, ds_t, preferred_element_type=jnp.float32)
+                dq_acc[t] = dq_acc[t] + dq_t if t in started else dq_t
+                started.add(t)
+        # q was pre-scaled, so dk already carries one factor of `scale`
+        dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+    for t in range(group * n_q):
+        rows = slice(t * block_q, (t + 1) * block_q)
+        if t in started:
+            dq_ref[0, rows, :] = (dq_acc[t].T * scale).astype(dq_ref.dtype)
+        else:  # a q tile whose window misses the whole chunk
+            dq_ref[0, rows, :] = jnp.zeros((block_q, d), dq_ref.dtype)
 
 
 def _bwd_dq_kernel_stream(
@@ -558,78 +818,6 @@ def _bwd_dq_kernel_stream(
     @pl.when(ki == num_ki - 1)
     def _finalize():
         dq_ref[0] = (dq_acc_ref[...] * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    block_q, block_k, scale, seq_len, has_segments, causal=True, window=0,
-    group=1, q_offset=0,
-):
-    """Resident dk/dv: grid (b*h_kv, ki).  Under GQA (group > 1) the
-    q/do/lse/delta operands arrive reshaped to [b*h_kv, group*seq, ...] and
-    the kernel statically unrolls over the group's query heads, summing their
-    contributions — the reduction over the group happens here, not via an
-    expanded K/V."""
-    if has_segments:
-        seg_q_ref, seg_k_ref, dk_ref, dv_ref = rest
-    else:
-        dk_ref, dv_ref = rest
-    ki = pl.program_id(1)
-    k = k_ref[0]  # [block_k, D]
-    v = v_ref[0]
-    if has_segments:
-        seg_k = seg_k_ref[0]  # [bk, 1] — block ki via the index map
-    # shared q-range helper: [first, last] may be empty; fori_loop with
-    # lower >= upper simply runs zero iterations
-    first_q_block, last_q_block = _stream_q_range(
-        ki, block_q, block_k, causal, window, seq_len // block_q, q_offset
-    )
-    num_q_blocks = last_q_block + 1
-
-    def make_body(g):
-        base = g * seq_len
-
-        def body(qi, carry):
-            dk, dv = carry
-            q = (
-                q_ref[0, pl.ds(base + qi * block_q, block_q), :]
-                * jnp.asarray(scale, q_ref.dtype)
-            ).astype(q_ref.dtype)
-            do = do_ref[0, pl.ds(base + qi * block_q, block_q), :]
-            lse = lse_ref[0, pl.ds(base + qi * block_q, block_q), :]
-            delta = delta_ref[0, pl.ds(base + qi * block_q, block_q), :]
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [bq, bk]
-            mask = _band_mask(qi, ki, s.shape, block_q, block_k, causal, window,
-                              q_offset)
-            if has_segments:
-                seg_q = seg_q_ref[0, pl.ds(qi * block_q, block_q), :]
-                same = seg_q == seg_k.T
-                mask = same if mask is None else jnp.logical_and(mask, same)
-            if mask is not None:
-                s = jnp.where(mask, s, NEG_INF)
-            # empty rows (lse == NEG_INF, only in offset-window chunk mode)
-            # must contribute zero: exp(s - lse) would be exp(0) = 1 on
-            # their masked entries
-            p = jnp.where(lse <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-            dv = dv + jnp.dot(
-                p.astype(do.dtype).T, do, preferred_element_type=jnp.float32
-            )
-            dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta)).astype(q.dtype)
-            dk = dk + jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
-            return dk, dv
-
-        return body
-
-    d = k_ref.shape[-1]
-    zeros = jnp.zeros((block_k, d), jnp.float32)
-    carry = (zeros, zeros)
-    for g in range(group):  # static unroll: one pass per query head in group
-        carry = lax.fori_loop(first_q_block, num_q_blocks, make_body(g), carry)
-    dk, dv = carry
-    # q was pre-scaled, so dk already carries one factor of `scale`
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _bwd_dkv_kernel_stream(
@@ -712,101 +900,123 @@ def _flash_bwd(
         delta = delta - dlse
     qf = q.reshape(bh, s, d)
     kf, vf = (x.reshape(b_kv, s_kv, d) for x in (k, v))
+    has_segments = seg_q is not None
+    kernel_kwargs = dict(
+        block_q=block_q,
+        block_k=block_k,
+        scale=scale,
+        has_segments=has_segments,
+        causal=causal,
+        window=window,
+        q_offset=q_offset,
+    )
+    n_q = s // block_q
+    tiles, _ = _count_tiles(
+        n_q, s_kv // block_k, block_q, block_k, causal, window, q_offset
+    )
+    # the resident kernel holds [group*s, d] q/do/dq rows in VMEM and unrolls
+    # group * tiles bodies, so under GQA the decision budgets for group*s,
+    # not just s_kv — e.g. group=8 at s=4096 is an 8MB bf16 q row
+    if not _use_stream(max(s_kv, group * s), group * tiles, stream):
+        # group the query-head operands by K/V head: [b*h_kv, group*s, ...];
+        # lse / delta as lane-dense rows, one per q tile
+        rows = group * s
+        qg = q.reshape(b_kv, rows, d)
+        dog = do.reshape(b_kv, rows, d)
+        lseg = lse.reshape(b_kv, group * n_q, 1, block_q)
+        deltag = delta.reshape(b_kv, group * n_q, 1, block_q)
+        q_spec = pl.BlockSpec((1, rows, d), lambda bkv_: (bkv_, 0, 0))
+        kv_spec = pl.BlockSpec((1, s_kv, d), lambda bkv_: (bkv_, 0, 0))
+        stat_spec = pl.BlockSpec(
+            (1, group * n_q, 1, block_q), lambda bkv_: (bkv_, 0, 0, 0)
+        )
+        in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
+        args = [qg, kf, vf, dog, lseg, deltag]
+        block_bytes = 3 * _padded_bytes(rows, d, q.dtype) + 4 * _padded_bytes(
+            s_kv, d, q.dtype
+        )
+        if has_segments:
+            in_specs.append(
+                pl.BlockSpec(
+                    (1, n_q, 1, block_q), lambda bkv_: (bkv_ // h_kv, 0, 0, 0)
+                )
+            )
+            in_specs.append(
+                pl.BlockSpec((1, s_kv, 1), lambda bkv_: (bkv_ // h_kv, 0, 0))
+            )
+            args += [seg_q.reshape(b, n_q, 1, block_q), seg_k]
+            block_bytes += _padded_bytes(s_kv, 1, jnp.int32)
+        scratch_bytes = group * n_q * _padded_bytes(d, block_q, jnp.float32)
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(
+                _bwd_kernel, seq_len=s, group=group,
+                can_be_empty=_rows_can_be_empty(causal, q_offset),
+                **kernel_kwargs,
+            ),
+            grid=(b_kv,),
+            in_specs=in_specs,
+            out_specs=[q_spec, kv_spec, kv_spec],
+            out_shape=[
+                _sds((b_kv, rows, d), q.dtype, qf),
+                _sds((b_kv, s_kv, d), q.dtype, qf),
+                _sds((b_kv, s_kv, d), q.dtype, qf),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((group * n_q, d, block_q), jnp.float32)
+            ],
+            interpret=interpret,
+            **_resident_params(
+                interpret, block_bytes, scratch_bytes, block_q, block_k
+            ),
+        )(*args)
+        return (
+            dq.reshape(b, h, s, d),
+            dk.reshape(b, h_kv, s_kv, d),
+            dv.reshape(b, h_kv, s_kv, d),
+        )
+
+    # ---- streamed: the dq / dkv pair ----
     dof = do.reshape(bh, s, d)
     lsef = lse.reshape(bh, s, 1)
     deltaf = delta.reshape(bh, s, 1)
-    has_segments = seg_q is not None
     kv_row = _kv_row_map(h, h_kv)
-    # the resident dkv kernel holds [group*s, d] q/do operands in VMEM, so
-    # under GQA the stream decision must budget for group*s, not just s_kv —
-    # e.g. group=8 at s=4096 is an 8MB bf16 q tile, past v5e VMEM
-    streamed = _use_stream(max(s_kv, group * s), stream)
+    num_ki = s_kv // block_k
+    kv_map = _stream_kv_map(
+        kv_row, block_q, block_k, causal, window, num_ki, q_offset
+    )
 
-    # ---- dq ----
-    if streamed:
-        num_ki = s_kv // block_k
-        kv_map = _stream_kv_map(
-            kv_row, block_q, block_k, causal, window, num_ki, q_offset
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)),
+        pl.BlockSpec((1, block_k, d), kv_map),
+        pl.BlockSpec((1, block_k, d), kv_map),
+        pl.BlockSpec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)),
+        pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_, qi, 0)),
+        pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_, qi, 0)),
+    ]
+    args = [qf, kf, vf, dof, lsef, deltaf]
+    if has_segments:
+        in_specs.append(
+            pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_ // h, qi, 0))
         )
+        in_specs.append(
+            pl.BlockSpec(
+                (1, block_k, 1),
+                lambda bh_, qi, ki: (bh_ // h,) + kv_map(bh_, qi, ki)[1:],
+            )
+        )
+        args += [seg_q, seg_k]
+    dq = pl.pallas_call(
+        functools.partial(_bwd_dq_kernel_stream, num_ki=num_ki, **kernel_kwargs),
+        grid=(bh, s // block_q, num_ki),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec(
+            (1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)
+        ),
+        out_shape=_sds((bh, s, d), q.dtype, qf),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        interpret=interpret,
+    )(*args)
 
-        in_specs = [
-            pl.BlockSpec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_, qi, 0)),
-        ]
-        args = [qf, kf, vf, dof, lsef, deltaf]
-        if has_segments:
-            in_specs.append(
-                pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_ // h, qi, 0))
-            )
-            in_specs.append(
-                pl.BlockSpec(
-                    (1, block_k, 1),
-                    lambda bh_, qi, ki: (bh_ // h,) + kv_map(bh_, qi, ki)[1:],
-                )
-            )
-            args += [seg_q, seg_k]
-        dq = pl.pallas_call(
-            functools.partial(
-                _bwd_dq_kernel_stream,
-                block_q=block_q,
-                block_k=block_k,
-                scale=scale,
-                has_segments=has_segments,
-                causal=causal,
-                window=window,
-                num_ki=num_ki,
-                q_offset=q_offset,
-            ),
-            grid=(bh, s // block_q, num_ki),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)
-            ),
-            out_shape=_sds((bh, s, d), q.dtype, qf),
-            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            interpret=interpret,
-        )(*args)
-    else:
-        in_specs = [
-            pl.BlockSpec((1, block_q, d), lambda bh_, qi: (bh_, qi, 0)),
-            pl.BlockSpec((1, s_kv, d), lambda bh_, qi: (kv_row(bh_), 0, 0)),
-            pl.BlockSpec((1, s_kv, d), lambda bh_, qi: (kv_row(bh_), 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh_, qi: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh_, qi: (bh_, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh_, qi: (bh_, qi, 0)),
-        ]
-        args = [qf, kf, vf, dof, lsef, deltaf]
-        if has_segments:
-            in_specs.append(
-                pl.BlockSpec((1, block_q, 1), lambda bh_, qi: (bh_ // h, qi, 0))
-            )
-            in_specs.append(
-                pl.BlockSpec((1, s_kv, 1), lambda bh_, qi: (bh_ // h, 0, 0))
-            )
-            args += [seg_q, seg_k]
-        dq = pl.pallas_call(
-            functools.partial(
-                _bwd_dq_kernel,
-                block_q=block_q,
-                block_k=block_k,
-                scale=scale,
-                has_segments=has_segments,
-                causal=causal,
-                window=window,
-                q_offset=q_offset,
-            ),
-            grid=(bh, s // block_q),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, block_q, d), lambda bh_, qi: (bh_, qi, 0)),
-            out_shape=_sds((bh, s, d), q.dtype, qf),
-            interpret=interpret,
-        )(*args)
-
-    # ---- dk/dv ----
     dkv_out_specs = [
         pl.BlockSpec((1, block_k, d), lambda bh_, ki, *_: (bh_, ki, 0)),
         pl.BlockSpec((1, block_k, d), lambda bh_, ki, *_: (bh_, ki, 0)),
@@ -815,115 +1025,63 @@ def _flash_bwd(
         _sds((b_kv, s_kv, d), q.dtype, qf),
         _sds((b_kv, s_kv, d), q.dtype, qf),
     ]
-    if streamed:
-        num_qi = s // block_q
+    num_qi = s // block_q
 
-        def q_row(bkv_, g):
-            if group == 1:
-                return bkv_
-            return (bkv_ // h_kv) * h + (bkv_ % h_kv) * group + g
+    def q_row(bkv_, g):
+        if group == 1:
+            return bkv_
+        return (bkv_ // h_kv) * h + (bkv_ % h_kv) * group + g
 
-        def qi_clip(ki, qi):
-            first_q, last_q = _stream_q_range(
-                ki, block_q, block_k, causal, window, num_qi, q_offset
-            )
-            # negative q_offset (ahead ring chunks) can push first_q past
-            # the last block for late k blocks; keep the index in bounds —
-            # those grid steps are compute-predicated off
-            first_q = jnp.clip(first_q, 0, num_qi - 1)
-            return jnp.clip(qi, first_q, jnp.maximum(last_q, first_q))
+    def qi_clip(ki, qi):
+        first_q, last_q = _stream_q_range(
+            ki, block_q, block_k, causal, window, num_qi, q_offset
+        )
+        # negative q_offset (ahead ring chunks) can push first_q past
+        # the last block for late k blocks; keep the index in bounds —
+        # those grid steps are compute-predicated off
+        first_q = jnp.clip(first_q, 0, num_qi - 1)
+        return jnp.clip(qi, first_q, jnp.maximum(last_q, first_q))
 
-        def q_map(bkv_, ki, g, qi):
-            return (q_row(bkv_, g), qi_clip(ki, qi), 0)
+    def q_map(bkv_, ki, g, qi):
+        return (q_row(bkv_, g), qi_clip(ki, qi), 0)
 
-        in_specs = [
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_k, d), lambda bkv_, ki, g, qi: (bkv_, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bkv_, ki, g, qi: (bkv_, ki, 0)),
-            pl.BlockSpec((1, block_q, d), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
-            pl.BlockSpec((1, block_q, 1), q_map),
-        ]
-        args = [qf, kf, vf, dof, lsef, deltaf]
-        if has_segments:
-            in_specs.append(
-                pl.BlockSpec(
-                    (1, block_q, 1),
-                    lambda bkv_, ki, g, qi: (bkv_ // h_kv, qi_clip(ki, qi), 0),
-                )
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, block_k, d), lambda bkv_, ki, g, qi: (bkv_, ki, 0)),
+        pl.BlockSpec((1, block_k, d), lambda bkv_, ki, g, qi: (bkv_, ki, 0)),
+        pl.BlockSpec((1, block_q, d), q_map),
+        pl.BlockSpec((1, block_q, 1), q_map),
+        pl.BlockSpec((1, block_q, 1), q_map),
+    ]
+    args = [qf, kf, vf, dof, lsef, deltaf]
+    if has_segments:
+        in_specs.append(
+            pl.BlockSpec(
+                (1, block_q, 1),
+                lambda bkv_, ki, g, qi: (bkv_ // h_kv, qi_clip(ki, qi), 0),
             )
-            in_specs.append(
-                pl.BlockSpec(
-                    (1, block_k, 1),
-                    lambda bkv_, ki, g, qi: (bkv_ // h_kv, ki, 0),
-                )
+        )
+        in_specs.append(
+            pl.BlockSpec(
+                (1, block_k, 1),
+                lambda bkv_, ki, g, qi: (bkv_ // h_kv, ki, 0),
             )
-            args += [seg_q, seg_k]
-        dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_dkv_kernel_stream,
-                block_q=block_q,
-                block_k=block_k,
-                scale=scale,
-                has_segments=has_segments,
-                causal=causal,
-                window=window,
-                group=group,
-                num_qi=num_qi,
-                q_offset=q_offset,
-            ),
-            grid=(b_kv, s_kv // block_k, group, num_qi),
-            in_specs=in_specs,
-            out_specs=dkv_out_specs,
-            out_shape=dkv_out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
-            ],
-            interpret=interpret,
-        )(*args)
-    else:
-        # group the query-head operands by K/V head: [b*h_kv, group*s, ...]
-        qg = q.reshape(b_kv, group * s, d)
-        dog = do.reshape(b_kv, group * s, d)
-        lseg = lse.reshape(b_kv, group * s, 1)
-        deltag = delta.reshape(b_kv, group * s, 1)
-        in_specs = [
-            pl.BlockSpec((1, group * s, d), lambda bh_, ki: (bh_, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, ki: (bh_, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh_, ki: (bh_, ki, 0)),
-            pl.BlockSpec((1, group * s, d), lambda bh_, ki: (bh_, 0, 0)),
-            pl.BlockSpec((1, group * s, 1), lambda bh_, ki: (bh_, 0, 0)),
-            pl.BlockSpec((1, group * s, 1), lambda bh_, ki: (bh_, 0, 0)),
-        ]
-        args = [qg, kf, vf, dog, lseg, deltag]
-        if has_segments:
-            in_specs.append(
-                pl.BlockSpec((1, s, 1), lambda bh_, ki: (bh_ // h_kv, 0, 0))
-            )
-            in_specs.append(
-                pl.BlockSpec((1, block_k, 1), lambda bh_, ki: (bh_ // h_kv, ki, 0))
-            )
-            args += [seg_q, seg_k]
-        dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_dkv_kernel,
-                block_q=block_q,
-                block_k=block_k,
-                scale=scale,
-                seq_len=s,
-                has_segments=has_segments,
-                causal=causal,
-                window=window,
-                group=group,
-                q_offset=q_offset,
-            ),
-            grid=(b_kv, s_kv // block_k),
-            in_specs=in_specs,
-            out_specs=dkv_out_specs,
-            out_shape=dkv_out_shape,
-            interpret=interpret,
-        )(*args)
+        )
+        args += [seg_q, seg_k]
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_dkv_kernel_stream, group=group, num_qi=num_qi, **kernel_kwargs
+        ),
+        grid=(b_kv, s_kv // block_k, group, num_qi),
+        in_specs=in_specs,
+        out_specs=dkv_out_specs,
+        out_shape=dkv_out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*args)
 
     return (
         dq.reshape(b, h, s, d),
@@ -939,7 +1097,8 @@ def _flash_bwd(
 def _flash_finalize(
     q, k, v, seg_q, seg_k, out, lse, block_q, block_k, interpret, window, stream
 ):
-    """Identity on ``out``; exists to attach the backward kernels.
+    """Identity on ``out``; exists to attach the backward kernels
+    (``block_q`` / ``block_k`` are the BACKWARD's tiles).
 
     The forward kernel runs *outside* this custom_vjp (see
     ``_flash_attention_bhsd``) so its outputs are ordinary named values in
@@ -973,8 +1132,8 @@ def _finalize_bwd(block_q, block_k, interpret, window, stream, residuals, do):
 _flash_finalize.defvjp(_finalize_fwd, _finalize_bwd)
 
 
-def _flash_attention_bhsd(q, k, v, seg, block_q, block_k, interpret, window=0,
-                          stream=None):
+def _flash_attention_bhsd(q, k, v, seg, fwd_tile, bwd_tile, interpret,
+                          window=0, stream=None):
     from jax.ad_checkpoint import checkpoint_name
 
     # self-attention: q and k index the same positions, so one segment
@@ -989,8 +1148,8 @@ def _flash_attention_bhsd(q, k, v, seg, block_q, block_k, interpret, window=0,
         lax.stop_gradient(v),
         seg,
         seg,
-        block_q=block_q,
-        block_k=block_k,
+        block_q=fwd_tile[0],
+        block_k=fwd_tile[1],
         interpret=interpret,
         window=window,
         stream=stream,
@@ -998,7 +1157,8 @@ def _flash_attention_bhsd(q, k, v, seg, block_q, block_k, interpret, window=0,
     out = checkpoint_name(out, "attn")
     lse = checkpoint_name(lse, "attn")
     return _flash_finalize(
-        q, k, v, seg, seg, out, lse, block_q, block_k, interpret, window, stream
+        q, k, v, seg, seg, out, lse, bwd_tile[0], bwd_tile[1], interpret,
+        window, stream
     )
 
 
@@ -1046,8 +1206,8 @@ _chunk_finalize.defvjp(_chunk_finalize_fwd, _chunk_finalize_bwd)
 
 
 def _chunk_attention_bhsd(
-    q, k, v, seg_q, seg_k, causal, block_q, block_k, interpret, stream, window,
-    q_offset
+    q, k, v, seg_q, seg_k, causal, fwd_tile, bwd_tile, interpret, stream,
+    window, q_offset
 ):
     from jax.ad_checkpoint import checkpoint_name
 
@@ -1056,15 +1216,15 @@ def _chunk_attention_bhsd(
         lax.stop_gradient(k),
         lax.stop_gradient(v),
         seg_q, seg_k,
-        block_q=block_q, block_k=block_k,
+        block_q=fwd_tile[0], block_k=fwd_tile[1],
         interpret=interpret, causal=causal, stream=stream,
         window=window, q_offset=q_offset,
     )
     out = checkpoint_name(out, "attn")
     lse = checkpoint_name(lse, "attn")
     return _chunk_finalize(
-        q, k, v, seg_q, seg_k, out, lse, causal, block_q, block_k, interpret,
-        stream, window, q_offset
+        q, k, v, seg_q, seg_k, out, lse, causal, bwd_tile[0], bwd_tile[1],
+        interpret, stream, window, q_offset
     )
 
 
@@ -1074,8 +1234,8 @@ def flash_chunk_attention(
     v: jax.Array,
     *,
     causal: bool,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
     stream: Optional[bool] = None,
     window: int = 0,
@@ -1090,8 +1250,16 @@ def flash_chunk_attention(
     normalized *within the chunk* and ``lse`` [batch, heads, seq_q] its
     log-sum-exp; partials from different chunks combine exactly via
     :func:`tpu_parallel.ops.ring_attention.combine_chunks`.  Differentiable
-    in both outputs — the lse cotangent folds into the backward kernels'
+    in both outputs — the lse cotangent folds into the backward kernel's
     delta correction, which is what makes the combine's gradient exact.
+
+    Same kernels as :func:`flash_attention`: up to ``STREAM_SEQ_THRESHOLD``
+    the resident pair (static tile walk, ONE backward pass with ``dq`` in an
+    fp32 VMEM scratch), the streamed ones beyond.  ``block_q`` / ``block_k``
+    None derives each pass's tiles from the chunk lengths
+    (:func:`flash_plan`).  The ``lse <= NEG_INF / 2`` guard for rows with no
+    visible key is compiled in exactly where such rows can exist
+    (``causal=False`` or ``q_offset != 0``): a static condition.
 
     ``causal=True`` is the diagonal chunk of a sequence-sharded causal
     attention (q and k index the same positions); ``causal=False`` is a
@@ -1131,25 +1299,31 @@ def flash_chunk_attention(
     # an error.  gcd shrinks to the largest legal tile; warn when it bites.
     import math
 
-    bq = math.gcd(q.shape[1], min(block_q, q.shape[1]))
-    bk = math.gcd(k.shape[1], min(block_k, k.shape[1]))
-    if causal:
-        bk = math.gcd(bq, bk)  # causal num_k_blocks needs block_q % block_k == 0
-    if bq < min(block_q, q.shape[1]) or bk < min(block_k, k.shape[1]):
-        warnings.warn(
-            f"flash_chunk_attention shrank tiles to {bq}x{bk}: chunk lengths "
-            f"q={q.shape[1]}/kv={k.shape[1]} are not divisible by the "
-            f"requested {block_q}x{block_k}",
-            stacklevel=2,
-        )
+    s_q, s_kv, d = q.shape[1], k.shape[1], q.shape[3]
+    tiles = []
+    for preferred in _preferred_tiles(d):  # forward, backward
+        want_q = block_q or _derive_tile(s_q, preferred) or preferred
+        want_k = block_k or _derive_tile(s_kv, preferred) or preferred
+        bq = math.gcd(s_q, min(want_q, s_q))
+        bk = math.gcd(s_kv, min(want_k, s_kv))
+        if causal:
+            bk = math.gcd(bq, bk)  # the diagonal chunk: block_q % block_k == 0
+        if bq < min(want_q, s_q) or bk < min(want_k, s_kv):
+            warnings.warn(
+                f"flash_chunk_attention shrank tiles to {bq}x{bk}: chunk "
+                f"lengths q={s_q}/kv={s_kv} are not divisible by the "
+                f"requested {want_q}x{want_k}",
+                stacklevel=2,
+            )
+        tiles.append((bq, bk))
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     seg_q = seg_k = None
     if segment_ids_q is not None:
         seg_q = segment_ids_q.astype(jnp.int32)[:, :, None]
         seg_k = segment_ids_kv.astype(jnp.int32)[:, :, None]
     out, lse = _chunk_attention_bhsd(
-        qt, kt, vt, seg_q, seg_k, causal, bq, bk, interpret, stream, window,
-        q_offset
+        qt, kt, vt, seg_q, seg_k, causal, tiles[0], tiles[1], interpret, stream,
+        window, q_offset
     )
     return out.transpose(0, 2, 1, 3), lse
 
@@ -1160,8 +1334,8 @@ def flash_attention(
     v: jax.Array,
     *,
     segment_ids: Optional[jax.Array] = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     window: int = 0,
     interpret: Optional[bool] = None,
     stream: Optional[bool] = None,
@@ -1177,9 +1351,21 @@ def flash_attention(
     (t - window, t] only, and whole key blocks outside the window are
     skipped, not masked — O(seq * window) compute at long sequence.
 
+    ``block_q`` / ``block_k`` None (the default) derives the tiles of the
+    forward and of the backward from the shape (:func:`flash_plan`; they need
+    not be equal); an explicit value is used for both passes.
+
     ``stream`` selects the long-sequence kernels (K/V walked as a grid
     dimension, O(block_k) VMEM residency); ``None`` auto-selects them above
-    ``STREAM_SEQ_THRESHOLD`` tokens.
+    ``STREAM_SEQ_THRESHOLD`` tokens (or ``MAX_STATIC_TILES`` tile bodies).
+    Below that the resident kernels run: a tile walk that is static at trace
+    time (tiles outside the band emit nothing, tiles inside it no mask), and
+    a backward that is ONE pass — per (batch, kv head) row every tile is
+    visited once and yields ``dv``, ``dk`` and ``dq`` from one score tile,
+    ``dq`` accumulating in an fp32 VMEM scratch ``[group * seq / block_q,
+    head_dim, block_q]`` that is written once.  The streamed backward is
+    the dq / dkv pair.  No ``lse`` guard for empty rows is compiled into
+    causal self-attention: a row always sees itself.
 
     Drop-in replacement for
     :func:`tpu_parallel.models.layers.causal_attention` (the ``attn_fn``
@@ -1193,14 +1379,19 @@ def flash_attention(
         raise ValueError(f"q heads {h} not a multiple of k/v heads {h_kv}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
-    if s % block_q != 0 or s % block_k != 0 or block_q % block_k != 0:
+    plan = flash_plan(
+        s, d, h // h_kv, q.dtype, window=window, block_q=block_q,
+        block_k=block_k, stream=stream,
+    )
+    if plan is None or any(
+        plan[p]["block_q"] % plan[p]["block_k"] for p in ("fwd", "bwd")
+    ):
         # O(seq^2) escape hatch for shapes the kernel can't tile — loud, not
         # silent: this is a memory/perf cliff the caller should know about
         warnings.warn(
             f"flash_attention falling back to the O(seq^2) reference path: "
-            f"seq_len={s} not divisible by block_q={block_q}/block_k={block_k}",
+            f"seq_len={s} not divisible by block_q={block_q}/block_k={block_k}"
+            + (" (no multiple of 128 divides it)" if block_q is None else ""),
             stacklevel=2,
         )
         from tpu_parallel.models.layers import causal_attention
@@ -1216,6 +1407,9 @@ def flash_attention(
         seg = segment_ids.astype(jnp.int32)[:, :, None]
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     out = _flash_attention_bhsd(
-        qt, kt, vt, seg, block_q, block_k, interpret, window, stream
+        qt, kt, vt, seg,
+        (plan["fwd"]["block_q"], plan["fwd"]["block_k"]),
+        (plan["bwd"]["block_q"], plan["bwd"]["block_k"]),
+        interpret, window, stream,
     )
     return out.transpose(0, 2, 1, 3)

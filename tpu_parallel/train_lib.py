@@ -10,6 +10,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import json
+import logging
 import time
 from typing import Any, Callable, Dict, Optional
 
@@ -187,6 +189,7 @@ class Trainer:
                  tracer: Optional[Tracer] = None,
                  registry: Optional[MetricRegistry] = None):
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.flash_plan: Optional[dict] = None  # set below for attn_impl="flash"
         self.registry = registry if registry is not None else MetricRegistry()
         self.config = config
         self.mesh = mesh if mesh is not None else make_mesh(config.mesh)
@@ -327,6 +330,25 @@ class Trainer:
             grad_fn=grad_fn,
         )
         self.state: Optional[TrainState] = None
+        # what the flash kernels will do at this shape, said once: the tiles
+        # of each pass, resident or streamed, one backward pass or two
+        self.flash_plan = self._flash_plan()
+        if self.flash_plan is not None:
+            logging.getLogger(__name__).info(
+                "flash_plan %s", json.dumps(self.flash_plan)
+            )
+
+    def _flash_plan(self) -> Optional[dict]:
+        cfg = self.model_config
+        if cfg.attn_impl != "flash":
+            return None
+        from tpu_parallel.ops.flash_attention import flash_plan
+
+        return flash_plan(
+            cfg.seq_len, cfg.head_dim, cfg.n_heads // (cfg.n_kv_heads or cfg.n_heads),
+            cfg.dtype, causal=not cfg.bidirectional, window=cfg.attn_window,
+            block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
+        )
 
     def _init_seq2seq(self, config: TrainerConfig) -> None:
         """Encoder-decoder family wiring: same Trainer surface, different
@@ -404,6 +426,13 @@ class Trainer:
         t_start = t0 = time.perf_counter()
         timed_from = 0  # throughput covers steps AFTER this one
         tr = self.tracer
+        if tr.enabled and self.flash_plan is not None:
+            plan = self.flash_plan
+            tr.instant(
+                "flash_plan", track="trainer", fused_bwd=plan["fused_bwd"],
+                **{f"{p}_{k}": v for p in ("fwd", "bwd")
+                   for k, v in plan[p].items()},
+            )
         for step in range(1, steps + 1):
             if tr.enabled:
                 with tr.span("data_wait", track="trainer", step=step):
