@@ -174,6 +174,74 @@ def test_flash_kernel_compiles_for_v5e(case, v5e_chip, monkeypatch):
     lowered.compile()  # raises what the chip's compiler would raise
 
 
+@pytest.mark.parametrize("program", ["decode_tick", "prefill_8192"])
+def test_expert_share_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
+    """The serving cell of the parallel-block expert decoder at its published
+    widths, built from the benchmark's own configuration and cell files: the
+    fused decode tick over 32 slots of 8192 positions and the largest
+    whole-prompt prefill (flash kernels at heads of 128, GQA 16:1, window
+    4096 and none; grouped expert matmuls with both buffers) fit one v5e."""
+    import json
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from drivers.serve_moe import model_config
+
+    from tpu_parallel.models import GPTLM
+    from tpu_parallel.serving import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    read = lambda *rel: json.load(open(os.path.join(REPO, "benchmarks", *rel)))
+    cell = read("workloads", "serve-command_a_plus_share8-longshort.json")
+    cfg = model_config(
+        read("configs", "command_a_plus_share8.json"), cell["engine"]
+    )
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (
+        4096, 16, 1, 128
+    )
+    model, n = GPTLM(cfg), cell["engine"]["n_slots"]
+    on_chip = lambda x, dtype=None: jax.ShapeDtypeStruct(
+        x.shape, dtype or x.dtype, sharding=v5e_chip
+    )
+    params = jax.tree.map(
+        lambda x: on_chip(x, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 16), jnp.int32),
+            train=False,
+        ))["params"],
+    )
+    assert sum(x.size for x in jax.tree.leaves(params)) == 4_234_170_368
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_chip)
+    floats = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=v5e_chip
+    )
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip)
+    if program == "prefill_8192":
+        width = cfg.seq_len
+        lowered = jax.jit(
+            lambda p, toks, pos, last, rng: engine._prefill_core(
+                model, p, toks, pos, last, rng
+            )
+        ).lower(params, ints(1, width), ints(1, width), ints(1), key)
+        # two attention kernels a layer kind... at least one call a layer
+        assert lowered.as_text().count("tpu_custom_call") >= cfg.n_layers
+    else:
+        pool = jax.tree.map(on_chip, jax.eval_shape(
+            lambda p: engine._prefill_core(
+                model, p, jnp.zeros((n, 16), jnp.int32),
+                jnp.zeros((n, 16), jnp.int32), jnp.zeros((n,), jnp.int32), None,
+            )[1], params,
+        ))
+        live = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=v5e_chip)
+        state = (ints(n), ints(n), ints(n), live, ints(n))
+        knobs = (ints(n), floats(n), ints(n), floats(n))
+        lowered = engine._fused_engine_fn(model, 8).lower(
+            params, state, knobs, pool, key
+        )
+    memory = lowered.compile().memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 8.4e9 < held < 15.75e9, memory
+
+
 def test_compile_cache_honours_the_environment(monkeypatch, tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set, JAX already uses it and the code
     sets no directory of its own."""

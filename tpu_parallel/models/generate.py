@@ -113,6 +113,28 @@ def _sample_sharded(
     return vocab_parallel_argmax(lf + g, axis_name)
 
 
+def _cached_apply(model: GPTLM, variables, tokens, with_rows: bool, **kwargs):
+    """``model.apply`` in decode mode: ``(hidden, cache)`` or, ``with_rows``,
+    ``(hidden, cache, rows)`` with ``rows`` the ``[layers, held + 1]`` count
+    of rows this call routed to each held expert (None for a model without
+    a dropless expert layer: :func:`tpu_parallel.models.moe.expert_rows`)."""
+    from tpu_parallel.models.moe import MOE_STATS, expert_rows
+
+    counted = with_rows and model.config.routed_layers > 0
+    hidden, updated = model.apply(
+        variables,
+        tokens,
+        train=False,
+        decode=True,
+        hidden_only=True,
+        mutable=["cache", MOE_STATS] if counted else ["cache"],
+        **kwargs,
+    )
+    if not with_rows:
+        return hidden, updated["cache"]
+    return hidden, updated["cache"], expert_rows(updated) if counted else None
+
+
 def decode_step(
     model: GPTLM,
     params,
@@ -121,6 +143,7 @@ def decode_step(
     positions: jax.Array,
     write_index: Optional[jax.Array] = None,
     block_table: Optional[jax.Array] = None,
+    with_rows: bool = False,
 ):
     """One single-token decode tick — THE reusable core of every decode loop.
 
@@ -133,20 +156,18 @@ def decode_step(
     multi-step tick, and as the decode phase of its UNIFIED ragged tick
     right after a :func:`prefill_extend_step` chunk phase in the same
     dispatch; sharing this one core is what makes every tick family's
-    greedy output bitwise identical by construction).
+    greedy output bitwise identical by construction).  ``with_rows`` adds
+    the expert layers' row counts (:func:`_cached_apply`).
     """
-    hidden, updated = model.apply(
+    return _cached_apply(
+        model,
         {"params": params, "cache": cache},
         tok[:, None],
+        with_rows,
         positions=positions[:, None],
-        train=False,
-        decode=True,
-        hidden_only=True,
-        mutable=["cache"],
         write_index=write_index,
         block_table=block_table,
     )
-    return hidden, updated["cache"]
 
 
 def padded_prefill_inputs(lengths, width: int):
@@ -169,7 +190,7 @@ def padded_prefill_inputs(lengths, width: int):
 
 
 def prefill_step(model: GPTLM, params, tokens: jax.Array,
-                 positions: jax.Array):
+                 positions: jax.Array, with_rows: bool = False):
     """Fresh-cache prefill over ``tokens`` [b, P] at explicit ``positions``
     [b, P] — THE pad-aware prefill core of the serving engine's fast path.
 
@@ -182,21 +203,15 @@ def prefill_step(model: GPTLM, params, tokens: jax.Array,
     composition) is bit-identical to an exact-length prefill.  Returns
     ``(hidden [b, P, d_model], cache)``.
     """
-    hidden, variables = model.apply(
-        {"params": params},
-        tokens,
-        positions=positions,
-        train=False,
-        decode=True,
-        hidden_only=True,
-        mutable=["cache"],
+    return _cached_apply(
+        model, {"params": params}, tokens, with_rows, positions=positions
     )
-    return hidden, variables["cache"]
 
 
 def prefill_extend_step(model: GPTLM, params, cache, tokens: jax.Array,
                         positions: jax.Array, write_start: jax.Array,
-                        block_table: Optional[jax.Array] = None):
+                        block_table: Optional[jax.Array] = None,
+                        with_rows: bool = False):
     """Continue a prefill INTO an existing cache: ``tokens`` [b, T] at
     global ``positions`` [b, T] (pads -1), K/V written at cache slots
     ``write_start + [0..T)`` per row (the multi-token ``write_index`` path
@@ -220,18 +235,15 @@ def prefill_extend_step(model: GPTLM, params, cache, tokens: jax.Array,
     call changes no row's math (row-parallel ops — the same argument
     that makes batch composition invisible everywhere else).
     """
-    hidden, updated = model.apply(
+    return _cached_apply(
+        model,
         {"params": params, "cache": cache},
         tokens,
+        with_rows,
         positions=positions,
-        train=False,
-        decode=True,
-        hidden_only=True,
-        mutable=["cache"],
         write_index=write_start,
         block_table=block_table,
     )
-    return hidden, updated["cache"]
 
 
 def verify_step(model: GPTLM, params, cache, tokens: jax.Array,
@@ -259,18 +271,15 @@ def verify_step(model: GPTLM, params, cache, tokens: jax.Array,
     ``kp <= qp`` keeps them invisible.  Pad offsets (positions -1) write
     -1 into the position table, invalidating their columns outright.
     """
-    hidden, updated = model.apply(
+    return _cached_apply(
+        model,
         {"params": params, "cache": cache},
         tokens,
+        False,
         positions=positions,
-        train=False,
-        decode=True,
-        hidden_only=True,
-        mutable=["cache"],
         write_index=write_index,
         block_table=block_table,
     )
-    return hidden, updated["cache"]
 
 
 def _generate_core(
@@ -299,11 +308,16 @@ def _generate_core(
     position table and are never attended; each row continues from its own
     length.  None = all rows full length (the aligned fast path).
     """
-    from tpu_parallel.models.gpt import _lm_head_params, _make_lm_head
+    from tpu_parallel.models.gpt import _apply_lm_head, _lm_head_params
     from tpu_parallel.parallel.tp import axis_size_or_none
 
     cfg = model.config
     b, prompt_len = prompt.shape
+    if prompt_mask is not None and cfg.prefill_flash:
+        raise NotImplementedError(
+            "ragged (left-padded) prompts with prefill_flash: the kernels "
+            "mask by index, and a left pad would be attended"
+        )
     if prompt_len + max_new_tokens > cfg.seq_len:
         raise ValueError(
             f"prompt ({prompt_len}) + max_new_tokens ({max_new_tokens}) "
@@ -316,12 +330,11 @@ def _generate_core(
         )
     # unwrapped head + one up-front FSDP gather: the wrapped head would
     # re-all_gather the vocab kernel every decode step inside the scan
-    head = _make_lm_head(cfg, name=None, gather=False, fsdp_wrap=False)
     lm_params = _lm_head_params(cfg, params)
 
     def next_token(h, rng):
         # h: [b, t, d] hidden states; head only the final position
-        logits = head.apply({"params": lm_params}, h[:, -1:])[:, 0]
+        logits = _apply_lm_head(cfg, lm_params, h[:, -1:])[:, 0]
         if axis_size_or_none(cfg.model_axis) is not None:
             return _sample_sharded(
                 logits, rng, temperature, top_k, top_p, cfg.model_axis

@@ -37,9 +37,12 @@ from tpu_parallel.models.layers import (
     Block,
     BlockStack,
     Embedding,
+    ExpertsSpec,
+    LayerSpec,
     RelativePositionBias,
     TransformerConfig,
     make_norm,
+    tied_logits,
 )
 from tpu_parallel.parallel import fsdp, pp
 from tpu_parallel.parallel.tp import TPDense
@@ -108,6 +111,23 @@ def _make_lm_head(
     )
 
 
+def _apply_lm_head(cfg: "GPTConfig", lm_params, hidden, gather: bool = False):
+    """The output head over ``hidden`` from :func:`_lm_head_params`' tree:
+    the tied token embedding where ``cfg.tie_embeddings``, else the
+    ``lm_head`` projection (``gather`` all-gathers a model-sharded
+    vocabulary)."""
+    if cfg.tie_embeddings:
+        return tied_logits(cfg, lm_params["embedding"], hidden)
+    head = _make_lm_head(cfg, name=None, gather=gather, fsdp_wrap=False)
+    return head.apply({"params": lm_params}, hidden)
+
+
+def lm_logits(cfg: "GPTConfig", params, hidden, gather: bool = False):
+    """The output head applied from ``params`` outside the model (the
+    serving engine's one-position reads)."""
+    return _apply_lm_head(cfg, _lm_head_params(cfg, params), hidden, gather)
+
+
 def _lm_head_params(cfg: "GPTConfig", params):
     """The lm_head param subtree, FSDP-gathered ONCE when sharded.
 
@@ -119,6 +139,10 @@ def _lm_head_params(cfg: "GPTConfig", params):
     (plain ``generate`` on exported params) or ``cfg.fsdp`` is off."""
     from tpu_parallel.parallel.tp import axis_size_or_none
 
+    if cfg.tie_embeddings:
+        if cfg.fsdp:
+            raise NotImplementedError("tie_embeddings with fsdp")
+        return params["embed"]["tok"]
     lm = params["lm_head"]
     if cfg.fsdp and axis_size_or_none(cfg.data_axis) is not None:
         lm = fsdp.gather_params(lm, cfg.data_axis)
@@ -183,9 +207,8 @@ class GPTLM(nn.Module):
                 counter.value + jnp.arange(tokens.shape[1])[None, :], tokens.shape
             )
             counter.value = counter.value + tokens.shape[1]
-        x = fsdp.maybe_shard(Embedding, cfg)(cfg, name="embed")(
-            tokens, positions=positions
-        )
+        embed = fsdp.maybe_shard(Embedding, cfg)(cfg, name="embed")
+        x = embed(tokens, positions=positions)
 
         attn_bias = None
         if cfg.positional == "relative":
@@ -312,6 +335,10 @@ class GPTLM(nn.Module):
         # an fp32 cast here would only double the largest tensor in the
         # program (see token_cross_entropy, which upcasts inside the
         # reductions instead).
+        if cfg.tie_embeddings:
+            if cfg.fsdp:
+                raise NotImplementedError("tie_embeddings with fsdp")
+            return embed(x, attend=True)
         return _make_lm_head(cfg)(x)
 
 
@@ -327,7 +354,6 @@ def make_ce_fn(config: GPTConfig):
     from tpu_parallel.core.losses import vocab_parallel_cross_entropy
 
     chunk = config.loss_chunk
-    head = _make_lm_head(config, name=None, gather=False, fsdp_wrap=False)
 
     def ce_block(lm_params, h, targets, mask):
         """lm_head + CE + accuracy on one block of hidden states; returns
@@ -338,7 +364,7 @@ def make_ce_fn(config: GPTConfig):
         # stable names for the step's second bottleneck: this head is
         # unnamed (applied outside the model), so it gets no module scope
         with jax.named_scope("lm_head"):
-            logits = head.apply({"params": lm_params}, h)
+            logits = _apply_lm_head(config, lm_params, h)
         with jax.named_scope("cross_entropy"):
             if axis_size_or_none(config.model_axis) is not None:
                 ce, pred = vocab_parallel_cross_entropy(
@@ -782,6 +808,62 @@ def bert_base_hf(**overrides) -> GPTConfig:
             ),
             **overrides,
         }
+    )
+
+
+def parallel_experts_decoder(
+    *,
+    window: int,
+    experts: ExpertsSpec,
+    window_layers: int = 3,
+    **overrides,
+) -> GPTConfig:
+    """A decoder of parallel blocks (attention and experts from one
+    bias-free LayerNorm, added to the residual together) whose period is
+    ``window_layers`` sliding-window layers with rotary positions and then
+    one full-attention layer with no positions at all; every MLP is the
+    dropless ``experts`` layer; the head is the token embedding.  Sizes
+    (``d_model``, ``n_heads``, ``n_kv_heads``, ``head_dim``, ``n_layers``,
+    ``vocab_size``, ``seq_len``, ``rope_theta``) come as ``overrides``."""
+    local = LayerSpec("window", window, "rope", "experts", experts)
+    full = LayerSpec("full", 0, "none", "experts", experts)
+    return GPTConfig(
+        **{
+            **dict(
+                positional="rope",
+                norm="layernorm_nobias",
+                dense_bias=False,
+                parallel_block=True,
+                tie_embeddings=True,
+                scan_layers=False,
+                layer_pattern=(local,) * window_layers + (full,),
+            ),
+            **overrides,
+        }
+    )
+
+
+def tiny_parallel_experts(**overrides) -> GPTConfig:
+    """``parallel_experts_decoder`` at CPU-test size: two periods, window 8,
+    16 sigmoid-routed experts top-4 of which 4 are held, 2 shared."""
+    experts = overrides.pop(
+        "experts",
+        ExpertsSpec(
+            n_experts=16, top_k=4, width=48, score="sigmoid", shared=2,
+            held=(4, 4),
+        ),
+    )
+    return parallel_experts_decoder(
+        window=overrides.pop("window", 8),
+        experts=experts,
+        **{
+            **dict(
+                vocab_size=256, d_model=32, n_layers=8, n_heads=4,
+                n_kv_heads=1, head_dim=16, seq_len=40, rope_theta=50000.0,
+                dtype=jnp.float32, remat=False,
+            ),
+            **overrides,
+        },
     )
 
 

@@ -16,6 +16,7 @@ transformer configs (GPT-2 125M/350M, Llama-style 1B).  TPU-first choices:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Callable, Optional, Tuple
@@ -31,6 +32,40 @@ from tpu_parallel.parallel.tp import TPDense, axis_size_or_none
 
 
 @dataclasses.dataclass(frozen=True)
+class ExpertsSpec:
+    """A layer's routed experts (``models/moe.py::RoutedExperts``): dropless
+    top-k routing over ``n_experts``, of which this program holds ``held =
+    (first, count)`` (None = all of them).  The router always scores all
+    ``n_experts`` and normalises over its true top-k; the layer adds only
+    what its held experts give."""
+
+    n_experts: int
+    top_k: int
+    width: int  # hidden width of one expert, routed or shared
+    score: str = "softmax"  # "softmax" | "sigmoid" over the router's logits
+    shared: int = 0  # shared experts beside the routed ones, their mean added
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One block's kind.  ``TransformerConfig.layer_pattern`` holds one period
+    of these, repeated over the depth; a model whose blocks are all alike is
+    the one-entry case (:meth:`TransformerConfig.layer_specs` derives it)."""
+
+    attn: str = "full"  # "full" | "window"
+    window: int = 0  # keys a query sees, itself included ("window" only)
+    positions: str = "model"  # "model" (config.positional) | "rope" | "none"
+    mlp: str = "dense"  # "dense" | "experts"
+    # None with mlp="experts": the config's capacity-routed moe_* experts
+    experts: Optional[ExpertsSpec] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     """Architecture + parallelism knobs for the transformer family."""
 
@@ -42,8 +77,14 @@ class TransformerConfig:
     # Q heads are grouped onto the K/V heads after RoPE — natively (no K/V
     # expansion) on the flash and decode paths, by repetition elsewhere.
     n_kv_heads: Optional[int] = None
+    # width of one head; None = d_model // n_heads (a share of a wider model
+    # holds fewer heads than d_model / head_dim, so the size is stated)
+    head_dim: Optional[int] = None
     seq_len: int = 1024
     mlp_ratio: int = 4
+    # hidden width of the dense MLP (and of a capacity-routed expert);
+    # None = mlp_ratio * d_model
+    mlp_dim: Optional[int] = None
     dropout_rate: float = 0.0
     dtype: Any = jnp.bfloat16
     # positional encoding: "learned" (GPT-2), "rope" (Llama), or "relative"
@@ -55,7 +96,8 @@ class TransformerConfig:
     # T5 relative-bias shape knobs (used when positional="relative")
     rel_num_buckets: int = 32
     rel_max_distance: int = 128
-    # norm: "layernorm" (GPT-2) or "rmsnorm" (Llama)
+    # norm: "layernorm" (GPT-2), "layernorm_nobias" (scale only) or
+    # "rmsnorm" (Llama)
     norm: str = "layernorm"
     # norm placement: True = pre-norm (GPT/Llama/T5: x + f(norm(x)), final
     # norm after the stack); False = post-norm (original BERT:
@@ -200,10 +242,61 @@ class TransformerConfig:
     # different drop choices under pressure.  topk router only
     # (expert_choice needs global top-capacity; it stays dense).
     moe_dispatch: str = "dense"
+    # one period of block kinds, repeated over the depth (n_layers is a
+    # whole number of periods); None = every block alike, its kind read off
+    # attn_window / positional / moe_experts
+    layer_pattern: Optional[Tuple[LayerSpec, ...]] = None
+    # parallel block: attention and MLP read ONE norm of the residual and
+    # are added to it together (x + attn(h) + mlp(h))
+    parallel_block: bool = False
+    # output head tied to the token embedding (logits = h E^T * logit_scale)
+    tie_embeddings: bool = False
+    logit_scale: float = 1.0
+    # fresh-cache prefill attends within the call through the flash kernels
+    # instead of reading the whole cache stripe back.  Right-padded aligned
+    # rows only (token j at position j): the serving engine's bucketed
+    # prefill; left-padded ragged generate() refuses it.
+    prefill_flash: bool = False
+
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
+        if self.mlp_dim is None:
+            object.__setattr__(self, "mlp_dim", self.mlp_ratio * self.d_model)
+        if self.layer_pattern is not None:
+            if not self.layer_pattern or self.n_layers % len(self.layer_pattern):
+                raise ValueError(
+                    f"n_layers={self.n_layers} is not a whole number of "
+                    f"periods of {len(self.layer_pattern or ())} layers"
+                )
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def layer_specs(self) -> Tuple[LayerSpec, ...]:
+        """One period of block kinds (one entry for a uniform model)."""
+        if self.layer_pattern is not None:
+            return self.layer_pattern
+        return (
+            LayerSpec(
+                attn="window" if self.attn_window else "full",
+                window=self.attn_window,
+                mlp="experts" if self.moe_experts > 0 else "dense",
+            ),
+        )
+
+    @property
+    def routed_layers(self) -> int:
+        """Layers whose MLP is a dropless :class:`ExpertsSpec` layer."""
+        period = self.layer_specs
+        per = sum(1 for s in period if s.experts is not None)
+        return per * (self.n_layers // len(period))
+
+    @property
+    def drops_tokens(self) -> bool:
+        """Whether some layer routes with a capacity (a token's output then
+        depends on its batch-mates: no serving comparison can accept it)."""
+        return any(
+            s.mlp == "experts" and s.experts is None for s in self.layer_specs
+        )
 
 
 def seq_parallel_active(config: TransformerConfig) -> bool:
@@ -218,7 +311,10 @@ def make_norm(config: TransformerConfig, name: str):
     """fp32 norm (LayerNorm or RMSNorm) — small, precision-critical."""
     if config.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=config.norm_eps, dtype=jnp.float32, name=name)
-    return nn.LayerNorm(epsilon=config.norm_eps, dtype=jnp.float32, name=name)
+    return nn.LayerNorm(
+        epsilon=config.norm_eps, dtype=jnp.float32, name=name,
+        use_bias=config.norm != "layernorm_nobias",
+    )
 
 
 def apply_rope(
@@ -543,6 +639,28 @@ class Attention(nn.Module):
     config: TransformerConfig
     # injected attention implementation; defaults resolved in __call__
     attn_fn: Optional[Callable] = None
+    # this layer's kind (window, positions); None = the uniform model's
+    spec: Optional[LayerSpec] = None
+
+    @property
+    def window(self) -> int:
+        spec = self.spec or self.config.layer_specs[0]
+        return spec.window if spec.attn == "window" else 0
+
+    def _scope(self):
+        """A model of several layer kinds names each kind's attention for
+        the device trace (``attn.window`` / ``attn.full``); a uniform
+        model's ops keep the names they had."""
+        if self.spec is None:
+            return contextlib.nullcontext()
+        return jax.named_scope(f"attn.{self.spec.attn}")
+
+    @property
+    def rotary(self) -> bool:
+        positions = (self.spec or self.config.layer_specs[0]).positions
+        if positions == "model":
+            return self.config.positional == "rope"
+        return positions == "rope"
 
     @nn.compact
     def __call__(
@@ -583,7 +701,7 @@ class Attention(nn.Module):
                 )
         if n_kv == cfg.n_heads:
             qkv = TPDense(
-                features=3 * cfg.d_model,
+                features=3 * cfg.n_heads * cfg.head_dim,
                 axis_name=cfg.model_axis,
                 style="column",
                 use_bias=cfg.dense_bias,
@@ -669,6 +787,15 @@ class Attention(nn.Module):
                 kv_store = (b, cfg.seq_len, local_kv, cfg.head_dim)
                 scale_store = (b, cfg.seq_len, local_kv, 1)
                 pos_store = (b, cfg.seq_len)
+            # a prefill that CREATES its cache holds every key it may read in
+            # this call: with prefill_flash it attends through the kernels
+            # and never reads the seq_len-long stripe back
+            fresh_prefill = (
+                cfg.prefill_flash
+                and x.shape[1] > 1
+                and write_index is None
+                and not self.has_variable("cache", "cached_key")
+            )
             # cache at K/V-head width (local_kv): under GQA this is the whole
             # point — n_heads/n_kv less cache HBM; decode_attention contracts
             # grouped queries against it directly (no expansion)
@@ -722,7 +849,7 @@ class Attention(nn.Module):
                 positions = jnp.broadcast_to(
                     idx + jnp.arange(x.shape[1])[None, :], x.shape[:2]
                 )
-        if cfg.positional == "rope":
+        if self.rotary:
             if positions is None:
                 local = jnp.arange(x.shape[1])
                 if seq_parallel_active(cfg):
@@ -850,8 +977,15 @@ class Attention(nn.Module):
                 beam_src.value = keep(new_src, beam_src.value)
                 out = beam_decode_attention(
                     q, k_all, v_all, positions, new_src, cfg.beam_width,
-                    window=cfg.attn_window, bias=attn_bias, k_positions=new_p,
+                    window=self.window, bias=attn_bias, k_positions=new_p,
                 )
+            elif fresh_prefill:
+                if quant_cache or paged or attn_bias is not None:
+                    raise NotImplementedError(
+                        "prefill_flash with an int8 / paged cache or a "
+                        "score bias"
+                    )
+                out = self._attend(q, k, v, None, impl="flash")
             else:
                 k_pos = new_p
                 if paged:
@@ -879,11 +1013,12 @@ class Attention(nn.Module):
                     k_pos = jnp.where(mapped, pages(new_p), -1)
                 # decode_attention contracts grouped queries against the
                 # kv-width cache directly — no K/V expansion
-                out = decode_attention(
-                    q, k_all, v_all, positions, window=cfg.attn_window,
-                    bias=attn_bias, k_positions=k_pos,
-                    k_scale=k_scale, v_scale=v_scale,
-                )
+                with self._scope():
+                    out = decode_attention(
+                        q, k_all, v_all, positions, window=self.window,
+                        bias=attn_bias, k_positions=k_pos,
+                        k_scale=k_scale, v_scale=v_scale,
+                    )
         else:
             out = self._attend(q, k, v, segment_ids, attn_bias)
         if cfg.attn_impl != "flash":
@@ -907,8 +1042,10 @@ class Attention(nn.Module):
             out = nn.Dropout(rate=cfg.dropout_rate, deterministic=not train)(out)
         return out
 
-    def _attend(self, q, k, v, segment_ids, attn_bias=None):
+    def _attend(self, q, k, v, segment_ids, attn_bias=None, impl=None):
         cfg = self.config
+        if impl is not None and impl != cfg.attn_impl:
+            cfg = dataclasses.replace(cfg, attn_impl=impl)
         if attn_bias is not None and cfg.attn_impl != "xla":
             # the Pallas/ring/ulysses kernels take no additive score bias;
             # T5-style models must run the xla attention path
@@ -940,7 +1077,7 @@ class Attention(nn.Module):
                 attn_fn = functools.partial(
                     bidirectional_flash_attention,
                     block_q=cfg.flash_block_q, block_k=cfg.flash_block_k,
-                    window=cfg.attn_window,
+                    window=self.window,
                 )
             elif cfg.attn_impl == "flash":
                 from tpu_parallel.ops.flash_attention import flash_attention
@@ -949,7 +1086,7 @@ class Attention(nn.Module):
                     flash_attention,
                     block_q=cfg.flash_block_q,
                     block_k=cfg.flash_block_k,
-                    window=cfg.attn_window,
+                    window=self.window,
                 )
             elif cfg.attn_impl == "ring":
                 from tpu_parallel.ops.ring_attention import (
@@ -969,7 +1106,7 @@ class Attention(nn.Module):
                             q, k, v, axis_name=cfg.seq_axis,
                             block_q=cfg.flash_block_q,
                             block_k=cfg.flash_block_k,
-                            window=cfg.attn_window,
+                            window=self.window,
                             segment_ids=segment_ids,
                             causal=not cfg.bidirectional,
                         )
@@ -979,7 +1116,7 @@ class Attention(nn.Module):
                     def attn_fn(q, k, v, segment_ids=None):
                         return ring_attention(
                             q, k, v, axis_name=cfg.seq_axis,
-                            window=cfg.attn_window,
+                            window=self.window,
                             segment_ids=segment_ids,
                             causal=not cfg.bidirectional,
                         )
@@ -996,14 +1133,14 @@ class Attention(nn.Module):
                         bidirectional_flash_attention,
                         block_q=cfg.flash_block_q,
                         block_k=cfg.flash_block_k,
-                        window=cfg.attn_window,
+                        window=self.window,
                     )
                 else:
                     inner = functools.partial(
                         flash_attention,
                         block_q=cfg.flash_block_q,
                         block_k=cfg.flash_block_k,
-                        window=cfg.attn_window,
+                        window=self.window,
                     )
 
                 def attn_fn(q, k, v, segment_ids=None):
@@ -1021,10 +1158,11 @@ class Attention(nn.Module):
 
             else:
                 attn_fn = functools.partial(
-                    causal_attention, window=cfg.attn_window,
+                    causal_attention, window=self.window,
                     causal=not cfg.bidirectional, bias=attn_bias,
                 )
-        return attn_fn(q, k, v, segment_ids=segment_ids)
+        with self._scope():
+            return attn_fn(q, k, v, segment_ids=segment_ids)
 
 
 class MLP(nn.Module):
@@ -1042,7 +1180,7 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, train: bool = True) -> jax.Array:
         cfg = self.config
-        hidden = cfg.mlp_ratio * cfg.d_model
+        hidden = cfg.mlp_dim
         gated = cfg.mlp in ("swiglu", "geglu")
         if gated:
             gate = TPDense(
@@ -1082,9 +1220,12 @@ class MLP(nn.Module):
 
 
 class Block(nn.Module):
-    """Pre-norm transformer block: x + attn(norm(x)); x + mlp(norm(x))."""
+    """Pre-norm transformer block: x + attn(norm(x)); x + mlp(norm(x)).
+    ``config.parallel_block``: x + attn(h) + mlp(h), both from h = norm(x).
+    ``spec`` is this layer's kind (None: the uniform model's one kind)."""
 
     config: TransformerConfig
+    spec: Optional[LayerSpec] = None
 
     @nn.compact
     def __call__(
@@ -1101,7 +1242,9 @@ class Block(nn.Module):
         block_table: Optional[jax.Array] = None,
     ) -> jax.Array:
         cfg = self.config
-        if decode and cfg.moe_experts > 0 and cfg.moe_router == "expert_choice":
+        spec = self.spec or cfg.layer_specs[0]
+        capacity_experts = spec.mlp == "experts" and spec.experts is None
+        if decode and capacity_experts and cfg.moe_router == "expert_choice":
             # EC routes over the whole token pool; a single-token decode
             # step degenerates to a dense all-expert mixture that resembles
             # nothing the model trained on — refuse loudly
@@ -1109,15 +1252,21 @@ class Block(nn.Module):
                 "incremental decoding with expert-choice routing "
                 "(the routing pool collapses to one token per row)"
             )
-        attn = Attention(cfg, name="attn")
+        attn = Attention(cfg, spec=self.spec, name="attn")
         mlp_fn = (
             lambda h: MLP(cfg, name="mlp")(h, train=train)
         )
-        if cfg.moe_experts > 0:
+        if capacity_experts:
             from tpu_parallel.models.moe import MoEMLP
 
             mlp_fn = lambda h: MoEMLP(cfg, name="moe")(
                 h, train=train, aux_scale=aux_scale
+            )
+        elif spec.mlp == "experts":
+            from tpu_parallel.models.moe import RoutedExperts
+
+            mlp_fn = lambda h: RoutedExperts(cfg, spec.experts, name="moe")(
+                h, valid=None if positions is None else positions >= 0
             )
         attn_kwargs = dict(
             positions=positions,
@@ -1129,7 +1278,12 @@ class Block(nn.Module):
             write_index=write_index,
             block_table=block_table,
         )
-        if cfg.prenorm:
+        if cfg.parallel_block:
+            if not cfg.prenorm:
+                raise ValueError("parallel_block is a pre-norm block")
+            h = make_norm(cfg, "norm")(x).astype(cfg.dtype)
+            x = x + attn(h, **attn_kwargs) + mlp_fn(h)
+        elif cfg.prenorm:
             h = make_norm(cfg, "norm_attn")(x).astype(cfg.dtype)
             x = x + attn(h, **attn_kwargs)
             h = make_norm(cfg, "norm_mlp")(x).astype(cfg.dtype)
@@ -1170,9 +1324,11 @@ class _ScanBlock(nn.Module):
             x, positions, segment_ids, aux_scale, cache_valid, attn_bias,
             write_index, block_table,
         ) = carry
+        period = self.config.layer_specs
         for j in range(self.group):
             name = "block" if self.group == 1 else f"block{j}"
-            x = self.block_cls(self.config, name=name)(
+            spec = period[j % len(period)] if len(period) > 1 else None
+            x = self.block_cls(self.config, spec, name=name)(
                 x,
                 positions=positions,
                 segment_ids=segment_ids,
@@ -1245,6 +1401,12 @@ class BlockStack(nn.Module):
         block_table: Optional[jax.Array] = None,
     ) -> jax.Array:
         cfg = self.config
+        period = cfg.layer_specs
+        if self.n_layers % len(period) != 0:
+            raise ValueError(
+                f"a stack of {self.n_layers} layers is not a whole number "
+                f"of periods of {len(period)}"
+            )
         remat_kwargs = remat_kwargs_for(cfg)
         # ZeRO-3 over the layers themselves: each tick (scan) or layer
         # (unrolled) gathers ITS params just-in-time and the backward
@@ -1278,6 +1440,12 @@ class BlockStack(nn.Module):
 
                 x = pvary_missing(x, (cfg.model_axis,))
             group = max(1, cfg.scan_group)
+            if group % len(period) != 0:
+                raise ValueError(
+                    f"scan_group={group} must hold whole periods of "
+                    f"{len(period)} layer kinds (the scanned body is one "
+                    "period or several)"
+                )
             if self.n_layers % group != 0:
                 raise ValueError(
                     f"scan_group={group} must divide n_layers={self.n_layers}"
@@ -1314,29 +1482,55 @@ class BlockStack(nn.Module):
                 else base_block
             )
             for i in range(self.n_layers):
-                x = block_cls(cfg, name=f"layer_{i}")(
+                spec = period[i % len(period)] if len(period) > 1 else None
+                x = block_cls(cfg, spec, name=f"layer_{i}")(
                     x, positions, segment_ids, train, decode, aux_scale,
                     cache_valid, attn_bias, write_index, block_table,
                 )
         return x
 
 
+def tied_logits(config: TransformerConfig, table: jax.Array, hidden):
+    """``hidden E^T * logit_scale`` against the token embedding ``table``
+    ``[vocab, d_model]``: the output head of ``tie_embeddings`` models."""
+    if axis_size_or_none(config.model_axis) is not None:
+        raise NotImplementedError(
+            "tie_embeddings under a bound model axis (the tied table is not "
+            "vocabulary-sharded)"
+        )
+    logits = jnp.einsum(
+        "...d,vd->...v", hidden.astype(config.dtype),
+        jnp.asarray(table, config.dtype),
+    )
+    if config.logit_scale != 1.0:
+        logits = logits * config.logit_scale
+    return logits
+
+
 class Embedding(nn.Module):
-    """Token (+ learned positional) embedding, bf16 output."""
+    """Token (+ learned positional) embedding, bf16 output; with
+    ``attend=True`` the tied output head over a hidden state."""
 
     config: TransformerConfig
 
     @nn.compact
     def __call__(
-        self, tokens: jax.Array, positions: Optional[jax.Array] = None
+        self,
+        tokens: jax.Array,
+        positions: Optional[jax.Array] = None,
+        attend: bool = False,
     ) -> jax.Array:
         cfg = self.config
-        emb = nn.Embed(
+        tok = nn.Embed(
             num_embeddings=cfg.vocab_size,
             features=cfg.d_model,
             dtype=cfg.dtype,
             name="tok",
-        )(tokens)
+        )
+        if attend:
+            # the tied output head: ``tokens`` is the final hidden state
+            return tied_logits(cfg, tok.embedding, tokens)
+        emb = tok(tokens)
         if cfg.positional == "learned":
             if positions is None:
                 local = jnp.arange(tokens.shape[1])

@@ -30,9 +30,18 @@ framework's EP slot, TPU-first:
 Works mesh-free too (no bound model axis): all experts live on the one
 device, no slicing, no psum — same module, same params layout rules as the
 rest of the structural-TP design.
+
+:class:`RoutedExperts` is the DROPLESS layer (serving, and one chip's share
+of a deployment): assignments sorted by expert, grouped matmuls whose work
+follows the rows routed here, a buffer for the worst case, shared experts
+beside the routed ones, and a ``held`` range of the experts.  :class:`MoEMLP`
+above it keeps the capacity formulation for the training configurations
+that use it; the serving engine refuses a model that would drop.
 """
 
 from __future__ import annotations
+
+import functools
 
 import flax.linen as nn
 import jax
@@ -57,7 +66,7 @@ class ExpertFFN(nn.Module):
         from jax.ad_checkpoint import checkpoint_name
 
         cfg = self.config
-        hidden = cfg.mlp_ratio * cfg.d_model
+        hidden = cfg.mlp_dim
         if cfg.mlp == "swiglu":
             gate = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype, name="gate")(x)
             up = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype, name="up")(x)
@@ -390,3 +399,232 @@ class MoEMLP(nn.Module):
         if cfg.dropout_rate > 0.0:
             y = nn.Dropout(rate=cfg.dropout_rate, deterministic=not train)(y)
         return y
+
+
+# --- dropless routing ---------------------------------------------------------
+
+# collection the dropless layer sows its per-call row counts into
+MOE_STATS = "moe_stats"
+# below this many assignments one buffer serves; above it, a quarter-size
+# buffer runs whenever the rows routed here fit it (twice the 1/8 a share of
+# an eighth expects), and the worst-case buffer otherwise
+SMALL_BUFFER_MIN_ROWS = 2048
+
+
+def moe_plan(spec, tokens: int, ep_size: int = 1) -> dict:
+    """What a dropless layer does with ``tokens`` rows: experts held of how
+    many, top-k, the worst-case buffer (every token on ``min(top_k, held)``
+    held experts) and the small buffer that runs whenever the rows routed
+    here fit it.  :class:`RoutedExperts` sizes its buffers from this; the
+    serving engine logs it at build for each of its program shapes, as
+    ``flash_plan`` is for the attention kernels.  The grouped matmuls'
+    tiles are the compiler's (``lax.ragged_dot``) and are not in it."""
+    held = spec.held_range[1] // ep_size
+    worst = tokens * min(spec.top_k, held)
+    small = worst
+    if worst >= SMALL_BUFFER_MIN_ROWS:
+        small = -(-worst // 4 // 128) * 128
+    return dict(
+        experts=spec.n_experts, held=held, top_k=spec.top_k,
+        shared=spec.shared, width=spec.width, score=spec.score,
+        tokens=tokens, buffer_rows=worst, small_buffer_rows=small,
+    )
+
+
+def expert_rows(variables):
+    """``[layers, held + 1]`` rows routed to each held expert by one apply
+    (last column: assignments to experts held elsewhere), from the
+    ``moe_stats`` collection it returned; None for a model without a
+    dropless layer."""
+    leaves = jax.tree_util.tree_leaves(variables.get(MOE_STATS, {}))
+    if not leaves:
+        return None
+    return jnp.concatenate([x.reshape(-1, x.shape[-1]) for x in leaves])
+
+
+# stacked ``[experts, in, out]`` weights, each expert drawn at its own fan-in
+_EXPERT_INIT = nn.initializers.variance_scaling(
+    1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,)
+)
+
+
+class _Stacked(nn.Module):
+    """``[experts, in, out]`` weights as a ``kernel`` of their own, so that
+    whatever reads a parameter tree by name (an initialiser by fan-in, a
+    re-layout) sees a matrix where there is one."""
+
+    shape: tuple
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param("kernel", _EXPERT_INIT, self.shape)
+
+
+class _HeldExperts(nn.Module):
+    """The held experts' weights ``(gate, up, down)``, each expert
+    ``W_down(silu(W_gate x) * (W_up x))``; a module of its own so that
+    :class:`~tpu_parallel.parallel.tp.ModuleShard` can give every rank of
+    an expert axis its part."""
+
+    n_local: int
+    d_model: int
+    width: int
+    dtype: "jnp.dtype"
+
+    @nn.compact
+    def __call__(self):
+        shape_in = (self.n_local, self.d_model, self.width)
+        return tuple(
+            _Stacked(shape, name=name)().astype(self.dtype)
+            for name, shape in (
+                ("gate", shape_in), ("up", shape_in),
+                ("down", (self.n_local, self.width, self.d_model)),
+            )
+        )
+
+
+def _grouped_ffn(rows, weights, group_sizes):
+    """Every expert's FFN over its own run of ``rows`` (sorted by expert);
+    rows past the groups are not computed and hold nothing."""
+    w_gate, w_up, w_down = weights
+    gate = lax.ragged_dot(rows, w_gate, group_sizes)
+    up = lax.ragged_dot(rows, w_up, group_sizes)
+    return lax.ragged_dot(nn.silu(gate) * up, w_down, group_sizes)
+
+
+class RoutedExperts(nn.Module):
+    """Dropless top-k experts, shared experts beside them, told which experts
+    it holds.
+
+    ``y = sum_{e in top-k, e held} w_e E_e(x) + mean_i S_i(x)`` with ``w`` the
+    router's scores (softmax or sigmoid over ALL ``n_experts``) renormalised
+    over the token's true top-k.  What the experts held elsewhere would have
+    added is left out: on one chip the layer runs without its exchange.  Under
+    a bound model axis each rank holds an equal part of the held range and the
+    routed sum closes with the ``psum`` the capacity layer has; router and
+    shared experts are replicated and counted once.
+
+    Assignments are sorted by expert with those of absent experts behind the
+    held ones; the grouped matmuls (``lax.ragged_dot``: on the TPU a kernel
+    that walks only the tiles its groups fill) do work for the rows routed
+    here, while the buffer holds the worst case - every token on
+    ``min(top_k, held)`` held experts - so that no imbalance drops a token.
+    """
+
+    config: "TransformerConfig"  # noqa: F821
+    spec: "ExpertsSpec"  # noqa: F821
+
+    @nn.compact
+    def __call__(
+        self, x: jax.Array, valid: jax.Array | None = None
+    ) -> jax.Array:
+        """``valid`` ``[b, s]`` marks the rows that are tokens (a padded
+        prefill's pad rows are routed and computed like any row, and left
+        out of the row counts)."""
+        cfg, es = self.config, self.spec
+        first, count = es.held_range
+        if not (0 <= first and first + count <= es.n_experts and count > 0):
+            raise ValueError(f"held={es.held} outside 0..{es.n_experts}")
+        if not 1 <= es.top_k <= es.n_experts:
+            raise ValueError(f"top_k={es.top_k} of {es.n_experts} experts")
+        ep_size = axis_size_or_none(cfg.model_axis) or 1
+        if count % ep_size:
+            raise ValueError(
+                f"{count} held experts not divisible by model axis {ep_size}"
+            )
+        n_local = count // ep_size
+        if ep_size > 1:
+            first = first + lax.axis_index(cfg.model_axis) * n_local
+        b, s, d = x.shape
+        tokens, k = b * s, es.top_k
+        xf = x.reshape(tokens, d)
+
+        with jax.named_scope("moe.router"):
+            logits = nn.Dense(
+                es.n_experts, use_bias=False, dtype=jnp.float32, name="router"
+            )(xf.astype(jnp.float32))
+            if es.score == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+            elif es.score == "softmax":
+                scores = jax.nn.softmax(logits, axis=-1)
+            else:
+                raise ValueError(f"score={es.score!r} (softmax | sigmoid)")
+            top_s, top_e = lax.top_k(scores, k)  # [T, k]
+            weights = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+
+        with jax.named_scope("moe.experts"):
+            local = top_e.reshape(-1) - first  # [T * k]
+            here = (local >= 0) & (local < n_local)
+            key = jnp.where(here, local, n_local)  # absent behind the held
+            order = jnp.argsort(key, stable=True)  # assignments by expert
+            place = jnp.argsort(order)  # where each assignment's row went
+            sizes = jnp.sum(
+                key[:, None] == jnp.arange(n_local + 1)[None, :], axis=0,
+                dtype=jnp.int32,
+            )
+            counted = sizes
+            if valid is not None:
+                real = jnp.repeat(valid.reshape(-1), k)
+                counted = jnp.sum(
+                    (key[:, None] == jnp.arange(n_local + 1)[None, :])
+                    & real[:, None], axis=0, dtype=jnp.int32,
+                )
+            self.sow(
+                MOE_STATS, "rows", counted,
+                reduce_fn=lambda a, b_: a + b_,
+                init_fn=lambda: jnp.zeros((n_local + 1,), jnp.int32),
+            )
+            group_sizes, routed = sizes[:n_local], tokens * k - sizes[n_local]
+            expert_weights = ModuleShard(
+                functools.partial(
+                    _HeldExperts, n_local, d, es.width, cfg.dtype
+                ),
+                axis_name=cfg.model_axis,
+                name="experts",
+            )()
+            mix = jnp.where(here, weights.reshape(-1), 0.0)
+
+            def run(cap: int) -> jax.Array:
+                """The routed sum through a buffer of ``cap`` rows (the held
+                assignments are its first ``routed``).  Gathers only: no
+                scatter-add, whose order of summation is not fixed."""
+                rows = xf[order[:cap] // k].astype(cfg.dtype)
+                out = _grouped_ffn(rows, expert_weights, group_sizes)
+                back = out[jnp.minimum(place, cap - 1)]  # [T * k, d]
+                back = jnp.where(here[:, None], back.astype(jnp.float32), 0.0)
+                return jnp.sum(
+                    back.reshape(tokens, k, d) * mix.reshape(tokens, k, 1),
+                    axis=1,
+                )
+
+            plan = moe_plan(es, tokens, ep_size)
+            worst, small = plan["buffer_rows"], plan["small_buffer_rows"]
+            if small == worst:
+                y = run(worst)
+            else:
+                y = lax.cond(
+                    routed <= small, lambda: run(small), lambda: run(worst)
+                )
+            if ep_size > 1:
+                with jax.named_scope("moe_combine_psum"):
+                    y = lax.psum(y, cfg.model_axis)
+
+        if es.shared:
+            with jax.named_scope("moe.shared"):
+                w_gate, w_up, w_down = (
+                    _Stacked(shape, name=name)().astype(cfg.dtype)
+                    for name, shape in (
+                        ("shared_gate", (es.shared, d, es.width)),
+                        ("shared_up", (es.shared, d, es.width)),
+                        ("shared_down", (es.shared, es.width, d)),
+                    )
+                )
+                h = xf.astype(cfg.dtype)
+                mid = nn.silu(jnp.einsum("td,edw->tew", h, w_gate)) * (
+                    jnp.einsum("td,edw->tew", h, w_up)
+                )
+                y = y + jnp.einsum(
+                    "tew,ewd->td", mid, w_down,
+                    preferred_element_type=jnp.float32,
+                ) / es.shared
+        return y.astype(cfg.dtype).reshape(b, s, d)

@@ -113,6 +113,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import json
+import logging
 import math
 import time
 from typing import (
@@ -277,7 +279,7 @@ def _full_last_logits(cfg, params, hidden, last_idx=None):
     prefill's per-row LAST REAL token — right padding means it is not
     uniformly -1); None reads the final position (decode steps, exact
     prefill)."""
-    from tpu_parallel.models.gpt import _lm_head_params, _make_lm_head
+    from tpu_parallel.models.gpt import lm_logits
     from tpu_parallel.parallel.tp import axis_size_or_none
 
     if last_idx is None:
@@ -288,8 +290,7 @@ def _full_last_logits(cfg, params, hidden, last_idx=None):
             (hidden.shape[0], 1, hidden.shape[2]),
         )
         hidden = jnp.take_along_axis(hidden, idx, axis=1)
-    head = _make_lm_head(cfg, name=None, gather=False, fsdp_wrap=False)
-    logits = head.apply({"params": _lm_head_params(cfg, params)}, hidden)[:, 0]
+    logits = lm_logits(cfg, params, hidden)[:, 0]
     if axis_size_or_none(cfg.model_axis) is not None:
         logits = lax.all_gather(logits, cfg.model_axis, axis=-1, tiled=True)
     return logits
@@ -300,14 +301,19 @@ def _full_logits(cfg, params, hidden):
     vocab width on every rank — the speculative verify needs all T target
     distributions, not just the last (one [batch, T, vocab] all_gather
     under TP; T = draft_tokens + 1, batch = n_slots — still tiny)."""
-    from tpu_parallel.models.gpt import _lm_head_params, _make_lm_head
+    from tpu_parallel.models.gpt import lm_logits
     from tpu_parallel.parallel.tp import axis_size_or_none
 
-    head = _make_lm_head(cfg, name=None, gather=False, fsdp_wrap=False)
-    logits = head.apply({"params": _lm_head_params(cfg, params)}, hidden)
+    logits = lm_logits(cfg, params, hidden)
     if axis_size_or_none(cfg.model_axis) is not None:
         logits = lax.all_gather(logits, cfg.model_axis, axis=-1, tiled=True)
     return logits
+
+
+def _calls(rows):
+    """One apply's expert row counts ``[layers, held + 1]`` as a one-call
+    ``[1, layers, held + 1]`` block (the fused tick's are one a step)."""
+    return None if rows is None else rows[None]
 
 
 def _prefill_core(model, params, prompt, positions, last_idx, rng):
@@ -318,8 +324,11 @@ def _prefill_core(model, params, prompt, positions, last_idx, rng):
     (sampling happens outside so the prefill compiles per SHAPE only, not
     per knob set)."""
     del rng
-    hidden, cache = prefill_step(model, params, prompt, positions)
-    return _full_last_logits(model.config, params, hidden, last_idx), cache
+    hidden, cache, rows = prefill_step(
+        model, params, prompt, positions, with_rows=True
+    )
+    logits = _full_last_logits(model.config, params, hidden, last_idx)
+    return logits, cache, _calls(rows)
 
 
 def _extend_core(
@@ -331,10 +340,11 @@ def _extend_core(
     chunk's last real position's logits (read only for the FINAL chunk)
     + the extended cache."""
     del rng
-    hidden, cache = prefill_extend_step(
-        model, params, cache, tokens, positions, write_start
+    hidden, cache, rows = prefill_extend_step(
+        model, params, cache, tokens, positions, write_start, with_rows=True
     )
-    return _full_last_logits(model.config, params, hidden, last_idx), cache
+    logits = _full_last_logits(model.config, params, hidden, last_idx)
+    return logits, cache, _calls(rows)
 
 
 def _decode_core(
@@ -342,12 +352,12 @@ def _decode_core(
 ):
     """One engine tick over the slot pool: slot-indexed cache writes,
     per-slot sampling.  Returns (next_tokens [n_slots], new cache)."""
-    hidden, cache = decode_step(
-        model, params, cache, tok, pos, write_index=widx
+    hidden, cache, rows = decode_step(
+        model, params, cache, tok, pos, write_index=widx, with_rows=True
     )
     logits = _full_last_logits(model.config, params, hidden)
     nxt = sample_tokens(logits, rng, temperature, top_k, top_p)
-    return nxt, cache
+    return nxt, cache, _calls(rows)
 
 
 def _fused_decode_core(
@@ -374,11 +384,14 @@ def _fused_decode_core(
     without an EOS id (sampled tokens are nonnegative, so -1 never
     matches).
 
-    Returns ``(block [steps, n_slots], counts [n_slots], state, cache)``
-    where ``block`` holds each step's emitted token per slot (-1 where
-    the slot was not live) and ``counts`` is each slot's progress this
-    tick — live steps form a PREFIX of the scan, so the host delivers
+    Returns ``(block [steps, n_slots], counts [n_slots], state, cache,
+    rows)`` where ``block`` holds each step's emitted token per slot (-1
+    where the slot was not live) and ``counts`` is each slot's progress
+    this tick — live steps form a PREFIX of the scan, so the host delivers
     ``block[:counts[s], s]`` through the existing StreamEvent path.
+    ``rows`` ``[steps, layers, held + 1]`` counts the rows each step routed
+    to each held expert (None for a model without a dropless expert layer:
+    the program is then the one it was).
     """
     cfg = model.config
     seq_len = cfg.seq_len
@@ -386,9 +399,9 @@ def _fused_decode_core(
     def body(carry, step_rng):
         tok, pos, widx, live, budget, cache = carry
         widx_eff = jnp.where(live, widx, seq_len)
-        hidden, cache = decode_step(
+        hidden, cache, rows = decode_step(
             model, params, cache, tok, pos, write_index=widx_eff,
-            block_table=table,
+            block_table=table, with_rows=True,
         )
         logits = _full_last_logits(cfg, params, hidden)
         nxt = sample_tokens(logits, step_rng, temp, topk, topp)
@@ -405,9 +418,9 @@ def _fused_decode_core(
         widx = widx + adv
         tok = jnp.where(live, nxt, tok)
         live = live & ~done
-        return (tok, pos, widx, live, budget, cache), emitted
+        return (tok, pos, widx, live, budget, cache), (emitted, rows)
 
-    (tok, pos, widx, live, budget, cache), block = lax.scan(
+    (tok, pos, widx, live, budget, cache), (block, rows) = lax.scan(
         body, (tok, pos, widx, live, budget, cache),
         jax.random.split(rng, steps),
     )
@@ -415,7 +428,7 @@ def _fused_decode_core(
     # which is a PROGRESS signal (the typed failure) even though it is
     # not a token; parked steps emit -1 and live steps never do
     counts = (block != -1).sum(axis=0).astype(jnp.int32)
-    return block, counts, (tok, pos, widx, live, budget), cache
+    return block, counts, (tok, pos, widx, live, budget), cache, rows
 
 
 def _verify_core(
@@ -499,8 +512,9 @@ def _ragged_chunk_phase(
         iota < clen[:, None], cstart[:, None] + iota, -1
     )
     wstart = jnp.where(clen > 0, cstart, seq_len)
-    hidden, cache = prefill_extend_step(
-        model, params, cache, ctoks, positions, wstart, block_table=table
+    hidden, cache, rows = prefill_extend_step(
+        model, params, cache, ctoks, positions, wstart, block_table=table,
+        with_rows=True,
     )
     logits = _full_last_logits(
         cfg, params, hidden, jnp.maximum(clen - 1, 0)
@@ -516,7 +530,7 @@ def _ragged_chunk_phase(
     budget = jnp.where(act, nb, budget)
     live = jnp.where(act, ~done0, live)
     act_emit = jnp.where(act, tok0, -1)
-    return act_emit, (tok, pos, widx, live, budget), cache
+    return act_emit, (tok, pos, widx, live, budget), cache, _calls(rows)
 
 
 def _unified_tick_core(
@@ -534,19 +548,24 @@ def _unified_tick_core(
     same row-parallel argument as the batched bucketed prefill (batch
     composition is invisible to each row; every op is row/position
     parallel).  Returns ``(act_emit [n], block [steps, n], counts [n],
-    state, cache)``.
+    state, cache, rows)`` (``rows``: the chunk phase's expert row counts,
+    then each decode step's).
     """
     rng_act, rng_scan = jax.random.split(rng)
-    act_emit, (tok, pos, widx, live, budget), cache = _ragged_chunk_phase(
-        model, params, tok, pos, widx, live, budget, eos, temp, topk,
-        topp, ctoks, clen, cstart, cfinal, cbudget, cache, rng_act,
-        table=table,
+    act_emit, (tok, pos, widx, live, budget), cache, chunk_rows = (
+        _ragged_chunk_phase(
+            model, params, tok, pos, widx, live, budget, eos, temp, topk,
+            topp, ctoks, clen, cstart, cfinal, cbudget, cache, rng_act,
+            table=table,
+        )
     )
-    block, counts, state, cache = _fused_decode_core(
+    block, counts, state, cache, rows = _fused_decode_core(
         model, params, steps, tok, pos, widx, live, budget, eos, temp,
         topk, topp, cache, rng_scan, table=table,
     )
-    return act_emit, block, counts, state, cache
+    if rows is not None:
+        rows = jnp.concatenate([chunk_rows, rows])
+    return act_emit, block, counts, state, cache, rows
 
 
 def _fused_spec_core(
@@ -692,7 +711,7 @@ def _unified_spec_core(
     verify and acceptance all inside one dispatch."""
     seq_len = model.config.seq_len
     rng_act, rng_scan = jax.random.split(rng)
-    act_emit, (tok, pos, widx, live, budget), cache = _ragged_chunk_phase(
+    act_emit, (tok, pos, widx, live, budget), cache, _ = _ragged_chunk_phase(
         model, params, tok, pos, widx, live, budget, eos, temp, topk,
         topp, ctoks, clen, cstart, cfinal, cbudget, cache, rng_act,
         table=table,
@@ -723,11 +742,12 @@ def _extend_core_paged(
     which is the whole point of paging.  Dummy rows pass an all--1 table
     (every write dropped)."""
     del rng
-    hidden, cache = prefill_extend_step(
+    hidden, cache, rows = prefill_extend_step(
         model, params, cache, tokens, positions, write_start,
-        block_table=table,
+        block_table=table, with_rows=True,
     )
-    return _full_last_logits(model.config, params, hidden, last_idx), cache
+    logits = _full_last_logits(model.config, params, hidden, last_idx)
+    return logits, cache, _calls(rows)
 
 
 def _decode_core_paged(
@@ -738,12 +758,13 @@ def _decode_core_paged(
     (same ``decode_step`` / lm_head / sampler), with cache reads and
     writes routed through the per-slot block tables — greedy output is
     bitwise identical to the fixed-slot layout."""
-    hidden, cache = decode_step(
-        model, params, cache, tok, pos, write_index=widx, block_table=table
+    hidden, cache, rows = decode_step(
+        model, params, cache, tok, pos, write_index=widx, block_table=table,
+        with_rows=True,
     )
     logits = _full_last_logits(model.config, params, hidden)
     nxt = sample_tokens(logits, rng, temperature, top_k, top_p)
-    return nxt, cache
+    return nxt, cache, _calls(rows)
 
 
 @functools.lru_cache(maxsize=8)
@@ -984,18 +1005,23 @@ def _sharded_engine_fns(model, mesh, specs: _HashableTree,
 
     param_specs = specs.tree()
     cspecs = cache_specs.tree()
+    if model.config.routed_layers:
+        raise NotImplementedError(
+            "dropless expert layers under a serving mesh (the sharded "
+            "programs' out_specs carry no expert row counts)"
+        )
     prefill = build_sharded_serving(
-        model, mesh, param_specs, (P(), P(), P()), (P(), cspecs),
+        model, mesh, param_specs, (P(), P(), P()), (P(), cspecs, None),
         _prefill_core, fold_axes=(),
     )
     extend = build_sharded_serving(
         model, mesh, param_specs, (P(), P(), P(), P(), cspecs),
-        (P(), cspecs), _extend_core, fold_axes=(),
+        (P(), cspecs, None), _extend_core, fold_axes=(),
     )
     decode = build_sharded_serving(
         model, mesh, param_specs,
-        (P(), P(), P(), P(), P(), P(), cspecs), (P(), cspecs), _decode_core,
-        fold_axes=(),
+        (P(), P(), P(), P(), P(), P(), cspecs), (P(), cspecs, None),
+        _decode_core, fold_axes=(),
     )
     verify = build_sharded_serving(
         model, mesh, param_specs,
@@ -1033,6 +1059,25 @@ class _ChunkState:
         self.offset = offset
 
 
+class _WithoutExpertRows:
+    """A jitted engine program minus its last output, the dropless expert
+    layers' row counts (None for a model without one): those go, unread,
+    onto ``sink`` for the tick's collect, and no call site changes.
+    Everything else (``_cache_size``, ``lower``) is the program's own."""
+
+    def __init__(self, fn, sink: list):
+        self._fn, self._sink = fn, sink
+
+    def __call__(self, *args):
+        *out, rows = self._fn(*args)
+        if rows is not None:
+            self._sink.append(rows)
+        return tuple(out)
+
+    def __getattr__(self, name):
+        return getattr(self._fn, name)
+
+
 class _PendingTick:
     """One engine tick in flight between :meth:`ServingEngine.launch` and
     :meth:`ServingEngine.collect`: the dispatch's UNSYNCED device result
@@ -1045,6 +1090,7 @@ class _PendingTick:
         "kind", "start", "t0", "t1", "tick_span", "events", "admitted",
         "chunks_advanced", "chunk_tokens", "chunk_spans", "active_tokens",
         "entering", "finals", "payload", "overlapped", "phases", "between",
+        "expert_rows",
     )
 
     def __init__(self):
@@ -1065,6 +1111,9 @@ class _PendingTick:
         self.finals: List[tuple] = []
         self.payload = None
         self.overlapped = False
+        # expert row counts this tick's programs returned (device arrays
+        # until collect reads them)
+        self.expert_rows: list = []
         # the phase clock: seconds by leaf phase (a phase entered twice
         # in one tick adds up), and the gap since the previous busy
         # tick's collect (None: there was none, or it was not busy)
@@ -1247,6 +1296,13 @@ class ServingEngine:
                 "the serving engine does not run positional='relative' "
                 "models (per-row slot depths break the shared bias "
                 "table) — serve those through generate()"
+            )
+        if cfg.drops_tokens:
+            raise NotImplementedError(
+                "the serving engine does not run capacity-routed experts: a "
+                "dropped token makes a request's output depend on its "
+                "batch-mates — give the layers an ExpertsSpec (the dropless "
+                "RoutedExperts layer)"
             )
         self.model = model
         self.params = params
@@ -1617,6 +1673,18 @@ class ServingEngine:
                 shardings=pool_shardings, row_fns=row_fns,
             )
 
+        # dropless expert layers: every program returns its row counts as
+        # one more output; strip it here so that no call site changes, and
+        # keep the device arrays until the tick's collect reads them
+        self._expert_rows: list = []
+        for name in ("_prefill_fn", "_extend_fn", "_decode_fn", "_fused_fn",
+                     "_unified_fn"):
+            fn = getattr(self, name)
+            if fn is not None:
+                setattr(self, name, _WithoutExpertRows(fn, self._expert_rows))
+
+        self.moe_plan = self._plan_experts(n_slots)
+
         n = n_slots
         self._tok = np.zeros(n, np.int32)
         self._pos = np.zeros(n, np.int32)
@@ -1633,6 +1701,34 @@ class ServingEngine:
         # acceptance-adaptive effective draft length (<= cap)
         self._spec_max = np.zeros(n, np.int32)
         self._spec_k = np.zeros(n, np.int32)
+
+    def _plan_experts(self, n_slots: int) -> Optional[Dict[str, dict]]:
+        """What the dropless expert layers do in each program shape this
+        engine runs (:func:`~tpu_parallel.models.moe.moe_plan`), logged
+        and put on the tracer once at build; None without such a layer."""
+        from tpu_parallel.models.moe import moe_plan
+
+        cfg = self.model.config
+        spec = next(
+            (s.experts for s in cfg.layer_specs if s.experts is not None),
+            None,
+        )
+        if spec is None:
+            return None
+        shapes = {"decode": n_slots}
+        if self._chunk_tokens and self._unified:
+            shapes["chunk"] = n_slots * self._chunk_tokens
+        for b in self._buckets or ():
+            shapes[f"prefill_{b}"] = self._prefill_batch * b
+        plan = {name: moe_plan(spec, t) for name, t in shapes.items()}
+        logging.getLogger(__name__).info("moe_plan %s", json.dumps(plan))
+        if self.tracer.enabled:
+            self.tracer.instant(
+                "moe_plan", track="scheduler", layers=cfg.routed_layers,
+                **{f"{name}_{k}": v for name, p in plan.items()
+                   for k, v in p.items()},
+            )
+        return plan
 
     # -- submission --------------------------------------------------------
 
@@ -1858,6 +1954,8 @@ class ServingEngine:
             p.active_tokens = int(self._pos[self._active].sum()) + sum(
                 st.offset for st in self._chunking.values()
             ) + sum(plen for (_, _, plen) in p.finals)
+        p.expert_rows = self._expert_rows[:]
+        del self._expert_rows[:]
         p.t0 = dispatch.end
         return p
 
@@ -1953,6 +2051,9 @@ class ServingEngine:
         chunks_advanced = p.chunks_advanced
         active_tokens = p.active_tokens
         with self._phase(p, "record") as record:
+            for rows in p.expert_rows:  # small counts of programs synced above
+                rows = np.asarray(rows)  # host-sync: a tick's expert counts
+                self.metrics.record_expert_rows(rows)
             if self._prefix is not None:
                 entry_bytes = None
                 if self._radix is not None:

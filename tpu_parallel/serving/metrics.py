@@ -252,6 +252,16 @@ class ServingMetrics:
         self._occupancy = r.histogram("serving_slot_occupancy")
         self._queue_depth_last = r.gauge("serving_queue_depth_last")
         self._occupancy_last = r.gauge("serving_slot_occupancy_last")
+        # dropless expert layers (models/moe.py RoutedExperts): what the
+        # tick and prefill programs return beside their tokens.  A call is
+        # one layer's pass over one program step's rows.
+        self._moe_calls = r.counter("serving_moe_calls_total")
+        self._moe_assignments = r.counter("serving_moe_assignments_total")
+        self._moe_held = r.counter("serving_moe_assignments_held_total")
+        self._moe_touched = r.counter("serving_moe_experts_touched_total")
+        # rows by (layer, held expert), summed over calls: its max over its
+        # mean is the imbalance the grouped matmuls saw
+        self._moe_rows: Optional[np.ndarray] = None
         self._t_start: Optional[float] = None
         self._t_last: Optional[float] = None
 
@@ -465,6 +475,18 @@ class ServingMetrics:
         consumed plus tokens generated by its ONE dispatch (chunk
         counting goes through :meth:`record_chunks`)."""
         self._unified_tick_tokens.observe(tokens)
+
+    def record_expert_rows(self, rows: np.ndarray) -> None:
+        """``rows`` ``[calls, layers, held + 1]`` from one program: the rows
+        each call routed to each held expert, and (last column) its
+        assignments to experts held elsewhere."""
+        here = rows[..., :-1]
+        self._moe_calls.inc(here.shape[0] * here.shape[1])
+        self._moe_assignments.inc(int(rows.sum()))
+        self._moe_held.inc(int(here.sum()))
+        self._moe_touched.inc(int((here > 0).sum()))
+        total = here.sum(axis=0, dtype=np.int64)
+        self._moe_rows = total if self._moe_rows is None else self._moe_rows + total
 
     def record_overlap(self) -> None:
         """One decode-family dispatch launched while the PREVIOUS tick's
@@ -740,6 +762,25 @@ class ServingMetrics:
                 None if qd_max is None else int(qd_max)
             ),
         }
+        # expert rows only appear once a dropless layer has reported
+        calls = int(self._moe_calls.value)
+        if calls:
+            mean_rows = float(self._moe_rows.mean())
+            out.update(
+                {
+                    "moe_calls": calls,
+                    "moe_assignments_total": int(self._moe_assignments.value),
+                    "moe_assignments_held": int(self._moe_held.value),
+                    "moe_experts_touched_mean": round(
+                        self._moe_touched.value / calls, 4
+                    ),
+                    "moe_rows_per_expert_max_over_mean": (
+                        round(float(self._moe_rows.max()) / mean_rows, 4)
+                        if mean_rows
+                        else None
+                    ),
+                }
+            )
         # SSD-tier rows only appear once a disk store has synced at
         # least once — a summary without them means "no disk tier",
         # which old consumers (and disk-less configs) rely on

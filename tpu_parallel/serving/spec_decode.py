@@ -491,7 +491,7 @@ def generate_speculative(
     positions = jnp.broadcast_to(
         jnp.arange(prompt_len, dtype=jnp.int32), (b, prompt_len)
     )
-    logits, cache = prefill_fn(
+    logits, cache, _ = prefill_fn(
         params, prompt.astype(jnp.int32), positions,
         jnp.full((b,), prompt_len - 1, jnp.int32), split(),
     )
