@@ -20,17 +20,23 @@ delivery), so tick N's host bookkeeping can overlap tick N+1's device
 compute.  ONE sync anywhere on the launch side serializes the pipeline
 — the host stalls before the next tick is even dispatched and the
 overlap ratio silently collapses to zero.  So any device-sync call
-lexically inside a function named ``launch`` or ``_launch*`` under
-``tpu_parallel/serving/`` flags, loop or no loop (same ``# host-sync:``
-whitelist for a justified exception).
+inside a function named ``launch`` or ``_launch*`` under
+``tpu_parallel/serving/``, or inside a function of the same file that
+one of those reaches through ``self.<name>(...)`` / ``<name>(...)``
+calls (admission, the prefill, the chunk block: ``step()`` launches
+tick N+1 before it reads tick N, so a sync anywhere on that side idles
+the device), flags, loop or no loop (same ``# host-sync:`` whitelist
+for a justified exception).
 
 Like ``check_clock.py`` (the injectable-clock contract) this turns a
 prose rule into a tier-1 test
 (``tests/test_cluster.py::test_serving_no_per_slot_host_sync`` and the
-``check_all`` registry).  The check is LEXICAL: it sees calls written
-inside loop/launch bodies, not syncs reached through function calls —
-the gated debug fetch in ``CachePool.assert_slot_aligned`` (called per
-slot under ``spec_check_invariants=True``) is out of scope by design.
+``check_all`` registry).  The check is LEXICAL within one file: it sees
+calls written inside loop bodies and inside the functions the launch
+side reaches by name in that file, not syncs reached through another
+object (``self.pool.<...>``) or another module — the gated debug fetch
+in ``CachePool.assert_slot_aligned`` (called per slot under
+``spec_check_invariants=True``) is out of scope by design.
 
 Usage: ``python scripts/check_host_sync.py [paths...]`` — prints one
 ``file:line: <call> syncs the device ...`` per violation, exits nonzero
@@ -76,13 +82,49 @@ def _is_launch_name(name: str) -> bool:
     return name == "launch" or name.startswith("_launch")
 
 
+def _launch_side(tree: ast.AST) -> frozenset:
+    """Names of the file's functions that run on the launch side: the
+    launch-named ones, and whatever they reach through ``self.<name>()``
+    or ``<name>()`` calls to functions defined in the same file."""
+    defs = {}
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            defs.setdefault(node.name, []).append(node)
+
+    def callees(fn: ast.AST):
+        for node in ast.walk(fn):
+            if not isinstance(node, ast.Call):
+                continue
+            func = node.func
+            if isinstance(func, ast.Name):
+                yield func.id
+            elif (
+                isinstance(func, ast.Attribute)
+                and isinstance(func.value, ast.Name)
+                and func.value.id == "self"
+            ):
+                yield func.attr
+
+    reached = {name for name in defs if _is_launch_name(name)}
+    frontier = list(reached)
+    while frontier:
+        for fn in defs[frontier.pop()]:
+            for name in callees(fn):
+                if name in defs and name not in reached:
+                    reached.add(name)
+                    frontier.append(name)
+    return frozenset(reached)
+
+
 def check_source(source: str, filename: str) -> List[str]:
     """Return ``file:line: message`` strings for every device-sync call
     lexically inside a ``for``/``while`` body or a comprehension's
     per-iteration positions, OR anywhere inside a ``launch``/``_launch*``
-    function body (the launch/collect overlap contract), minus lines
-    carrying the ``# host-sync: <why>`` whitelist annotation."""
+    function body or a function of the file those reach (the
+    launch/collect overlap contract), minus lines carrying the
+    ``# host-sync: <why>`` whitelist annotation."""
     tree = ast.parse(source, filename=filename)
+    launch_side = _launch_side(tree)
     lines = source.splitlines()
     problems: List[str] = []
 
@@ -148,8 +190,8 @@ def check_source(source: str, filename: str) -> List[str]:
             node, (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda)
         ):
             enter_loop = False
-            if not isinstance(node, ast.Lambda) and _is_launch_name(
-                node.name
+            if not isinstance(node, ast.Lambda) and (
+                node.name in launch_side
             ):
                 enter_launch = True
         for child in ast.iter_child_nodes(node):

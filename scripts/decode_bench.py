@@ -218,7 +218,7 @@ def run_spec(batch, prompt_len, new_tokens, kv_dtype="bf16", ks=(2, 4, 8),
 
 def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
                ticks=(1, 4, 8, 16), reps=3, warmup=1, chunk=0,
-               overlap=False, model_name="tiny"):
+               model_name="tiny"):
     """ENGINE-mode decode throughput: the ServingEngine's decode hot loop
     across the ``--fused-tick`` sweep — T=1 is the per-step tick (one
     host dispatch + sync per token, the DECODE_r06 348-tok/s-at-batch-1
@@ -231,9 +231,9 @@ def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
 
     ``chunk`` > 0 runs chunked prefill, which at T>1 rides the UNIFIED
     ragged tick (chunk advance + decode in one dispatch — T=1 keeps the
-    per-phase alternating engine as the comparison row); ``overlap``
-    drains through the launch/collect pipeline and the record's
-    ``host_overlap_ratio`` shows the measured launch-ahead fraction."""
+    per-phase alternating engine as the comparison row); the record's
+    ``launch_ahead_share`` is the share of busy ticks that ``step()``
+    launched with their predecessor still on the device (0 at T=1)."""
     import numpy as np
 
     from tpu_parallel.models.generate import generate
@@ -271,7 +271,7 @@ def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
                 eng.add_request(Request(prompt=p, max_new_tokens=n_new))
                 for p in prompts
             ]
-            eng.run(overlap=overlap)
+            eng.run()
             return outs
 
         for _ in range(max(warmup, 1)):
@@ -302,7 +302,6 @@ def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
             decode_steps_per_tick=steps,
             prefill_chunk_tokens=chunk or None,
             unified_tick=eng.unified_tick,
-            overlap=bool(overlap),
             engine_decode_tokens_per_sec=round(
                 batch * (new_tokens - 1) / decode_dt, 1
             ),
@@ -317,7 +316,7 @@ def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
             dispatches_per_token=round(
                 s["host_dispatches"] / max(s["tokens_out"], 1), 4
             ),
-            host_overlap_ratio=s["host_overlap_ratio"],
+            launch_ahead_share=s["launch_ahead_share"],
             unified_tick_tokens_mean=s["unified_tick_tokens_mean"],
             host_ms_per_tick_p50=s["host_ms_per_tick_p50"],
         )), flush=True)
@@ -399,10 +398,6 @@ def main():
                     help="--engine: prefill_chunk_tokens (0 = off); at "
                          "T>1 chunked prompts ride the UNIFIED ragged "
                          "tick, at T=1 the per-phase alternating engine")
-    ap.add_argument("--overlap", action="store_true",
-                    help="--engine: drain through the double-buffered "
-                         "launch/collect pipeline (records "
-                         "host_overlap_ratio)")
     args = ap.parse_args()
     if args.combos[:1] == ["beam"]:
         run_beam(*(int(a) for a in args.combos[1:]), model_name=args.model)
@@ -443,7 +438,7 @@ def main():
             elif args.engine:
                 run_engine(*combo, ticks=fused_ticks, reps=args.reps,
                            warmup=args.warmup, chunk=args.chunk,
-                           overlap=args.overlap, model_name=args.model)
+                           model_name=args.model)
                 continue  # run_engine prints one record per T itself
             else:
                 record = run_one(*combo, reps=args.reps, warmup=args.warmup,

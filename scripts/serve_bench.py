@@ -1868,15 +1868,14 @@ def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
     """SERVE_r08: the UNIFIED ragged tick vs the per-phase ALTERNATING
     engine under a mixed prefill+decode Zipf workload — long multi-chunk
     prompts (tenant headers drawn Zipf, so the prefix load is realistic)
-    continuously interleaving with in-flight decodes.  Four legs on the
-    IDENTICAL workload: alternating (per-slot chunk extends + fused
+    continuously interleaving with in-flight decodes.  Three legs on
+    the IDENTICAL workload: alternating (per-slot chunk extends + fused
     decode dispatch, ``unified_tick=False``), unified (one dispatch per
-    tick), unified+overlap (the launch/collect pipeline), and the
-    speculative pair (per-step verify vs fused verify blocks).  Gates:
-    every leg bitwise-identical to its baseline; the unified tick cuts
-    device dispatches per delivered token >= 2x vs alternating; ITL p95
-    no worse; measured host/device overlap ratio > 0 on the pipelined
-    leg."""
+    tick), and the speculative pair (per-step verify vs fused verify
+    blocks).  Gates: every leg bitwise-identical to its baseline; the
+    unified tick cuts device dispatches per delivered token >= 2x vs
+    alternating; ITL p95 no worse; ``step()`` launched busy ticks ahead
+    of their predecessor's collect on the unified leg."""
     import json
     import time as _time
 
@@ -1916,9 +1915,6 @@ def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
         "unified": dict(
             prefill_chunk_tokens=chunk, decode_steps_per_tick=8,
         ),
-        "unified_overlap": dict(
-            prefill_chunk_tokens=chunk, decode_steps_per_tick=8,
-        ),
         "alternating_spec": dict(
             prefill_chunk_tokens=chunk, decode_steps_per_tick=1,
             draft_tokens=3,
@@ -1941,7 +1937,7 @@ def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
         ]
         # one warm drain compiles every shape, then measure from clean
         # metrics on the SAME engine (long-lived server discipline)
-        eng.run(overlap=leg == "unified_overlap")
+        eng.run()
         warm_tokens = [list(o.tokens) for o in outs]
         eng.reset_metrics()
         outs = [
@@ -1949,7 +1945,7 @@ def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
             for p in prompts
         ]
         t0 = _time.perf_counter()
-        eng.run(overlap=leg == "unified_overlap")
+        eng.run()
         wall = _time.perf_counter() - t0
         tokens_by_leg[leg] = [list(o.tokens) for o in outs]
         assert tokens_by_leg[leg] == warm_tokens  # warm == measured
@@ -1967,7 +1963,7 @@ def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
             "ttft_ms_p95": s["ttft_ms_p95"],
             "host_ms_per_tick_p50": s["host_ms_per_tick_p50"],
             "host_ms_per_tick_p95": s["host_ms_per_tick_p95"],
-            "host_overlap_ratio": s["host_overlap_ratio"],
+            "launch_ahead_share": s["launch_ahead_share"],
             "unified_tick_tokens_mean": s["unified_tick_tokens_mean"],
             "tokens_per_sec": s["tokens_per_sec"],
             "wall_s": round(wall, 3),
@@ -1975,7 +1971,6 @@ def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
     violations = []
     for base, fast in (
         ("alternating", "unified"),
-        ("alternating", "unified_overlap"),
         ("alternating_spec", "unified_spec"),
         # spec-vs-nonspec greedy parity closes the square
         ("alternating", "alternating_spec"),
@@ -1997,8 +1992,8 @@ def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
         violations.append(
             f"unified dispatch cut {cut:.2f}x < 2x vs alternating"
         )
-    if results["unified_overlap"]["host_overlap_ratio"] <= 0:
-        violations.append("pipelined leg measured zero host overlap")
+    if results["unified"]["launch_ahead_share"] <= 0:
+        violations.append("the unified leg launched no tick ahead")
     itl_base = results["alternating"]["itl_ms_p95"]
     itl_uni = results["unified"]["itl_ms_p95"]
     if itl_base is not None and itl_uni is not None and (
@@ -2239,12 +2234,11 @@ def main():
                     help="kv-disk: write the record to this JSON file")
     ap.add_argument("--unified-bench", action="store_true",
                     help="unified-ragged-tick acceptance bench "
-                         "(SERVE_r08): alternating vs unified vs "
-                         "pipelined engines on a mixed prefill+decode "
-                         "Zipf workload at equal budgets — bitwise "
-                         "parity, >= 2x dispatch cut per token, "
-                         "measured host overlap; nonzero exit on any "
-                         "violation")
+                         "(SERVE_r08): alternating vs unified engines "
+                         "on a mixed prefill+decode Zipf workload at "
+                         "equal budgets — bitwise parity, >= 2x "
+                         "dispatch cut per token, ticks launched "
+                         "ahead; nonzero exit on any violation")
     ap.add_argument("--unified-record", type=str, default="",
                     help="unified-bench: write the record to this JSON "
                          "file (SERVE_r08.json)")

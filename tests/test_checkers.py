@@ -192,6 +192,29 @@ def test_check_host_sync_launch_rule():
         "        return np.asarray(fetch())\n"
     )
     assert chs.check_source(ok, "engine.py") == []
+    # the rule holds for what launch REACHES in its file (step() runs the
+    # next launch before the pending collect: a sync in an admission
+    # helper idles the device just the same), through self.<name>() and
+    # <name>() calls; what only the collect side calls stays legal
+    reached = (
+        "import numpy as np\n"
+        "class Engine:\n"
+        "    def launch(self):\n"
+        "        return self._admit()\n"
+        "    def _admit(self):\n"
+        "        return first_tokens(self._sample())\n"
+        "    def _sample(self):\n"
+        "        return np.asarray(self.logits)\n"
+        "    def collect(self, pending):\n"
+        "        return self._deliver(pending)\n"
+        "    def _deliver(self, pending):\n"
+        "        return np.asarray(pending)\n"
+        "def first_tokens(x):\n"
+        "    return x.block_until_ready()\n"
+    )
+    found = chs.check_source(reached, "engine.py")
+    assert len(found) == 2, found
+    assert any(":8:" in p for p in found) and any(":14:" in p for p in found)
     # the live engine's launch side is clean — the gate would catch a
     # regression that moved a sync back before the dispatch
     results = chs.check_paths()

@@ -97,14 +97,14 @@ def env():
     return cfg, model, params, prompts, refs
 
 
-def _factory(env, **fe_kw):
+def _factory(env, steps=1, **fe_kw):
     cfg, model, params, _, _ = env
 
     def frontend_factory(clock):
         engine = ServingEngine(
             model, params, n_slots=2,
             scheduler=SchedulerConfig(max_prefills_per_tick=2),
-            decode_steps_per_tick=1,
+            decode_steps_per_tick=steps,
         )
         return Frontend(
             [engine], router="least",
@@ -115,10 +115,10 @@ def _factory(env, **fe_kw):
     return frontend_factory
 
 
-def _daemon(env, path, clock=None, fe_kw=None, **cfg_kw):
+def _daemon(env, path, clock=None, fe_kw=None, steps=1, **cfg_kw):
     cfg_kw.setdefault("fsync_batch", 4)
     return ServingDaemon(
-        _factory(env, **(fe_kw or {})), str(path),
+        _factory(env, steps=steps, **(fe_kw or {})), str(path),
         clock=clock or FakeClock(),
         config=DaemonConfig(**cfg_kw),
     )
@@ -256,6 +256,65 @@ def test_crash_replay_recovers_unfinished_bitwise(env, tmp_path):
     assert int(
         d2.registry.counter("daemon_recovered_requests_total").value
     ) == 3
+
+
+def test_kill_replay_and_drain_with_a_tick_in_flight(env, tmp_path):
+    """The fused engine keeps a tick queued on the device across the
+    pump's ticks.  A kill -9 then loses that tick's tokens and nothing
+    that was delivered: the restarted daemon finishes every stream
+    bitwise, the journal holds each request's tokens gap-free, in order
+    and before its terminal, as with the per-step engine.  A drain that
+    starts with a tick in flight collects it and exits clean."""
+    _, _, _, prompts, refs = env
+    path = tmp_path / "j.jsonl"
+    d1 = _daemon(env, path, steps=2)
+    for i in range(3):  # a queue deeper than the two slots
+        d1.submit(Request(prompt=prompts[i], max_new_tokens=8,
+                          request_id=f"r{i}"))
+    for _ in range(2):
+        d1.tick()
+    engine = d1.frontend.replicas[0].engine
+    assert engine._pending is not None  # the kill lands on a tick in flight
+    assert engine.metrics.summary()["overlapped_dispatches"] >= 1
+    seen = {f"r{i}": list(d1.result(f"r{i}")["tokens"]) for i in range(3)}
+    assert any(0 < len(t) < 8 for t in seen.values()), seen
+    d1.journal.abort()  # the kill -9
+
+    d2 = _daemon(env, path, steps=2)
+    for i in range(3):
+        # what a client saw before the kill is a prefix of the truth
+        assert refs[i][: len(seen[f"r{i}"])] == seen[f"r{i}"]
+    for _ in range(8):  # the recovered tails drain, the queued one seats
+        d2.tick()
+        if d2.frontend.replicas[0].engine._pending is not None:
+            break
+    assert d2.frontend.replicas[0].engine._pending is not None
+    d2.request_drain()  # SIGTERM, with a tick in flight
+    assert d2.run(max_ticks=100) == EXIT_CLEAN
+    engine = d2.frontend.replicas[0].engine
+    assert engine._pending is None and not engine.has_work()
+    assert engine.pool.n_free == engine.pool.n_slots
+    assert d2.frontend._reserved == 0
+    for i in range(3):
+        rec = d2.result(f"r{i}")
+        assert rec["status"] == "finished" and rec["tokens"] == refs[i]
+    records, torn = read_journal(str(path))
+    assert torn == 0 and records[-1]["record"] == REC_SHUTDOWN
+    assert records[-1]["clean"]
+    for i in range(3):
+        mine = [r for r in records if r.get("request_id") == f"r{i}"]
+        kinds = [r["record"] for r in mine]
+        assert kinds[0] == REC_SUBMIT and kinds[-1] == REC_TERMINAL
+        assert kinds.count(REC_TERMINAL) == 1
+        have = 0
+        for r in mine:
+            if r["record"] == REC_TOKENS:
+                # a re-stream after recovery may overlap, never skip
+                assert r["index"] <= have
+                have = max(have, r["index"] + len(r["tokens"]))
+        assert have == 8
+    st = load_state(str(path))
+    assert st.clean_shutdown and not st.unfinished
 
 
 def test_recovery_synthesizes_lost_terminals(env, tmp_path):

@@ -1577,16 +1577,46 @@ def test_unified_tick_compile_count_pin(rng):
     assert eng._fused_fn._cache_size() == 1
 
 
-def test_run_overlap_bitwise_and_donation_audit(rng):
-    """Double-buffered host/device overlap: run(overlap=True) launches
-    tick N+1 before collecting tick N on pure-decode stretches — output
-    BITWISE identical to the sequential loop, measured overlap ratio
-    > 0, and the donation audit: after a launch the previous tick's
-    state/cache buffers are deleted (donated into the in-flight
-    dispatch), and the engine never reads the pending tick's donated
-    buffers before collect (a read would raise on the deleted buffer)."""
+def _sequential(eng):
+    """The loop ``step()`` replaced: every tick collected before the next
+    is launched."""
+    events = []
+    while eng.has_work():
+        events.extend(eng.collect(eng.launch()))
+    return events
+
+
+PIPELINE_KINDS = dict(
+    # the fused tick, whole-prompt prefill in buckets, one prompt a call
+    fused=dict(
+        prefill_buckets=(8, 16), prefill_batch=1,
+        scheduler=SchedulerConfig(max_prefills_per_tick=1),
+        decode_steps_per_tick=2,
+    ),
+    # the unified tick: prompts past the chunk budget ride it in chunks,
+    # the short ones prefill in their bucket
+    unified=dict(
+        prefill_buckets=(4, 8), prefill_chunk_tokens=6,
+        scheduler=SchedulerConfig(max_prefills_per_tick=2),
+        decode_steps_per_tick=2,
+    ),
+    per_step=dict(decode_steps_per_tick=1),
+)
+
+
+@pytest.mark.parametrize("kind", list(PIPELINE_KINDS))
+def test_step_pipeline_bitwise_and_donation_audit(rng, kind):
+    """``step()`` keeps one tick queued on the device: with a queue deeper
+    than the slots (admissions, whole-prompt and chunked prefills,
+    retirements every few ticks) it launches tick N+1 before it reads
+    tick N on nine busy ticks of ten, and every request's greedy tokens
+    arrive in the order a ``collect(launch())`` loop gives them; a
+    per-step engine never launches ahead.  The donation audit: a launch
+    ahead donates the pending tick's state and cache, and nothing reads
+    them before collect (a read would raise on the deleted buffer)."""
     cfg, model, _, params = _build(rng)
-    lens, budgets = [3, 5, 9, 4], [12, 9, 11, 10]
+    lens = [3, 5, 9, 4, 12, 7, 10, 6, 11, 8]
+    budgets = [14, 9, 17, 12, 15, 18, 10, 16, 13, 11]
     prompts = [
         [int(t) for t in np.asarray(
             jax.random.randint(
@@ -1595,82 +1625,137 @@ def test_run_overlap_bitwise_and_donation_audit(rng):
         )]
         for i, L in enumerate(lens)
     ]
-    for layout in ({}, {"kv_block_tokens": "auto"}):
-        seq_eng = ServingEngine(
-            model, params, n_slots=2,
-            scheduler=SchedulerConfig(max_prefills_per_tick=2), **layout,
-        )
-        seq = [
-            seq_eng.add_request(_req(p, n))
-            for p, n in zip(prompts, budgets)
+
+    def serve(drain):
+        eng = ServingEngine(model, params, n_slots=2, **PIPELINE_KINDS[kind])
+        order = []
+        outs = [
+            eng.add_request(_req(
+                p, n, request_id=f"r{i}",
+                on_token=lambda ev: order.append((ev.request_id, ev.index)),
+            ))
+            for i, (p, n) in enumerate(zip(prompts, budgets))
         ]
-        seq_eng.run()
-        ov_eng = ServingEngine(
-            model, params, n_slots=2,
-            scheduler=SchedulerConfig(max_prefills_per_tick=2), **layout,
-        )
-        ov = [
-            ov_eng.add_request(_req(p, n))
-            for p, n in zip(prompts, budgets)
-        ]
-        ov_eng.run(overlap=True)
-        for i, (a, b) in enumerate(zip(seq, ov)):
-            assert a.status == FINISHED and b.status == FINISHED
-            np.testing.assert_array_equal(
-                np.asarray(b.tokens), np.asarray(a.tokens),
-                err_msg=f"request {i} ({layout})",
-            )
-        s = ov_eng.metrics.summary()
-        assert s["host_overlap_ratio"] > 0, layout
-        assert s["overlapped_dispatches"] > 0
-        assert seq_eng.metrics.summary()["host_overlap_ratio"] == 0.0
-    # donation audit on the pipelined pair: launch-ahead donates the
-    # previous tick's state+cache into the in-flight dispatch
+        drain(eng)
+        assert eng.pool.n_free == 2 and not eng.has_work()
+        return eng, outs, order
+
+    seq_eng, seq, seq_order = serve(_sequential)
+    eng, piped, order = serve(lambda e: e.run())
+    for i, (a, b) in enumerate(zip(seq, piped)):
+        assert a.status == FINISHED and b.status == FINISHED
+        assert b.tokens == a.tokens, f"request {i}"
+    by_request = lambda pairs, rid: [x for x in pairs if x[0] == rid]
+    for i in range(len(prompts)):
+        assert by_request(order, f"r{i}") == by_request(seq_order, f"r{i}")
+    s = eng.metrics.summary()
+    assert seq_eng.metrics.summary()["launch_ahead_share"] == 0.0
+    if kind == "per_step":
+        assert s["launch_ahead_share"] == 0.0
+        assert s["launch_ahead_flushes"] == {}
+        return
+    assert s["launch_ahead_share"] >= 0.9, s
+    assert s["overlapped_dispatches"] >= 0.9 * s["busy_ticks"]
+    if kind == "unified":
+        assert s["prefill_chunks"] > 0 and s["unified_tick_tokens_mean"] > 0
+    # the only launches that waited are the last ticks': nothing was
+    # certain to be left for a successor
+    assert set(s["launch_ahead_flushes"]) == {"draining"}
+    assert s["launch_ahead_flushes"]["draining"] <= 2
+    # donation audit on a pipelined pair
     eng = ServingEngine(model, params, n_slots=1)
     out = eng.add_request(_req(prompts[0], 28))
-    eng.step()  # admit + first fused tick (clean state now)
-    assert eng._can_launch_ahead()
     p1 = eng.launch()
     old_state = jax.tree_util.tree_leaves(eng._dev_state)
     old_cache = jax.tree_util.tree_leaves(eng.pool.cache)
-    assert eng._can_launch_ahead()
+    assert eng._ahead_refusal(p1) is None
     p2 = eng.launch(ahead=True)  # donates p1's returned buffers
     assert all(leaf.is_deleted() for leaf in old_state), (
         "launch-ahead did not donate the pending tick's state buffers"
     )
     assert all(leaf.is_deleted() for leaf in old_cache)
-    ev1 = eng.collect(p1)
+    ev1 = eng.collect(p1)  # the first token rides its tick's collect
     ev2 = eng.collect(p2)
-    assert len(ev1) == len(ev2) == eng.decode_steps_per_tick
+    assert len(ev1) - 1 == len(ev2) == eng.decode_steps_per_tick
     eng.run()
     assert out.status == FINISHED and len(out.tokens) == 28
 
 
-def test_run_overlap_finish_and_retire_in_flight(rng):
-    """Overlap pipeline edge: requests FINISHING inside a pipelined tick
-    retire cleanly — the overlapped surplus tick parks on the device
-    live-mask, the host retires at collect, and the trailing pending
-    tick is always collected (no hang, no stray tokens, slots free)."""
-    cfg, model, prompt, params = _build(rng, n_rows=2)
+@pytest.mark.parametrize(
+    "case", ["length_in_flight", "first_token", "eos", "cancel", "max_wait"]
+)
+def test_step_pipeline_edges_end_clean(rng, case):
+    """What ends a request while a tick is in flight: its budget (the
+    surplus tick parks on the device's live mask and the host retires at
+    collect), its first token, an EOS inside the tick in flight, a
+    ``cancel()`` and a ``max_wait`` expiry.  Each ends with the tokens of
+    the sequential loop, every slot free, nothing in flight, and the
+    launch that had to wait counted under its cause."""
+    cfg, model, prompt, params = _build(rng, n_rows=3)
     refs = [
-        np.asarray(generate(
-            model, params, prompt[i : i + 1], max_new_tokens=9
-        ))[0]
-        for i in range(2)
+        [int(t) for t in np.asarray(generate(
+            model, params, prompt[i : i + 1], max_new_tokens=12
+        ))[0]]
+        for i in range(3)
     ]
+    now = [0.0]
     eng = ServingEngine(
-        model, params, n_slots=2,
-        scheduler=SchedulerConfig(max_prefills_per_tick=2),
-        decode_steps_per_tick=4,
+        model, params, n_slots=2, decode_steps_per_tick=4,
+        clock=lambda: now[0],
+        scheduler=SchedulerConfig(max_prefills_per_tick=2, max_wait=5.0),
     )
-    outs = [eng.add_request(_req(prompt[i], 9)) for i in range(2)]
-    events = eng.run(overlap=True)
+    want = {0: refs[0], 1: refs[1], 2: refs[2]}
+    knobs = {0: {}, 1: {}, 2: {}}
+    if case == "length_in_flight":
+        # 9-token budgets on 4-step ticks: the finish lands mid-pipeline
+        want = {i: refs[i][:9] for i in range(3)}
+    elif case == "first_token":
+        want[1] = refs[1][:1]
+    elif case == "eos":
+        # stops at the sixth token: inside the second tick, in flight
+        # while the third is launched
+        knobs[1] = dict(eos_token_id=refs[1][5])
+        want[1] = refs[1][: refs[1].index(refs[1][5]) + 1]
+    outs = [
+        eng.add_request(_req(
+            prompt[i], len(want[i]) if i != 1 or case != "eos" else 12,
+            request_id=f"r{i}", **knobs[i],
+        ))
+        for i in range(3)
+    ]
+    events = eng.step()
+    assert eng._pending is not None  # a tick is in flight from here on
+    if case == "cancel":
+        assert eng.cancel("r0")
+        want[0] = list(outs[0].tokens)
+    elif case == "max_wait":
+        now[0] += 10.0  # r2 still queues behind the two seated
+        want[2] = []
+    while eng.has_work():
+        events.extend(eng.step())
     for i, out in enumerate(outs):
-        assert out.status == FINISHED and out.finish_reason == "length"
-        np.testing.assert_array_equal(np.asarray(out.tokens), refs[i])
-    assert eng.pool.n_free == 2 and not eng.has_work()
-    # 9-token budgets on 4-step ticks: the finish lands mid-pipeline
-    assert sum(1 for ev in events if ev.token >= 0) == 18
+        assert out.tokens == want[i], (case, i)
+    assert outs[1].status == FINISHED
+    assert outs[1].finish_reason == ("eos" if case == "eos" else "length")
+    if case == "cancel":
+        assert outs[0].status == "cancelled" and outs[2].status == FINISHED
+    if case == "max_wait":
+        assert outs[2].status == EXPIRED
+        assert [ev.finish_reason for ev in events if ev.token < 0] == [
+            "max_wait"
+        ]
+    assert sum(1 for ev in events if ev.token >= 0) == sum(
+        len(out.tokens) for out in outs
+    )
+    assert eng.pool.n_free == 2 and eng._pending is None
+    assert not eng._active.any() and not eng.has_work()
+    assert all(out is None for out in eng._slot_out)
+    flushes = eng.metrics.summary()["launch_ahead_flushes"]
+    assert flushes.get("cancel" if case == "cancel" else "draining", 0) >= 1
+    # and the engine serves on, its device state whole
+    again = eng.add_request(_req(prompt[2], 12))
+    eng.run()
+    assert again.tokens == refs[2]
 
 
 @pytest.mark.slow
@@ -1966,7 +2051,10 @@ def test_nan_sentinel_mid_stream_fused_tick(rng):
     eng.run(max_ticks=10)
     assert out.status == FAILED
     assert out.finish_reason == FAIL_INTEGRITY
-    assert out.tokens == delivered  # nothing after the trip streamed
+    # nothing after the trip streamed: what stands is what was delivered
+    # and the tick that was already on the device with sound weights
+    assert out.tokens[: len(delivered)] == delivered
+    assert len(out.tokens) == len(delivered) + 4
     assert eng.integrity_trips == 1
     assert eng.pool.n_free == eng.pool.n_slots
     assert not eng.has_work()
@@ -2025,7 +2113,8 @@ def test_nan_sentinel_spec_verify_path(rng, spec_steps):
 PHASES = ("schedule", "prefill", "dispatch", "device_wait", "deliver", "record")
 OLD_SUMMARY_KEYS = {
     "cancelled", "decode_ticks", "expired", "finished", "host_dispatches",
-    "host_ms_per_tick_p50", "host_ms_per_tick_p95", "host_overlap_ratio",
+    "host_ms_per_tick_p50", "host_ms_per_tick_p95", "launch_ahead_share",
+    "launch_ahead_flushes",
     "integrity_trips", "itl_ms_p50", "itl_ms_p95", "kv_block_cow_copies",
     "kv_blocks_free", "kv_blocks_in_use", "kv_bytes_per_active_token",
     "kv_host_blocks_in_use", "kv_host_breaker_state",
@@ -2087,10 +2176,15 @@ def _phase_hists(eng):
 
 @pytest.mark.parametrize("kind", ["fused", "unified", "per_step"])
 def test_phases_partition_busy_ticks_and_skip_idle_ones(rng, kind):
-    """The six in-tick phases add up to the busy tick's wall time and
-    `between` to the gaps between consecutive busy ticks; idle ticks and
-    the sleep before a burst enter no histogram; a busy tick reads the
-    clock at most 12 times beside its per-token stamps."""
+    """Every busy tick enters the phase series once, pipelined or not: its
+    six phases with what they cost, its period in `busy_tick`, the gap
+    before its launch in `between`.  On the per-step engine, where every
+    tick is collected before the next is launched, the six phases add up
+    to the busy ticks' wall time; on the engines that keep a tick in
+    flight the periods tile the run, gaps and all, and what ran beside
+    device work is not host-exposed.  Idle ticks and the sleep before a
+    burst enter no histogram; a step reads the clock at most 12 times
+    beside its per-token stamps."""
     cfg, model, prompt, params = _build(rng, n_rows=2, prompt_len=7)
     clock = _SetClock()
     knobs = dict(
@@ -2119,7 +2213,7 @@ def test_phases_partition_busy_ticks_and_skip_idle_ones(rng, kind):
         events = eng.step()
         ticks += 1
         tokens += len(events)
-        if all(ev.index > 0 for ev in events):  # no first token: steady
+        if events and all(ev.index > 0 for ev in events):  # steady
             assert clock.reads - before - len(events) <= 12
         clock.t += 0.5  # whatever the engine's owner does between ticks
     assert ticks >= 3
@@ -2131,18 +2225,42 @@ def test_phases_partition_busy_ticks_and_skip_idle_ones(rng, kind):
     assert {counts[name] for name in PHASES} == {ticks}
     for name in ("schedule", "dispatch", "device_wait"):
         assert sums[name] == pytest.approx(cost[name] * ticks)
-    # a first token is delivered by the prefill that made it (in the
-    # unified tick: by the collect of the tick that activated it)
-    first = 0 if kind == "unified" else 2
+    # a first token is delivered by the prefill that made it only where
+    # the engine reads it back there; where ticks chain it rides the
+    # collect of the tick it was dispatched before
+    first = 2 if kind == "per_step" else 0
     assert sums["prefill"] == pytest.approx(
         cost["prefill"] * ticks + cost["deliver"] * first
     )
     assert sums["deliver"] == pytest.approx(
         cost["deliver"] * (tokens - first)
     )
-    assert sum(sums[name] for name in PHASES) == pytest.approx(busy_sum)
-    assert counts["between"] == ticks - 1
-    assert sums["between"] == pytest.approx(0.5 * (ticks - 1))
+    six = sum(sums[name] for name in PHASES)
+    s = eng.metrics.summary()
+    exposed = sum(
+        s[f"tick_{n}_ms_mean"] for n in ("schedule", "deliver", "record",
+                                         "between")
+    )
+    everything = sum(s[f"tick_{n}_ms_mean"] for n in PHASES + ("between",))
+    if kind == "per_step":
+        assert s["launch_ahead_share"] == 0.0
+        assert six == pytest.approx(busy_sum)
+        assert counts["between"] == ticks - 1
+        assert sums["between"] == pytest.approx(0.5 * (ticks - 1))
+        assert s["host_exposed_share"] == pytest.approx(
+            100.0 * exposed / everything, abs=1e-3
+        )
+    else:
+        assert s["overlapped_dispatches"] == ticks - 1
+        # a period runs from the previous collect: it holds the owner's
+        # gap, which `between` reads at the launch that follows it (the
+        # first step launches two ticks, the last one none)
+        assert counts["between"] == ticks - 2
+        assert sums["between"] == pytest.approx(0.5 * (ticks - 2))
+        assert busy_sum == pytest.approx(six + 0.5 * (ticks - 1))
+        # exposed: the first tick's launch, the last one's collect
+        assert 0.0 < s["host_exposed_share"] < 5.0
+        assert s["host_exposed_share"] < 100.0 * exposed / everything
 
     # idle ticks, then the sleep before the next burst: observed nowhere
     clock.t += 100.0
@@ -2154,19 +2272,10 @@ def test_phases_partition_busy_ticks_and_skip_idle_ones(rng, kind):
     eng.step()
     sums2, counts2, _, busy_count2 = _phase_hists(eng)
     assert busy_count2 == ticks + 1
-    assert counts2["between"] == ticks - 1  # the tick before was idle
+    assert counts2["between"] == counts["between"]  # the tick before was idle
     assert sums2["between"] == pytest.approx(sums["between"])
-
-    s = eng.metrics.summary()
-    assert s["busy_ticks"] == ticks + 1 < s["ticks"]
-    exposed = sum(
-        s[f"tick_{n}_ms_mean"] for n in ("schedule", "deliver", "record",
-                                         "between")
-    )
-    everything = sum(s[f"tick_{n}_ms_mean"] for n in PHASES + ("between",))
-    assert s["host_exposed_share"] == pytest.approx(
-        100.0 * exposed / everything, abs=1e-3
-    )
+    assert eng.metrics.summary()["busy_ticks"] == ticks + 1
+    assert ticks + 1 < eng.metrics.summary()["ticks"]
 
 
 def test_summary_keeps_every_old_key_and_has_the_phase_clock(rng):
@@ -2187,18 +2296,53 @@ def test_summary_keeps_every_old_key_and_has_the_phase_clock(rng):
     assert s["tick_between_ms_mean"] is not None
 
 
-def test_pipelined_ticks_stay_out_of_the_phase_histograms(rng):
-    """`run(overlap=True)` collects tick N with tick N+1 queued on the
-    device: such ticks count in `overlapped_dispatches` and in no phase
-    series, so `host_exposed_share` never reads hidden time as exposed."""
-    cfg, model, prompt, params = _build(rng, n_rows=1)
-    eng = ServingEngine(model, params, n_slots=1)
-    eng.add_request(_req(prompt[0], 24))
-    eng.run(overlap=True)
-    s = eng.metrics.summary()
+BENCHMARK_READS = (
+    # what benchmarks/metrics/engine.*.py read off summary()
+    "busy_tick_ms_mean", "tick_device_wait_ms_mean", "tick_deliver_ms_mean",
+    "tick_between_ms_mean", "host_exposed_share", "slot_occupancy_mean",
+    "tokens_out", "prefill_tick_ms_mean", "decode_only_tick_ms_mean",
+    "host_ms_per_tick_p50", "launch_ahead_share",
+)
+
+
+def test_pipelined_ticks_enter_the_phase_histograms(rng):
+    """`step()` collects tick N with tick N+1 queued on the device: such
+    ticks count in `overlapped_dispatches` AND in every phase series (a
+    pipelined run that left them out would turn the benchmark's readers
+    to null), with `busy_tick` as the tick's period, and what ran beside
+    device work kept out of `host_exposed_share`."""
+    cfg, model, prompt, params = _build(rng, n_rows=3)
+
+    def serve(drain):
+        clock = _SetClock()
+        eng = ServingEngine(
+            model, params, n_slots=2, clock=clock,
+            scheduler=SchedulerConfig(max_prefills_per_tick=1),
+            decode_steps_per_tick=2,
+        )
+        _slow(eng, "_sync_payload", clock, 0.1)
+        _slow(eng.scheduler, "schedule", clock, 0.01)
+        for i in range(3):  # a queue deeper than the slots
+            eng.add_request(_req(prompt[i], 20))
+        drain(eng)
+        return eng.metrics.summary()
+
+    s = serve(lambda eng: eng.run())
     assert s["overlapped_dispatches"] >= 2
-    assert s["busy_ticks"] < s["decode_ticks"]
-    assert s["busy_ticks"] <= s["decode_ticks"] - s["overlapped_dispatches"]
+    assert s["busy_ticks"] == s["decode_ticks"]
+    assert s["launch_ahead_share"] >= 0.8
+    for key in BENCHMARK_READS:
+        assert s[key] is not None, key
+    # a period is a sync and a schedule long; the sync is device time
+    assert s["busy_tick_ms_mean"] == pytest.approx(110.0, rel=0.05)
+    assert s["tick_device_wait_ms_mean"] == pytest.approx(100.0)
+    assert s["host_ms_per_tick_p50"] == pytest.approx(110.0, rel=0.2)
+    # the same work, every tick collected before the next launch: the
+    # schedule phase is then exposed, and it was hidden above
+    seq = serve(_sequential)
+    assert seq["launch_ahead_share"] == 0.0
+    assert seq["host_exposed_share"] == pytest.approx(100 * 0.01 / 0.11, rel=0.05)
+    assert s["host_exposed_share"] < 0.2 * seq["host_exposed_share"]
 
 
 def test_tick_span_carries_its_phases(rng):
@@ -2217,22 +2361,33 @@ def test_tick_span_carries_its_phases(rng):
     eng = ServingEngine(
         model, params, n_slots=1, clock=fake_clock, tracer=tracer,
     )
-    eng.add_request(_req(prompt[0], 10))
+    eng.add_request(_req(prompt[0], 26))
     eng.run()
     ticks = [s for s in tracer.spans if s.name == "tick"]
     assert len(ticks) >= 2
+    leaves = sorted(
+        (s for s in tracer.spans
+         if s.track == "scheduler" and s.name.startswith("tick.")
+         and s.name != "tick.between"),
+        key=lambda s: s.start,
+    )
+    # leaves: sequential, never overlapping one another, though a tick
+    # span now holds its successor's launch between its own two halves
+    assert all(a.end <= b.start for a, b in zip(leaves, leaves[1:]))
+    assert len(leaves) == len(PHASES) * len(ticks)
     for tick in ticks:
         assert {f"{n}_ms" for n in PHASES} <= set(tick.attrs)
         inside = [
-            s for s in tracer.spans
-            if s.track == "scheduler" and s.name.startswith("tick.")
-            and s.name != "tick.between"
-            and tick.start <= s.start and s.end <= tick.end
+            s for s in leaves if tick.start <= s.start and s.end <= tick.end
         ]
         assert {s.name for s in inside} == {f"tick.{n}" for n in PHASES}
-        # leaves: sequential, never overlapping one another
-        inside.sort(key=lambda s: s.start)
-        assert all(a.end <= b.start for a, b in zip(inside, inside[1:]))
+        # its own launch opens it, its own collect closes it
+        assert [s.name for s in inside[:3]] == [
+            "tick.schedule", "tick.prefill", "tick.dispatch"
+        ]
+        assert [s.name for s in inside[-3:]] == [
+            "tick.device_wait", "tick.deliver", "tick.record"
+        ]
     # the decode dispatch's window holds exactly the wait for the device
     decode = [s for s in tracer.spans if s.name == "decode_tick"]
     waits = [s for s in tracer.spans if s.name == "tick.device_wait"]

@@ -26,9 +26,10 @@ length retirement) between ticks:
   distribution via the Leviathan rejection rule).  An explicit T > 1
   fuses T whole draft-verify-accept blocks per dispatch, drafting
   in-scan from a device-resident token history via the traceable NGram
-  twin.  ``step()`` itself is ``collect(launch())``; ``run(
-  overlap=True)`` pipelines the halves one tick deep so tick N's host
-  sync + delivery overlaps tick N+1's device compute.
+  twin.  ``step()`` is ``launch()`` then ``collect()``, one tick deep:
+  on the fused and unified ticks it launches tick N+1 before it reads
+  tick N, so N's host sync and delivery, and the caller's work between
+  two steps, overlap N+1's device compute.
 - the decode step threads per-slot positions and per-slot cache write
   indices (``write_index`` — the slot-indexed write path in
   ``models/layers.py``) because rows sit at different depths of their
@@ -994,6 +995,38 @@ def _own_arrays(tree):
     return jax.tree_util.tree_map(own, tree)
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _seat_rows(
+    state, knobs, slots, first, plen, budget, eos, temp, topk, topp, act
+):
+    """Write admitted requests' rows into the device-resident slot state,
+    as the unified tick's final chunk does in-device: ``slots`` [nb] (a
+    dummy row carries ``n_slots`` and is dropped), ``first`` the sampled
+    first tokens still on the device, ``plen`` / ``budget`` / ``eos`` and
+    the sampling knobs per row.  ``act`` rows go live unless the first
+    token already ends them (EOS, a budget of one, the non-finite
+    sentinel); the others (a chunked prompt's start) get their knobs and
+    stay dead until the tick's chunk phase activates them.  The state is
+    donated and chains between two ticks; the knobs are small and are
+    not."""
+    left = budget - 1
+    done = (first == eos) | (left <= 0) | (first == NON_FINITE_TOKEN)
+
+    def put(rows, values):
+        return rows.at[slots].set(values.astype(rows.dtype), mode="drop")
+
+    tok, pos, widx, live, bud = state
+    state = (
+        put(tok, first), put(pos, plen), put(widx, plen),
+        put(live, act & ~done), put(bud, left),
+    )
+    knobs = tuple(
+        put(rows, values)
+        for rows, values in zip(knobs, (eos, temp, topk, topp))
+    )
+    return state, knobs
+
+
 @functools.lru_cache(maxsize=8)
 def _sharded_engine_fns(model, mesh, specs: _HashableTree,
                         cache_specs: _HashableTree):
@@ -1090,7 +1123,7 @@ class _PendingTick:
         "kind", "start", "t0", "t1", "tick_span", "events", "admitted",
         "chunks_advanced", "chunk_tokens", "chunk_spans", "active_tokens",
         "entering", "finals", "payload", "overlapped", "phases", "between",
-        "expert_rows",
+        "expert_rows", "firsts", "owners",
     )
 
     def __init__(self):
@@ -1110,7 +1143,14 @@ class _PendingTick:
         self.entering: Tuple[int, ...] = ()
         self.finals: List[tuple] = []
         self.payload = None
+        # launched while its predecessor was still uncollected
         self.overlapped = False
+        # admissions seated on the device by this launch: (first tokens
+        # [nb], still on the device until collect, [(row, slot, out)]);
+        # and who held each slot when the tick was dispatched: a later
+        # tenant of a slot never receives this tick's tokens
+        self.firsts: List[tuple] = []
+        self.owners: List[Optional[RequestOutput]] = []
         # expert row counts this tick's programs returned (device arrays
         # until collect reads them)
         self.expert_rows: list = []
@@ -1171,8 +1211,10 @@ class ServingEngine:
       donated — one host dispatch + one device sync per T tokens instead
       of per token (the per-step tick's dominant cost at small batch).
       Slot state (current token, cache position, write index, live mask,
-      remaining budget) lives in device arrays between ticks; the host
-      re-uploads only after admissions/retirements.  Slots finishing
+      remaining budget) lives in device arrays between ticks; admissions
+      write their rows into it, and the host re-uploads only after a
+      cancel (paged and speculative engines: after every admission and
+      release).  Slots finishing
       mid-scan (EOS, budget) park their writes at column ``seq_len`` for
       the remaining steps.  Streaming granularity becomes per-tick
       (bounded by T).  ``"auto"`` (default) = 8; spec engines
@@ -1198,16 +1240,19 @@ class ServingEngine:
       (:func:`~tpu_parallel.serving.spec_decode.ngram_draft_tokens`) —
       custom drafters refuse (their host state is invisible mid-scan).
 
-    Double-buffered host/device overlap (``run(overlap=True)``, or the
-    :meth:`launch` / :meth:`collect` halves directly): tick N's
-    device->host sync, delivery and admission bookkeeping overlap tick
-    N+1's device compute — :meth:`launch` dispatches without syncing and
-    :meth:`collect` syncs/delivers, with a one-tick-deep pipeline on
-    pure-decode ticks (the only ticks whose launch reads no host-mutable
-    state).  The donation-ownership contract from the fused tick is the
-    invariant: buffers a launch's dispatch returned belong to the device
-    until that tick's collect (``scripts/check_host_sync.py`` gates
-    launch bodies against syncs lexically).
+    One tick queued on the device (:meth:`step`, or the :meth:`launch` /
+    :meth:`collect` halves directly): tick N's device->host sync and
+    delivery, and the caller's work between two steps, overlap tick
+    N+1's device compute — :meth:`launch` dispatches without syncing,
+    admissions and whole-prompt prefills included, and :meth:`collect`
+    syncs and delivers, one tick deep on the fused and unified ticks
+    over the fixed-slot pool (their slot state stays on the device as
+    the authority; the other engines' launches read host mirrors and
+    keep ``collect(launch())``).  The donation-ownership contract from
+    the fused tick is the invariant: buffers a launch's dispatch
+    returned belong to the device until that tick's collect
+    (``scripts/check_host_sync.py`` gates what launch reaches against
+    syncs).
 
     Speculative decode knobs (exact for every drafter — see the module
     docstring and ``docs/10_serving_engine.md``):
@@ -1334,11 +1379,18 @@ class ServingEngine:
                 scheduler, clock=clock, registry=self.registry
             )
         self._queue_spans: Dict[str, object] = {}
+        # the one-tick pipeline: the tick step() left on the device, and
+        # why the next launch may not go ahead of a collect (a host-side
+        # change the device state has to be rebuilt for)
+        self._pending: Optional[_PendingTick] = None
+        self._flush_cause: Optional[str] = None
         # the phase clock's memory across ticks: the newest launched
         # tick (a collect that finds another one in flight beside its
-        # own was pipelined), and the end of the last busy sequential
+        # own ran beside device work), the end of the last collect (a
+        # pipelined tick's period starts there) and of the last busy
         # tick's collect — what `between` is measured from
         self._newest_tick: Optional[_PendingTick] = None
+        self._collect_end = float("-inf")
         self._busy_end: Optional[float] = None
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
 
@@ -1601,12 +1653,20 @@ class ServingEngine:
                 )
         else:
             self._fused_fn = None
-        # device-resident slot state (fused path): uploaded lazily after
-        # host-side mutations, otherwise the previous tick's returned
-        # arrays are re-donated — steady-state decode never re-uploads
+        # device-resident slot state (fused path): the previous tick's
+        # returned arrays are re-donated, so steady-state decode never
+        # re-uploads.  On the engines that chain ticks (fused or unified,
+        # fixed-slot pool) it is the authority: admissions write their
+        # own rows into it (_seat_rows), retirements are already dead in
+        # its live mask, and the host mirrors follow at collect; only a
+        # host-side removal (cancel, an integrity trip) rebuilds it from
+        # the mirrors, once nothing is in flight.  The other fused
+        # engines (paged, speculative) re-upload after every admission
+        # and release, as they did.
         self._dev_state = None
         self._dev_knobs = None
         self._state_dirty = True
+        self._chains = fused > 1 and not self._spec_fused and not self._paged
         # device copy of the paged block-table mirror, re-uploaded only
         # when the allocator bumped table_version
         self._dev_table = None
@@ -1823,6 +1883,7 @@ class ServingEngine:
             if out is None:
                 return False
             self.release_slot(slot)
+            self._flush("cancel")
         now = self.clock()
         span = self._queue_spans.pop(request_id, None)
         if span is not None:
@@ -1856,8 +1917,21 @@ class ServingEngine:
         self._active[slot] = False
         self._slot_out[slot] = None
         self._widx[slot] = self.model.config.seq_len
-        self._state_dirty = True  # fused path re-uploads before its next tick
+        if not self._chains:
+            # re-upload before the next tick; where ticks chain, a slot
+            # retired by length or EOS is already dead in the live mask
+            # the device carries, and only a removal the device cannot
+            # know of rebuilds its state (_flush)
+            self._state_dirty = True
         self.pool.release(slot)
+
+    def _flush(self, cause: str) -> None:
+        """The host removed a seated request behind the device's back:
+        the next launch waits for the tick in flight and rebuilds the
+        device's slot state from the host mirrors, which are whole again
+        once nothing is in flight."""
+        self._state_dirty = True
+        self._flush_cause = cause
 
     def begin_drain(self) -> None:
         """Graceful-drain admission gate: new ``add_request`` submissions
@@ -1896,10 +1970,52 @@ class ServingEngine:
         admissions as one batched prefill), one decode tick over the
         pool (``decode_steps_per_tick`` fused scan steps — or one
         per-step / speculative-verify step), retire finished slots.
-        ``collect(launch())`` — the two halves exist so a caller (or
-        ``run(overlap=True)``) can overlap tick N's host bookkeeping
-        with tick N+1's device compute.  Returns this tick's events."""
-        return self.collect(self.launch())
+        Returns the events of ONE tick.
+
+        The two halves are :meth:`launch` (dispatch, no sync) and
+        :meth:`collect` (one sync, delivery), and ``step()`` keeps one
+        tick queued on the device between them: with tick N pending it
+        launches tick N+1 FIRST and then collects N, so N's sync and
+        delivery, and whatever the caller does before its next
+        ``step()``, run beside device work.  N+1 admits into the slots
+        that were free at the last collect; a slot N frees is refilled
+        by N+2.  Whether a launch may go ahead is read off the engine
+        (:meth:`_ahead_refusal`); where it may not, this is
+        ``collect(launch())``."""
+        p, self._pending = self._pending, None
+        if p is None:
+            p = self.launch()
+        refusal = self._ahead_refusal(p)
+        if refusal is None:
+            self._pending = self.launch(ahead=True)
+        elif p.kind != "idle" and self._chains:
+            self.metrics.record_flush(refusal)
+        return self.collect(p)
+
+    def _ahead_refusal(self, p: _PendingTick) -> Optional[str]:
+        """Why the next tick may NOT be dispatched before ``p``, the tick
+        in flight, is collected; None when it may.  Ticks chain on the
+        engines whose launch reads nothing the tick in flight will
+        change: the fused and unified ticks over the fixed-slot pool,
+        whose slot state stays on the device (the per-step, speculative,
+        paged and mesh engines draft, grow block tables or upload from
+        host mirrors that lag a tick in flight).  A host-side removal
+        (``_flush``) waits for the collect; and a launch goes ahead only
+        for work that is certain: a slot with budget beyond the tick in
+        flight, a chunked prompt mid-way, or a queued request and a free
+        slot — else the tick after a drain would run over dead slots."""
+        if not self._chains or p.kind == "idle":
+            return "tick_kind"
+        if self._flush_cause is not None:
+            return self._flush_cause
+        if self._chunking or (self.scheduler.depth and self.pool.n_free):
+            return None
+        reach = self._fused_steps + 1  # the tick in flight, a first token
+        for slot in np.nonzero(self._active)[0]:
+            out = self._slot_out[slot]
+            if out.request.max_new_tokens - len(out.tokens) > reach:
+                return None
+        return "draining"
 
     def launch(self, ahead: bool = False) -> _PendingTick:
         """The tick's HOST->DEVICE half: expire, fold/advance chunked
@@ -1907,12 +2023,12 @@ class ServingEngine:
         syncing.  Returns the pending handle :meth:`collect` finishes.
 
         ``ahead=True`` marks a pipelined launch (tick N+1 dispatched
-        while tick N is still uncollected — only legal on a pure-decode
-        tick, :meth:`_can_launch_ahead`); the paged write-window then
-        covers two ticks, since the host mirrors lag the device by one.
+        while tick N is still uncollected, :meth:`_ahead_refusal`): the
+        host mirrors then lag the device by the tick in flight, and what
+        this launch dispatches takes its slot state from the device.
         Between launch and collect every donated buffer belongs to the
-        device: nothing here may read device results (the launch-body
-        sync gate in ``scripts/check_host_sync.py``)."""
+        device: nothing launch reaches may read device results (the
+        launch rule in ``scripts/check_host_sync.py``)."""
         p = self._newest_tick = _PendingTick()
         p.overlapped = ahead
         if self.tracer.enabled:
@@ -1928,6 +2044,12 @@ class ServingEngine:
             now = p.start = first.start
             if self._busy_end is not None:
                 p.between, self._busy_end = now - self._busy_end, None
+            if self._chains and (
+                self._state_dirty or self._dev_state is None
+            ):
+                # before any admission writes its rows: the mirrors hold
+                # no first token of a request seated by this launch
+                self._upload_slot_state()
             self._expire_queue(now, p.events)
             if not chunks_first:
                 p.admitted = self._schedule(now)
@@ -1953,7 +2075,8 @@ class ServingEngine:
             # capacity denominator behind kv_bytes_per_active_token
             p.active_tokens = int(self._pos[self._active].sum()) + sum(
                 st.offset for st in self._chunking.values()
-            ) + sum(plen for (_, _, plen) in p.finals)
+            )
+        p.owners = list(self._slot_out)
         p.expert_rows = self._expert_rows[:]
         del self._expert_rows[:]
         p.t0 = dispatch.end
@@ -2026,17 +2149,20 @@ class ServingEngine:
 
     def collect(self, p: _PendingTick) -> List[StreamEvent]:
         """The tick's DEVICE->HOST half: ONE sync on the launch's result
-        handles, then delivery, retirement, metric syncs and the tick
-        record.  Pure host work apart from the sync — under
-        ``run(overlap=True)`` all of it runs while the NEXT tick's
-        device dispatch is already computing."""
+        handles, then delivery (the first tokens of the requests the
+        launch seated, then the tick's block), retirement, metric syncs
+        and the tick record.  Pure host work apart from the sync — from
+        ``step()`` all of it runs while the NEXT tick's device dispatch
+        is already computing."""
         events = p.events
         decoded = p.kind != "idle"
         if decoded:
             with self._phase(p, "device_wait") as wait:
-                p.payload = self._sync_payload(p)
+                self._sync_payload(p)
             p.t1 = wait.end
             with self._phase(p, "deliver"):
+                for tokens, rows in p.firsts:
+                    events.extend(self._deliver_firsts(tokens, rows))
                 if p.kind == "fused":
                     events.extend(self._collect_fused(p))
                 elif p.kind == "unified":
@@ -2087,6 +2213,10 @@ class ServingEngine:
             else:
                 stall = STALL_NONE
         end = record.end
+        # the tick's period: from its predecessor's collect where it was
+        # launched ahead of that, else from its own launch
+        period = end - max(p.start, self._collect_end)
+        self._collect_end = end
         self.metrics.record_tick(
             now=end,
             queue_depth=self.scheduler.depth,
@@ -2096,18 +2226,20 @@ class ServingEngine:
             prefills=len(admitted),
             decoded=decoded,
             stall=stall,
-            host_ms=(end - p.start) * 1000.0,
+            host_ms=period * 1000.0,
         )
-        if p.overlapped:
-            self.metrics.record_overlap()
-        # a tick is pipelined when it was launched ahead, or when the
-        # next one was: either way part of it ran with device work
-        # queued, so its phases say nothing about host-exposed time
-        pipelined = p.overlapped or self._newest_tick is not p
-        if decoded and not pipelined:
+        if decoded:
+            # what ran beside device work is not host-exposed: this
+            # tick's launch and the gap before it where its predecessor
+            # was in flight, its collect where its successor already is
+            hidden = set()
+            if p.overlapped:
+                hidden.update(("between", "schedule", "prefill", "dispatch"))
+            if self._newest_tick is not p:
+                hidden.update(("device_wait", "deliver", "record"))
             self.metrics.record_busy_tick(
-                end - p.start, p.phases, prefill=stall == STALL_PREFILL,
-                between=p.between,
+                period, p.phases, prefill=stall == STALL_PREFILL,
+                between=p.between, ahead=p.overlapped, hidden=hidden,
             )
             self._busy_end = end
             if p.between is not None and self.tracer.enabled:
@@ -2125,84 +2257,39 @@ class ServingEngine:
             )
         return events
 
-    def _sync_payload(self, p: _PendingTick):
+    def _sync_payload(self, p: _PendingTick) -> None:
         """The tick's ONE device sync: the launch's result handles (all
-        of one dispatch) become host arrays; a host-side entry (the spec
-        tick's draft lengths) passes through."""
+        of one dispatch) become host arrays on ``p.payload``; a host-side
+        entry (the spec tick's draft lengths) passes through.  The first
+        tokens of the requests the launch seated are read in the same
+        wait: they were sampled before the tick ran."""
+        p.firsts = [
+            (np.asarray(tokens), rows)  # host-sync: with the tick's block
+            for tokens, rows in p.firsts
+        ]
         if p.kind == "step":
-            return np.asarray(p.payload)
-        return tuple(
-            x if x is None else np.asarray(x)  # host-sync: once a tick
-            for x in p.payload
-        )
+            p.payload = np.asarray(p.payload)
+        else:
+            p.payload = tuple(
+                x if x is None else np.asarray(x)  # host-sync: once a tick
+                for x in p.payload
+            )
 
     def has_work(self) -> bool:
         return (
-            self.scheduler.depth > 0
+            self._pending is not None
+            or self.scheduler.depth > 0
             or bool(self._active.any())
             or bool(self._chunking)
         )
 
-    def _can_launch_ahead(self) -> bool:
-        """True when the NEXT tick may dispatch before the pending one
-        collects: a pure-decode fused/unified tick whose launch reads no
-        host-mutable state — device-resident slot state chains through
-        donation, the queue is empty (no admissions or expiries), no
-        chunk work, and the host mirrors are clean.  Any other tick
-        flushes the pipeline first (host mirrors must catch up before
-        they feed a dispatch)."""
-        return (
-            self._fused_steps > 1
-            and not self._state_dirty
-            and self._dev_state is not None
-            and not self._chunking
-            and self.scheduler.depth == 0
-            and bool(self._active.any())
-        )
-
-    def run(
-        self, max_ticks: Optional[int] = None, overlap: bool = False
-    ) -> List[StreamEvent]:
-        """Tick until idle (or ``max_ticks``); returns all events.
-
-        ``overlap=True`` runs the one-tick-deep launch/collect pipeline:
-        on pure-decode stretches tick N+1's device dispatch is issued
-        BEFORE tick N's results are synced, so tick N's host sync +
-        delivery bookkeeping overlaps tick N+1's device compute (the
-        ``serving_host_overlap_ratio`` gauge records how often).  Output
-        is bitwise identical to the sequential loop: a launch-ahead only
-        happens when the next launch reads no host-mutable state, and a
-        slot that finishes inside tick N is already dead in the device
-        live-mask tick N+1 carries — its surplus tick is parked, and the
-        host retires it when tick N collects."""
+    def run(self, max_ticks: Optional[int] = None) -> List[StreamEvent]:
+        """``step()`` until idle (or ``max_ticks`` steps, which may leave
+        a tick in flight for the next call); returns all events."""
         events: List[StreamEvent] = []
         ticks = 0
-        if not overlap:
-            while self.has_work() and (
-                max_ticks is None or ticks < max_ticks
-            ):
-                events.extend(self.step())
-                ticks += 1
-            return events
-        pending: Optional[_PendingTick] = None
-        while True:
-            if pending is not None:
-                if self._can_launch_ahead() and (
-                    max_ticks is None or ticks < max_ticks
-                ):
-                    nxt = self.launch(ahead=True)
-                    ticks += 1
-                    events.extend(self.collect(pending))
-                    pending = nxt
-                else:
-                    events.extend(self.collect(pending))
-                    pending = None
-                continue
-            if not self.has_work() or (
-                max_ticks is not None and ticks >= max_ticks
-            ):
-                break
-            pending = self.launch()
+        while self.has_work() and (max_ticks is None or ticks < max_ticks):
+            events.extend(self.step())
             ticks += 1
         return events
 
@@ -2583,12 +2670,12 @@ class ServingEngine:
             # legacy exact-length path: batch-1 prefill per request,
             # compiled per distinct prompt length (the PR 1 behavior)
             for out in batch:
-                events.append(self._admit_exact(out))
+                events.extend(self._admit_exact(out))
             return events
         events.extend(self._admit_bucketed(batch))
         return events
 
-    def _admit_exact(self, out: RequestOutput) -> StreamEvent:
+    def _admit_exact(self, out: RequestOutput) -> List[StreamEvent]:
         req = out.request
         slot = self.pool.acquire()
         assert slot is not None, "scheduler admitted beyond free slots"
@@ -2605,14 +2692,14 @@ class ServingEngine:
         self._prefill_shapes.add(("prefill", 1, length))
         self.metrics.record_prefill_call()
         self.pool.insert(fresh, slot)
-        tok0 = self._sample_first(logits, [out])[0]
+        first = self._sample_first(logits, [out])
         if self.tracer.enabled:
             self.tracer.record(
                 "prefill", f"slot {slot}", t0, self.tracer.now(),
                 request_id=req.request_id, slot=slot, bucket=length,
                 cache_hit=False,
             )
-        return self._activate(slot, out, tok0, length)
+        return self._seat([slot], [out], first)
 
     def _admit_bucketed(
         self, outs: List[RequestOutput]
@@ -2659,15 +2746,11 @@ class ServingEngine:
                     request_id=out.request.request_id, slot=int(slots[i]),
                     bucket=width, cache_hit=False,
                 )
-        events = []
         for i, out in enumerate(outs):
             # store BEFORE activating (uniform with the paged path, where
             # immediate retirement wipes the slot's block table)
             self._maybe_store_prefix(out, int(slots[i]))
-            events.append(
-                self._activate(int(slots[i]), out, firsts[i], int(lengths[i]))
-            )
-        return events
+        return self._seat(slots, outs, firsts)
 
     def _admit_prefix_batch(
         self, group: List[tuple], prefix_len: int, width: int
@@ -2719,7 +2802,6 @@ class ServingEngine:
                     request_id=out.request.request_id, slot=int(slots[i]),
                     bucket=width, cache_hit=True, prefix_len=prefix_len,
                 )
-        events = []
         for i, out in enumerate(outs):
             # a request hitting on a SHORT prefix may carry a longer
             # bucket-aligned prefix that was LRU-evicted — re-seed it
@@ -2727,12 +2809,7 @@ class ServingEngine:
             # activating (uniform with the paged path, where immediate
             # retirement wipes the slot's block table)
             self._maybe_store_prefix(out, int(slots[i]))
-            events.append(
-                self._activate(
-                    int(slots[i]), out, firsts[i], len(out.request.prompt)
-                )
-            )
-        return events
+        return self._seat(slots, outs, firsts)
 
     def _admit_batch_paged(
         self, admitted: List[RequestOutput]
@@ -2849,19 +2926,13 @@ class ServingEngine:
                     request_id=out.request.request_id, slot=slots[i],
                     bucket=width, cache_hit=plen > 0, prefix_len=plen,
                 )
-        events = []
         for i, out in enumerate(outs):
             # store BEFORE activating: a request finishing on its first
             # token (max_new_tokens=1 / immediate EOS) releases its slot
             # inside _activate's delivery, wiping the block table the
             # snapshot needs
             self._maybe_store_prefix(out, slots[i])
-            events.append(
-                self._activate(
-                    slots[i], out, firsts[i], len(out.request.prompt)
-                )
-            )
-        return events
+        return self._seat(slots, outs, firsts)
 
     def _extend_slot(
         self, slot: int, tokens_seq, offset: int, width: int
@@ -2954,20 +3025,17 @@ class ServingEngine:
         if self._unified and self._fused_steps > 1:
             # the unified tick runs this slot's first chunk inside THIS
             # tick's one dispatch; activation may happen in-device, so
-            # the slot's sampling knobs (and spec caps) must reach the
-            # device state before then — mark them now and dirty the
-            # upload
-            sp = out.request.sampling
-            self._temp[slot] = sp.temperature
-            self._topk[slot] = sp.top_k
-            self._topp[slot] = sp.top_p
-            req_k = out.request.draft_tokens
-            cap = self._spec_width if req_k is None else min(
-                req_k, self._spec_width
-            )
-            self._spec_max[slot] = cap
-            self._spec_k[slot] = cap
-            self._state_dirty = True
+            # the slot's sampling knobs, EOS (and spec caps) must reach
+            # the device state before then: as rows of their own where
+            # ticks chain, else through the mirrors and a re-upload
+            self._mirror_knobs(slot, out)
+            if self._chains:
+                self._write_rows(
+                    [(0, slot, out)], jnp.asarray(np.zeros(1, np.int32)),
+                    live=False,
+                )
+            else:
+                self._state_dirty = True
             return []
         return self._advance_chunk(slot)
 
@@ -2996,11 +3064,11 @@ class ServingEngine:
         if st.offset < len(prompt):
             return []
         del self._chunking[slot]
-        tok0 = self._sample_first(logits, [st.out])[0]
+        first = self._sample_first(logits, [st.out])
         # store BEFORE activating: immediate retirement inside _activate
         # releases the slot (paged: wipes the table the snapshot needs)
         self._maybe_store_prefix(st.out, slot)
-        return [self._activate(slot, st.out, tok0, len(prompt))]
+        return self._seat([slot], [st.out], first)
 
     def _maybe_store_prefix(self, out: RequestOutput, slot: int) -> None:
         """Seed the prefix cache from a freshly prefilled slot row (every
@@ -3043,10 +3111,10 @@ class ServingEngine:
             return
         self._prefix.store(prompt, self._buckets, self.pool.extract(slot))
 
-    def _sample_first(self, logits, outs: List[RequestOutput]) -> List[int]:
+    def _sample_first(self, logits, outs: List[RequestOutput]) -> jax.Array:
         """Sample each admitted request's FIRST token from its prefill
-        logits (rows beyond ``outs`` are a padded batch's dummies —
-        sampled greedily and discarded)."""
+        logits, on the device and left there (rows beyond ``outs`` are a
+        padded batch's dummies — sampled greedily and discarded)."""
         nb = logits.shape[0]
         temp = np.zeros(nb, np.float32)
         topk = np.zeros(nb, np.int32)
@@ -3054,25 +3122,78 @@ class ServingEngine:
         for i, out in enumerate(outs):
             sp = out.request.sampling
             temp[i], topk[i], topp[i] = sp.temperature, sp.top_k, sp.top_p
-        first = self._sample_fn(
+        return self._sample_fn(
             logits,
             self._next_rng(),
             jnp.asarray(temp),
             jnp.asarray(topk),
             jnp.asarray(topp),
         )
-        first = np.asarray(first)
-        return [int(first[i]) for i in range(len(outs))]
 
-    def _activate(
-        self, slot: int, out: RequestOutput, tok0: int, prompt_len: int
-    ) -> StreamEvent:
-        """Commit an admitted request to its slot: decode state, knobs,
-        first-token delivery."""
+    def _seat(
+        self, slots, outs: List[RequestOutput], first: jax.Array
+    ) -> List[StreamEvent]:
+        """Commit admitted requests to their slots (``slots[i]`` takes
+        ``outs[i]``, its first token ``first[i]`` still on the device).
+        Where ticks chain, the rows go into the device's slot state
+        (:func:`_seat_rows`) and the first tokens travel with this
+        launch's tick, to be delivered at its collect: no event yet.  The
+        other engines read them back now and deliver."""
+        if not self._chains:
+            first = np.asarray(  # host-sync: these engines do not chain
+                first
+            )
+            return [
+                self._activate(int(slot), out, int(first[i]))
+                for i, (slot, out) in enumerate(zip(slots, outs))
+            ]
+        # any sequence of token ids will do (tests/benchmarks doctors them)
+        first = jnp.asarray(first, jnp.int32)
+        rows = [
+            (i, int(slot), out)
+            for i, (slot, out) in enumerate(zip(slots, outs))
+        ]
+        for _, slot, out in rows:
+            self._occupy(slot, out)
+        self._write_rows(rows, first, live=True)
+        self._newest_tick.firsts.append((first, rows))
+        return []
+
+    def _write_rows(self, rows, first: jax.Array, live: bool) -> None:
+        """One :func:`_seat_rows` call: row ``i`` of ``first`` and its
+        request's budget, EOS and knobs go to ``slot`` for each ``(i,
+        slot, out)``; ``live`` rows decode from their prompt's end, the
+        others (a chunked prompt's start) only get their knobs."""
+        nb, n = first.shape[0], self.pool.n_slots
+        where = np.full(nb, n, np.int32)  # a batch's dummy rows drop
+        ints = np.zeros((3, nb), np.int32)  # prompt length, budget, EOS
+        knobs = np.zeros((3, nb), np.float32)  # temperature, top-k, top-p
+        for i, slot, out in rows:
+            req, sp = out.request, out.request.sampling
+            where[i] = slot
+            eos = -1 if req.eos_token_id is None else req.eos_token_id
+            ints[:, i] = len(req.prompt), req.max_new_tokens, eos
+            knobs[:, i] = sp.temperature, sp.top_k, sp.top_p
+        self._dev_state, self._dev_knobs = _seat_rows(
+            self._dev_state, self._dev_knobs, jnp.asarray(where), first,
+            *(jnp.asarray(x) for x in ints),
+            jnp.asarray(knobs[0]), jnp.asarray(knobs[1].astype(np.int32)),
+            jnp.asarray(knobs[2]),
+            jnp.asarray((where < n) & live),
+        )
+
+    def _occupy(self, slot: int, out: RequestOutput) -> None:
+        """The host mirrors of a slot whose request decodes from its
+        prompt's end: everything but its current token, which the device
+        may still hold."""
+        self._pos[slot] = self._widx[slot] = len(out.request.prompt)
+        self._mirror_knobs(slot, out)
+        self._active[slot] = True
+        self._slot_out[slot] = out
+        out.status = RUNNING
+
+    def _mirror_knobs(self, slot: int, out: RequestOutput) -> None:
         sp = out.request.sampling
-        self._tok[slot] = tok0
-        self._pos[slot] = prompt_len
-        self._widx[slot] = prompt_len
         self._temp[slot] = sp.temperature
         self._topk[slot] = sp.top_k
         self._topp[slot] = sp.top_p
@@ -3086,12 +3207,29 @@ class ServingEngine:
         )
         self._spec_max[slot] = cap
         self._spec_k[slot] = cap
-        self._active[slot] = True
-        self._slot_out[slot] = out
-        self._state_dirty = True  # fused path re-uploads before its next tick
-        out.status = RUNNING
-        out.first_token_time = self.clock()
+
+    def _activate(
+        self, slot: int, out: RequestOutput, tok0: int
+    ) -> StreamEvent:
+        """Commit an admitted request to its slot on the host: mirrors,
+        first-token delivery, and a re-upload before the next tick."""
+        self._occupy(slot, out)
+        self._state_dirty = True
+        return self._first_token(slot, tok0)
+
+    def _first_token(self, slot: int, tok0: int) -> StreamEvent:
+        self._tok[slot] = tok0
+        self._slot_out[slot].first_token_time = self.clock()
         return self._deliver(slot, tok0)
+
+    def _deliver_firsts(self, tokens, rows) -> List[StreamEvent]:
+        """The first tokens of requests a launch seated on the device,
+        now on the host; a request cancelled since gets nothing."""
+        return [
+            self._first_token(slot, int(tokens[i]))
+            for i, slot, out in rows
+            if self._slot_out[slot] is out
+        ]
 
     def _launch_per_step(self, p: _PendingTick) -> None:
         if self._paged:
@@ -3159,10 +3297,12 @@ class ServingEngine:
 
     def _upload_slot_state(self) -> None:
         """Rebuild the device-resident slot-state arrays from the host
-        mirrors.  Runs only after a host-side mutation (admission,
-        retirement, cancel); between mutations the fused tick re-donates
-        the arrays the previous tick returned, so a steady-state decode
-        never re-uploads.  Budget and EOS derive from the live request
+        mirrors, with nothing in flight.  Where ticks chain it runs at
+        the head of a launch, after a removal the device could not know
+        of (cancel, an integrity trip) and on the first tick; on the
+        paged and speculative engines after every admission and release.
+        Otherwise the fused tick re-donates the arrays the previous tick
+        returned.  Budget and EOS derive from the live request
         records (budget = remaining new tokens; EOS -1 = no stop id);
         mid-chunked-prefill slots contribute their EOS too — the unified
         tick's in-device activation checks it before the host ever sees
@@ -3202,7 +3342,7 @@ class ServingEngine:
                     self._spec_max,
                 ),
             ))
-            self._state_dirty = False
+            self._state_dirty, self._flush_cause = False, None
             return
         # one jitted call producing XLA-OWNED buffers (never zero-copy
         # views of the host mirrors — see _own_arrays for why donating
@@ -3211,7 +3351,7 @@ class ServingEngine:
             (self._tok, self._pos, self._widx, self._active, budget),
             (eos, self._temp, self._topk, self._topp),
         ))
-        self._state_dirty = False
+        self._state_dirty, self._flush_cause = False, None
 
     def _ensure_decode_writable(self, p: _PendingTick, width: int) -> None:
         """Paged launches: make every column this tick CAN write writable
@@ -3220,13 +3360,10 @@ class ServingEngine:
         scan's inputs loop-invariant — steady-state ticks re-upload
         nothing and the compile count stays pinned.  ``width`` is the
         tick's worst-case per-slot column advance (T decode steps, or
-        T * (K + 1) verify columns); a pipelined (``ahead``) launch
-        doubles it — the host mirrors lag the in-flight tick by up to
-        one width, and the budget clamp keeps the doubled window inside
-        the slot's entitlement."""
+        T * (K + 1) verify columns).  Paged ticks never launch ahead:
+        the window is read off host mirrors that a tick in flight would
+        leave a width behind."""
         seq_len = self.model.config.seq_len
-        if p.overlapped:
-            width *= 2
         for slot in p.entering:
             out = self._slot_out[slot]
             if out is None:
@@ -3235,9 +3372,10 @@ class ServingEngine:
             rem = out.request.max_new_tokens - len(out.tokens)
             end = min(w + min(width, max(rem, 0)), seq_len)
             self.pool.ensure_writable(slot, w, end)
-        for slot, out, plen in p.finals:
+        for slot, out in p.finals:
             # a chunk completing this tick activates in-device and
             # decodes from its prompt length immediately
+            plen = len(out.request.prompt)
             end = min(
                 plen + min(width, out.request.max_new_tokens), seq_len
             )
@@ -3298,9 +3436,12 @@ class ServingEngine:
             if st.offset >= len(prompt):
                 cfinal[slot] = True
                 cbudget[slot] = st.out.request.max_new_tokens
-                p.finals.append((slot, st.out, len(prompt)))
-        for slot, _, _ in p.finals:
+                p.finals.append((slot, st.out))
+        for slot, out in p.finals:
+            # activated in-device by this dispatch: the mirrors follow
+            # now, but for the first token, which collect brings
             del self._chunking[slot]
+            self._occupy(slot, out)
         p.chunk_tokens = consumed
         # chunk operands are per-tick uploads, never donated — plain
         # device puts are safe (no ownership hazard to launder)
@@ -3360,9 +3501,12 @@ class ServingEngine:
         pure chunk advancement counts as progress instead of tripping
         the guard (the unified tick's chunk-only regression), and a
         pipelined tick's stale mirror of a slot that finished in flight
-        is skipped via the activity re-check."""
+        is skipped via the activity re-check, or the owner's where the
+        slot has a new tenant by now."""
         stuck = [
-            s for s in p.entering if counts[s] == 0 and self._active[s]
+            s for s in p.entering
+            if counts[s] == 0 and self._active[s]
+            and self._slot_out[s] is p.owners[s]
         ]
         if stuck:
             raise RuntimeError(
@@ -3382,8 +3526,12 @@ class ServingEngine:
             c = int(counts[slot])
             # re-check liveness: a stream callback may have cancel()ed
             # this slot (releasing it, _slot_out -> None) while an
-            # earlier slot's tokens were being delivered
-            if c == 0 or not self._active[slot]:
+            # earlier slot's tokens were being delivered; and a tenant
+            # seated after this tick's dispatch has nothing in it
+            if (
+                c == 0 or not self._active[slot]
+                or self._slot_out[slot] is not p.owners[slot]
+            ):
                 continue
             if trace:
                 out = self._slot_out[slot]
@@ -3443,24 +3591,17 @@ class ServingEngine:
         self.metrics.record_dispatch(tokens=len(events))
         return events
 
-    def _activate_from_device(
-        self, slot: int, out: RequestOutput, tok0: int, prompt_len: int
-    ) -> StreamEvent:
-        """Finish a unified-tick in-device activation on the host side:
-        the device already sampled the first token, flipped the slot
-        live, and advanced its state — mirror that WITHOUT dirtying the
-        upload flag (the device state is the fresher of the two), then
-        deliver the first token."""
-        self._tok[slot] = tok0
-        self._pos[slot] = prompt_len
-        self._widx[slot] = prompt_len
-        self._active[slot] = True
-        self._slot_out[slot] = out
-        # the device seeded the slot's adaptive draft length at its cap
-        self._spec_k[slot] = self._spec_max[slot]
-        out.status = RUNNING
-        out.first_token_time = self.clock()
-        return self._deliver(slot, tok0)
+    def _deliver_finals(self, p: _PendingTick, act_emit) -> List[StreamEvent]:
+        """Finish the unified tick's in-device activations on the host:
+        the device sampled each first token, flipped the slot live and
+        advanced its state, and the launch moved the mirrors
+        (:meth:`_build_chunk_block`) — deliver the first token, but to no
+        request cancelled since."""
+        return [
+            self._first_token(slot, int(act_emit[slot]))
+            for slot, out in p.finals
+            if self._slot_out[slot] is out
+        ]
 
     def _collect_unified(self, p: _PendingTick) -> List[StreamEvent]:
         """Collect one unified ragged tick: sync the activation row and
@@ -3472,12 +3613,7 @@ class ServingEngine:
         trace = self.tracer.enabled
         t1 = p.t1
         self._collect_chunks(p, t1)
-        for slot, out, plen in p.finals:
-            events.append(
-                self._activate_from_device(
-                    slot, out, int(act_emit[slot]), plen
-                )
-            )
+        events.extend(self._deliver_finals(p, act_emit))
         self._check_progress(p, counts)
         if trace:
             self.tracer.record(
@@ -3691,12 +3827,7 @@ class ServingEngine:
         trace = self.tracer.enabled
         t1 = p.t1
         self._collect_chunks(p, t1)
-        for slot, out, plen in p.finals:
-            events.append(
-                self._activate_from_device(
-                    slot, out, int(act_emit[slot]), plen
-                )
-            )
+        events.extend(self._deliver_finals(p, act_emit))
         # the per-slot DELIVERED totals play the fused tick's counts role
         self._check_progress(p, counts.sum(axis=0))
         if trace:
@@ -3775,6 +3906,7 @@ class ServingEngine:
         out = self._slot_out[slot]
         req = out.request
         self.release_slot(slot)
+        self._flush("integrity")
         out.status = FAILED
         out.finish_reason = FAIL_INTEGRITY
         out.detail = (

@@ -212,10 +212,10 @@ class ServingMetrics:
         )
         self._host_ms_per_tick = r.histogram("serving_host_ms_per_tick")
         # the phase clock: where a BUSY tick's wall time went, phase by
-        # phase (idle ticks and pipelined ticks are observed in neither
-        # series — see record_busy_tick), and the tick's whole wall time
-        # from launch entry to collect exit, split by whether it carried
-        # prefill work.  Sums are exact: summary() reports sum / ticks.
+        # phase (idle ticks are observed in neither series — see
+        # record_busy_tick), and the tick's period, split by whether it
+        # carried prefill work.  Sums are exact: summary() reports
+        # sum / ticks.
         self._tick_phase = {
             name: r.histogram("serving_tick_phase_seconds", phase=name)
             for name in TICK_PHASES
@@ -224,19 +224,22 @@ class ServingMetrics:
             flag: r.histogram("serving_busy_tick_seconds", prefill=flag)
             for flag in ("0", "1")
         }
-        # the unified ragged tick + double-buffered launch/collect
-        # pipeline: tokens (prompt chunk tokens consumed + tokens
-        # generated) each unified dispatch advanced, and how many
-        # decode-family dispatches were launched while the previous
-        # tick's results were still uncollected — host bookkeeping for
-        # tick N overlapping device compute for tick N+1.  The ratio
-        # gauge is overlapped / decode dispatches (0 without the
-        # pipeline; > 0 is the acceptance gate for measured overlap).
+        # the unified ragged tick: tokens (prompt chunk tokens consumed
+        # + tokens generated) each unified dispatch advanced
         self._unified_tick_tokens = r.histogram(
             "serving_unified_tick_tokens"
         )
+        # step()'s one-tick pipeline: busy ticks launched while their
+        # predecessor was still uncollected (its sync and delivery, and
+        # the caller's work between two steps, then ran beside this
+        # tick's device work), the seconds of host-exposed phases that
+        # ran with nothing in flight, and each time a launch had to wait
+        # for the collect instead, by cause
         self._overlapped = r.counter("serving_overlapped_dispatches_total")
-        self._overlap_ratio = r.gauge("serving_host_overlap_ratio")
+        self._exposed_seconds = r.counter(
+            "serving_host_exposed_seconds_total"
+        )
+        self._flushes: Dict[str, object] = {}
         # per-tick stall attribution, pre-registered so every cause shows
         # a (possibly zero) series in exports
         self._stall = {
@@ -376,8 +379,6 @@ class ServingMetrics:
             self._host_ms_per_tick.observe(host_ms)
         if decoded:
             self._decode_ticks.inc()
-            if int(self._overlapped.value):
-                self._refresh_overlap_ratio()
         self._tokens_out.inc(new_tokens)
         self._prefills.inc(prefills)
         self._queue_depth.observe(queue_depth)
@@ -407,21 +408,37 @@ class ServingMetrics:
         phases: Dict[str, float],
         prefill: bool,
         between: Optional[float] = None,
+        ahead: bool = False,
+        hidden=(),
     ) -> None:
-        """One BUSY, sequential tick (it dispatched decode work and no
-        other tick was in flight beside it): its wall time from launch
-        entry to collect exit, the seconds each leaf phase took, and
-        ``between`` — the gap since the previous busy tick's collect,
-        None unless that tick was busy and sequential too, so an idle
-        sleep never enters.  Idle ticks are left out (they would pull
-        every mean toward the cost of doing nothing); so are pipelined
-        ticks (``launch(ahead=True)``), whose deliver / record run with
-        device work queued and would read as host-exposed time."""
+        """One BUSY tick (it dispatched decode work): its period
+        ``seconds`` — from its launch's entry to its collect's exit, or
+        from its predecessor's collect where it was launched ``ahead`` of
+        that — the seconds each leaf phase took, and ``between``, the gap
+        from the previous busy tick's collect to this launch (None where
+        that tick was idle, so an idle sleep never enters).  ``hidden``
+        names the phases that ran beside a tick in flight: they count in
+        their series, and not towards ``host_exposed_share``.  Idle ticks
+        are left out (they would pull every mean toward the cost of doing
+        nothing)."""
         self._busy_tick["1" if prefill else "0"].observe(seconds)
+        if between is not None:
+            phases = {**phases, "between": between}
         for name, dt in phases.items():
             self._tick_phase[name].observe(dt)
-        if between is not None:
-            self._tick_phase["between"].observe(between)
+            if name in HOST_EXPOSED_PHASES and name not in hidden:
+                self._exposed_seconds.inc(dt)
+        if ahead:
+            self._overlapped.inc()
+
+    def record_flush(self, cause: str) -> None:
+        """A busy tick was collected before its successor was launched:
+        ``cause`` says what made the launch wait."""
+        if cause not in self._flushes:
+            self._flushes[cause] = self.registry.counter(
+                "serving_launch_ahead_flushes_total", cause=cause
+            )
+        self._flushes[cause].inc()
 
     def record_finished(self, out) -> None:
         """Fold one retired RequestOutput's latencies in."""
@@ -487,25 +504,6 @@ class ServingMetrics:
         self._moe_touched.inc(int((here > 0).sum()))
         total = here.sum(axis=0, dtype=np.int64)
         self._moe_rows = total if self._moe_rows is None else self._moe_rows + total
-
-    def record_overlap(self) -> None:
-        """One decode-family dispatch launched while the PREVIOUS tick's
-        results were still uncollected (the launch/collect pipeline's
-        launch-ahead) — tick N's host sync + delivery then overlapped
-        tick N+1's device compute."""
-        self._overlapped.inc()
-        self._refresh_overlap_ratio()
-
-    def _refresh_overlap_ratio(self) -> None:
-        """Overlapped / decode dispatches.  Recomputed on every decode
-        tick AND at summary time, not only when an overlap lands — a
-        long non-overlapped stretch after early launch-aheads must pull
-        the ratio DOWN, or the gauge (and the bench records built on
-        it) would freeze at the early high-water mark."""
-        decode = max(int(self._decode_ticks.value), 1)
-        self._overlap_ratio.set(
-            min(int(self._overlapped.value) / decode, 1.0)
-        )
 
     def record_spec(self, drafted: int, accepted: int, wasted: int) -> None:
         """One active slot's share of a speculative verify tick: how many
@@ -696,19 +694,18 @@ class ServingMetrics:
             "unified_tick_tokens_mean": hist_mean(
                 self._unified_tick_tokens, 3
             ),
+            # step()'s pipeline: the busy ticks launched with their
+            # predecessor uncollected, as a count and as a share, and
+            # the launches that waited for a collect, by cause
             "overlapped_dispatches": int(self._overlapped.value),
-            "host_overlap_ratio": (
-                round(
-                    min(
-                        int(self._overlapped.value)
-                        / max(self.decode_ticks, 1),
-                        1.0,
-                    ),
-                    4,
-                )
-                if int(self._overlapped.value)
+            "launch_ahead_share": (
+                round(int(self._overlapped.value) / busy_ticks, 4)
+                if busy_ticks
                 else 0.0
             ),
+            "launch_ahead_flushes": {
+                cause: int(c.value) for cause, c in self._flushes.items()
+            },
             "host_ms_per_tick_p50": (
                 None
                 if self._host_ms_per_tick.percentile(50) is None
@@ -719,8 +716,8 @@ class ServingMetrics:
                 if self._host_ms_per_tick.percentile(95) is None
                 else round(self._host_ms_per_tick.percentile(95), 3)
             ),
-            # the phase clock, over the busy sequential ticks: exact
-            # (sum / ticks), not bucket midpoints
+            # the phase clock, over the busy ticks: exact (sum / ticks),
+            # not bucket midpoints.  busy_tick is the tick's period
             "busy_ticks": busy_ticks,
             "busy_tick_ms_mean": per_tick_ms(
                 decode_only.sum + with_prefill.sum, busy_ticks
@@ -736,11 +733,12 @@ class ServingMetrics:
                 for name in TICK_PHASES
             },
             # the share of those ticks' time in which the engine had
-            # nothing queued on the device, by the host's clock alone;
-            # `dispatch` straddles and is left out of the numerator
+            # nothing queued on the device, by the host's clock alone:
+            # the host-exposed phases, but for what ran beside a tick in
+            # flight; `dispatch` straddles and is left out
             "host_exposed_share": (
                 round(
-                    100.0 * sum(phase_s[n] for n in HOST_EXPOSED_PHASES)
+                    100.0 * float(self._exposed_seconds.value)
                     / all_phases_s,
                     4,
                 )
