@@ -31,7 +31,7 @@ pins one).
 Usage:
   python scripts/chaos_bench.py [--seed S] [--replicas N] [--requests N]
       [--slots K] [--new T] [--router rr|least|prefix] [--horizon H]
-      [--max-ticks M] [--record CHAOS_r01.json]
+      [--max-ticks M] [--record FILE]
 
 Exits nonzero on any invariant violation.  ``--record`` writes one JSON
 record (schedule summary, death/restart/watchdog tallies, invariant

@@ -7,8 +7,7 @@ device round-trip per TICK (the fused decode tick pays one per
 ``.block_until_ready()`` / ``jax.device_get(...)`` call INSIDE a ``for``
 or ``while`` loop under ``tpu_parallel/serving/`` is the tell-tale of a
 per-slot (or per-item) device sync: each iteration stalls the host on
-the device pipeline, and the DECODE_r06 measurement says that tax is
-worth 14x at batch 1.  Tick-BOUNDARY syncs — one per engine tick, before
+the device pipeline, once a token at batch 1.  Tick-BOUNDARY syncs — one per engine tick, before
 the host unpacks a token block — are the intended pattern and sit
 outside loops by construction; a loop that genuinely needs one (e.g. the
 standalone speculative host loop, which syncs once per verify tick)

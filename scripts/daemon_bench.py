@@ -28,7 +28,7 @@ Entry modes:
   5. **graceful exit** — SIGTERM drains and exits 0 inside the grace
      window, with a clean shutdown record as the journal's last word.
 
-  ``--record DAEMON_r01.json`` writes the per-trial evidence.
+  ``--record FILE`` writes the per-trial evidence.
 
 - ``--disk-faults SEED``: the media-integrity soak.  Per seeded trial:
   (a) life 1 accepts traffic and is SIGKILLed mid-stream; (b) the
@@ -45,7 +45,7 @@ Entry modes:
   the daemon must serve 503s with a typed ``degraded`` reason and a
   ``degraded_reason`` on ``/healthz``, finish its accepted in-flight
   work, and STILL drain exit 0 on SIGTERM.
-  ``--record DAEMON_r02.json`` writes the per-trial evidence.
+  ``--record FILE`` writes the per-trial evidence.
 
 - ``--smoke``: the fast CI gate (wired into ``scripts/check_all.py``
   and tier-1 via ``tests/test_daemon.py``): one subprocess — start,
@@ -56,7 +56,7 @@ Entry modes:
   degraded leg) — ``check_daemon`` runs both.
 
 - ``--kv-disk SEED``: the SSD-KV-tier acceptance bench
-  (``KVDISK_r01.json``).  Life 1 builds a warm set of long shared
+  (record: ``--record``, else ``kv_disk_bench.json``).  Life 1 builds a warm set of long shared
   headers through a tight radix+host hierarchy backed by a disk tier
   (``--kv-disk-dir``), forcing cold host evictions to SPILL block
   payloads to per-block-CRC'd files, then is SIGKILLed.  Three
@@ -1084,7 +1084,7 @@ def run_kv_disk_trial(args, seed, refs, *, timing=True, rot_leg=True):
 def run_kv_disk_soak(args):
     """The SSD-tier acceptance bench: restart-TTFT warm vs cold on the
     same disk, seeded blob rot, plus serve_bench's disk-vs-RAM-only
-    hit-rate leg — one ``KVDISK_r01.json`` record."""
+    hit-rate leg — one record."""
     import importlib.util
     import types
 
@@ -1126,7 +1126,7 @@ def run_kv_disk_soak(args):
     problems.extend(f"hit-rate leg: {v}" for v in hit_violations)
 
     record["ok"] = not problems
-    out = args.record or os.path.join(REPO_ROOT, "KVDISK_r01.json")
+    out = args.record or os.path.join(REPO_ROOT, "kv_disk_bench.json")
     with open(out, "w") as fh:
         json.dump(record, fh, indent=2)
         fh.write("\n")
@@ -1344,7 +1344,7 @@ def main():
                     help="SSD-KV-tier acceptance bench: warm vs cold "
                          "restart TTFT on the same disk, seeded blob "
                          "rot, and the serve_bench hit-rate leg; "
-                         "writes KVDISK_r01.json by default")
+                         "writes kv_disk_bench.json by default")
     ap.add_argument("--kv-disk-smoke", action="store_true",
                     help="fast SSD-tier gate: one reduced warm-restart "
                          "trial (spill, kill -9, manifest warm-start, "

@@ -221,8 +221,7 @@ def run_engine(batch, prompt_len, new_tokens, kv_dtype="bf16",
                model_name="tiny"):
     """ENGINE-mode decode throughput: the ServingEngine's decode hot loop
     across the ``--fused-tick`` sweep — T=1 is the per-step tick (one
-    host dispatch + sync per token, the DECODE_r06 348-tok/s-at-batch-1
-    configuration), T>1 the fused lax.scan tick with donated cache +
+    host dispatch + sync per token), T>1 the fused lax.scan tick with donated cache +
     slot state.  One JSON record per T, parity-asserted against static
     ``generate()`` (greedy bitwise), carrying the engine's own dispatch
     metrics (tokens_per_dispatch, dispatches_per_tick, host_ms_per_tick)

@@ -41,12 +41,12 @@ Entry modes:
 - ``--soak SEED``: the acceptance soak — per seeded trial: router + 3
   daemons, a seeded request schedule, a seeded victim SIGKILLed at a
   seeded point, full invariant sweep, restart + warm start, corrupt
-  leg, graceful stop.  ``--record FLEET_r01.json`` writes the
+  leg, graceful stop.  ``--record FILE`` writes the
   per-trial evidence.
 - ``--disagg SEED``: the disaggregation bench — 1-prefill/2-decode vs
   3-mixed at equal hardware on one seeded schedule (a long-prefill
   burst contending with decode-heavy probes); records decode ITL p95,
-  TTFT, and handoff bytes/latency per leg into ``FLEET_r02.json``,
+  TTFT, and handoff bytes/latency per leg into ``fleet_disagg_bench.json``,
   failing on any lost/duplicated/non-bitwise stream.
 - ``--serve``: INTERNAL daemon child — the ``daemon_bench`` child with
   radix-cached engines (``kv_block_tokens=4`` + ``kv_radix_cache``) so
@@ -694,7 +694,7 @@ def stitch_and_judge(trace_out, router_log, peers, rids, evidence):
     peer's span log, then judge the stitched forest: each request in
     ``rids`` must map (via the router's ``route`` span) to a trace that
     is single-rooted, touches >= 2 pids and carries a cross-process
-    parent link.  Fills ``evidence`` (the TRACE_r01 record) and returns
+    parent link.  Fills ``evidence`` (the trace record) and returns
     a problem list."""
     from tpu_parallel.obs.spool import read_span_log
 
@@ -795,7 +795,7 @@ def run_smoke(tmpdir=None, keep=False, trace_out="", record=""):
     Perfetto file — every disagg request must form a single-rooted
     trace crossing >= 2 pids with a cross-process parent link.
     ``trace_out`` names the stitched file (default: inside tmpdir);
-    ``record`` writes the TRACE_r01-shape evidence JSON."""
+    ``record`` writes that evidence as JSON."""
     import tempfile
 
     problems = []
@@ -1360,7 +1360,7 @@ def _disagg_leg(tmpdir, label, roles, refs, burst, measured):
 def run_disagg(args):
     """1-prefill/2-decode vs 3-mixed at equal hardware, same seeded
     schedule: a long-prefill burst contends with decode-heavy probes;
-    the record (FLEET_r02.json) captures decode ITL p95 / TTFT per leg
+    the record captures decode ITL p95 / TTFT per leg
     plus the handoff byte/latency cost, and any correctness problem
     (lost, duplicated, or non-bitwise stream) fails the bench."""
     import tempfile
@@ -1428,7 +1428,7 @@ def run_disagg(args):
         record["itl_p95_ratio_disagg_over_baseline"] = round(d / b, 4)
     record["problems"] = problems
     record["ok"] = not problems
-    path = args.record or "FLEET_r02.json"
+    path = args.record or "fleet_disagg_bench.json"
     with open(path, "w") as fh:
         json.dump(record, fh, indent=2)
         fh.write("\n")
@@ -1735,7 +1735,7 @@ def main():
     ap.add_argument("--disagg", type=int, default=None, metavar="SEED",
                     help="prefill/decode disaggregation bench: "
                          "1-prefill/2-decode vs 3-mixed at equal "
-                         "hardware, records FLEET_r02.json")
+                         "hardware, records fleet_disagg_bench.json")
     ap.add_argument("--peers", type=str, default="")
     ap.add_argument("--role", type=str, default="mixed",
                     help="INTERNAL (--serve): this daemon's fleet role")

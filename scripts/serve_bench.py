@@ -31,12 +31,11 @@ arrival stream is bit-identical to unshaped schedules at the same seed;
 the tenant rides traces as ``prefix_group`` and replays exactly.
 ``--kv-radix`` / ``--kv-host-blocks`` arm the hierarchical KV memory
 (radix prefix tree + host-RAM offload tier, serving/kv_hierarchy.py)
-for the measured points, and ``--kv-bench`` is its acceptance bench
-(SERVE_r07): radix+host vs aligned-LRU at equal HBM pool bytes on the
+for the measured points, and ``--kv-bench`` is its acceptance bench:
+radix+host vs aligned-LRU at equal HBM pool bytes on the
 Zipf mix, plus a KV-migration relocation leg asserting a relocated
 request continues from shipped blocks bitwise-identically.
-``--kv-disk`` is the SSD tier's hit-rate bench (KVDISK_r01's
-in-process leg): the same hierarchy with and without a disk tier under
+``--kv-disk`` is the SSD tier's in-process hit-rate bench: the same hierarchy with and without a disk tier under
 it, at equal RAM budgets, on a working set far above
 ``kv_host_blocks`` — the restart-TTFT legs live in ``daemon_bench
 --kv-disk``, where process death makes the comparison honest.
@@ -58,8 +57,8 @@ classes and per-request deadlines from weighted draws on a child rng —
 recorded traces carry the drawn values, so a replayed overload trace
 exercises priority shedding exactly as recorded.
 
-``--autopilot`` is the SLO-autopilot acceptance bench (SERVE_r06,
-docs/12): deterministic fake-clock legs over one seeded 2x-overload
+``--autopilot`` is the SLO-autopilot acceptance bench
+(docs/12): deterministic fake-clock legs over one seeded 2x-overload
 schedule — a no-autopilot leg whose queue age diverges and deadlines
 miss en masse, then the same schedule with the autopilot shedding a
 bounded lowest-priority slice and scaling the fleet through the
@@ -77,7 +76,7 @@ requests, in-flight-at-swap streams bitwise identical to baseline,
 fleet ends 100% on the new version), and an injected regression whose
 stalled canary must trigger automatic rollback (fleet ends 100% on the
 OLD version).  Exits nonzero on any invariant violation;
-``--swap-record`` writes the ``SERVE_r05.json``-style record.
+``--swap-record`` writes the record.
 
 ``--replicas N`` (N > 1) switches to CLUSTER mode: N engine replicas
 behind the ``tpu_parallel.cluster`` Frontend, one record per (rate,
@@ -115,7 +114,7 @@ starts with the same ``--prefix-len`` system header followed by a random
 suffix in [prompt-min, prompt-max] — the shape the prefill fast path
 (bucketing + batched prefill + prefix reuse) is built for.  ``--compare``
 emits each point twice: the legacy exact batch-1 prefill engine
-("prefill_mode": "exact", the SERVE_r01 configuration) and the fast path
+("prefill_mode": "exact") and the fast path
 ("bucketed"), so a single file records the improvement.
 
 ``--smoke`` runs a small greedy parity gate first — every fast-path mode
@@ -164,8 +163,8 @@ def make_prompts(cfg, *, n_requests, prompt_min, prompt_max, prefix_len,
     for _ in range(n_requests):
         n = rnd.randint(prompt_min, prompt_max)
         # single-group draws NO group index, preserving the exact RNG
-        # stream (and therefore the workload) of pre-cluster SERVE_r01/
-        # r02 records at the same --seed
+        # stream (and therefore the workload) of single-engine runs at
+        # the same --seed
         g = 0 if len(headers) == 1 else rnd.randrange(len(headers))
         prompts.append(
             headers[g]
@@ -658,7 +657,7 @@ def run_cluster_point(model, params, cfg, prompts, *, rate, n_replicas,
     fault_plans = fault_plans or {}
     # self-healing matters once a replica can die more than once (flap /
     # chaos); one-shot crash specs keep the historical no-restart shape
-    # so --fault records stay comparable to SERVE_r03
+    # so --fault records stay comparable with earlier ones
     selfheal = chaos_seed is not None or any(
         p.crash_every is not None for p in fault_plans.values()
     )
@@ -742,7 +741,7 @@ def run_cluster_point(model, params, cfg, prompts, *, rate, n_replicas,
 
 def run_swap_bench(model, params, cfg, schedule, *, n_replicas, n_slots,
                    router, seed, dt, swap_at_tick, logger):
-    """The rolling weight hot-swap acceptance bench (SERVE_r05): three
+    """The rolling weight hot-swap acceptance bench: three
     legs over ONE replayed schedule on a FAKE clock (dt per cluster
     tick), so every trajectory is a pure function of (schedule, seed).
 
@@ -1004,7 +1003,7 @@ def run_autopilot_bench(model, params, cfg, *, n_replicas=2, max_replicas=4,
                         n_slots=2, router="least", seed=0, dt=0.05,
                         n_requests=96, new_tokens=8, overload=2.0,
                         logger=None, determinism_check=False):
-    """The SLO-autopilot acceptance bench (SERVE_r06): deterministic
+    """The SLO-autopilot acceptance bench: deterministic
     fake-clock legs over ONE seeded overload schedule — offered load
     ``overload`` x the starting fleet's service capacity, mixed priority
     classes, per-request deadlines.
@@ -1398,7 +1397,7 @@ def run_capacity_probe(model, params, cfg, *, seed, logger):
 
 def run_kv_hierarchy_bench(model, params, cfg, *, seed, logger,
                            n_requests=96, dt=0.05):
-    """The hierarchical-KV-memory acceptance bench (SERVE_r07, docs/10):
+    """The hierarchical-KV-memory acceptance bench (docs/10):
     radix prefix tree + host-RAM offload tier vs the aligned-LRU prefix
     cache, at EQUAL HBM pool bytes, on a Zipf multi-tenant workload —
     plus a KV-migration leg proving a relocated request continues from
@@ -1698,7 +1697,7 @@ def run_kv_hierarchy_bench(model, params, cfg, *, seed, logger,
 
 def run_kv_disk_bench(model, params, cfg, *, seed, logger,
                       n_requests=96, workdir=None):
-    """The SSD-KV-tier hit-rate bench (KVDISK_r01, docs/10): the
+    """The SSD-KV-tier hit-rate bench (docs/10): the
     radix + host hierarchy WITH a disk tier under it vs the identical
     RAM-only hierarchy, at equal HBM pool bytes and equal RAM budgets,
     on a Zipf multi-tenant workload whose working set is far above
@@ -1865,7 +1864,7 @@ def run_kv_disk_bench(model, params, cfg, *, seed, logger,
 
 
 def run_unified_bench(model, params, cfg, *, seed, logger, n_requests=24):
-    """SERVE_r08: the UNIFIED ragged tick vs the per-phase ALTERNATING
+    """The UNIFIED ragged tick vs the per-phase ALTERNATING
     engine under a mixed prefill+decode Zipf workload — long multi-chunk
     prompts (tenant headers drawn Zipf, so the prefix load is realistic)
     continuously interleaving with in-flight decodes.  Three legs on
@@ -2217,14 +2216,13 @@ def main():
                          "stream stays bit-identical to unshaped "
                          "schedules at the same --seed")
     ap.add_argument("--kv-bench", action="store_true",
-                    help="hierarchical-KV acceptance bench (SERVE_r07): "
+                    help="hierarchical-KV acceptance bench: "
                          "radix+host vs aligned-LRU at equal HBM pool "
                          "bytes on a Zipf multi-tenant mix, plus the "
                          "KV-migration relocation leg; nonzero exit on "
                          "any invariant violation")
     ap.add_argument("--kv-record", type=str, default="",
-                    help="kv-bench: write the record to this JSON file "
-                         "(SERVE_r07.json)")
+                    help="kv-bench: write the record to this JSON file")
     ap.add_argument("--kv-disk", action="store_true",
                     help="SSD-KV-tier hit-rate bench: disk-backed vs "
                          "RAM-only hierarchy at equal RAM budgets on a "
@@ -2233,15 +2231,15 @@ def main():
     ap.add_argument("--kv-disk-record", type=str, default="",
                     help="kv-disk: write the record to this JSON file")
     ap.add_argument("--unified-bench", action="store_true",
-                    help="unified-ragged-tick acceptance bench "
-                         "(SERVE_r08): alternating vs unified engines "
+                    help="unified-ragged-tick acceptance bench: "
+                         "alternating vs unified engines "
                          "on a mixed prefill+decode Zipf workload at "
                          "equal budgets — bitwise parity, >= 2x "
                          "dispatch cut per token, ticks launched "
                          "ahead; nonzero exit on any violation")
     ap.add_argument("--unified-record", type=str, default="",
                     help="unified-bench: write the record to this JSON "
-                         "file (SERVE_r08.json)")
+                         "file")
     ap.add_argument("--capacity-probe", action="store_true",
                     help="emit a serve_paged_capacity record: concurrent "
                          "short-request admissions and burst decode "
@@ -2301,7 +2299,7 @@ def main():
                          "re-run; nonzero exit on any invariant violation")
     ap.add_argument("--autopilot-record", type=str, default="",
                     help="autopilot bench: write the record to this JSON "
-                         "file (SERVE_r06.json)")
+                         "file")
     ap.add_argument("--priority-dist", type=str, default="",
                     help="weighted priority classes for the generated "
                          "schedule, VALUE:WEIGHT,... (e.g. '0:6,1:3,2:1')")
@@ -2314,8 +2312,8 @@ def main():
                          "--prompt-dist workload (cluster mode: the "
                          "prefix-affinity placement unit)")
     ap.add_argument("--compare", action="store_true",
-                    help="emit every point twice: exact (SERVE_r01 "
-                         "config) vs the requested fast path")
+                    help="emit every point twice: exact prefill vs "
+                         "the requested fast path")
     ap.add_argument("--smoke", action="store_true",
                     help="run the fast-path parity gate (+ registry "
                          "snapshot schema check); nonzero exit on "

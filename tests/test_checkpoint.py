@@ -70,7 +70,7 @@ def test_profiling_helpers(devices):
     # expert_choice active-param accounting: every expert fills its
     # capacity, so per-token FLOPs scale with moe_capacity_factor — a
     # capacity factor of 1.25 must read ~25% more FFN work than 1.0
-    # (ADVICE.md round-5: the old k=1 accounting overstated MFU)
+    # (the old k=1 accounting overstated MFU)
     ec1 = transformer_flops_per_token(
         tiny_test(
             moe_experts=4, moe_router="expert_choice", moe_capacity_factor=1.0

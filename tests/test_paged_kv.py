@@ -2,8 +2,10 @@
 double-free, fragmentation round-trip), copy-on-write under concurrent
 sharers, greedy bitwise parity with the fixed-slot engine across every
 serving path (per-step / fused / speculative / chunked / int8 / cluster
-crash-replay), zero-copy prefix sharing, admission-by-blocks, donation
-and compile-count pins.  (The ``check_blocks`` mutation fence moved to
+crash-replay), zero-copy prefix sharing, admission-by-blocks, the
+donation pin (the compile-count pin is the ``paged`` case of
+``test_serving.py::test_fused_tick_compile_count_pin``), one program
+family for both pools.  (The ``check_blocks`` mutation fence moved to
 ``tests/test_checkers.py``, the single entry point over the
 ``scripts/check_all.py`` registry.)"""
 
@@ -541,36 +543,36 @@ def test_paged_fused_tick_donation_invalidates_old_buffers(env):
         assert out.status == FINISHED and len(out.tokens) == 12
 
 
-def test_paged_fused_compile_count_pin(env):
-    """The paged fused tick compiles ONCE: the block table rides the
-    carry-adjacent inputs at a fixed [n_slots, max_blocks] shape, so
-    admissions, retirements and table growth never retrace."""
+def test_pools_share_one_program_family(env):
+    """A fixed-slot and a paged engine over one base model draw their tick
+    programs from the same factories, one cache entry per pool's model
+    (the paged one carries ``kv_block_tokens``); the block table is their
+    last operand and on the fixed-slot pool no parameter at all: the
+    fused program lowers from its five operands."""
     from tpu_parallel.serving import engine as engine_mod
 
-    engine_mod._paged_engine_fns.cache_clear()
-    engine_mod._paged_fused_engine_fn.cache_clear()
-    cfg, model, params, prompts = env
-    eng = ServingEngine(
-        model, params, n_slots=4, decode_steps_per_tick=4,
-        kv_block_tokens=BT, prefill_buckets=(8, 16, 32),
-        prefix_cache_size=2,
-        scheduler=SchedulerConfig(max_prefills_per_tick=2),
+    engine_mod._engine_fns.cache_clear()
+    engine_mod._fused_engine_fn.cache_clear()
+    cfg, model, params, _ = env
+    kw = dict(n_slots=2, decode_steps_per_tick=4)
+    fixed = ServingEngine(model, params, **kw)
+    assert engine_mod._fused_engine_fn.cache_info().currsize == 1
+    paged = ServingEngine(model, params, kv_block_tokens=BT, **kw)
+    assert engine_mod._fused_engine_fn.cache_info().currsize == 2
+    assert engine_mod._engine_fns.cache_info().currsize == 2
+    assert fixed.model is model and paged.model.config.kv_block_tokens == BT
+    assert fixed._device_table() is None
+    assert paged._device_table().shape == (2, cfg.seq_len // BT)
+    fixed._upload_slot_state()
+    operands = (
+        params, fixed._dev_state, fixed._dev_knobs, fixed.pool.cache,
+        jax.random.PRNGKey(0),
     )
-    outs = []
-    for i, p in enumerate(prompts):
-        outs.append(
-            eng.add_request(
-                Request(request_id=str(i), prompt=p,
-                        max_new_tokens=6 + i)
-            )
-        )
-        eng.step()
-    eng.run(max_ticks=300)
-    assert all(o.status == FINISHED for o in outs)
-    assert eng._fused_fn._cache_size() == 1, (
-        f"paged fused tick retraced: {eng._fused_fn._cache_size()} "
-        "compiles (table upload must be loop-invariant)"
-    )
+    five = fixed._fused_fn.lower(*operands)
+    six = fixed._fused_fn.lower(*operands, fixed._device_table())
+    assert five.as_text() == six.as_text()
+    n_leaves = len(jax.tree_util.tree_leaves(operands))
+    assert len(jax.tree_util.tree_leaves(five.in_avals)) == n_leaves
 
 
 # (The block-table mutation fence — and every other AST contract gate —

@@ -1094,11 +1094,15 @@ def test_fused_tick_donation_invalidates_old_buffers(rng):
         assert out.status == FINISHED and len(out.tokens) == 12
 
 
-def test_fused_tick_compile_count_pin(rng):
-    """The fused tick compiles ONCE: its state/cache shapes are fixed by
-    (n_slots, seq_len), so a mixed workload — staggered arrivals, EOS,
-    varying budgets, prefix hits — adds prefill shapes only, bounded by
-    the bucket set (+1 extend shape per distinct hit group width)."""
+@pytest.mark.parametrize("pool", ["fixed", "paged"])
+def test_fused_tick_compile_count_pin(rng, pool):
+    """The fused tick compiles ONCE on either pool: its state/cache shapes
+    are fixed by (n_slots, seq_len) and the paged block table rides its
+    inputs at a fixed [n_slots, max_blocks] shape, so a mixed workload —
+    staggered arrivals, EOS, varying budgets, prefix hits, table growth —
+    adds prefill shapes only, bounded by the bucket set (+1 extend shape
+    per distinct hit group width; the paged pool prefills through the
+    extend alone)."""
     from tpu_parallel.serving import engine as engine_mod
 
     engine_mod._engine_fns.cache_clear()
@@ -1109,6 +1113,7 @@ def test_fused_tick_compile_count_pin(rng):
         scheduler=SchedulerConfig(max_prefills_per_tick=2),
         prefill_buckets=(4, 8, 16), prefix_cache_size=2,
         decode_steps_per_tick=8,
+        kv_block_tokens=4 if pool == "paged" else None,
     )
     if not hasattr(eng._fused_fn, "_cache_size"):
         pytest.skip("jax.jit cache inspection unavailable")
@@ -1125,12 +1130,13 @@ def test_fused_tick_compile_count_pin(rng):
     eng.run()
     assert eng.metrics.finished == len(lengths)
     n_buckets = 4  # (4, 8, 16) + seq_len appended
-    assert eng._fused_fn._cache_size() == 1  # ONE fused program, ever
-    assert eng._prefill_fn._cache_size() <= n_buckets
+    # ONE fused program, ever (paged: the table upload is loop-invariant)
+    assert eng._fused_fn._cache_size() == 1
+    prefills = 0 if pool == "paged" else eng._prefill_fn._cache_size()
+    assert prefills <= n_buckets
     # total jitted decode+prefill+extend shapes stay <= #buckets + 2
     assert (
-        eng._fused_fn._cache_size()
-        + eng._prefill_fn._cache_size()
+        eng._fused_fn._cache_size() + prefills
         + eng._extend_fn._cache_size()
     ) <= n_buckets + 2
 
@@ -1803,48 +1809,6 @@ def test_spec_engine_wall_clock_with_oracle(rng):
         draft_tokens=4, drafter=OracleDrafter(_ref_map(prompts, refs)),
     )
     assert dt_spec < dt_plain
-
-
-def test_engine_sharded_tp_matches_static(mesh_data4_model2, rng):
-    """TP serving through the engine: mesh-sharded weights, head-sharded
-    cache pool, greedy tokens identical to generate_sharded on the same
-    mesh."""
-    import flax.linen as nn
-    from jax.sharding import PartitionSpec as P
-
-    from tpu_parallel.models.generate import generate_sharded
-
-    mesh = mesh_data4_model2
-    cfg = tiny_test(dtype=jnp.float32, remat=False)
-    model = GPTLM(cfg)
-    prompt = jax.random.randint(rng, (2, 5), 1, cfg.vocab_size)
-
-    def init(r, p):
-        return model.init({"params": r}, p, train=False)["params"]
-
-    probe = jax.shard_map(
-        init, mesh=mesh, in_specs=(P(), P("data")), out_specs=P(),
-        check_vma=False,
-    )
-    specs = nn.get_partition_spec(jax.eval_shape(probe, rng, prompt))
-    params = jax.jit(
-        jax.shard_map(
-            init, mesh=mesh, in_specs=(P(), P("data")), out_specs=specs,
-            check_vma=False,
-        )
-    )(rng, prompt)
-
-    want = np.asarray(
-        generate_sharded(model, params, prompt, mesh, max_new_tokens=6)
-    )
-    eng = ServingEngine(
-        model, params, n_slots=2, mesh=mesh, param_specs=specs,
-        scheduler=SchedulerConfig(max_prefills_per_tick=2),
-    )
-    outs = [eng.add_request(_req(prompt[i], 6)) for i in range(2)]
-    eng.run()
-    for i, out in enumerate(outs):
-        np.testing.assert_array_equal(np.asarray(out.tokens), want[i])
 
 
 # -- unified telemetry: lifecycle tracing through the engine ---------------

@@ -7,8 +7,7 @@ this request — ordered by replica id, so policies stay pure ranking
 logic with no health bookkeeping of their own.
 
 - :class:`RoundRobinRouter` — the baseline: cycle the candidate list.
-  Ignores load AND locality; every comparison in ``SERVE_r03.json``
-  starts here.
+  Ignores load AND locality; the baseline of every comparison.
 - :class:`LeastLoadedRouter` — rank by :meth:`ReplicaHandle.load`
   (queue depth + active slots + discounted pending prefill tokens),
   ties to the lowest replica id.  The right default when prompts share

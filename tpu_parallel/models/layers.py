@@ -139,11 +139,11 @@ class TransformerConfig:
     remat_policy: str = "full"
     scan_layers: bool = True
     # layers per unrolled step of the layer scan (nn.scan's ``unroll``).
-    # Measured verdict (SWEEP_r04.json): at 125M the ~11% scan cost persists
-    # unchanged under plain remat (not a remat-policy interaction) AND
-    # in-scan unrolling makes it WORSE (0.389 MFU at unroll=1 vs
-    # 0.349/0.343/0.334 at 2/4/6) — the cost is the per-tick carry
-    # round-trips, which unrolling the loop body does not remove.  Deep
+    # The scan's cost persists unchanged under plain remat (it is no
+    # remat-policy interaction) AND in-scan unrolling makes it worse:
+    # the cost is the per-tick carry
+    # round-trips, which unrolling the loop body does not remove (on the
+    # chip: not measured).  Deep
     # configs should keep scan_unroll=1 and accept the scan tax, or go
     # fully unrolled (scan_layers=False) where compile budget allows; the
     # knob stays for measurement on other shapes/hardware.
@@ -411,8 +411,8 @@ def decode_attention(
     payload feeds the dot directly (the int8→compute-dtype cast is
     elementwise, fused into the dot's operand read); no dequantized
     cache-sized copy is ever materialized — the transient bf16 K+V copies
-    per layer per step were the whole int8 decode cliff (DECODE_r06:
-    9.8k vs 22.6k tok/s at batch 32).
+    per layer per step were the whole int8 decode cliff (int8 fell
+    behind bf16 at batch 32 while it copied them).
     """
     b, nq, h, head_dim = q.shape
     h_kv = k_all.shape[2]

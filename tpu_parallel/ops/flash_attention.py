@@ -1105,8 +1105,8 @@ def _flash_finalize(
     the surrounding jaxpr: a ``save_only_these_names(..., "attn")`` remat
     policy can then keep them, and the backward never re-runs the forward
     kernel.  Residuals hidden inside a custom_vjp are invisible to remat
-    policies — measured as a full forward-kernel re-run per layer
-    (scripts/attn_wrap_bisect.py).
+    policies: the forward kernel would then run again for every layer
+    in the backward pass.
     """
     del q, k, v, seg_q, seg_k, lse
     return out

@@ -44,6 +44,7 @@ Read-side ops (``extract``, ``stack_prefix``) copy and may be held.
 
 from __future__ import annotations
 
+import functools
 import heapq
 import zlib
 from typing import List, Optional
@@ -202,9 +203,7 @@ def copy_prefix_rows(pool_cache, prefix_cache, slot, length):
 
 def _pool_cache_shapes(model, params, n_slots: int):
     """abstract shapes of the model's decode cache at batch ``n_slots``,
-    via ``jax.eval_shape`` — no forward pass runs.  The ONE shape probe
-    behind both :func:`empty_pool` and :func:`cache_partition_specs`, so
-    the allocated pool tree and its partition specs cannot drift."""
+    via ``jax.eval_shape`` — no forward pass runs."""
 
     def probe():
         tok = jnp.zeros((n_slots, 1), jnp.int32)
@@ -233,69 +232,24 @@ def _pool_cache_shapes(model, params, n_slots: int):
     return jax.eval_shape(probe)
 
 
-def empty_pool(model, params, n_slots: int, shardings=None):
+def empty_pool(model, params, n_slots: int):
     """Allocate the pool cache: the model's own decode-cache structure at
     batch ``n_slots``, zero-filled, with every position-table entry at -1
     (no slot attends until a request's prefill row is inserted).
 
     Only the cache STRUCTURE comes from the model, so any config (GQA
     widths, int8 scales, unrolled vs scanned stacks) produces its
-    matching pool.  ``shardings`` (a matching tree of ``jax.sharding``
-    objects) places each leaf sharded at BIRTH — allocating host-side and
-    ``device_put``-ing per leaf, so a TP-sharded pool never transits one
-    device whole (a pool sized to the per-device share would otherwise
-    OOM device 0 at construction).
+    matching pool.
     """
-    import numpy as np
 
-    shapes = _pool_cache_shapes(model, params, n_slots)
-    if shardings is None:
-        def alloc(path, leaf):
-            if _leaf_name(path).startswith("cached_pos"):
-                return jnp.full(leaf.shape, -1, leaf.dtype)
-            return jnp.zeros(leaf.shape, leaf.dtype)
+    def alloc(path, leaf):
+        if _leaf_name(path).startswith("cached_pos"):
+            return jnp.full(leaf.shape, -1, leaf.dtype)
+        return jnp.zeros(leaf.shape, leaf.dtype)
 
-        return jax.tree_util.tree_map_with_path(alloc, shapes)
-
-    def alloc_sharded(path, leaf, sharding):
-        fill = -1 if _leaf_name(path).startswith("cached_pos") else 0
-        host = np.full(leaf.shape, fill, leaf.dtype)
-        return jax.device_put(host, sharding)
-
-    return jax.tree_util.tree_map_with_path(alloc_sharded, shapes, shardings)
-
-
-def cache_partition_specs(model, params, n_slots: int, mesh):
-    """PartitionSpecs for every pool-cache leaf under ``mesh`` — the
-    out/in specs the sharded engine threads through
-    :func:`~tpu_parallel.models.generate.build_sharded_serving`.
-
-    K/V payloads and their int8 scales shard over the model (TP) axis at
-    the kv-head dim (ndim-2) exactly as activations do; position tables and
-    scalar counters are replicated.  Slots are NOT sharded over the data
-    axis — admission is a per-slot host decision, so every data rank holds
-    every slot (documented engine caveat: data ranks duplicate decode
-    work).  When the mesh has no model axis the payloads are replicated
-    too.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    model_axis = model.config.model_axis
-    if model_axis not in mesh.axis_names:
-        model_axis = None
-    shapes = _pool_cache_shapes(model, params, n_slots)
-
-    def spec(path, leaf):
-        name = _leaf_name(path)
-        if model_axis is not None and name.startswith(
-            ("cached_key", "cached_value", "cross_key", "cross_value")
-        ):
-            parts = [None] * leaf.ndim
-            parts[leaf.ndim - 2] = model_axis  # the kv-head dim
-            return P(*parts)
-        return P()
-
-    return jax.tree_util.tree_map_with_path(spec, shapes)
+    return jax.tree_util.tree_map_with_path(
+        alloc, _pool_cache_shapes(model, params, n_slots)
+    )
 
 
 def stack_prefix_rows(rows, length):
@@ -330,26 +284,14 @@ class CachePool:
     every engine decode tick.
     """
 
-    def __init__(self, model, params, n_slots: int, insert_fn=None,
-                 shardings=None, row_fns=None):
+    def __init__(self, model, params, n_slots: int):
         if n_slots < 1:
             raise ValueError(f"n_slots={n_slots} < 1")
         self.n_slots = n_slots
-        self.cache = empty_pool(model, params, n_slots, shardings=shardings)
+        self.cache = empty_pool(model, params, n_slots)
         self._free: List[int] = list(range(n_slots))
-        # donate the pool operand: the old tree is dead after every insert,
-        # and without donation XLA keeps a full second pool copy alive
-        self._insert = (
-            insert_fn
-            if insert_fn is not None
-            else jax.jit(insert_rows, donate_argnums=0)
-        )
-        # row-level fast-path ops (scatter/extract/clear/copy_prefix),
-        # injectable so the engine's lru-cached jits are shared per model
-        if row_fns is None:
-            row_fns = default_row_fns()
-        (self._scatter, self._extract, self._clear,
-         self._copy_prefix, self.stack_prefix) = row_fns
+        (self._insert, self._scatter, self._extract, self._clear,
+         self._copy_prefix, self.stack_prefix) = default_row_fns()
 
     @property
     def n_free(self) -> int:
@@ -440,12 +382,16 @@ class CachePool:
         jax.tree_util.tree_map_with_path(check, self.cache)
 
 
+@functools.lru_cache(maxsize=None)
 def default_row_fns():
-    """Jitted (scatter, extract, clear, copy_prefix, stack_prefix) with
-    the pool operand donated on every WRITE op (the old pool tree is dead
-    the moment the call returns; extract reads only, and stack_prefix's
-    inputs stay live in the prefix cache — neither donates)."""
+    """Jitted (insert, scatter, extract, clear, copy_prefix, stack_prefix),
+    one set a process (every pool shares the traces), with the pool
+    operand donated on every WRITE op (the old pool tree is dead the
+    moment the call returns, and without donation XLA keeps a full second
+    pool copy alive; extract reads only, and stack_prefix's inputs stay
+    live in the prefix cache — neither donates)."""
     return (
+        jax.jit(insert_rows, donate_argnums=0),
         jax.jit(scatter_rows, donate_argnums=0),
         jax.jit(extract_rows, static_argnums=2),
         jax.jit(clear_rows, donate_argnums=0),
@@ -534,10 +480,12 @@ def scatter_block_rows(pool_cache, rows, blocks):
     return jax.tree_util.tree_map_with_path(scat, pool_cache)
 
 
+@functools.lru_cache(maxsize=None)
 def default_block_fns():
     """Jitted (free_block_pos, copy_block, gather_block_rows,
-    scatter_block_rows) — the write ops donate the pool operand under the
-    module's donation contract; the gather is a read and never does."""
+    scatter_block_rows), one set a process — the write ops donate the
+    pool operand under the module's donation contract; the gather is a
+    read and never does."""
     return (
         jax.jit(free_block_pos, donate_argnums=0),
         jax.jit(copy_block, donate_argnums=0),
@@ -649,7 +597,7 @@ class PagedCachePool:
     valid handle and stale references point at deleted buffers.
     """
 
-    def __init__(self, model, params, n_slots: int, block_fns=None):
+    def __init__(self, model, params, n_slots: int):
         import numpy as np
 
         cfg = model.config
@@ -684,10 +632,8 @@ class PagedCachePool:
         # cumulative tallies (ServingMetrics delta-syncs these)
         self.cow_copies = 0
         self.shared_block_maps = 0
-        if block_fns is None:
-            block_fns = default_block_fns()
         (self._free_pos, self._copy_block, self._gather_rows,
-         self._scatter_rows) = block_fns
+         self._scatter_rows) = default_block_fns()
         # bytes of ONE block across every payload leaf (all layers) — the
         # capacity denominator behind kv_bytes_per_active_token
         self.bytes_per_block = sum(

@@ -80,8 +80,10 @@ refcounted block SHARING with copy-on-write instead of row copies.  All
 serving paths (per-step / fused / speculative / chunked / int8 /
 crash-replay) stay greedy-bitwise-identical to the fixed layout
 (``tests/test_paged_kv.py``; memory-model story in
-``docs/10_serving_engine.md``).  Not yet paged: mesh serving and lazy
-beam search.
+``docs/10_serving_engine.md``).  Both pools run ONE family of device
+programs: the block table is an operand of each (``None`` on the
+fixed-slot pool, where it adds no parameter to the compiled program).
+Not yet paged: lazy beam search.
 
 Hierarchical KV memory (``kv_radix_cache`` / ``kv_host_blocks``, paged
 only — ``serving/kv_hierarchy.py``): the aligned-LRU prefix cache swaps
@@ -100,14 +102,9 @@ in ``tests/test_serving.py``) — row-parallel ops make batch composition
 invisible to each row, and both paths share
 :func:`~tpu_parallel.models.generate.decode_step`.
 
-TP serving: pass ``mesh`` (and mesh-sharded ``params``) and the engine
-wraps its prefill/extend/decode cores in the same
-:func:`~tpu_parallel.models.generate.build_sharded_serving` harness as
-``generate_sharded`` — weights stay split, the cache pool shards over
-heads, sampling runs on gathered ``[n_slots, vocab]`` logits (small), with
-``fold_axes=()`` so every rank draws identical noise (slot arrays ride
-replicated over the data axis; data ranks duplicate decode work).  Pipe
-meshes are refused — serve those through ``generate_sharded``.
+One chip: the engine's programs are plain ``jit``s over unsharded
+params.  Weights split over a mesh are served statically through
+``generate_sharded``.
 """
 
 from __future__ import annotations
@@ -134,8 +131,6 @@ import numpy as np
 from jax import lax
 
 from tpu_parallel.models.generate import (
-    _HashableTree,
-    build_sharded_serving,
     decode_step,
     padded_prefill_inputs,
     prefill_extend_step,
@@ -150,10 +145,6 @@ from tpu_parallel.serving.cache_pool import (
     KVIntegrityError,
     PagedCachePool,
     block_checksums,
-    cache_partition_specs,
-    default_block_fns,
-    default_row_fns,
-    insert_rows,
 )
 from tpu_parallel.serving.metrics import (
     STALL_NONE,
@@ -272,16 +263,14 @@ def sample_tokens(
 
 
 def _full_last_logits(cfg, params, hidden, last_idx=None):
-    """lm_head over ONE position per row, FULL vocab width on every rank
-    (one tiny [batch, vocab] all_gather under TP — the per-row knob sampler
-    needs the whole row; batch is n_slots, not tokens).
+    """lm_head over ONE position per row (the per-row knob sampler needs
+    the whole vocab row; batch is n_slots, not tokens).
 
     ``last_idx`` [batch] selects each row's position (the bucketed
     prefill's per-row LAST REAL token — right padding means it is not
     uniformly -1); None reads the final position (decode steps, exact
     prefill)."""
     from tpu_parallel.models.gpt import lm_logits
-    from tpu_parallel.parallel.tp import axis_size_or_none
 
     if last_idx is None:
         hidden = hidden[:, -1:]
@@ -291,24 +280,16 @@ def _full_last_logits(cfg, params, hidden, last_idx=None):
             (hidden.shape[0], 1, hidden.shape[2]),
         )
         hidden = jnp.take_along_axis(hidden, idx, axis=1)
-    logits = lm_logits(cfg, params, hidden)[:, 0]
-    if axis_size_or_none(cfg.model_axis) is not None:
-        logits = lax.all_gather(logits, cfg.model_axis, axis=-1, tiled=True)
-    return logits
+    return lm_logits(cfg, params, hidden)[:, 0]
 
 
 def _full_logits(cfg, params, hidden):
-    """lm_head over EVERY position of [batch, T, d_model] hidden, full
-    vocab width on every rank — the speculative verify needs all T target
-    distributions, not just the last (one [batch, T, vocab] all_gather
-    under TP; T = draft_tokens + 1, batch = n_slots — still tiny)."""
+    """lm_head over EVERY position of [batch, T, d_model] hidden — the
+    speculative verify needs all T target distributions, not just the
+    last (T = draft_tokens + 1, batch = n_slots — still tiny)."""
     from tpu_parallel.models.gpt import lm_logits
-    from tpu_parallel.parallel.tp import axis_size_or_none
 
-    logits = lm_logits(cfg, params, hidden)
-    if axis_size_or_none(cfg.model_axis) is not None:
-        logits = lax.all_gather(logits, cfg.model_axis, axis=-1, tiled=True)
-    return logits
+    return lm_logits(cfg, params, hidden)
 
 
 def _calls(rows):
@@ -333,28 +314,41 @@ def _prefill_core(model, params, prompt, positions, last_idx, rng):
 
 
 def _extend_core(
-    model, params, tokens, positions, last_idx, write_start, cache, rng
+    model, params, tokens, positions, last_idx, write_start, cache, rng,
+    table=None,
 ):
-    """Continue a prefill into an existing batch-1 cache row (chunked
-    prefill / prefix-reuse remainder): tokens at global ``positions``
-    (pads -1) write K/V at slots ``write_start + [0..T)``.  Returns the
-    chunk's last real position's logits (read only for the FINAL chunk)
-    + the extended cache."""
+    """Continue a prefill into existing cache rows (chunked prefill /
+    prefix-reuse remainder / every paged admission): tokens at global
+    ``positions`` (pads -1) write K/V at columns ``write_start + [0..T)``.
+    Returns the chunk's last real position's logits (read only for the
+    FINAL chunk) + the extended cache.
+
+    ``table`` is the block table of the rows in ``cache``: None on the
+    fixed-slot pool, whose ``cache`` is the rows themselves; on the paged
+    pool ``cache`` is the whole block pool and each row's K/V lands
+    DIRECTLY in it (column ``c`` at ``table[row, c // bt] * bt + c % bt``;
+    a dummy row's all--1 table drops every write), so there is no fresh
+    per-request cache to insert or scatter."""
     del rng
     hidden, cache, rows = prefill_extend_step(
-        model, params, cache, tokens, positions, write_start, with_rows=True
+        model, params, cache, tokens, positions, write_start,
+        block_table=table, with_rows=True,
     )
     logits = _full_last_logits(model.config, params, hidden, last_idx)
     return logits, cache, _calls(rows)
 
 
 def _decode_core(
-    model, params, tok, pos, widx, temperature, top_k, top_p, cache, rng
+    model, params, tok, pos, widx, temperature, top_k, top_p, cache, rng,
+    table=None,
 ):
     """One engine tick over the slot pool: slot-indexed cache writes,
-    per-slot sampling.  Returns (next_tokens [n_slots], new cache)."""
+    per-slot sampling; with a block ``table`` the reads and writes go
+    through it and the math is the same (greedy output bitwise identical
+    across the pools).  Returns (next_tokens [n_slots], new cache)."""
     hidden, cache, rows = decode_step(
-        model, params, cache, tok, pos, write_index=widx, with_rows=True
+        model, params, cache, tok, pos, write_index=widx, block_table=table,
+        with_rows=True,
     )
     logits = _full_last_logits(model.config, params, hidden)
     nxt = sample_tokens(logits, rng, temperature, top_k, top_p)
@@ -732,120 +726,54 @@ def _unified_spec_core(
     return act_emit, blocks, counts, drafted, accepted, state, cache
 
 
-def _extend_core_paged(
-    model, params, tokens, positions, last_idx, write_start, table, cache,
-    rng,
-):
-    """The paged prefill/extend core: rows' K/V land DIRECTLY in the
-    shared block pool through their block-table rows (``write_start +
-    [0..T)`` translated per token to ``table[row, col // bt] * bt +
-    col % bt``) — there is no fresh per-request cache to insert/scatter,
-    which is the whole point of paging.  Dummy rows pass an all--1 table
-    (every write dropped)."""
-    del rng
-    hidden, cache, rows = prefill_extend_step(
-        model, params, cache, tokens, positions, write_start,
-        block_table=table, with_rows=True,
-    )
-    logits = _full_last_logits(model.config, params, hidden, last_idx)
-    return logits, cache, _calls(rows)
-
-
-def _decode_core_paged(
-    model, params, tok, pos, widx, table, temperature, top_k, top_p, cache,
-    rng,
-):
-    """One paged engine tick: identical math to :func:`_decode_core`
-    (same ``decode_step`` / lm_head / sampler), with cache reads and
-    writes routed through the per-slot block tables — greedy output is
-    bitwise identical to the fixed-slot layout."""
-    hidden, cache, rows = decode_step(
-        model, params, cache, tok, pos, write_index=widx, block_table=table,
-        with_rows=True,
-    )
-    logits = _full_last_logits(model.config, params, hidden)
-    nxt = sample_tokens(logits, rng, temperature, top_k, top_p)
-    return nxt, cache, _calls(rows)
-
-
-@functools.lru_cache(maxsize=8)
-def _paged_engine_fns(model):
-    """Jitted engine steps for the BLOCK-PAGED pool, cached per (paged)
-    model.  The cache pool is DONATED on every call exactly as on the
-    fixed-slot path; block tables are NOT donated — they are small
-    per-call uploads of the host-authoritative mirror, so donation would
-    only buy an ownership hazard."""
-    extend = jax.jit(
-        lambda params, tokens, positions, last_idx, wstart, table, cache, \
-            rng: _extend_core_paged(
-                model, params, tokens, positions, last_idx, wstart, table,
-                cache, rng,
-            ),
-        donate_argnums=6,
-    )
-    decode = jax.jit(
-        lambda params, tok, pos, widx, table, temp, tk, tp, cache, rng: (
-            _decode_core_paged(
-                model, params, tok, pos, widx, table, temp, tk, tp, cache,
-                rng,
-            )
-        ),
-        donate_argnums=8,
-    )
-    verify = jax.jit(
-        lambda params, tok, drafts, dlen, pos, widx, table, temp, tk, tp, \
-            cache, rng: _verify_core(
-                model, params, tok, drafts, dlen, pos, widx, temp, tk, tp,
-                cache, rng, table=table,
-            ),
-        donate_argnums=10,
-    )
-    sample = jax.jit(sample_tokens)
-    return extend, decode, verify, sample, default_block_fns()
-
-
-@functools.lru_cache(maxsize=8)
+@functools.lru_cache(maxsize=16)
 def _engine_fns(model):
-    """Jitted engine step functions for the single-host path, cached per
-    model so every engine instance (tests build many) shares traces.
+    """Jitted per-step engine programs ``(prefill, extend, decode, verify,
+    sample)``, cached per model so every engine instance (tests build
+    many) shares traces.  A paged engine's model carries
+    ``kv_block_tokens``, so each pool has its own entry.
 
-    The cache-pool operand is DONATED in the decode step, the extend, the
-    insert, and the row ops: the old tree is dead the moment the call
-    returns, and without donation XLA holds a second full pool (the
-    engine's dominant HBM) at every tick."""
+    The cache-pool operand is DONATED in the extend, the decode step and
+    the verify: the old tree is dead the moment the call returns, and
+    without donation XLA holds a second full pool (the engine's dominant
+    HBM) at every tick.  The block table is every program's LAST operand:
+    None on the fixed-slot pool (an empty pytree: no parameter of the
+    compiled program), the per-slot table on the paged pool, never
+    donated — it is a small upload of the host-authoritative mirror, and
+    donation would only buy an ownership hazard."""
     prefill = jax.jit(
         lambda params, prompt, positions, last_idx, rng: _prefill_core(
             model, params, prompt, positions, last_idx, rng
         )
     )
     extend = jax.jit(
-        lambda params, tokens, positions, last_idx, wstart, cache, rng: (
-            _extend_core(
-                model, params, tokens, positions, last_idx, wstart, cache, rng
-            )
-        ),
+        lambda params, tokens, positions, last_idx, wstart, cache, rng, \
+            table=None: _extend_core(
+                model, params, tokens, positions, last_idx, wstart, cache,
+                rng, table,
+            ),
         donate_argnums=5,
     )
     decode = jax.jit(
-        lambda params, tok, pos, widx, temp, tk, tp, cache, rng: _decode_core(
-            model, params, tok, pos, widx, temp, tk, tp, cache, rng
-        ),
+        lambda params, tok, pos, widx, temp, tk, tp, cache, rng, \
+            table=None: _decode_core(
+                model, params, tok, pos, widx, temp, tk, tp, cache, rng,
+                table,
+            ),
         donate_argnums=7,
     )
     verify = jax.jit(
         lambda params, tok, drafts, dlen, pos, widx, temp, tk, tp, cache, \
-            rng: _verify_core(
+            rng, table=None: _verify_core(
                 model, params, tok, drafts, dlen, pos, widx, temp, tk, tp,
-                cache, rng,
+                cache, rng, table,
             ),
         donate_argnums=9,
     )
-    sample = jax.jit(sample_tokens)
-    insert = jax.jit(insert_rows, donate_argnums=0)
-    return prefill, extend, decode, verify, sample, insert, default_row_fns()
+    return prefill, extend, decode, verify, jax.jit(sample_tokens)
 
 
-@functools.lru_cache(maxsize=8)
+@functools.lru_cache(maxsize=16)
 def _fused_engine_fn(model, steps: int):
     """The jitted fused decode tick at compiled width ``steps``, cached
     per (model, steps) so engines sharing a model share the trace.  The
@@ -853,71 +781,45 @@ def _fused_engine_fn(model, steps: int):
     DONATED: the engine re-donates the state arrays the previous tick
     returned, so steady-state decode recycles every buffer in place; the
     knob tuple (eos/temperature/top_k/top_p, argnum 2) is NOT donated —
-    it only changes on admission, when the host re-uploads anyway."""
+    it only changes on admission, when the host re-uploads anyway.  The
+    block table (last, None on the fixed-slot pool) is not donated
+    either: it is loop-invariant through the scan and the host re-uploads
+    it only when the allocator moved a mapping, so steady-state decode
+    re-dispatches the same device table and the compile count stays
+    pinned per (model, steps)."""
     return jax.jit(
-        lambda params, state, knobs, cache, rng: _fused_decode_core(
-            model, params, steps, *state, *knobs, cache, rng
+        lambda params, state, knobs, cache, rng, table=None: (
+            _fused_decode_core(
+                model, params, steps, *state, *knobs, cache, rng, table
+            )
         ),
         donate_argnums=(1, 3),
     )
 
 
-@functools.lru_cache(maxsize=8)
-def _paged_fused_engine_fn(model, steps: int):
-    """The fused decode tick over the block-paged pool: same donated
-    (state, cache) contract as :func:`_fused_engine_fn`; the block table
-    rides the tick's inputs un-donated (loop-invariant through the scan —
-    the host re-uploads only when the allocator moved a mapping, so
-    steady-state decode re-dispatches the same device table and the
-    compile count stays pinned per (model, steps))."""
-    return jax.jit(
-        lambda params, state, knobs, table, cache, rng: _fused_decode_core(
-            model, params, steps, *state, *knobs, cache, rng, table=table
-        ),
-        donate_argnums=(1, 4),
-    )
-
-
-@functools.lru_cache(maxsize=8)
+@functools.lru_cache(maxsize=16)
 def _unified_engine_fn(model, steps: int, chunk: int):
     """The jitted UNIFIED ragged tick (chunk phase + decode scan) at
     compiled widths ``(steps, chunk)`` — exactly ONE program per engine
     configuration, so the compile-shape family stays O(#buckets + 1):
     the bucketed prefill/extend shapes plus this.  Donation contract
-    matches :func:`_fused_engine_fn` (slot state + cache donated; knobs
-    and the per-tick chunk operands are small uploads, never donated),
-    and the state tuples are structurally identical, so pure-decode
-    ticks chain the SAME donated carry through ``_fused_fn`` without a
-    re-upload."""
+    matches :func:`_fused_engine_fn` (slot state + cache donated; knobs,
+    the per-tick chunk operands and the trailing block table are never
+    donated), and the state tuples are structurally identical, so
+    pure-decode ticks chain the SAME donated carry through ``_fused_fn``
+    without a re-upload."""
     return jax.jit(
-        lambda params, state, knobs, chunk_ops, cache, rng: (
+        lambda params, state, knobs, chunk_ops, cache, rng, table=None: (
             _unified_tick_core(
                 model, params, steps, *state, *knobs, *chunk_ops, cache,
-                rng,
+                rng, table,
             )
         ),
         donate_argnums=(1, 4),
     )
 
 
-@functools.lru_cache(maxsize=8)
-def _paged_unified_engine_fn(model, steps: int, chunk: int):
-    """Paged variant of :func:`_unified_engine_fn`: the block table rides
-    the tick's inputs un-donated exactly as on the paged fused tick —
-    both the chunk phase's multi-token writes and the decode scan route
-    through it, loop-invariant through the scan."""
-    return jax.jit(
-        lambda params, state, knobs, chunk_ops, table, cache, rng: (
-            _unified_tick_core(
-                model, params, steps, *state, *knobs, *chunk_ops, cache,
-                rng, table=table,
-            )
-        ),
-        donate_argnums=(1, 5),
-    )
-
-
-@functools.lru_cache(maxsize=8)
+@functools.lru_cache(maxsize=16)
 def _fused_spec_engine_fn(
     model, steps: int, chunk: int, k: int, max_ngram: int, min_ngram: int,
     adaptive: bool,
@@ -926,49 +828,25 @@ def _fused_spec_engine_fn(
     blocks per dispatch (``chunk`` > 0 additionally folds the ragged
     chunk phase in front — the unified spec tick).  The spec slot state
     (the fused 5-tuple + per-slot draft length + the token-history
-    carry) and the cache are donated; knobs/chunk operands are not."""
+    carry) and the cache are donated; knobs, chunk operands and the
+    trailing block table are not."""
     if chunk > 0:
         return jax.jit(
-            lambda params, state, knobs, chunk_ops, cache, rng: (
+            lambda params, state, knobs, chunk_ops, cache, rng, table=None: (
                 _unified_spec_core(
                     model, params, steps, k, max_ngram, min_ngram,
                     adaptive, *state, *knobs, *chunk_ops, cache, rng,
+                    table,
                 )
             ),
             donate_argnums=(1, 4),
         )
     return jax.jit(
-        lambda params, state, knobs, cache, rng: _fused_spec_core(
+        lambda params, state, knobs, cache, rng, table=None: _fused_spec_core(
             model, params, steps, k, max_ngram, min_ngram, adaptive,
-            *state, *knobs, cache, rng,
+            *state, *knobs, cache, rng, table,
         ),
         donate_argnums=(1, 3),
-    )
-
-
-@functools.lru_cache(maxsize=8)
-def _paged_fused_spec_engine_fn(
-    model, steps: int, chunk: int, k: int, max_ngram: int, min_ngram: int,
-    adaptive: bool,
-):
-    """Paged :func:`_fused_spec_engine_fn` — block table un-donated."""
-    if chunk > 0:
-        return jax.jit(
-            lambda params, state, knobs, chunk_ops, table, cache, rng: (
-                _unified_spec_core(
-                    model, params, steps, k, max_ngram, min_ngram,
-                    adaptive, *state, *knobs, *chunk_ops, cache, rng,
-                    table=table,
-                )
-            ),
-            donate_argnums=(1, 5),
-        )
-    return jax.jit(
-        lambda params, state, knobs, table, cache, rng: _fused_spec_core(
-            model, params, steps, k, max_ngram, min_ngram, adaptive,
-            *state, *knobs, cache, rng, table=table,
-        ),
-        donate_argnums=(1, 4),
     )
 
 
@@ -1025,48 +903,6 @@ def _seat_rows(
         for rows, values in zip(knobs, (eos, temp, topk, topp))
     )
     return state, knobs
-
-
-@functools.lru_cache(maxsize=8)
-def _sharded_engine_fns(model, mesh, specs: _HashableTree,
-                        cache_specs: _HashableTree):
-    """shard_map-wrapped engine step functions (TP serving), through the
-    same ``build_sharded_serving`` harness as ``generate_sharded`` —
-    ``fold_axes=()`` keeps sampling noise identical on every rank (the
-    slot arrays are replicated, so outputs must be too)."""
-    from jax.sharding import PartitionSpec as P
-
-    param_specs = specs.tree()
-    cspecs = cache_specs.tree()
-    if model.config.routed_layers:
-        raise NotImplementedError(
-            "dropless expert layers under a serving mesh (the sharded "
-            "programs' out_specs carry no expert row counts)"
-        )
-    prefill = build_sharded_serving(
-        model, mesh, param_specs, (P(), P(), P()), (P(), cspecs, None),
-        _prefill_core, fold_axes=(),
-    )
-    extend = build_sharded_serving(
-        model, mesh, param_specs, (P(), P(), P(), P(), cspecs),
-        (P(), cspecs, None), _extend_core, fold_axes=(),
-    )
-    decode = build_sharded_serving(
-        model, mesh, param_specs,
-        (P(), P(), P(), P(), P(), P(), cspecs), (P(), cspecs, None),
-        _decode_core, fold_axes=(),
-    )
-    verify = build_sharded_serving(
-        model, mesh, param_specs,
-        (P(), P(), P(), P(), P(), P(), P(), P(), cspecs),
-        (P(), P(), cspecs), _verify_core, fold_axes=(),
-    )
-    sample = jax.jit(sample_tokens)
-    # the shard_map-wrapped decode cannot donate (build_sharded_serving
-    # does not expose donation), so the TP tick holds a transient second
-    # pool; the insert and row ops at least recycle their operands
-    insert = jax.jit(insert_rows, donate_argnums=0)
-    return prefill, extend, decode, verify, sample, insert, default_row_fns()
 
 
 def default_prefill_buckets(seq_len: int, start: int = 32) -> Tuple[int, ...]:
@@ -1219,9 +1055,8 @@ class ServingEngine:
       the remaining steps.  Streaming granularity becomes per-tick
       (bounded by T).  ``"auto"`` (default) = 8; spec engines
       (``draft_tokens > 0``) resolve to 1 under "auto" but an EXPLICIT
-      T > 1 fuses T draft-verify blocks per dispatch (below); mesh
-      serving keeps the per-step path (auto resolves to 1; explicit
-      T > 1 raises).  1 = the per-step engine.
+      T > 1 fuses T draft-verify blocks per dispatch (below).  1 = the
+      per-step engine.
     - ``unified_tick``: the UNIFIED RAGGED tick — prefill-chunk slots
       consume their next prompt chunk (fixed ``[n_slots, chunk_tokens]``
       input block, right-padded, pad positions -1) while decode slots
@@ -1300,8 +1135,6 @@ class ServingEngine:
         params,
         n_slots: int = 8,
         scheduler: Union[SchedulerConfig, FIFOScheduler, None] = None,
-        mesh=None,
-        param_specs=None,
         rng: Optional[jax.Array] = None,
         metrics: Optional[ServingMetrics] = None,
         tracer: Optional[Tracer] = None,
@@ -1418,12 +1251,6 @@ class ServingEngine:
             self._paged = False
             self._block_tokens = 0
         else:
-            if mesh is not None:
-                raise NotImplementedError(
-                    "paged KV cache under a mesh (build_sharded_serving "
-                    "has no block-table plumbing) — mesh serving keeps "
-                    "the fixed-slot pool"
-                )
             if kv_block_tokens == "auto":
                 # the bucket quantum: the largest size dividing seq_len,
                 # every prefill bucket, and 32 — so bucket-aligned prefix
@@ -1572,10 +1399,9 @@ class ServingEngine:
         # EXPLICIT T > 1 with draft_tokens > 0 instead fuses T
         # draft-verify blocks per dispatch, drafting ON DEVICE via the
         # traceable NGram twin, so it refuses custom drafters whose
-        # host state the scan cannot see; the shard_map harness exposes
-        # no donation, so a mesh always resolves/refuses to 1)
+        # host state the scan cannot see)
         if decode_steps_per_tick == "auto":
-            fused = 1 if (draft_tokens > 0 or mesh is not None) else 8
+            fused = 1 if draft_tokens > 0 else 8
         else:
             fused = int(decode_steps_per_tick)
             if fused < 1:
@@ -1591,12 +1417,6 @@ class ServingEngine:
                     "on device via the traceable NGram drafter — a "
                     "custom Drafter's host state is invisible to the "
                     "scan; keep decode_steps_per_tick=1 for it"
-                )
-            if fused > 1 and mesh is not None:
-                raise NotImplementedError(
-                    "decode_steps_per_tick > 1 under a mesh "
-                    "(build_sharded_serving exposes no buffer donation) "
-                    "— mesh serving decodes per-step"
                 )
         self._fused_steps = fused
         self._spec_fused = fused > 1 and draft_tokens > 0
@@ -1614,20 +1434,19 @@ class ServingEngine:
                 "phase folded in)"
             )
         self._unified = (
-            fused > 1 and mesh is None
-            if unified_tick == "auto"
-            else bool(unified_tick)
+            fused > 1 if unified_tick == "auto" else bool(unified_tick)
         )
         chunkw = int(prefill_chunk_tokens or 0) if self._unified else 0
+        # one family of programs for both pools, cached by model (the
+        # paged model above carries kv_block_tokens): the block table is
+        # their last operand, None on the fixed-slot pool
+        (self._prefill_fn, self._extend_fn, self._decode_fn,
+         self._verify_fn, self._sample_fn) = _engine_fns(model)
+        self._fused_fn = None
         self._unified_fn = None
         self._spec_fused_fn = None
         self._spec_unified_fn = None
         if self._spec_fused:
-            mk = (
-                _paged_fused_spec_engine_fn
-                if self._paged
-                else _fused_spec_engine_fn
-            )
             spec_sig = (
                 draft_tokens, self._drafter.max_ngram,
                 self._drafter.min_ngram, bool(spec_adaptive),
@@ -1635,24 +1454,17 @@ class ServingEngine:
             # two programs when chunking is configured: the pure-decode
             # fused verify scan and the unified (chunk-phase) variant;
             # their state tuples match, so the donated carry chains
-            self._spec_fused_fn = mk(model, fused, 0, *spec_sig)
-            if chunkw > 0:
-                self._spec_unified_fn = mk(model, fused, chunkw, *spec_sig)
-            self._fused_fn = None
-        elif fused > 1:
-            self._fused_fn = (
-                _paged_fused_engine_fn(model, fused)
-                if self._paged
-                else _fused_engine_fn(model, fused)
+            self._spec_fused_fn = _fused_spec_engine_fn(
+                model, fused, 0, *spec_sig
             )
             if chunkw > 0:
-                self._unified_fn = (
-                    _paged_unified_engine_fn(model, fused, chunkw)
-                    if self._paged
-                    else _unified_engine_fn(model, fused, chunkw)
+                self._spec_unified_fn = _fused_spec_engine_fn(
+                    model, fused, chunkw, *spec_sig
                 )
-        else:
-            self._fused_fn = None
+        elif fused > 1:
+            self._fused_fn = _fused_engine_fn(model, fused)
+            if chunkw > 0:
+                self._unified_fn = _unified_engine_fn(model, fused, chunkw)
         # device-resident slot state (fused path): the previous tick's
         # returned arrays are re-donated, so steady-state decode never
         # re-uploads.  On the engines that chain ticks (fused or unified,
@@ -1672,35 +1484,10 @@ class ServingEngine:
         self._dev_table = None
         self._table_version = -1
 
-        pool_shardings = None
-        if mesh is not None:
-            import flax.linen as nn
-            from jax.sharding import NamedSharding
-            from jax.sharding import PartitionSpec as P
-
-            if param_specs is None:
-                param_specs = nn.get_partition_spec(params)
-            cspecs = cache_partition_specs(model, params, n_slots, mesh)
-            # allocate the pool sharded at birth: a TP-split pool must
-            # never transit one device whole
-            pool_shardings = jax.tree_util.tree_map(
-                lambda spec: NamedSharding(mesh, spec), cspecs,
-                is_leaf=lambda x: isinstance(x, P),
-            )
-            fns = _sharded_engine_fns(
-                model, mesh, _HashableTree.of(param_specs),
-                _HashableTree.of(cspecs),
-            )
-        elif self._paged:
-            fns = None
-        else:
-            fns = _engine_fns(model)
         if self._paged:
-            (self._extend_fn, self._decode_fn, self._verify_fn,
-             self._sample_fn, block_fns) = _paged_engine_fns(model)
             self._prefill_fn = None  # paged prefill IS the extend path
             self.pool: Union[CachePool, PagedCachePool] = PagedCachePool(
-                model, params, n_slots, block_fns=block_fns
+                model, params, n_slots
             )
             if self._radix_requested:
                 # the radix hierarchy replaces the aligned-LRU cache: the
@@ -1726,12 +1513,7 @@ class ServingEngine:
                 )
                 self._prefix = self._radix
         else:
-            (self._prefill_fn, self._extend_fn, self._decode_fn,
-             self._verify_fn, self._sample_fn, insert, row_fns) = fns
-            self.pool = CachePool(
-                model, params, n_slots, insert_fn=insert,
-                shardings=pool_shardings, row_fns=row_fns,
-            )
+            self.pool = CachePool(model, params, n_slots)
 
         # dropless expert layers: every program returns its row counts as
         # one more output; strip it here so that no call site changes, and
@@ -1997,9 +1779,9 @@ class ServingEngine:
         in flight, is collected; None when it may.  Ticks chain on the
         engines whose launch reads nothing the tick in flight will
         change: the fused and unified ticks over the fixed-slot pool,
-        whose slot state stays on the device (the per-step, speculative,
-        paged and mesh engines draft, grow block tables or upload from
-        host mirrors that lag a tick in flight).  A host-side removal
+        whose slot state stays on the device (the per-step, speculative
+        and paged engines draft, grow block tables or upload from host
+        mirrors that lag a tick in flight).  A host-side removal
         (``_flush``) waits for the collect; and a launch goes ahead only
         for work that is certain: a slot with budget beyond the tick in
         flight, a chunked prompt mid-way, or a queued request and a free
@@ -2512,8 +2294,7 @@ class ServingEngine:
     @property
     def decode_steps_per_tick(self) -> int:
         """Decode steps per fused tick (1 = the per-step engine — spec
-        "auto" and mesh serving resolve here; plain ``"auto"`` resolves
-        to 8)."""
+        "auto" resolves here; plain ``"auto"`` resolves to 8)."""
         return self._fused_steps
 
     @property
@@ -2544,12 +2325,15 @@ class ServingEngine:
         self._rng, sub = jax.random.split(self._rng)
         return sub
 
-    def _device_table(self) -> jax.Array:
-        """Device copy of the pool's host-authoritative block-table
-        mirror, re-uploaded ONLY when the allocator moved a mapping
-        (``table_version``) — steady-state decode re-dispatches the same
-        device array, so the fused tick's inputs are loop-invariant and
-        its compile count stays pinned."""
+    def _device_table(self) -> Optional[jax.Array]:
+        """Every tick program's block-table operand: None on the
+        fixed-slot pool; on the paged pool the device copy of the pool's
+        host-authoritative block-table mirror, re-uploaded ONLY when the
+        allocator moved a mapping (``table_version``) — steady-state
+        decode re-dispatches the same device array, so the fused tick's
+        inputs are loop-invariant and its compile count stays pinned."""
+        if not self._paged:
+            return None
         if (
             self._dev_table is None
             or self._table_version != self.pool.table_version
@@ -2907,8 +2691,8 @@ class ServingEngine:
         positions = jnp.where(base >= 0, base + plen, -1)
         logits, self.pool.cache = self._extend_fn(
             self.params, jnp.asarray(tokens), positions, last_idx,
-            jnp.full((nb,), plen, jnp.int32), jnp.asarray(table),
-            self.pool.cache, self._next_rng(),
+            jnp.full((nb,), plen, jnp.int32), self.pool.cache,
+            self._next_rng(), jnp.asarray(table),
         )
         self._prefill_shapes.add(("extend", nb, width))
         self.metrics.record_prefill_call()
@@ -2937,33 +2721,31 @@ class ServingEngine:
     def _extend_slot(
         self, slot: int, tokens_seq, offset: int, width: int
     ):
-        """Extract the slot's row, extend it with ``tokens_seq`` (padded
-        right to ``width``) writing at cache columns ``offset + [0..)``,
-        scatter it back; returns the extension's last real logits."""
+        """Extend the slot's row with ``tokens_seq`` (padded right to
+        ``width``) writing at cache columns ``offset + [0..)``; returns
+        the extension's last real logits.  The fixed-slot pool extracts
+        the row and inserts it back; the paged chunk writes straight
+        into the shared pool through the slot's table row."""
         take = len(tokens_seq)
         tokens = np.zeros((1, width), np.int32)
         tokens[0, :take] = tokens_seq
         base, last_idx = padded_prefill_inputs([take], width)
         positions = jnp.where(base >= 0, base + offset, -1)
         if self._paged:
-            # the chunk writes straight into the shared pool through the
-            # slot's table row — no extract/insert round-trip exists
             self.pool.ensure_writable(slot, offset, offset + take)
-            logits, self.pool.cache = self._extend_fn(
-                self.params, jnp.asarray(tokens), positions, last_idx,
-                jnp.asarray([offset], jnp.int32),
-                jnp.asarray(self.pool.block_table[slot : slot + 1]),
-                self.pool.cache, self._next_rng(),
-            )
-            self._prefill_shapes.add(("extend", 1, width))
-            return logits
-        row = self.pool.extract(slot)
-        logits, row = self._extend_fn(
+            rows = self.pool.cache
+            table = jnp.asarray(self.pool.block_table[slot : slot + 1])
+        else:
+            rows, table = self.pool.extract(slot), None
+        logits, rows = self._extend_fn(
             self.params, jnp.asarray(tokens), positions, last_idx,
-            jnp.asarray([offset], jnp.int32), row, self._next_rng(),
+            jnp.asarray([offset], jnp.int32), rows, self._next_rng(), table,
         )
         self._prefill_shapes.add(("extend", 1, width))
-        self.pool.insert(row, slot)
+        if table is None:
+            self.pool.insert(rows, slot)
+        else:
+            self.pool.cache = rows
         return logits
 
     def _lookup_prefix(self, prompt, reserve: int = 0):
@@ -3238,30 +3020,18 @@ class ServingEngine:
                 w = int(self._widx[slot])
                 if w < seq_len:
                     self.pool.ensure_writable(slot, w, w + 1)
-            nxt, self.pool.cache = self._decode_fn(
-                self.params,
-                jnp.asarray(self._tok),
-                jnp.asarray(self._pos),
-                jnp.asarray(self._widx),
-                self._device_table(),
-                jnp.asarray(self._temp),
-                jnp.asarray(self._topk),
-                jnp.asarray(self._topp),
-                self.pool.cache,
-                self._next_rng(),
-            )
-        else:
-            nxt, self.pool.cache = self._decode_fn(
-                self.params,
-                jnp.asarray(self._tok),
-                jnp.asarray(self._pos),
-                jnp.asarray(self._widx),
-                jnp.asarray(self._temp),
-                jnp.asarray(self._topk),
-                jnp.asarray(self._topp),
-                self.pool.cache,
-                self._next_rng(),
-            )
+        nxt, self.pool.cache = self._decode_fn(
+            self.params,
+            jnp.asarray(self._tok),
+            jnp.asarray(self._pos),
+            jnp.asarray(self._widx),
+            jnp.asarray(self._temp),
+            jnp.asarray(self._topk),
+            jnp.asarray(self._topp),
+            self.pool.cache,
+            self._next_rng(),
+            self._device_table(),
+        )
         p.kind = "step"
         p.payload = nxt
 
@@ -3354,15 +3124,19 @@ class ServingEngine:
         self._state_dirty, self._flush_cause = False, None
 
     def _ensure_decode_writable(self, p: _PendingTick, width: int) -> None:
-        """Paged launches: make every column this tick CAN write writable
-        up front (budget-clamped so a finishing slot never draws blocks
-        beyond its admission entitlement); the table then rides the
+        """Paged launches (nothing to do on the fixed-slot pool, whose
+        columns are the slot's own): make every column this tick CAN
+        write writable up front (budget-clamped so a finishing slot
+        never draws blocks beyond its admission entitlement); the table
+        then rides the
         scan's inputs loop-invariant — steady-state ticks re-upload
         nothing and the compile count stays pinned.  ``width`` is the
         tick's worst-case per-slot column advance (T decode steps, or
         T * (K + 1) verify columns).  Paged ticks never launch ahead:
         the window is read off host mirrors that a tick in flight would
         leave a width behind."""
+        if not self._paged:
+            return
         seq_len = self.model.config.seq_len
         for slot in p.entering:
             out = self._slot_out[slot]
@@ -3387,17 +3161,11 @@ class ServingEngine:
         buffers donated (:func:`_fused_decode_core`)."""
         if self._state_dirty or self._dev_state is None:
             self._upload_slot_state()
-        if self._paged:
-            self._ensure_decode_writable(p, self._fused_steps)
-            block, counts, self._dev_state, self.pool.cache = self._fused_fn(
-                self.params, self._dev_state, self._dev_knobs,
-                self._device_table(), self.pool.cache, self._next_rng(),
-            )
-        else:
-            block, counts, self._dev_state, self.pool.cache = self._fused_fn(
-                self.params, self._dev_state, self._dev_knobs,
-                self.pool.cache, self._next_rng(),
-            )
+        self._ensure_decode_writable(p, self._fused_steps)
+        block, counts, self._dev_state, self.pool.cache = self._fused_fn(
+            self.params, self._dev_state, self._dev_knobs, self.pool.cache,
+            self._next_rng(), self._device_table(),
+        )
         p.kind = "fused"
         p.payload = (block, counts)
 
@@ -3459,17 +3227,11 @@ class ServingEngine:
         if self._state_dirty or self._dev_state is None:
             self._upload_slot_state()
         chunk_ops = self._build_chunk_block(p)
-        if self._paged:
-            self._ensure_decode_writable(p, self._fused_steps)
-            out = self._unified_fn(
-                self.params, self._dev_state, self._dev_knobs, chunk_ops,
-                self._device_table(), self.pool.cache, self._next_rng(),
-            )
-        else:
-            out = self._unified_fn(
-                self.params, self._dev_state, self._dev_knobs, chunk_ops,
-                self.pool.cache, self._next_rng(),
-            )
+        self._ensure_decode_writable(p, self._fused_steps)
+        out = self._unified_fn(
+            self.params, self._dev_state, self._dev_knobs, chunk_ops,
+            self.pool.cache, self._next_rng(), self._device_table(),
+        )
         act_emit, block, counts, self._dev_state, self.pool.cache = out
         p.kind = "unified"
         p.payload = (act_emit, block, counts)
@@ -3675,34 +3437,20 @@ class ServingEngine:
                     w,
                     min(w + int(dlen[slot]) + 1, cfg.seq_len),
                 )
-            block, accepted, self.pool.cache = self._verify_fn(
-                self.params,
-                jnp.asarray(self._tok),
-                jnp.asarray(drafts),
-                jnp.asarray(dlen),
-                jnp.asarray(self._pos),
-                jnp.asarray(self._widx),
-                self._device_table(),
-                jnp.asarray(self._temp),
-                jnp.asarray(self._topk),
-                jnp.asarray(self._topp),
-                self.pool.cache,
-                self._next_rng(),
-            )
-        else:
-            block, accepted, self.pool.cache = self._verify_fn(
-                self.params,
-                jnp.asarray(self._tok),
-                jnp.asarray(drafts),
-                jnp.asarray(dlen),
-                jnp.asarray(self._pos),
-                jnp.asarray(self._widx),
-                jnp.asarray(self._temp),
-                jnp.asarray(self._topk),
-                jnp.asarray(self._topp),
-                self.pool.cache,
-                self._next_rng(),
-            )
+        block, accepted, self.pool.cache = self._verify_fn(
+            self.params,
+            jnp.asarray(self._tok),
+            jnp.asarray(drafts),
+            jnp.asarray(dlen),
+            jnp.asarray(self._pos),
+            jnp.asarray(self._widx),
+            jnp.asarray(self._temp),
+            jnp.asarray(self._topk),
+            jnp.asarray(self._topp),
+            self.pool.cache,
+            self._next_rng(),
+            self._device_table(),
+        )
         p.kind = "spec"
         p.payload = (block, accepted, dlen)
 
@@ -3780,40 +3528,22 @@ class ServingEngine:
         chunk_ops = (
             self._build_chunk_block(p) if unified_chunks else None
         )
-        if self._paged:
-            self._ensure_decode_writable(
-                p, self._fused_steps * (self._spec_width + 1)
-            )
-            args = (self.params, self._dev_state, self._dev_knobs)
-            if chunk_ops is not None:
-                out = self._spec_unified_fn(
-                    *args, chunk_ops, self._device_table(),
-                    self.pool.cache, self._next_rng(),
-                )
-            else:
-                out = self._spec_fused_fn(
-                    *args, self._device_table(), self.pool.cache,
-                    self._next_rng(),
-                )
+        self._ensure_decode_writable(
+            p, self._fused_steps * (self._spec_width + 1)
+        )
+        # the unified program takes the chunk operands after the knobs
+        # and returns the activation row first; else they are one call
+        if chunk_ops is None:
+            fn, chunk, first = self._spec_fused_fn, (), (None,)
         else:
-            args = (self.params, self._dev_state, self._dev_knobs)
-            if chunk_ops is not None:
-                out = self._spec_unified_fn(
-                    *args, chunk_ops, self.pool.cache, self._next_rng()
-                )
-            else:
-                out = self._spec_fused_fn(
-                    *args, self.pool.cache, self._next_rng()
-                )
-        if chunk_ops is not None:
-            (act_emit, blocks, counts, drafted, accepted,
-             self._dev_state, self.pool.cache) = out
-        else:
-            act_emit = None
-            (blocks, counts, drafted, accepted,
-             self._dev_state, self.pool.cache) = out
+            fn, chunk, first = self._spec_unified_fn, (chunk_ops,), ()
+        *out, self._dev_state, self.pool.cache = fn(
+            self.params, self._dev_state, self._dev_knobs, *chunk,
+            self.pool.cache, self._next_rng(), self._device_table(),
+        )
         p.kind = "spec_fused"
-        p.payload = (act_emit, blocks, counts, drafted, accepted)
+        # (act_emit, blocks, counts, drafted, accepted)
+        p.payload = (*first, *out)
 
     def _collect_spec_fused(self, p: _PendingTick) -> List[StreamEvent]:
         """Collect one fused speculative tick: ONE sync per T verify

@@ -1,7 +1,7 @@
 """Speculative decoding: draft-verify multi-token decode, exactly.
 
-Decode is the serving hot path (DECODE_r05: ~95% of e2e after the prefill
-fast path) and a single-token step is memory-bound — the whole model's
+Decode is the serving hot path (nearly all of a request's time after the
+prefill fast path) and a single-token step is memory-bound — the whole model's
 weights stream through HBM to produce ONE token per row.  Speculative
 decoding (Leviathan et al. 2023) buys back that bandwidth: a cheap DRAFTER
 proposes K tokens per row, one forward scores all K (+1 bonus position)
@@ -478,7 +478,7 @@ def generate_speculative(
         rng = jax.random.PRNGKey(0)
     if drafter is None:
         drafter = NGramDrafter()
-    prefill_fn, _, _, verify_fn, sample_fn, _, _ = _engine_fns(model)
+    prefill_fn, _, _, verify_fn, sample_fn = _engine_fns(model)
 
     def split():
         nonlocal rng

@@ -79,7 +79,7 @@ def transformer_flops_per_token(cfg) -> float:
     capacity by construction, so the per-token average is
     ``moe_capacity_factor`` experts (1.25 by default), not 1 — the FFN
     term scales by the capacity factor or expert-choice MFU reads ~25%
-    high (ADVICE.md round-5 finding).
+    high.
     """
     mlp_term = 2 * cfg.mlp_ratio * cfg.d_model**2
     moe_experts = getattr(cfg, "moe_experts", 0)
