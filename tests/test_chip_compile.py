@@ -14,6 +14,7 @@ entry points that fail without a TPU instead of falling back to the CPU.
 
 import functools
 import os
+import re
 import subprocess
 import sys
 
@@ -180,7 +181,9 @@ def test_expert_share_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
     widths, built from the benchmark's own configuration and cell files: the
     fused decode tick over 32 slots of 8192 positions and the largest
     whole-prompt prefill (flash kernels at heads of 128, GQA 16:1, window
-    4096 and none; grouped expert matmuls with both buffers) fit one v5e."""
+    4096 and none; grouped expert matmuls with both buffers, the decode
+    tick's through the streamed kernel of ``ops/grouped_ffn.py``) fit one
+    v5e."""
     import json
 
     sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -237,7 +240,17 @@ def test_expert_share_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
         lowered = engine._fused_engine_fn(model, 8).lower(
             params, state, knobs, pool, key
         )
-    memory = lowered.compile().memory_analysis()
+    compiled = lowered.compile()
+    if program == "decode_tick":
+        # a decode step's grouped matmuls are the streamed kernel, two calls
+        # a layer, by the name and under the scope that the benchmark's
+        # roofline reader finds them by; no ``lax.ragged_dot`` is left
+        text = compiled.as_text()
+        kernels = re.findall(r"%ragged-dot-streamed[.\d]* = .*", text)
+        assert len(kernels) == 2 * cfg.n_layers
+        assert all("moe.experts/jit(_planned)/ragged-dot/" in k for k in kernels)
+        assert "ragged-dot-none" not in text
+    memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 8.4e9 < held < 15.75e9, memory
 

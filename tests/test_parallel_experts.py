@@ -34,7 +34,7 @@ from tpu_parallel.models.layers import (  # noqa: E402
     ExpertsSpec,
     LayerSpec,
 )
-from tpu_parallel.models.moe import MOE_STATS, RoutedExperts  # noqa: E402
+from tpu_parallel.models.moe import MOE_STATS, RoutedExperts, moe_plan  # noqa: E402
 from tpu_parallel.serving import ServingEngine  # noqa: E402
 from tpu_parallel.serving.request import Request  # noqa: E402
 
@@ -84,10 +84,24 @@ def tokens_of(n, seed=0):
     return [int(t) for t in np.random.RandomState(seed).randint(1, 250, n)]
 
 
-@pytest.mark.parametrize("held", [(4, 4), (0, 16), (12, 4), (0, 1)])
-def test_full_forward_matches_reference(held):
-    cfg, model, abstract, params = build(held)
-    toks = tokens_of(36)
+def grouped_path(cfg, tokens):
+    """What ``moe_plan`` says runs the grouped matmuls of ``tokens`` rows."""
+    es = cfg.layer_specs[0].experts
+    return moe_plan(es, tokens, cfg.d_model, cfg.dtype)["grouped"]
+
+
+@pytest.mark.parametrize("held,tokens,path", [
+    ((4, 4), 36, "streamed"), ((0, 16), 36, "streamed"),
+    ((12, 4), 36, "streamed"), ((0, 1), 36, "streamed"),
+    ((4, 4), 136, "ragged_dot"), ((12, 4), 136, "ragged_dot"),
+])
+def test_full_forward_matches_reference(held, tokens, path):
+    """On both sides of the rule that picks the grouped matmuls: 36 tokens
+    (the buffer's rows an expert fit one window of the streamed kernel) and
+    136 on 4 held experts (they do not: ``lax.ragged_dot``)."""
+    cfg, model, abstract, params = build(held, seq_len=max(40, tokens))
+    assert grouped_path(cfg, tokens) == path
+    toks = tokens_of(tokens)
     got = model.apply({"params": params}, jnp.asarray([toks]), train=False)[0]
     want = reference_logits(cfg, abstract, toks)
     assert float(jnp.abs(got - want).max()) < TOL
@@ -265,17 +279,25 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
     assert float(jnp.abs(total - y_whole).max()) < TOL
 
 
-@pytest.mark.parametrize("tokens,case", [
-    (24, "natural"), (24, "all_on_one_expert"), (24, "all_on_the_held"),
-    (640, "natural"), (640, "all_on_the_held"),
+@pytest.mark.parametrize("tokens,case,path", [
+    (24, "natural", "streamed"), (24, "all_on_one_expert", "streamed"),
+    (24, "all_on_the_held", "streamed"),
+    (128, "all_on_one_expert", "streamed"), (128, "all_on_the_held", "streamed"),
+    (136, "all_on_one_expert", "ragged_dot"), (136, "all_on_the_held", "ragged_dot"),
+    (640, "natural", "ragged_dot"), (640, "all_on_the_held", "ragged_dot"),
 ])
-def test_no_imbalance_drops_a_token(tokens, case):
+def test_no_imbalance_drops_a_token(tokens, case, path):
     """Every token forced onto held experts (one of them, or all four of
     its choices): the worst-case buffer takes every assignment, none is
     dropped, and the layer still is the reference's.  640 tokens is past the
-    size at which the small buffer and the worst-case one are two programs."""
+    size at which the small buffer and the worst-case one are two programs.
+    Held on both sides of the rule that picks the grouped matmuls: 128 tokens
+    is the most the streamed kernel takes on 4 held experts (one expert with
+    every row then runs over two of its windows), 136 the first that
+    ``lax.ragged_dot`` takes."""
     spec = experts_spec((4, 4), n_experts=32)
     cfg, module, params, _ = _layer(spec)
+    assert moe_plan(spec, tokens, 32, jnp.float32)["grouped"] == path
     x = jnp.abs(
         jax.random.normal(jax.random.PRNGKey(5), (1, tokens, 32), jnp.float32)
     ) + 0.1

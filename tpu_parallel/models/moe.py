@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from tpu_parallel.ops.grouped_ffn import grouped_ffn, grouped_ffn_plan
 from tpu_parallel.parallel.tp import ModuleShard, axis_size_or_none
 
 
@@ -411,24 +412,38 @@ MOE_STATS = "moe_stats"
 SMALL_BUFFER_MIN_ROWS = 2048
 
 
-def moe_plan(spec, tokens: int, ep_size: int = 1) -> dict:
+def moe_plan(spec, tokens: int, d_model: int, dtype, ep_size: int = 1) -> dict:
     """What a dropless layer does with ``tokens`` rows: experts held of how
     many, top-k, the worst-case buffer (every token on ``min(top_k, held)``
-    held experts) and the small buffer that runs whenever the rows routed
-    here fit it.  :class:`RoutedExperts` sizes its buffers from this; the
-    serving engine logs it at build for each of its program shapes, as
-    ``flash_plan`` is for the attention kernels.  The grouped matmuls'
-    tiles are the compiler's (``lax.ragged_dot``) and are not in it."""
+    held experts), the small buffer that runs whenever the rows routed here
+    fit it, and what runs the grouped matmuls of each (``grouped``,
+    ``small_grouped``): ``"streamed"`` where the buffer's rows an expert are
+    few (:func:`~tpu_parallel.ops.grouped_ffn.grouped_ffn_plan` has the
+    rule; a decode step) with the kernel's window, slots, contraction blocks
+    and VMEM limit beside it, ``"ragged_dot"``, whose tiles are the
+    compiler's, where they are many (a prefill).  :class:`RoutedExperts`
+    sizes its buffers from this and :func:`_grouped_ffn` follows the same
+    rule; the serving engine logs it at build for each of its program
+    shapes, as ``flash_plan`` is for the attention kernels."""
     held = spec.held_range[1] // ep_size
     worst = tokens * min(spec.top_k, held)
     small = worst
     if worst >= SMALL_BUFFER_MIN_ROWS:
         small = -(-worst // 4 // 128) * 128
-    return dict(
+    plan = dict(
         experts=spec.n_experts, held=held, top_k=spec.top_k,
         shared=spec.shared, width=spec.width, score=spec.score,
         tokens=tokens, buffer_rows=worst, small_buffer_rows=small,
     )
+    buffers = {"": worst} if small == worst else {"": worst, "small_": small}
+    for prefix, rows in buffers.items():
+        streamed = grouped_ffn_plan(rows, held, d_model, spec.width, dtype)
+        plan[prefix + "grouped"] = "streamed" if streamed else "ragged_dot"
+        plan.update({
+            prefix + k: v for k, v in (streamed or {}).items()
+            if k != "buffer_rows"
+        })
+    return plan
 
 
 def expert_rows(variables):
@@ -485,8 +500,16 @@ class _HeldExperts(nn.Module):
 
 def _grouped_ffn(rows, weights, group_sizes):
     """Every expert's FFN over its own run of ``rows`` (sorted by expert);
-    rows past the groups are not computed and hold nothing."""
+    rows past the groups are not computed and hold nothing.  Where the
+    buffer's rows an expert are few (``grouped_ffn_plan``'s rule) the
+    streamed kernel reads each touched expert's matrices once
+    (``ops/grouped_ffn.py``); else three ``lax.ragged_dot``."""
     w_gate, w_up, w_down = weights
+    n_local, d_model, width = w_gate.shape
+    if grouped_ffn_plan(
+        rows.shape[0], n_local, d_model, width, rows.dtype
+    ) is not None:
+        return grouped_ffn(rows, weights, group_sizes)
     gate = lax.ragged_dot(rows, w_gate, group_sizes)
     up = lax.ragged_dot(rows, w_up, group_sizes)
     return lax.ragged_dot(nn.silu(gate) * up, w_down, group_sizes)
@@ -505,10 +528,15 @@ class RoutedExperts(nn.Module):
     shared experts are replicated and counted once.
 
     Assignments are sorted by expert with those of absent experts behind the
-    held ones; the grouped matmuls (``lax.ragged_dot``: on the TPU a kernel
-    that walks only the tiles its groups fill) do work for the rows routed
-    here, while the buffer holds the worst case - every token on
-    ``min(top_k, held)`` held experts - so that no imbalance drops a token.
+    held ones; the grouped matmuls do work for the rows routed here, while
+    the buffer holds the worst case - every token on ``min(top_k, held)``
+    held experts - so that no imbalance drops a token.  What runs them follows
+    the shape (:func:`moe_plan`): a decode step, where an expert receives a
+    row or two and the cost is reading its matrices, goes through the
+    streamed kernel of ``ops/grouped_ffn.py`` (each touched expert's matrices
+    once, nothing for the others); a prefill, where an expert receives
+    hundreds of rows, through ``lax.ragged_dot`` (on the TPU a kernel that
+    walks only the tiles its groups fill).
     """
 
     config: "TransformerConfig"  # noqa: F821
@@ -597,7 +625,7 @@ class RoutedExperts(nn.Module):
                     axis=1,
                 )
 
-            plan = moe_plan(es, tokens, ep_size)
+            plan = moe_plan(es, tokens, d, cfg.dtype, ep_size)
             worst, small = plan["buffer_rows"], plan["small_buffer_rows"]
             if small == worst:
                 y = run(worst)

@@ -1562,7 +1562,10 @@ class ServingEngine:
             shapes["chunk"] = n_slots * self._chunk_tokens
         for b in self._buckets or ():
             shapes[f"prefill_{b}"] = self._prefill_batch * b
-        plan = {name: moe_plan(spec, t) for name, t in shapes.items()}
+        plan = {
+            name: moe_plan(spec, t, cfg.d_model, cfg.dtype)
+            for name, t in shapes.items()
+        }
         logging.getLogger(__name__).info("moe_plan %s", json.dumps(plan))
         if self.tracer.enabled:
             self.tracer.instant(
