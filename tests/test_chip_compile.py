@@ -255,6 +255,83 @@ def test_expert_share_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
     assert 8.4e9 < held < 15.75e9, memory
 
 
+@pytest.mark.parametrize("program", ["decode_tick", "prefill_768"])
+def test_hybrid_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
+    """The serving cell of the hybrid decoder at its published widths, whole,
+    built from the benchmark's own configuration and cell files: the fused
+    decode tick over 64 slots (36 float32 recurrent states of 64 x 64 x 128 a
+    slot beside 4 K/V stripes of 1280 positions) and the largest
+    whole-prompt prefill (the chunked scan at chunks of 256; the flash
+    kernels at heads of 64, GQA 4:1, a stated score scale) fit one v5e."""
+    import json
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from drivers.serve_hybrid import model_config
+
+    from tpu_parallel.models import GPTLM
+    from tpu_parallel.serving import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    read = lambda *rel: json.load(open(os.path.join(REPO, "benchmarks", *rel)))
+    cell = read("workloads", "serve-granite_4_0_h_micro-shortchat.json")
+    cfg = model_config(read("configs", "granite_4_0_h_micro.json"), cell["engine"])
+    assert (cfg.d_model, cfg.n_layers, cfg.recurrent_layers, cfg.vocab_size) == (
+        2048, 40, 36, 100352
+    )
+    assert (cfg.attn_scale, cfg.embed_scale, cfg.residual_scale,
+            cfg.logit_scale) == (0.015625, 12.0, 0.22, 0.125)
+    model, n = GPTLM(cfg), cell["engine"]["n_slots"]
+    on_chip = lambda x, dtype=None: jax.ShapeDtypeStruct(
+        x.shape, dtype or x.dtype, sharding=v5e_chip
+    )
+    params = jax.tree.map(
+        lambda x: on_chip(x, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 16), jnp.int32),
+            train=False,
+        ))["params"],
+    )
+    assert sum(x.size for x in jax.tree.leaves(params)) == 3_191_396_096
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_chip)
+    floats = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=v5e_chip
+    )
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip)
+    if program == "prefill_768":
+        width = max(b for b in cell["engine"]["prefill_buckets"])
+        lowered = jax.jit(
+            lambda p, toks, pos, last, rng: engine._prefill_core(
+                model, p, toks, pos, last, rng
+            )
+        ).lower(params, ints(1, width), ints(1, width), ints(1), key)
+        # the four attention layers attend through the flash kernel
+        assert lowered.as_text().count("tpu_custom_call") == 4
+        low, high = 6.38e9, 8e9
+    else:
+        pool = jax.tree.map(on_chip, jax.eval_shape(
+            lambda p: engine._prefill_core(
+                model, p, jnp.zeros((n, 16), jnp.int32),
+                jnp.zeros((n, 16), jnp.int32), jnp.zeros((n,), jnp.int32), None,
+            )[1], params,
+        ))
+        state_bytes = sum(
+            x.size * x.dtype.itemsize
+            for p, x in jax.tree_util.tree_flatten_with_path(pool)[0]
+            if p[-1].key == "ssm_state"
+        )
+        assert state_bytes == n * 36 * 64 * 64 * 128 * 4  # 4.83 GB, float32
+        live = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=v5e_chip)
+        state = (ints(n), ints(n), ints(n), live, ints(n))
+        knobs = (ints(n), floats(n), ints(n), floats(n))
+        lowered = engine._fused_engine_fn(model, 8).lower(
+            params, state, knobs, pool, key
+        )
+        low, high = 11.9e9, 15.75e9  # 6.38 GB of weights + 5.56 GB of pool
+    memory = lowered.compile().memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert low < held < high, memory
+
+
 def test_compile_cache_honours_the_environment(monkeypatch, tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set, JAX already uses it and the code
     sets no directory of its own."""

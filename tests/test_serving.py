@@ -2099,6 +2099,11 @@ NEW_SUMMARY_KEYS = {
     "prefill_tick_ms_mean", "host_exposed_share",
     *(f"tick_{name}_ms_mean" for name in PHASES + ("between",)),
 }
+# what a prefill computed beside the prompt tokens, and a slot's bytes of
+# state of one size (PR 32): counters, 0 until something is recorded
+PREFILL_SUMMARY_KEYS = {
+    "prefill_tokens_real", "prefill_tokens_padded", "state_bytes_per_slot",
+}
 
 
 class _SetClock:
@@ -2246,13 +2251,16 @@ def test_summary_keeps_every_old_key_and_has_the_phase_clock(rng):
     cfg, model, prompt, params = _build(rng, n_rows=1)
     eng = ServingEngine(model, params, n_slots=1)
     empty = eng.metrics.summary()
-    assert set(empty) == OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS
+    every = OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS | PREFILL_SUMMARY_KEYS
+    assert set(empty) == every
+    assert all(empty[k] == 0 for k in PREFILL_SUMMARY_KEYS)
     assert empty["busy_ticks"] == 0
     assert all(empty[k] is None for k in NEW_SUMMARY_KEYS - {"busy_ticks"})
     eng.add_request(_req(prompt[0], 12))
     eng.run()
     s = eng.metrics.summary()
-    assert set(s) == OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS
+    assert set(s) == every
+    assert s["prefill_tokens_real"] == len(prompt[0])
     assert s["busy_ticks"] == s["decode_ticks"] >= 2
     # the one admission made the first tick a prefill tick
     assert s["prefill_tick_ms_mean"] > 0 and s["decode_only_tick_ms_mean"] > 0

@@ -578,14 +578,17 @@ def _sharded_generate_fn(
 def beam_cache_batch_axis(path, x):
     """Batch axis of a KV-cache leaf, by name — ONE registry for every
     family's beam search (a new cache leaf added here reorders correctly
-    in both).  K/V payloads (self and cross) carry batch at ndim-4; the
+    in both).  K/V payloads (self and cross) and a recurrent layer's
+    ``ssm_state`` carry batch at ndim-4; its ``conv_state`` at ndim-3; the
     per-slot position table and the cross padding mask at ndim-2; scalar
     counters return None (pass through)."""
     name = path[-1].key if hasattr(path[-1], "key") else str(path[-1])
     if name.startswith(
-        ("cached_key", "cached_value", "cross_key", "cross_value")
+        ("cached_key", "cached_value", "cross_key", "cross_value", "ssm_state")
     ):
         return x.ndim - 4
+    if name.startswith("conv_state"):
+        return x.ndim - 3
     if name.startswith(("cached_pos", "cross_mask")):
         return x.ndim - 2
     return None

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -840,6 +840,61 @@ def parallel_experts_decoder(
             ),
             **overrides,
         }
+    )
+
+
+def hybrid_ssm_decoder(
+    *,
+    pattern: Tuple[str, ...],
+    ssm: "SSMSpec",
+    **overrides,
+) -> GPTConfig:
+    """A pre-norm decoder whose token mixers are mixed by layer: ``pattern``
+    is one period of ``"ssm"`` (the recurrent Mamba-2 mixer of
+    ``models/ssm.py``, sized by ``ssm``) and ``"attention"`` (causal,
+    grouped-query, with no positional encoding at all); every layer has the
+    dense SwiGLU MLP, RMSNorm and no bias; the head is the token embedding.
+    Sizes and the stated scalars (``attn_scale``, ``embed_scale``,
+    ``residual_scale``, ``logit_scale``) come as ``overrides``."""
+    kinds = {
+        "ssm": LayerSpec(positions="none", mixer="ssm", ssm=ssm),
+        "attention": LayerSpec(positions="none"),
+    }
+    return GPTConfig(
+        **{
+            **dict(
+                positional="rope",  # no learned table; every layer says "none"
+                norm="rmsnorm",
+                mlp="swiglu",
+                dense_bias=False,
+                tie_embeddings=True,
+                scan_layers=False,
+                layer_pattern=tuple(kinds[k] for k in pattern),
+            ),
+            **overrides,
+        }
+    )
+
+
+def tiny_hybrid_ssm(**overrides) -> GPTConfig:
+    """``hybrid_ssm_decoder`` at CPU-test size: two periods of ``ssm, ssm,
+    attention, ssm``, 4 heads of 16, a state of 16, chunks of 8."""
+    from tpu_parallel.models.layers import SSMSpec
+
+    return hybrid_ssm_decoder(
+        pattern=overrides.pop("pattern", ("ssm", "ssm", "attention", "ssm")),
+        ssm=overrides.pop(
+            "ssm", SSMSpec(n_heads=8, head_dim=16, d_state=16, chunk=8)
+        ),
+        **{
+            **dict(
+                vocab_size=256, d_model=64, n_layers=8, n_heads=4,
+                n_kv_heads=2, head_dim=16, mlp_dim=128, seq_len=48,
+                attn_scale=0.09, embed_scale=3.0, residual_scale=0.5,
+                logit_scale=16.0, dtype=jnp.float32, remat=False,
+            ),
+            **overrides,
+        },
     )
 
 

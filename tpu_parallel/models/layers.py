@@ -52,6 +52,18 @@ class ExpertsSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMSpec:
+    """A recurrent (Mamba-2) mixer's sizes (``models/ssm.py::SSMMixer``)."""
+
+    n_heads: int
+    head_dim: int
+    d_state: int  # columns of a head's state ``[head_dim, d_state]``
+    n_groups: int = 1  # B and C are shared by the heads of a group
+    d_conv: int = 4  # width of the depthwise causal conv
+    chunk: int = 256  # tokens a block of the chunked scan
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerSpec:
     """One block's kind.  ``TransformerConfig.layer_pattern`` holds one period
     of these, repeated over the depth; a model whose blocks are all alike is
@@ -63,6 +75,8 @@ class LayerSpec:
     mlp: str = "dense"  # "dense" | "experts"
     # None with mlp="experts": the config's capacity-routed moe_* experts
     experts: Optional[ExpertsSpec] = None
+    mixer: str = "attention"  # "attention" | "ssm" (models/ssm.py)
+    ssm: Optional[SSMSpec] = None  # the recurrent mixer's sizes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -154,8 +168,9 @@ class TransformerConfig:
     # — unlike scan_unroll, which unrolls the loop but keeps one carry
     # round-trip per block.  Param layout changes to [n_layers/g] stacks of
     # g named blocks ("block0".."block{g-1}"); g=1 keeps the historical
-    # layout.  Must divide n_layers.  An earlier round found throughput
-    # FLAT in g at 125M (g=1..6) — which falsified the carry-round-trip
+    # layout.  Must divide n_layers and hold whole periods of layer kinds.
+    # An earlier round found throughput FLAT in g at 125M (g=1..6) — which
+    # falsified the carry-round-trip
     # theory of the scan tax; a bisect then located it in the backward.
     # Not measured on the current machine (PERF.md); the knob stays for
     # other depths/hardware.
@@ -257,6 +272,12 @@ class TransformerConfig:
     # rows only (token j at position j): the serving engine's bucketed
     # prefill; left-padded ragged generate() refuses it.
     prefill_flash: bool = False
+    # stated scalars (published configs give them): the attention score scale
+    # (None = head_dim ** -0.5), a multiplier on the token embedding, and one
+    # on what each mixer and MLP adds to the residual
+    attn_scale: Optional[float] = None
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -289,6 +310,12 @@ class TransformerConfig:
         period = self.layer_specs
         per = sum(1 for s in period if s.experts is not None)
         return per * (self.n_layers // len(period))
+
+    @property
+    def recurrent_layers(self) -> int:
+        """Layers whose mixer carries a recurrent state (``mixer="ssm"``)."""
+        per = sum(1 for s in self.layer_specs if s.mixer == "ssm")
+        return per * (self.n_layers // len(self.layer_specs))
 
     @property
     def drops_tokens(self) -> bool:
@@ -337,6 +364,13 @@ def apply_rope(
     return rotated.astype(x.dtype)
 
 
+def _score_scale(scale: Optional[float], head_dim: int, dtype):
+    """The factor on attention scores: a stated one, else ``head_dim ** -0.5``."""
+    if scale is not None:
+        return jnp.asarray(scale, dtype)
+    return 1.0 / jnp.sqrt(head_dim).astype(dtype)
+
+
 def causal_attention(
     q: jax.Array,
     k: jax.Array,
@@ -346,6 +380,7 @@ def causal_attention(
     window: int = 0,
     causal: bool = True,
     bias: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Reference attention: fp32 softmax, bf16 matmuls on the MXU.
 
@@ -356,7 +391,7 @@ def causal_attention(
     those within the symmetric band |q - k| < window.
     """
     head_dim = q.shape[-1]
-    scale = 1.0 / jnp.sqrt(head_dim).astype(q.dtype)
+    scale = _score_scale(scale, head_dim, q.dtype)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k)
     scores = scores.astype(jnp.float32)
     if bias is not None:
@@ -387,6 +422,7 @@ def decode_attention(
     k_positions: Optional[jax.Array] = None,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Attention of new queries against a full KV cache, GQA-native.
 
@@ -417,7 +453,7 @@ def decode_attention(
     b, nq, h, head_dim = q.shape
     h_kv = k_all.shape[2]
     group = h // h_kv
-    scale = 1.0 / jnp.sqrt(head_dim).astype(q.dtype)
+    scale = _score_scale(scale, head_dim, q.dtype)
     qg = (q * scale).reshape(b, nq, h_kv, group, head_dim)
     k_in = k_all if k_scale is None else k_all.astype(q.dtype)
     scores = jnp.einsum("bqngd,bknd->bngqk", qg, k_in).astype(jnp.float32)
@@ -457,6 +493,7 @@ def beam_decode_attention(
     beam_src: jax.Array, num_beams: int, window: int = 0,
     bias: Optional[jax.Array] = None,
     k_positions: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Decode attention against an UN-reordered beam-search KV cache.
 
@@ -480,7 +517,7 @@ def beam_decode_attention(
     cache_len = k_all.shape[1]
     h_kv = k_all.shape[2]
     group = h // h_kv
-    scale = 1.0 / jnp.sqrt(head_dim).astype(q.dtype)
+    scale = _score_scale(scale, head_dim, q.dtype)
     qg = (q * scale).reshape(b, kb, nq, h_kv, group, head_dim)
     kg = k_all.reshape(b, kb, cache_len, h_kv, head_dim)
     # all-pairs scores over the beam group: [b, j, j', h_kv, group, q, slot]
@@ -978,6 +1015,7 @@ class Attention(nn.Module):
                 out = beam_decode_attention(
                     q, k_all, v_all, positions, new_src, cfg.beam_width,
                     window=self.window, bias=attn_bias, k_positions=new_p,
+                    scale=cfg.attn_scale,
                 )
             elif fresh_prefill:
                 if quant_cache or paged or attn_bias is not None:
@@ -1018,6 +1056,7 @@ class Attention(nn.Module):
                         q, k_all, v_all, positions, window=self.window,
                         bias=attn_bias, k_positions=k_pos,
                         k_scale=k_scale, v_scale=v_scale,
+                        scale=cfg.attn_scale,
                     )
         else:
             out = self._attend(q, k, v, segment_ids, attn_bias)
@@ -1071,6 +1110,9 @@ class Attention(nn.Module):
             # h_kv doesn't divide the axis.
             k = jnp.repeat(k, group, axis=2)
             v = jnp.repeat(v, group, axis=2)
+        if cfg.attn_scale is not None and (self.attn_fn or cfg.attn_impl != "xla"):
+            # these paths scale by head_dim ** -0.5 themselves: fold the rest in
+            q = q * jnp.asarray(cfg.attn_scale * q.shape[-1] ** 0.5, q.dtype)
         attn_fn = self.attn_fn
         if attn_fn is None:
             if cfg.attn_impl == "flash" and cfg.bidirectional:
@@ -1160,6 +1202,7 @@ class Attention(nn.Module):
                 attn_fn = functools.partial(
                     causal_attention, window=self.window,
                     causal=not cfg.bidirectional, bias=attn_bias,
+                    scale=cfg.attn_scale,
                 )
         with self._scope():
             return attn_fn(q, k, v, segment_ids=segment_ids)
@@ -1219,6 +1262,31 @@ class MLP(nn.Module):
         return y
 
 
+def _scaled(fn, scale: float, *args, **kwargs):
+    """``fn``'s output times the residual multiplier, in its own type."""
+    y = fn(*args, **kwargs)
+    return y * jnp.asarray(scale, y.dtype)
+
+
+def make_mixer(config: TransformerConfig, spec: Optional[LayerSpec]):
+    """A block's token mixer by the layer's kind: :class:`Attention`
+    (``"attn"``) or the recurrent :class:`~tpu_parallel.models.ssm.SSMMixer`
+    (``"ssm"``); where the config states a residual multiplier, its output
+    times that.  Called inside the block's compact method."""
+    kind = spec or config.layer_specs[0]
+    if kind.mixer == "ssm":
+        from tpu_parallel.models.ssm import SSMMixer
+
+        mixer = SSMMixer(config, kind.ssm, name="ssm")
+    elif kind.mixer == "attention":
+        mixer = Attention(config, spec=spec, name="attn")
+    else:
+        raise ValueError(f"LayerSpec.mixer={kind.mixer!r} (attention | ssm)")
+    if config.residual_scale == 1.0:
+        return mixer
+    return functools.partial(_scaled, mixer, config.residual_scale)
+
+
 class Block(nn.Module):
     """Pre-norm transformer block: x + attn(norm(x)); x + mlp(norm(x)).
     ``config.parallel_block``: x + attn(h) + mlp(h), both from h = norm(x).
@@ -1252,7 +1320,7 @@ class Block(nn.Module):
                 "incremental decoding with expert-choice routing "
                 "(the routing pool collapses to one token per row)"
             )
-        attn = Attention(cfg, spec=self.spec, name="attn")
+        attn = make_mixer(cfg, self.spec)
         mlp_fn = (
             lambda h: MLP(cfg, name="mlp")(h, train=train)
         )
@@ -1268,6 +1336,8 @@ class Block(nn.Module):
             mlp_fn = lambda h: RoutedExperts(cfg, spec.experts, name="moe")(
                 h, valid=None if positions is None else positions >= 0
             )
+        if cfg.residual_scale != 1.0:
+            mlp_fn = functools.partial(_scaled, mlp_fn, cfg.residual_scale)
         attn_kwargs = dict(
             positions=positions,
             segment_ids=segment_ids,
@@ -1531,6 +1601,8 @@ class Embedding(nn.Module):
             # the tied output head: ``tokens`` is the final hidden state
             return tied_logits(cfg, tok.embedding, tokens)
         emb = tok(tokens)
+        if cfg.embed_scale != 1.0:
+            emb = emb * jnp.asarray(cfg.embed_scale, emb.dtype)
         if cfg.positional == "learned":
             if positions is None:
                 local = jnp.arange(tokens.shape[1])

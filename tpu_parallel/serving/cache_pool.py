@@ -4,7 +4,9 @@ The pool is ONE cache pytree in the exact per-layer layout the model's
 :class:`~tpu_parallel.models.layers.Attention` creates (stacked
 ``[n_layers, n_slots, seq_len, kv_heads, head_dim]`` payloads under
 ``nn.scan``, per-slot position tables, int8 scales under
-``kv_cache_dtype="int8"``) — the batch axis IS the slot axis.  Requests
+``kv_cache_dtype="int8"``; for a recurrent layer its ``ssm_state``
+``[n_slots, heads, head_dim, d_state]`` and ``conv_state``, of one size
+whatever the context length) — the batch axis IS the slot axis.  Requests
 own slots for their lifetime: admission prefills the request alone
 (batch 1) and row-inserts the fresh cache into the freed slot; retirement
 just returns the slot index to the free list (the row is dead weight until
@@ -152,23 +154,32 @@ def extract_rows(pool_cache, slot, n: int = 1):
     return jax.tree_util.tree_map_with_path(ext, pool_cache)
 
 
+# a recurrent layer's leaves (models/ssm.py): a summary of the row's past
+# with no position table over it, so a stale one is masked by nothing
+STATE_LEAVES = ("ssm_state", "conv_state")
+
+
 def clear_rows(pool_cache, slot):
     """Invalidate pool row ``slot``: every position-table entry to -1, so
-    no query ever attends the row's (stale) K/V again.  The K/V payloads
+    no query ever attends the row's (stale) K/V again, and a recurrent
+    layer's state leaves to ZERO (a chunked prompt starts from ``S = 0``;
+    nothing masks a stale state).  The K/V payloads
     are left untouched — dead bytes until overwritten.  Used before a
     chunked prefill starts writing a freed slot incrementally (a whole-row
     insert is not available until the LAST chunk; the stale occupant must
     not leak into the chunks' attention reads meanwhile)."""
 
     def clr(path, leaf):
-        if not _leaf_name(path).startswith(("cached_pos", "cross_mask")):
+        name = _leaf_name(path)
+        if not name.startswith(("cached_pos", "cross_mask") + STATE_LEAVES):
             return leaf
         ax = beam_cache_batch_axis(path, leaf)
         if ax is None:
             return leaf
         row_shape = leaf.shape[:ax] + (1,) + leaf.shape[ax + 1:]
+        fill = 0 if name.startswith(STATE_LEAVES) else -1
         return lax.dynamic_update_slice_in_dim(
-            leaf, jnp.full(row_shape, -1, leaf.dtype), slot, axis=ax
+            leaf, jnp.full(row_shape, fill, leaf.dtype), slot, axis=ax
         )
 
     return jax.tree_util.tree_map_with_path(clr, pool_cache)

@@ -292,6 +292,18 @@ def _full_logits(cfg, params, hidden):
     return lm_logits(cfg, params, hidden)
 
 
+def _pad_parked(cfg, pos, widx):
+    """Positions of a decode step with the parked rows (``widx`` at
+    ``seq_len``: free slots, finished ones, slots in the middle of a chunked
+    prompt) at -1.  Attention masks such a row by itself, its write falls
+    out of range; a recurrent layer has to be TOLD that the row is a pad,
+    and a negative position is how every caller tells it
+    (``models/ssm.py``).  A model without one gets ``pos`` as it is."""
+    if not cfg.recurrent_layers:
+        return pos
+    return jnp.where(widx < cfg.seq_len, pos, -1)
+
+
 def _calls(rows):
     """One apply's expert row counts ``[layers, held + 1]`` as a one-call
     ``[1, layers, held + 1]`` block (the fused tick's are one a step)."""
@@ -347,8 +359,8 @@ def _decode_core(
     through it and the math is the same (greedy output bitwise identical
     across the pools).  Returns (next_tokens [n_slots], new cache)."""
     hidden, cache, rows = decode_step(
-        model, params, cache, tok, pos, write_index=widx, block_table=table,
-        with_rows=True,
+        model, params, cache, tok, _pad_parked(model.config, pos, widx),
+        write_index=widx, block_table=table, with_rows=True,
     )
     logits = _full_last_logits(model.config, params, hidden)
     nxt = sample_tokens(logits, rng, temperature, top_k, top_p)
@@ -395,8 +407,8 @@ def _fused_decode_core(
         tok, pos, widx, live, budget, cache = carry
         widx_eff = jnp.where(live, widx, seq_len)
         hidden, cache, rows = decode_step(
-            model, params, cache, tok, pos, write_index=widx_eff,
-            block_table=table, with_rows=True,
+            model, params, cache, tok, _pad_parked(cfg, pos, widx_eff),
+            write_index=widx_eff, block_table=table, with_rows=True,
         )
         logits = _full_last_logits(cfg, params, hidden)
         nxt = sample_tokens(logits, step_rng, temp, topk, topp)
@@ -1021,7 +1033,8 @@ class ServingEngine:
     - ``prefill_batch``: row count every batched prefill call pads to
       (default: the scheduler's ``max_prefills_per_tick``), so batch size
       never adds compile shapes.  Dummy rows scatter out of range and
-      vanish.
+      vanish; a tick's same-bucket admissions beyond it run as further
+      calls of the same shape.
     - ``prefill_chunk_tokens``: prompts longer than this split into
       chunks interleaving with decode ticks (None = monolithic prefill).
     - ``prefix_cache_size``: LRU entries of bucket-aligned prefix K/V
@@ -1104,6 +1117,16 @@ class ServingEngine:
       invariant (:meth:`CachePool.assert_slot_aligned`) every verify
       tick — debug aid, one device fetch per slot per tick.
 
+    Models with recurrent (state-space) layers (``LayerSpec.mixer ==
+    "ssm"``, ``models/ssm.py``; docs/10_serving_engine.md): a slot holds a
+    float32 state of one size beside its K/V stripes, in the same
+    fixed-slot pool; whole-prompt, bucketed and chunked prefill and the
+    per-step, fused and unified ticks all run them (parked rows ride as
+    pads: position -1).  What cuts, shares or rolls a cache back by
+    position is refused at construction: ``prefix_cache_size > 0``,
+    ``kv_radix_cache``, ``kv_block_tokens``, the host and disk tiers,
+    ``draft_tokens > 0``.  ``ssm_plan`` says what a slot holds.
+
     Telemetry (docs/11_observability.md):
 
     - ``tracer``: a :class:`~tpu_parallel.obs.tracer.Tracer` records each
@@ -1182,6 +1205,45 @@ class ServingEngine:
                 "batch-mates — give the layers an ExpertsSpec (the dropless "
                 "RoutedExperts layer)"
             )
+        if cfg.recurrent_layers:
+            # a recurrent state is a summary of the row's whole past: it
+            # cannot be cut at a position, shared by position or rolled back
+            refused = {
+                "prefix_cache_size > 0": (
+                    prefix_cache_size > 0,
+                    "a stored prefix is K/V rows trimmed by position "
+                    "(serving/prefix_cache.py); reuse needs a snapshot of the "
+                    "state AT the prefix boundary",
+                ),
+                "kv_radix_cache": (
+                    bool(kv_radix_cache),
+                    "the radix tree (serving/kv_hierarchy.py) shares blocks of "
+                    "positions; a state has none",
+                ),
+                "kv_block_tokens": (
+                    kv_block_tokens not in (None, 0),
+                    "the block-paged pool (serving/cache_pool.py) pages "
+                    "positions; a state of one size a slot has no blocks",
+                ),
+                "kv_host_blocks / kv_disk_dir": (
+                    kv_host_blocks > 0 or kv_disk_dir is not None,
+                    "the host and disk tiers spill the paged pool's blocks",
+                ),
+                "draft_tokens > 0": (
+                    draft_tokens > 0,
+                    "a rejected draft is left behind a position mask in the "
+                    "K/V stripe; a state that has absorbed it cannot be "
+                    "rolled back by the verify tick",
+                ),
+            }
+            for option, (asked, why) in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"the serving engine does not run a model with "
+                        f"recurrent (state-space) layers under {option}: "
+                        f"{why} - serve it on the fixed-slot pool with "
+                        "bucketed or chunked prefill"
+                    )
         self.model = model
         self.params = params
         # the served weight set's identity — rebind_params() updates it;
@@ -1526,6 +1588,7 @@ class ServingEngine:
                 setattr(self, name, _WithoutExpertRows(fn, self._expert_rows))
 
         self.moe_plan = self._plan_experts(n_slots)
+        self.ssm_plan = self._plan_state(n_slots)
 
         n = n_slots
         self._tok = np.zeros(n, np.int32)
@@ -1573,6 +1636,48 @@ class ServingEngine:
                 **{f"{name}_{k}": v for name, p in plan.items()
                    for k, v in p.items()},
             )
+        return plan
+
+    def _plan_state(self, n_slots: int) -> Optional[Dict[str, object]]:
+        """What a slot holds for a model with recurrent layers: layers of
+        each kind, the state's shape and type, the scan's chunk, bytes of
+        state and of K/V a slot (read off the pool's own leaves), logged
+        and put on the tracer once at build; None without such a layer.
+        ``state_bytes_per_slot`` goes to the metrics either way."""
+        from tpu_parallel.serving.cache_pool import STATE_LEAVES, _leaf_name
+
+        cfg = self.model.config
+        state = kv = 0
+        dtypes = set()
+        for path, leaf in jax.tree_util.tree_flatten_with_path(
+            self.pool.cache
+        )[0]:
+            name = _leaf_name(path)
+            if name.startswith(STATE_LEAVES):
+                state += leaf.nbytes
+                if name.startswith("ssm_state"):
+                    dtypes.add(str(leaf.dtype))
+            elif name.startswith("cached_"):
+                kv += leaf.nbytes
+        self._state_bytes_per_slot = state // n_slots
+        self.metrics.set_state_bytes_per_slot(self._state_bytes_per_slot)
+        if not cfg.recurrent_layers:
+            return None
+        spec = next(s.ssm for s in cfg.layer_specs if s.mixer == "ssm")
+        plan = {
+            "ssm_layers": cfg.recurrent_layers,
+            "attention_layers": cfg.n_layers - cfg.recurrent_layers,
+            "heads": spec.n_heads, "head_dim": spec.head_dim,
+            "d_state": spec.d_state, "groups": spec.n_groups,
+            "conv_width": spec.d_conv, "chunk": spec.chunk,
+            "state_dtype": "/".join(sorted(dtypes)),
+            "state_bytes_per_slot": self._state_bytes_per_slot,
+            "kv_bytes_per_slot": kv // n_slots,
+            "slots": n_slots,
+        }
+        logging.getLogger(__name__).info("ssm_plan %s", json.dumps(plan))
+        if self.tracer.enabled:
+            self.tracer.instant("ssm_plan", track="scheduler", **plan)
         return plan
 
     # -- submission --------------------------------------------------------
@@ -2101,6 +2206,7 @@ class ServingEngine:
             # the pool's COW/share tallies are cumulative; watermark them
             # so the fresh record's delta-synced counters start at zero
             self.metrics.seed_block_pool(self.pool)
+        self.metrics.set_state_bytes_per_slot(self._state_bytes_per_slot)
         return self.metrics
 
     def rebind_params(self, params, version: Optional[str] = None) -> None:
@@ -2477,7 +2583,7 @@ class ServingEngine:
             jnp.asarray([length - 1], jnp.int32), self._next_rng(),
         )
         self._prefill_shapes.add(("prefill", 1, length))
-        self.metrics.record_prefill_call()
+        self.metrics.record_prefill_call(real=length)
         self.pool.insert(fresh, slot)
         first = self._sample_first(logits, [out])
         if self.tracer.enabled:
@@ -2495,10 +2601,16 @@ class ServingEngine:
         rows pad right to the bucket width, the batch pads to
         ``prefill_batch`` dummy rows (scattered out of range, dropped),
         and every real row's fresh cache scatters into its slot in one
-        call."""
+        call.  A group larger than ``prefill_batch`` runs as several such
+        calls, so that the group's size never adds a compile shape."""
+        nb = self._prefill_batch
+        if len(outs) > nb:
+            return [
+                ev for i in range(0, len(outs), nb)
+                for ev in self._admit_bucketed(outs[i:i + nb])
+            ]
         t0 = self.tracer.now()
         width = self._bucket_for(max(len(o.request.prompt) for o in outs))
-        nb = max(self._prefill_batch, len(outs))
         tokens = np.zeros((nb, width), np.int32)
         lengths = np.ones(nb, np.int32)  # dummy rows: 1 real token
         slots = np.full(nb, self.pool.n_slots, np.int32)  # dummies drop
@@ -2515,7 +2627,8 @@ class ServingEngine:
             self._next_rng(),
         )
         self._prefill_shapes.add(("prefill", nb, width))
-        self.metrics.record_prefill_call()
+        real = sum(len(o.request.prompt) for o in outs)
+        self.metrics.record_prefill_call(real=real, padded=nb * width - real)
         self.pool.scatter(fresh, slots)
         firsts = self._sample_first(logits, outs)
         if self.tracer.enabled:
@@ -2573,7 +2686,8 @@ class ServingEngine:
             self._next_rng(),
         )
         self._prefill_shapes.add(("extend", nb, width))
-        self.metrics.record_prefill_call()
+        real = int(rems[: len(group)].sum())
+        self.metrics.record_prefill_call(real=real, padded=nb * width - real)
         self.pool.scatter(ext, slots)
         outs = [out for (out, _) in group]
         firsts = self._sample_first(logits, outs)
@@ -2698,7 +2812,8 @@ class ServingEngine:
             self._next_rng(), jnp.asarray(table),
         )
         self._prefill_shapes.add(("extend", nb, width))
-        self.metrics.record_prefill_call()
+        real = int(rems[: len(group)].sum())
+        self.metrics.record_prefill_call(real=real, padded=nb * width - real)
         outs = [out for (out, _) in group]
         firsts = self._sample_first(logits, outs)
         if self.tracer.enabled:
@@ -2838,7 +2953,9 @@ class ServingEngine:
             offset=st.offset, width=self._chunk_tokens,
         )
         st.offset += take
-        self.metrics.record_prefill_call(chunks=1)
+        self.metrics.record_prefill_call(
+            chunks=1, real=take, padded=self._chunk_tokens - take
+        )
         if self.tracer.enabled:
             self.tracer.record(
                 "prefill_chunk", f"slot {slot}", t0, self.tracer.now(),
@@ -3255,6 +3372,11 @@ class ServingEngine:
                 )
         if p.chunk_spans:
             self.metrics.record_chunks(len(p.chunk_spans))
+            # the chunk block is [n_slots, chunk]: every slot rides along
+            self.metrics.record_prefill_tokens(
+                p.chunk_tokens,
+                self.pool.n_slots * self._chunk_tokens - p.chunk_tokens,
+            )
 
     def _check_progress(self, p: _PendingTick, counts) -> None:
         """The no-progress desync guard: a slot that was decode-live at

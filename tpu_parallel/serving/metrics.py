@@ -98,6 +98,13 @@ class ServingMetrics:
         # cache owns the counts, metrics snapshots them)
         self._prefill_calls = r.counter("serving_prefill_calls_total")
         self._prefill_chunks = r.counter("serving_prefill_chunks_total")
+        # positions the prefill programs computed: prompt tokens, and what
+        # rode along (bucket padding, dummy rows, a chunk block's idle slots)
+        self._prefill_real = r.counter("serving_prefill_tokens_real_total")
+        self._prefill_padded = r.counter("serving_prefill_tokens_padded_total")
+        # bytes one slot holds of state that has one size whatever the
+        # context length (a recurrent layer's leaves); 0 for K/V alone
+        self._state_bytes = r.gauge("serving_state_bytes_per_slot")
         self._prefix_hits = r.gauge("serving_prefix_hits")
         self._prefix_misses = r.gauge("serving_prefix_misses")
         self._prefix_evictions = r.gauge("serving_prefix_evictions")
@@ -462,13 +469,27 @@ class ServingMetrics:
         typed ``integrity`` instead of streaming garbage tokens."""
         self._integrity_trips.inc()
 
-    def record_prefill_call(self, chunks: int = 0) -> None:
+    def record_prefill_call(
+        self, chunks: int = 0, real: int = 0, padded: int = 0
+    ) -> None:
         """One batched prefill device call (``chunks`` counts any chunk
-        continuations it was split into).  Every prefill call is also a
+        continuations it was split into; ``real`` prompt tokens and
+        ``padded`` positions beside them).  Every prefill call is also a
         host dispatch."""
         self._prefill_calls.inc()
         self._prefill_chunks.inc(chunks)
         self._host_dispatches.inc()
+        self.record_prefill_tokens(real, padded)
+
+    def record_prefill_tokens(self, real: int, padded: int) -> None:
+        """Positions a prefill program computed: ``real`` prompt tokens and
+        ``padded`` ones (bucket padding, dummy rows, idle slots of a chunk
+        block), which a recurrent layer pays for at full cost."""
+        self._prefill_real.inc(real)
+        self._prefill_padded.inc(padded)
+
+    def set_state_bytes_per_slot(self, nbytes: int) -> None:
+        self._state_bytes.set(nbytes)
 
     def record_dispatch(self, tokens: Optional[int] = None) -> None:
         """One decode-family host->device dispatch (per-step decode,
@@ -635,6 +656,9 @@ class ServingMetrics:
             "prefills": self.prefills,
             "prefill_calls": self.prefill_calls,
             "prefill_chunks": self.prefill_chunks,
+            "prefill_tokens_real": int(self._prefill_real.value),
+            "prefill_tokens_padded": int(self._prefill_padded.value),
+            "state_bytes_per_slot": int(self._state_bytes.value),
             "prefix_hits": self.prefix_hits,
             "prefix_misses": self.prefix_misses,
             "prefix_evictions": self.prefix_evictions,
