@@ -2104,6 +2104,9 @@ NEW_SUMMARY_KEYS = {
 PREFILL_SUMMARY_KEYS = {
     "prefill_tokens_real", "prefill_tokens_padded", "state_bytes_per_slot",
 }
+# the busy ticks whose sampler could draw, and the share that could not
+# (PR 33): a counter, and a share that is None until a tick was busy
+SAMPLER_SUMMARY_KEYS = {"sampler_draw_ticks", "sampler_skip_share"}
 
 
 class _SetClock:
@@ -2251,9 +2254,12 @@ def test_summary_keeps_every_old_key_and_has_the_phase_clock(rng):
     cfg, model, prompt, params = _build(rng, n_rows=1)
     eng = ServingEngine(model, params, n_slots=1)
     empty = eng.metrics.summary()
-    every = OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS | PREFILL_SUMMARY_KEYS
+    every = (OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS | PREFILL_SUMMARY_KEYS
+             | SAMPLER_SUMMARY_KEYS)
     assert set(empty) == every
     assert all(empty[k] == 0 for k in PREFILL_SUMMARY_KEYS)
+    assert empty["sampler_draw_ticks"] == 0
+    assert empty["sampler_skip_share"] is None
     assert empty["busy_ticks"] == 0
     assert all(empty[k] is None for k in NEW_SUMMARY_KEYS - {"busy_ticks"})
     eng.add_request(_req(prompt[0], 12))
@@ -2273,7 +2279,7 @@ BENCHMARK_READS = (
     "busy_tick_ms_mean", "tick_device_wait_ms_mean", "tick_deliver_ms_mean",
     "tick_between_ms_mean", "host_exposed_share", "slot_occupancy_mean",
     "tokens_out", "prefill_tick_ms_mean", "decode_only_tick_ms_mean",
-    "host_ms_per_tick_p50", "launch_ahead_share",
+    "host_ms_per_tick_p50", "launch_ahead_share", "sampler_skip_share",
 )
 
 

@@ -232,6 +232,7 @@ def sample_tokens(
     temperature: jax.Array,
     top_k: jax.Array,
     top_p: jax.Array,
+    rows: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Per-ROW sampling from [batch, vocab] logits with per-row knobs.
 
@@ -245,6 +246,16 @@ def sample_tokens(
     rejection rule needs the SAME target distribution this sampler draws
     from, or spec-vs-nonspec would silently drift.
 
+    The sampler computes only what a row whose token will be READ asks
+    for.  ``rows`` is the bool mask of those rows (None: every row): a
+    slot's knobs outlive its request, so the callers pass their live
+    rows.  Where none of them is sampled, which the device decides a call
+    (``lax.cond``, both branches in the one program), the token is the
+    argmax and neither the filters' two full-vocabulary sorts nor the
+    Gumbel draw run; ``filter_logits`` applies the same rule to each sort.
+    A branch that runs, runs on all rows: a row's token does not depend
+    on who shares its batch.
+
     Integrity sentinel: a row whose logits contain ANY non-finite value
     returns ``NON_FINITE_TOKEN`` instead of a sample — ``argmax`` over
     NaN logits would otherwise return an arbitrary-but-valid token id
@@ -253,11 +264,17 @@ def sample_tokens(
     that produced the logits; finite rows are bitwise unchanged."""
     lf = logits.astype(jnp.float32)
     greedy = jnp.argmax(lf, axis=-1).astype(jnp.int32)
-    # greedy rows take the argmax branch of the final where, so their
-    # filtered (guard-divided) logits are never read
-    x = filter_logits(lf, temperature, top_k, top_p)
-    sampled = jax.random.categorical(rng, x, axis=-1).astype(jnp.int32)
-    out = jnp.where(temperature > 0.0, sampled, greedy)
+    sampled_rows = temperature > 0.0
+    counts = sampled_rows if rows is None else sampled_rows & rows
+
+    def draw(_):
+        # greedy rows take the argmax branch of the where, so their
+        # filtered (guard-divided) logits are never read
+        x = filter_logits(lf, temperature, top_k, top_p, rows)
+        sampled = jax.random.categorical(rng, x, axis=-1).astype(jnp.int32)
+        return jnp.where(sampled_rows, sampled, greedy)
+
+    out = lax.cond(jnp.any(counts), draw, lambda _: greedy, None)
     finite = jnp.isfinite(lf).all(axis=-1)
     return jnp.where(finite, out, jnp.int32(NON_FINITE_TOKEN))
 
@@ -363,7 +380,10 @@ def _decode_core(
         write_index=widx, block_table=table, with_rows=True,
     )
     logits = _full_last_logits(model.config, params, hidden)
-    nxt = sample_tokens(logits, rng, temperature, top_k, top_p)
+    nxt = sample_tokens(
+        logits, rng, temperature, top_k, top_p,
+        rows=widx < model.config.seq_len,
+    )
     return nxt, cache, _calls(rows)
 
 
@@ -411,7 +431,7 @@ def _fused_decode_core(
             write_index=widx_eff, block_table=table, with_rows=True,
         )
         logits = _full_last_logits(cfg, params, hidden)
-        nxt = sample_tokens(logits, step_rng, temp, topk, topp)
+        nxt = sample_tokens(logits, step_rng, temp, topk, topp, rows=live)
         emitted = jnp.where(live, nxt, -1)
         budget = budget - live.astype(budget.dtype)
         # the NaN/Inf sentinel stops the slot exactly like EOS: steps
@@ -526,8 +546,8 @@ def _ragged_chunk_phase(
     logits = _full_last_logits(
         cfg, params, hidden, jnp.maximum(clen - 1, 0)
     )
-    tok0 = sample_tokens(logits, rng, temp, topk, topp)
     act = cfinal & (clen > 0)
+    tok0 = sample_tokens(logits, rng, temp, topk, topp, rows=act)
     nb = cbudget - 1
     done0 = (tok0 == eos) | (nb <= 0)
     new_pos = cstart + clen
@@ -1589,6 +1609,7 @@ class ServingEngine:
 
         self.moe_plan = self._plan_experts(n_slots)
         self.ssm_plan = self._plan_state(n_slots)
+        self.sampler_plan = self._plan_sampler(n_slots)
 
         n = n_slots
         self._tok = np.zeros(n, np.int32)
@@ -1636,6 +1657,22 @@ class ServingEngine:
                 **{f"{name}_{k}": v for name, p in plan.items()
                    for k, v in p.items()},
             )
+        return plan
+
+    def _plan_sampler(self, n_slots: int) -> Dict[str, object]:
+        """What :func:`sample_tokens` is compiled for: rows x vocabulary
+        a decode step (and a prefill call's first tokens), and that what
+        it runs of that block is chosen on the device, a step, from the
+        live rows' knobs; logged and put on the tracer once at build."""
+        plan = {
+            "rows": n_slots, "vocab": self.model.config.vocab_size,
+            "steps_per_tick": self._fused_steps,
+            "first_token_rows": self._prefill_batch,
+            "chosen": "on_device_per_step",
+        }
+        logging.getLogger(__name__).info("sampler_plan %s", json.dumps(plan))
+        if self.tracer.enabled:
+            self.tracer.instant("sampler_plan", track="scheduler", **plan)
         return plan
 
     def _plan_state(self, n_slots: int) -> Optional[Dict[str, object]]:
@@ -2130,6 +2167,13 @@ class ServingEngine:
             self.metrics.record_busy_tick(
                 period, p.phases, prefill=stall == STALL_PREFILL,
                 between=p.between, ahead=p.overlapped, hidden=hidden,
+                # an upper bound of what the device chose: a slot's
+                # owner may have finished before the tick's last step
+                sampled=any(
+                    out is not None
+                    and out.request.sampling.temperature > 0.0
+                    for out in p.owners
+                ),
             )
             self._busy_end = end
             if p.between is not None and self.tracer.enabled:

@@ -247,6 +247,9 @@ class ServingMetrics:
             "serving_host_exposed_seconds_total"
         )
         self._flushes: Dict[str, object] = {}
+        # the sampler (engine.sample_tokens) sorts and draws only where a
+        # live row is sampled: the busy ticks launched with such an owner
+        self._sampler_ticks = r.counter("serving_sampler_draw_ticks_total")
         # per-tick stall attribution, pre-registered so every cause shows
         # a (possibly zero) series in exports
         self._stall = {
@@ -417,6 +420,7 @@ class ServingMetrics:
         between: Optional[float] = None,
         ahead: bool = False,
         hidden=(),
+        sampled: bool = False,
     ) -> None:
         """One BUSY tick (it dispatched decode work): its period
         ``seconds`` — from its launch's entry to its collect's exit, or
@@ -425,7 +429,9 @@ class ServingMetrics:
         from the previous busy tick's collect to this launch (None where
         that tick was idle, so an idle sleep never enters).  ``hidden``
         names the phases that ran beside a tick in flight: they count in
-        their series, and not towards ``host_exposed_share``.  Idle ticks
+        their series, and not towards ``host_exposed_share``.  ``sampled``
+        says that some slot's owner at the launch had a temperature, so
+        the tick's sampler could have drawn (the device decides).  Idle ticks
         are left out (they would pull every mean toward the cost of doing
         nothing)."""
         self._busy_tick["1" if prefill else "0"].observe(seconds)
@@ -437,6 +443,8 @@ class ServingMetrics:
                 self._exposed_seconds.inc(dt)
         if ahead:
             self._overlapped.inc()
+        if sampled:
+            self._sampler_ticks.inc()
 
     def record_flush(self, cause: str) -> None:
         """A busy tick was collected before its successor was launched:
@@ -730,6 +738,14 @@ class ServingMetrics:
             "launch_ahead_flushes": {
                 cause: int(c.value) for cause, c in self._flushes.items()
             },
+            # the busy ticks whose sampler had no sampled owner to draw
+            # for (the argmax alone ran), over the busy ticks
+            "sampler_draw_ticks": int(self._sampler_ticks.value),
+            "sampler_skip_share": (
+                round(1.0 - int(self._sampler_ticks.value) / busy_ticks, 4)
+                if busy_ticks
+                else None
+            ),
             "host_ms_per_tick_p50": (
                 None
                 if self._host_ms_per_tick.percentile(50) is None

@@ -110,6 +110,7 @@ def filter_logits(
     temperature: jax.Array,
     top_k: jax.Array,
     top_p: jax.Array,
+    rows: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Per-row sampling filters over [rows, vocab] fp32 logits with traced
     per-row knobs — the shared filter core of ``engine.sample_tokens`` and
@@ -119,26 +120,44 @@ def filter_logits(
     cut; ``top_k <= 0`` / ``top_p`` outside (0, 1) disable that filter.
     Greedy rows (``temperature <= 0``) get a guarded divide — callers take
     the argmax branch and never read their filtered values.
-    """
+
+    Each filter costs a sort of the whole [rows, vocab] block, and runs
+    only where some row that COUNTS asks for it: a row counts when its
+    filtered values will be read, i.e. it is sampled and in ``rows`` (a
+    bool mask; None: every row).  The choice is made on the device, a
+    call (``lax.cond``); a filter that runs, runs on all rows, and one
+    that is skipped was the identity on every row that counts."""
     lf = logits.astype(jnp.float32)
     t = jnp.where(temperature > 0.0, temperature, 1.0)[:, None]
     x = lf / t
     vocab = x.shape[-1]
-    # per-row top-k with traced k: the kth-largest value via one sort
+    counts = temperature > 0.0
+    if rows is not None:
+        counts = counts & rows
     k = jnp.clip(top_k.astype(jnp.int32), 0, vocab)
-    asc = jnp.sort(x, axis=-1)
-    kth = jnp.take_along_axis(
-        asc, jnp.clip(vocab - k, 0, vocab - 1)[:, None], axis=-1
-    )
-    x = jnp.where((k > 0)[:, None] & (x < kth), -jnp.inf, x)
-    # per-row nucleus on the (already top-k-filtered) distribution
-    desc = jnp.sort(x, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = cum - probs < top_p[:, None]  # mass BEFORE the token < p
-    cutoff = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1, keepdims=True)
-    use_p = ((top_p > 0.0) & (top_p < 1.0))[:, None]
-    return jnp.where(use_p & (x < cutoff), -jnp.inf, x)
+    use_p = (top_p > 0.0) & (top_p < 1.0)
+
+    def top_k_cut(x):
+        # per-row top-k with traced k: the kth-largest value via one sort
+        asc = jnp.sort(x, axis=-1)
+        kth = jnp.take_along_axis(
+            asc, jnp.clip(vocab - k, 0, vocab - 1)[:, None], axis=-1
+        )
+        return jnp.where((k > 0)[:, None] & (x < kth), -jnp.inf, x)
+
+    def nucleus_cut(x):
+        # per-row nucleus on the (already top-k-filtered) distribution
+        desc = jnp.sort(x, axis=-1)[:, ::-1]
+        probs = jax.nn.softmax(desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = cum - probs < top_p[:, None]  # mass BEFORE the token < p
+        cutoff = jnp.min(
+            jnp.where(keep, desc, jnp.inf), axis=-1, keepdims=True
+        )
+        return jnp.where(use_p[:, None] & (x < cutoff), -jnp.inf, x)
+
+    x = lax.cond(jnp.any(counts & (k > 0)), top_k_cut, lambda x: x, x)
+    return lax.cond(jnp.any(counts & use_p), nucleus_cut, lambda x: x, x)
 
 
 def target_probs(
