@@ -201,6 +201,12 @@ _EXPECTED_FAILURES = {
         "this configuration is unreduced under its own published keys "
         "(test_bench_serve_hybrid.py checks it against the catalog); the "
         "edit belongs to a benchmark PR",
+    "test_bench_manifest.py::test_config_entry[sdar_30b_a3b_depth6]":
+        "asserts GPT-2's key names and d_model == n_heads * head_dim (here "
+        "2048 beside 32 heads of 128); this configuration is reduced in "
+        "depth alone under its own published keys "
+        "(test_bench_serve_blockgen.py checks it against the catalog); the "
+        "edit belongs to a benchmark PR",
 }
 
 

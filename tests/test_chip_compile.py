@@ -332,6 +332,93 @@ def test_hybrid_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
     assert low < held < high, memory
 
 
+@pytest.mark.parametrize("program", ["block_tick", "prefill_3072"])
+def test_blockgen_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
+    """The serving cell of the block-diffusion expert decoder at its
+    published widths, one pipeline stage whole, built from the benchmark's
+    own configuration and cell files: the tick of 8 block forwards over 64
+    slots (256 rows a forward, 16 an expert, the streamed kernel) and the
+    largest whole-prompt prefill (the flash kernels under the block rule at
+    heads of 128, GQA 8:1; no head, no logits) fit one v5e."""
+    import json
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from drivers.serve_blockgen import model_config
+
+    from tpu_parallel.models import GPTLM
+    from tpu_parallel.serving import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    read = lambda *rel: json.load(open(os.path.join(REPO, "benchmarks", *rel)))
+    cell = read("workloads", "serve-sdar_30b_a3b_depth6-blockgen.json")
+    cfg = model_config(read("configs", "sdar_30b_a3b_depth6.json"), cell["engine"])
+    assert (cfg.d_model, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads,
+            cfg.head_dim, cfg.vocab_size) == (2048, 6, 32, 4, 128, 151936)
+    assert (cfg.block_len, cfg.mask_token_id, cfg.qk_norm,
+            cfg.rope_pairing) == (4, 151669, True, "half")
+    model, n = GPTLM(cfg), cell["engine"]["n_slots"]
+    on_chip = lambda x, dtype=None: jax.ShapeDtypeStruct(
+        x.shape, dtype or x.dtype, sharding=v5e_chip
+    )
+    params = jax.tree.map(
+        lambda x: on_chip(x, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 16), jnp.int32),
+            train=False,
+        ))["params"],
+    )
+    assert sum(x.size for x in jax.tree.leaves(params)) == 4_361_055_744
+    shaped = lambda dtype, *shape: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip
+    )
+    ints = lambda *shape: shaped(jnp.int32, *shape)
+    prefill, tick = engine._block_engine_fns(model, 8)
+    if program == "prefill_3072":
+        width = max(cell["engine"]["prefill_buckets"])
+        lowered = prefill.lower(params, ints(1, width), ints(1, width))
+        # one flash kernel a layer; no head: no logits come out
+        assert lowered.as_text().count("tpu_custom_call") >= cfg.n_layers
+        assert all(151936 not in x.shape for x in jax.tree.leaves(lowered.out_info))
+        low, high = 6.5e9, 12e9  # the unread head is no argument
+    else:
+        pool = jax.tree.map(on_chip, jax.eval_shape(
+            lambda p: engine._block_prefill_core(
+                model, p, jnp.zeros((n, 16), jnp.int32),
+                jnp.zeros((n, 16), jnp.int32),
+            )[0], params,
+        ))
+        kv = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool)
+                 if x.dtype == jnp.bfloat16)
+        assert kv == n * 4096 * 12288  # 3.22 GB
+        flags, size = shaped(jnp.bool_, n), cfg.block_len
+        state = (ints(n, size), shaped(jnp.bool_, n, size), ints(n, size),
+                 ints(n), ints(n), ints(n), flags, ints(n))
+        knobs = (ints(n), shaped(jnp.float32, n), ints(n),
+                 shaped(jnp.float32, n))
+        lowered = tick.lower(
+            params, state, knobs, pool, shaped(jnp.uint32, 2)
+        )
+        low, high = 11.9e9, 15.75e9  # 8.72 GB of weights + 3.22 GB of pool
+    compiled = lowered.compile()
+    if program == "block_tick":
+        text = compiled.as_text()
+        kernels = re.findall(r"%ragged-dot-streamed[.\d]* = .*", text)
+        # two calls a layer in each of the layer's two buffers (2048 rows
+        # reach the size at which a quarter-size buffer is compiled beside
+        # the worst case; with every expert held it never runs)
+        assert len(kernels) == 4 * cfg.n_layers
+        assert "ragged-dot-none" not in text
+        # the choice among a block's positions is counted, not sorted; what
+        # sorts are left are the experts' (by expert id), none over the
+        # vocabulary
+        assert not re.search(r"sort\([^)]*151936", text)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    print(program, memory.argument_size_in_bytes / 1e9,
+          memory.temp_size_in_bytes / 1e9)
+    assert low < held < high, memory
+
+
 def test_compile_cache_honours_the_environment(monkeypatch, tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set, JAX already uses it and the code
     sets no directory of its own."""

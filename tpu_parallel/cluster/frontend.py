@@ -115,6 +115,7 @@ from tpu_parallel.serving.request import (
     FAILED,
     FINISHED,
     REJECT_CAPACITY,
+    REJECT_UNSUPPORTED,
     REJECT_CLIENT_LIMIT,
     REJECT_DRAINING,
     REJECT_SHED,
@@ -444,6 +445,9 @@ class Frontend:
                     f"({self.seq_len})"
                 ),
             )
+        unsupported = self.replicas[0].engine.unsupported(request)
+        if unsupported is not None:
+            return reject(REJECT_UNSUPPORTED, detail=unsupported)
         cfg = self.config
         if cfg.max_per_client is not None and request.client_id is not None:
             open_for_client = sum(
@@ -1253,6 +1257,8 @@ class Frontend:
             eos_token_id=req.eos_token_id,
             request_id=f"{req.request_id}@{len(st.out.replicas)}",
             draft_tokens=req.draft_tokens,
+            denoising_steps=req.denoising_steps,
+            confidence_threshold=req.confidence_threshold,
             on_token=self._make_on_token(st),
         )
 

@@ -446,6 +446,10 @@ class ServingDaemon:
                 max_new_tokens=remainder,
                 sampling=SamplingParams(**sub.get("sampling") or {}),
                 eos_token_id=eos,
+                denoising_steps=sub.get("denoising_steps"),
+                confidence_threshold=float(
+                    sub.get("confidence_threshold") or 0.0
+                ),
                 request_id=entry.request_id,
                 client_id=sub.get("client_id"),
                 priority=int(sub.get("priority") or 0),
@@ -665,6 +669,8 @@ class ServingDaemon:
                 "deadline": request.deadline,
                 "max_new_tokens": request.max_new_tokens,
                 "eos_token_id": request.eos_token_id,
+                "denoising_steps": request.denoising_steps,
+                "confidence_threshold": request.confidence_threshold,
                 "sampling": {
                     "temperature": sampling.temperature,
                     "top_k": sampling.top_k,

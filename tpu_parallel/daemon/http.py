@@ -10,7 +10,9 @@ Endpoints (docs/13_daemon.md is the reference):
 
 - ``POST /v1/submit`` — JSON body ``{"prompt": [ids], "max_new_tokens",
   "dedupe_token", "priority", "deadline", "client_id", "temperature",
-  "top_k", "top_p", "eos_token_id"}``.  200 with the request record on
+  "top_k", "top_p", "eos_token_id", "denoising_steps",
+  "confidence_threshold"}`` (the last two: a block-diffusion model's steps a
+  block and dynamic-fill threshold).  200 with the request record on
   accept (the submit is journal-durable before the response); typed
   rejections map to 503 (``draining`` / ``degraded`` /
   ``journal_error`` — route elsewhere) / 429 (everything else) with
@@ -64,6 +66,7 @@ from tpu_parallel.serving.kv_wire import (
 )
 from tpu_parallel.serving.request import (
     REJECT_DRAINING,
+    REJECT_UNSUPPORTED,
     REJECTED,
     Request,
     SamplingParams,
@@ -111,11 +114,14 @@ def build_request(body: dict) -> Request:
         top_p=float(body.get("top_p", 0.0)),
     )
     deadline = body.get("deadline")
+    steps = body.get("denoising_steps")
     return Request(
         prompt=prompt,
         max_new_tokens=int(body.get("max_new_tokens", 32)),
         sampling=sampling,
         eos_token_id=body.get("eos_token_id"),
+        denoising_steps=None if steps is None else int(steps),
+        confidence_threshold=float(body.get("confidence_threshold", 0.0)),
         client_id=body.get("client_id"),
         priority=int(body.get("priority", 0)),
         deadline=None if deadline is None else float(deadline),
@@ -201,9 +207,11 @@ class _Handler(BaseHTTPRequestHandler):
             record = dict(record)
             record["ts"] = d.clock()
             if record["status"] == REJECTED:
+                reason = record["finish_reason"]
                 code = (
-                    503
-                    if record["finish_reason"] in _UNAVAILABLE_REASONS
+                    503 if reason in _UNAVAILABLE_REASONS
+                    # not load: what this model's decoding rule cannot do
+                    else 400 if reason == REJECT_UNSUPPORTED
                     else 429
                 )
                 return self._json(code, record)

@@ -268,7 +268,9 @@ def verify_step(model: GPTLM, params, cache, tokens: jax.Array,
     (column == stored position, :meth:`CachePool.assert_slot_aligned`)
     every stale column holds a position strictly greater than any query
     position that can occur before the column is overwritten — the mask
-    ``kp <= qp`` keeps them invisible.  Pad offsets (positions -1) write
+    ``kp <= qp`` keeps them invisible.  (Under the block rule a query sees
+    past itself, to its block's end, and the argument is made again in
+    :func:`block_step`; the serving engine refuses drafts on a block model.)  Pad offsets (positions -1) write
     -1 into the position table, invalidating their columns outright.
     """
     return _cached_apply(
@@ -278,6 +280,45 @@ def verify_step(model: GPTLM, params, cache, tokens: jax.Array,
         False,
         positions=positions,
         write_index=write_index,
+        block_table=block_table,
+    )
+
+
+def block_step(model: GPTLM, params, cache, tokens: jax.Array,
+               start: jax.Array, live: jax.Array,
+               block_table: Optional[jax.Array] = None,
+               with_rows: bool = False):
+    """One forward of a block model's CURRENT blocks: ``tokens`` [b, L] are
+    row ``i``'s block as it stands (filled ids and mask ids), at positions
+    ``start[i] + [0..L)``; its K/V are written at columns ``start[i] + [0..L)``
+    EVERY call, so a block's keys are rewritten until the block is final.
+    Rows that are not ``live`` ride as pads (positions -1, writes parked at
+    ``seq_len``).  Returns ``(hidden [b, L, d_model], cache)``.
+
+    Why no stale column can be seen.  Under the block rule
+    (``TransformerConfig.block_len``) a query of the block that starts at
+    ``s`` sees the stored positions ``<= s + L - 1`` and no other.  The layer
+    writes the call's K/V before it reads, so the block's own columns hold
+    THIS call's rows.  Every column before ``s`` was written by this same
+    occupant: by the prefill of its prompt's whole blocks, or by the last
+    forward of an earlier block, which fed the block clean (the engine's
+    commit pass) - a block is always written whole before it is read.  Every
+    column from ``s + L`` on holds -1 (a prefill's padding) or, in the aligned
+    layout (column == stored position), a position ``>= s + L`` left by a
+    longer earlier occupant of the slot or by this occupant's own prefill
+    padding: past the block's end, invisible.  So a slot needs no clearing
+    between occupants, and the half-filled keys of a block in progress are
+    overwritten before any later block can see them."""
+    width = tokens.shape[1]
+    offs = jnp.arange(width, dtype=jnp.int32)[None, :]
+    positions = jnp.where(live[:, None], start[:, None] + offs, -1)
+    return _cached_apply(
+        model,
+        {"params": params, "cache": cache},
+        tokens,
+        with_rows,
+        positions=positions,
+        write_index=jnp.where(live, start, model.config.seq_len),
         block_table=block_table,
     )
 

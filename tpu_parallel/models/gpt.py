@@ -876,6 +876,61 @@ def hybrid_ssm_decoder(
     )
 
 
+def block_diffusion_decoder(
+    *, experts: ExpertsSpec, block_len: int, mask_token_id: int, **overrides
+) -> GPTConfig:
+    """A pre-norm decoder generated by masked diffusion inside blocks of
+    ``block_len`` positions and autoregressively across them (Qwen3-MoE's
+    block under the block rule): RMSNorm, no bias, grouped-query attention
+    with a per-head RMSNorm on queries and keys before rotate-half rotary
+    positions, every MLP the dropless softmax-routed ``experts`` layer, the
+    head untied.  A position sees its own block whole and every earlier
+    block (``TransformerConfig.block_len``); a masked position holds
+    ``mask_token_id`` and predicts its own token.  Sizes come as
+    ``overrides``."""
+    return GPTConfig(
+        **{
+            **dict(
+                positional="rope",
+                rope_pairing="half",
+                qk_norm=True,
+                norm="rmsnorm",
+                norm_eps=1e-6,
+                dense_bias=False,
+                scan_layers=False,
+                block_len=block_len,
+                mask_token_id=mask_token_id,
+                layer_pattern=(
+                    LayerSpec("full", 0, "model", "experts", experts),
+                ),
+            ),
+            **overrides,
+        }
+    )
+
+
+def tiny_block_diffusion(**overrides) -> GPTConfig:
+    """``block_diffusion_decoder`` at CPU-test size: 3 layers, 4 heads of 16
+    on 2 K/V heads, 8 softmax-routed experts top-2, blocks of 4, the last id
+    the mask."""
+    experts = overrides.pop(
+        "experts", ExpertsSpec(n_experts=8, top_k=2, width=48)
+    )
+    return block_diffusion_decoder(
+        experts=experts,
+        block_len=overrides.pop("block_len", 4),
+        mask_token_id=overrides.pop("mask_token_id", 255),
+        **{
+            **dict(
+                vocab_size=256, d_model=64, n_layers=3, n_heads=4,
+                n_kv_heads=2, head_dim=16, seq_len=64, rope_theta=1e6,
+                dtype=jnp.float32, remat=False,
+            ),
+            **overrides,
+        },
+    )
+
+
 def tiny_hybrid_ssm(**overrides) -> GPTConfig:
     """``hybrid_ssm_decoder`` at CPU-test size: two periods of ``ssm, ssm,
     attention, ssm``, 4 heads of 16, a state of 16, chunks of 8."""

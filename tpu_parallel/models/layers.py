@@ -278,6 +278,22 @@ class TransformerConfig:
     attn_scale: Optional[float] = None
     embed_scale: float = 1.0
     residual_scale: float = 1.0
+    # per-head RMSNorm on queries and keys before the rotary embedding, each
+    # with its own learned scale over head_dim (Qwen3)
+    qk_norm: bool = False
+    # which two numbers of a head one rotation turns: "interleaved" pairs
+    # (2i, 2i + 1), "half" pairs (i, i + head_dim / 2) (rotate-half, Qwen/Llama
+    # checkpoints)
+    rope_pairing: str = "interleaved"
+    # block-causal visibility (generation by diffusion over blocks): 0 =
+    # causal; L > 0 = the sequence is cut into blocks of L from position 0 and
+    # a query at p sees every key j <= (p // L) * L + L - 1, its own block
+    # whole and all earlier blocks.  Not a function of p - j alone, so it is a
+    # third rule beside causal and window (which it refuses to combine with)
+    block_len: int = 0
+    # the id a position of a block holds until the denoising fills it (the
+    # serving engine's block step feeds it; no layer reads this field)
+    mask_token_id: Optional[int] = None
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -290,6 +306,20 @@ class TransformerConfig:
                     f"n_layers={self.n_layers} is not a whole number of "
                     f"periods of {len(self.layer_pattern or ())} layers"
                 )
+        if self.rope_pairing not in ("interleaved", "half"):
+            raise ValueError(
+                f"rope_pairing={self.rope_pairing!r} (interleaved | half)"
+            )
+        if self.block_len < 0 or (self.block_len and (
+            self.bidirectional or self.recurrent_layers
+            or any(s.attn == "window" for s in self.layer_specs)
+        )):
+            raise ValueError(
+                f"block_len={self.block_len}: the block rule is causal across "
+                "blocks and full inside one; it does not combine with a "
+                "window, a bidirectional stack or a recurrent layer (whose "
+                "state cannot take a block back)"
+            )
 
     @property
     def layer_specs(self) -> Tuple[LayerSpec, ...]:
@@ -345,11 +375,15 @@ def make_norm(config: TransformerConfig, name: str):
 
 
 def apply_rope(
-    x: jax.Array, positions: jax.Array, theta: float = 10000.0
+    x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+    pairing: str = "interleaved",
 ) -> jax.Array:
     """Rotary position embedding over the last (head_dim) axis.
 
     ``x``: [batch, seq, heads, head_dim]; ``positions``: [batch, seq].
+    ``pairing``: pair ``i`` turns by ``pos * theta ** (-2i / head_dim)`` and
+    is ``(x[2i], x[2i + 1])`` ("interleaved") or ``(x[i], x[i + head_dim /
+    2])`` ("half", the rotate-half form).
     """
     head_dim = x.shape[-1]
     freq_exponents = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
@@ -357,6 +391,11 @@ def apply_rope(
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, S, hd/2]
     angles = angles[:, :, None, :]  # broadcast over heads
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if pairing == "half":
+        x1, x2 = x[..., : head_dim // 2], x[..., head_dim // 2 :]
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).astype(x.dtype)
     x1, x2 = x[..., ::2], x[..., 1::2]
     rotated = jnp.stack(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
@@ -381,6 +420,7 @@ def causal_attention(
     causal: bool = True,
     bias: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    block_len: int = 0,
 ) -> jax.Array:
     """Reference attention: fp32 softmax, bf16 matmuls on the MXU.
 
@@ -388,7 +428,8 @@ def causal_attention(
     Pallas flash kernel (``ops.flash_attention``) replaces this on TPU for
     long sequences.  ``causal=False`` is the bidirectional (encoder) form:
     every position attends every (same-segment) position — with ``window``,
-    those within the symmetric band |q - k| < window.
+    those within the symmetric band |q - k| < window.  ``block_len`` L > 0
+    is the block rule: a query sees keys up to the end of its own block of L.
     """
     head_dim = q.shape[-1]
     scale = _score_scale(scale, head_dim, q.dtype)
@@ -400,6 +441,8 @@ def causal_attention(
     q_pos = lax.broadcasted_iota(jnp.int32, scores.shape, 2)
     k_pos = lax.broadcasted_iota(jnp.int32, scores.shape, 3)
     mask = q_pos >= k_pos if causal else None
+    if block_len:
+        mask = k_pos <= q_pos // block_len * block_len + (block_len - 1)
     if window:
         # causal: query t attends keys in (t - window, t]; bidirectional
         # (encoder local attention): the symmetric band |q - k| < window
@@ -423,6 +466,7 @@ def decode_attention(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     scale: Optional[float] = None,
+    block_len: int = 0,
 ) -> jax.Array:
     """Attention of new queries against a full KV cache, GQA-native.
 
@@ -449,6 +493,10 @@ def decode_attention(
     cache-sized copy is ever materialized — the transient bf16 K+V copies
     per layer per step were the whole int8 decode cliff (int8 fell
     behind bf16 at batch 32 while it copied them).
+
+    ``block_len`` L > 0 is the block rule: a query at ``p`` sees the stored
+    positions up to the end of its own block, ``(p // L) * L + L - 1``,
+    instead of up to ``p`` (a pad query at -1 still sees nothing).
     """
     b, nq, h, head_dim = q.shape
     h_kv = k_all.shape[2]
@@ -471,6 +519,8 @@ def decode_attention(
         k_pos = k_positions
     kp = k_pos[:, None, None, None, :]
     qp = positions[:, None, None, :, None]
+    if block_len:  # floor division: -1 // L * L + L - 1 == -1
+        qp = qp // block_len * block_len + (block_len - 1)
     mask = jnp.logical_and(kp >= 0, kp <= qp)
     if window:
         mask = jnp.logical_and(mask, qp - kp < window)
@@ -688,6 +738,8 @@ class Attention(nn.Module):
         """A model of several layer kinds names each kind's attention for
         the device trace (``attn.window`` / ``attn.full``); a uniform
         model's ops keep the names they had."""
+        if self.config.block_len:
+            return jax.named_scope("attn.block")
         if self.spec is None:
             return contextlib.nullcontext()
         return jax.named_scope(f"attn.{self.spec.attn}")
@@ -773,6 +825,13 @@ class Attention(nn.Module):
                 *x.shape[:-1], local_kv, 2 * cfg.head_dim
             )
             k, v = jnp.split(kv, 2, axis=-1)
+        if cfg.qk_norm:
+            q, k = (
+                nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)(
+                    t
+                ).astype(cfg.dtype)
+                for name, t in (("q_norm", q), ("k_norm", k))
+            )
         if decode:
             if seq_parallel_active(cfg):
                 raise NotImplementedError(
@@ -782,6 +841,8 @@ class Attention(nn.Module):
                 raise NotImplementedError(
                     "incremental decoding with packed sequences (segment_ids)"
                 )
+            if cfg.block_len and cfg.beam_width > 1:
+                raise NotImplementedError("the block rule under beam search")
             b = x.shape[0]
             if cfg.kv_cache_dtype not in ("bf16", "int8"):
                 raise ValueError(
@@ -893,8 +954,8 @@ class Attention(nn.Module):
                     # seq-sharded: offset local positions to global ones
                     local = local + lax.axis_index(cfg.seq_axis) * x.shape[1]
                 positions = jnp.broadcast_to(local, x.shape[:2])
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
+            q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_pairing)
+            k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_pairing)
         if decode:
             # cache_valid gates persistence (pipeline decode: only the rank
             # whose tick this is may commit writes — other ranks run the
@@ -1056,7 +1117,7 @@ class Attention(nn.Module):
                         q, k_all, v_all, positions, window=self.window,
                         bias=attn_bias, k_positions=k_pos,
                         k_scale=k_scale, v_scale=v_scale,
-                        scale=cfg.attn_scale,
+                        scale=cfg.attn_scale, block_len=cfg.block_len,
                     )
         else:
             out = self._attend(q, k, v, segment_ids, attn_bias)
@@ -1085,6 +1146,14 @@ class Attention(nn.Module):
         cfg = self.config
         if impl is not None and impl != cfg.attn_impl:
             cfg = dataclasses.replace(cfg, attn_impl=impl)
+        if cfg.block_len and (
+            self.attn_fn is not None or cfg.attn_impl not in ("xla", "flash")
+        ):
+            raise NotImplementedError(
+                "block_len > 0 (the block rule) runs on the xla and flash "
+                f"attention paths, not attn_impl={cfg.attn_impl!r} or an "
+                "injected attn_fn"
+            )
         if attn_bias is not None and cfg.attn_impl != "xla":
             # the Pallas/ring/ulysses kernels take no additive score bias;
             # T5-style models must run the xla attention path
@@ -1129,6 +1198,7 @@ class Attention(nn.Module):
                     block_q=cfg.flash_block_q,
                     block_k=cfg.flash_block_k,
                     window=self.window,
+                    block_len=cfg.block_len,
                 )
             elif cfg.attn_impl == "ring":
                 from tpu_parallel.ops.ring_attention import (
@@ -1202,7 +1272,7 @@ class Attention(nn.Module):
                 attn_fn = functools.partial(
                     causal_attention, window=self.window,
                     causal=not cfg.bidirectional, bias=attn_bias,
-                    scale=cfg.attn_scale,
+                    scale=cfg.attn_scale, block_len=cfg.block_len,
                 )
         with self._scope():
             return attn_fn(q, k, v, segment_ids=segment_ids)

@@ -128,12 +128,21 @@ def _window_first_k_block(qi, block_q: int, block_k: int, window: int,
     return jnp.maximum(0, q_offset + qi * block_q - window + 1) // block_k
 
 
-def _band_bounds(causal: bool, window: int):
+def _band_bounds(causal: int, window: int):
     """The band, once: key ``k`` is visible to query ``q`` iff
     ``lo <= q_pos - k_pos <= hi`` (``None`` = unbounded).  Causal bounds it
     below at 0; a window bounds it above at ``window - 1`` and, when not
-    causal (encoder local attention), symmetrically below."""
-    lo = 0 if causal else (-(window - 1) if window else None)
+    causal (encoder local attention), symmetrically below.
+
+    ``causal`` is a length: 1 (``True``) is the causal rule, ``L > 1`` the
+    BLOCK rule (the sequence cut into blocks of ``L`` from position 0, a
+    query sees keys up to the end of its own block: causal is the block rule
+    at ``L = 1``).  The block rule is not a function of the difference alone:
+    its lower bound is ``q_pos % L - (L - 1)``, and ``lo`` here is the
+    LOOSEST one, ``-(L - 1)``, which the first query of a block has;
+    :func:`_band_mask` adds each query's own ``q_pos % L`` and
+    :func:`_tile_kind` knows that tiles are whole blocks."""
+    lo = 1 - int(causal) if causal else (-(window - 1) if window else None)
     hi = window - 1 if window else None
     return lo, hi
 
@@ -152,19 +161,21 @@ def _band_mask(qi, ki, shape, block_q: int, block_k: int, causal: bool,
     the same geometry translated by that constant.
 
     ``q_pos - k_pos`` is a loop-invariant iota difference plus one scalar
-    per tile, so a masked tile costs a compare and a select per bound.
+    per tile, so a masked tile costs a compare and a select per bound.  Under
+    the block rule (``causal = L > 1``; tiles and ``q_offset`` are multiples
+    of ``L``, so a query's place in its block is its row's) the lower bound
+    moves with the query: ``q_pos - k_pos - q_pos % L >= -(L - 1)``.
     """
     lo, hi = _band_bounds(causal, window)
     if lo is None and hi is None:
         return None
     q_axis, k_axis = (1, 0) if transposed else (0, 1)
-    rel = lax.broadcasted_iota(jnp.int32, shape, q_axis) - lax.broadcasted_iota(
-        jnp.int32, shape, k_axis
-    )
+    q_row = lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    rel = q_row - lax.broadcasted_iota(jnp.int32, shape, k_axis)
     shift = q_offset + qi * block_q - ki * block_k
     mask = None
     if lo is not None:
-        mask = rel >= lo - shift
+        mask = (rel - q_row % int(causal) if causal > 1 else rel) >= lo - shift
     if hi is not None:
         near = rel <= hi - shift
         mask = near if mask is None else jnp.logical_and(mask, near)
@@ -182,12 +193,18 @@ def _tile_kind(qi: int, ki: int, block_q: int, block_k: int, causal: bool,
     to every query (no mask needed), ``MASKED`` if an edge of the band
     crosses it.  Interval arithmetic on ``q_pos - k_pos`` against
     :func:`_band_bounds` — the same inequality :func:`_band_mask` evaluates
-    per element."""
+    per element.  Under the block rule a tile is whole blocks, so its last
+    query ends a block and sees no key past itself (SKIP is at 0 as for
+    causal), while its first query starts one and sees ``L - 1`` keys ahead
+    (INTERIOR from ``lo``): only the tiles the diagonal crosses differ."""
     lo, hi = _band_bounds(causal, window)
     q_lo = q_offset + qi * block_q
     d_min = q_lo - ((ki + 1) * block_k - 1)
     d_max = q_lo + block_q - 1 - ki * block_k
-    if (lo is not None and d_max < lo) or (hi is not None and d_min > hi):
+    none_below = 0 if causal else lo
+    if (lo is not None and d_max < none_below) or (
+        hi is not None and d_min > hi
+    ):
         return SKIP
     if (lo is None or d_min >= lo) and (hi is None or d_max <= hi):
         return INTERIOR
@@ -322,7 +339,15 @@ def flash_plan(
     del dtype
     seq_kv = seq if seq_kv is None else seq_kv
     fwd_pref, bwd_pref = _preferred_tiles(head_dim)
-    plan = {}
+    block = int(causal) if causal > 1 else 0  # the block rule's length
+    if block and (window or q_offset % block):
+        raise ValueError(
+            f"the block rule (blocks of {block}) takes no window and an "
+            f"offset of whole blocks (window={window}, q_offset={q_offset})"
+        )
+    plan = {"rule": "block" if block else "causal" if causal else "full"}
+    if block:
+        plan["block_len"] = block
     for name, pref, rows, bodies in (
         ("fwd", fwd_pref, max(seq, seq_kv), 1),
         ("bwd", bwd_pref, max(seq_kv, group * seq), group),
@@ -336,6 +361,8 @@ def flash_plan(
         bk = _derive_tile(seq_kv, pref) if block_k is None else min(block_k, seq_kv)
         if bq is None or bk is None or seq % bq or seq_kv % bk:
             return None
+        if block and (bq % block or bk % block):
+            return None  # a tile has to be whole blocks
         computed, masked = _count_tiles(
             seq // bq, seq_kv // bk, bq, bk, causal, window, q_offset
         )
@@ -1339,8 +1366,14 @@ def flash_attention(
     window: int = 0,
     interpret: Optional[bool] = None,
     stream: Optional[bool] = None,
+    block_len: int = 0,
 ) -> jax.Array:
     """Causal flash attention on [batch, seq, heads, head_dim] inputs.
+
+    ``block_len`` L > 0 is the block rule in causal's place: query ``t`` sees
+    keys up to the end of its own block of L, ``(t // L) * L + L - 1``; tiles
+    are whole blocks, so only the tiles the diagonal crosses are masked
+    otherwise than under causal.  No window beside it.
 
     ``k``/``v`` may carry fewer heads than ``q`` (grouped-query attention:
     ``n_heads % n_kv_heads == 0``); the kernels route each query head to its
@@ -1379,8 +1412,9 @@ def flash_attention(
         raise ValueError(f"q heads {h} not a multiple of k/v heads {h_kv}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    rule = block_len if block_len > 1 else True  # L = 1 is causal
     plan = flash_plan(
-        s, d, h // h_kv, q.dtype, window=window, block_q=block_q,
+        s, d, h // h_kv, q.dtype, causal=rule, window=window, block_q=block_q,
         block_k=block_k, stream=stream,
     )
     if plan is None or any(
@@ -1399,17 +1433,26 @@ def flash_attention(
         if h_kv != h:  # the dense path has no head routing — expand
             k = jnp.repeat(k, h // h_kv, axis=2)
             v = jnp.repeat(v, h // h_kv, axis=2)
-        return causal_attention(q, k, v, segment_ids=segment_ids, window=window)
+        return causal_attention(
+            q, k, v, segment_ids=segment_ids, window=window, block_len=block_len
+        )
     seg = None
     if segment_ids is not None:
         # one int32 lane per batch row ([B, S, 1]); the kernels' BlockSpec
         # index maps route all H heads of row b to the same block
         seg = segment_ids.astype(jnp.int32)[:, :, None]
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    fwd_tile = (plan["fwd"]["block_q"], plan["fwd"]["block_k"])
+    bwd_tile = (plan["bwd"]["block_q"], plan["bwd"]["block_k"])
+    if rule is not True:
+        # the kernels that carry their rule as an argument, forward and
+        # backward: the same ones, the block length in ``causal``'s place
+        out, _ = _chunk_attention_bhsd(
+            qt, kt, vt, seg, seg, rule, fwd_tile, bwd_tile, interpret, stream,
+            0, 0,
+        )
+        return out.transpose(0, 2, 1, 3)
     out = _flash_attention_bhsd(
-        qt, kt, vt, seg,
-        (plan["fwd"]["block_q"], plan["fwd"]["block_k"]),
-        (plan["bwd"]["block_q"], plan["bwd"]["block_k"]),
-        interpret, window, stream,
+        qt, kt, vt, seg, fwd_tile, bwd_tile, interpret, window, stream,
     )
     return out.transpose(0, 2, 1, 3)

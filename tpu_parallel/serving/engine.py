@@ -131,6 +131,7 @@ import numpy as np
 from jax import lax
 
 from tpu_parallel.models.generate import (
+    block_step,
     decode_step,
     padded_prefill_inputs,
     prefill_extend_step,
@@ -173,6 +174,7 @@ from tpu_parallel.serving.request import (
     FAILED,
     FINISHED,
     REJECT_CAPACITY,
+    REJECT_UNSUPPORTED,
     REJECTED,
     RUNNING,
     Request,
@@ -758,6 +760,240 @@ def _unified_spec_core(
     return act_emit, blocks, counts, drafted, accepted, state, cache
 
 
+def sample_block(logits, rng, temperature, rows=None):
+    """A block model's pick and its CONFIDENCE, a position: ``(x0, conf)``
+    ``[n, L]`` from logits ``[n, L, vocab]`` with per-ROW temperature ``[n]``.
+
+    ``x0`` is the argmax (``temperature == 0``) or a draw from
+    ``softmax(logits / temperature)``; ``conf`` is that distribution's
+    probability of ``x0``.  Greedy needs the row's maximum, where it lies
+    and the sum of ``exp(l - max)``, reductions over the vocabulary that one
+    pass gives: ``conf = 1 / sum(exp(l - max))``.  No sort: the filters a
+    nucleus or a top-k would need are refused on a block model.  As in
+    :func:`sample_tokens`, the draw runs only where a row that will be READ
+    (``rows``) is sampled, chosen on the device a call (``lax.cond``).  A
+    position whose logits hold a NaN or an infinity has a non-finite
+    ``conf``: that is the integrity screen, at no pass of its own."""
+    lf = logits.astype(jnp.float32)
+    top = jnp.max(lf, axis=-1)
+    greedy = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+    conf = 1.0 / jnp.sum(jnp.exp(lf - top[..., None]), axis=-1)
+    sampled_rows = temperature > 0.0
+    counts = sampled_rows if rows is None else sampled_rows & rows
+
+    def draw(_):
+        t = jnp.where(sampled_rows, temperature, 1.0)[:, None, None]
+        scaled = lf / t
+        x = jax.random.categorical(rng, scaled, axis=-1).astype(jnp.int32)
+        peak = jnp.max(scaled, axis=-1)
+        at = jnp.take_along_axis(scaled, x[..., None], axis=-1)[..., 0]
+        p = jnp.exp(at - peak) / jnp.sum(
+            jnp.exp(scaled - peak[..., None]), axis=-1
+        )
+        keep = sampled_rows[:, None]
+        return jnp.where(keep, x, greedy), jnp.where(keep, p, conf)
+
+    return lax.cond(jnp.any(counts), draw, lambda _: (greedy, conf), None)
+
+
+def unmask_choice(conf, masked, nstep, dsteps, threshold):
+    """Which masked positions of each row's block a denoising step fills:
+    ``[n, L]`` bool from the confidences ``conf`` ``[n, L]``, the mask
+    ``masked``, the steps the block has had (``nstep`` ``[n]``), the
+    request's steps a block ``dsteps`` and its ``threshold``.
+
+    Static rule: the ``L // T`` (one more in the first ``L % T`` steps) most
+    confident masked positions, the lower index first among equals, at most
+    what is masked.  Dynamic rule (``threshold > 0``): every masked position
+    over the threshold where those are at least that many.  A rank is a
+    count over the block's ``L`` positions (``[n, L, L]`` compares), never a
+    sort, and never anything over the vocabulary."""
+    width = conf.shape[1]
+    conf = jnp.where(masked, conf, -jnp.inf)
+    count = width // dsteps + (nstep < width % dsteps).astype(jnp.int32)
+    count = jnp.minimum(count, masked.sum(axis=1, dtype=jnp.int32))
+    idx = jnp.arange(width)
+    ahead = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (idx[None, None, :] < idx[None, :, None])
+    )  # [n, i, j]: j goes before i
+    rank = ahead.sum(axis=2, dtype=jnp.int32)
+    static = masked & (rank < count[:, None])
+    over = masked & (conf > threshold[:, None])
+    dynamic = (threshold > 0.0) & (
+        over.sum(axis=1, dtype=jnp.int32) >= count
+    )
+    return jnp.where(dynamic[:, None], over, static)
+
+
+def _block_prefill_core(model, params, prompt, positions):
+    """A block model's prefill: the prompt's WHOLE blocks under the block
+    rule (through the flash kernels where ``prefill_flash``), their K/V
+    final.  No logits are read and no token is sampled: the first generated
+    block shares the prompt's tail and is denoised like every other."""
+    _, cache, rows = prefill_step(
+        model, params, prompt, positions, with_rows=True
+    )
+    return cache, _calls(rows)
+
+
+def _block_decode_core(
+    model, params, steps, blk, msk, fstep, start, ptail, nstep, live,
+    budget, eos, temp, dsteps, thr, cache, rng, table=None,
+):
+    """``steps`` forwards of every slot's CURRENT block in ONE jitted
+    ``lax.scan``: generation by diffusion over blocks (a model with
+    ``block_len`` L > 0).  The carry holds, a slot, its block (``blk`` [n, L]
+    ids, ``msk`` which are still masked, ``fstep`` the step at which each
+    was filled, -1 for a prompt's tail), the block's ``start``, the prompt
+    tail's length ``ptail`` (the first block only), the denoising steps the
+    block has had (``nstep``), and ``live`` / ``budget`` as the fused tick
+    has them; ``dsteps`` and ``thr`` are the request's steps a block and
+    confidence threshold.
+
+    One step feeds each live slot's block as it stands
+    (:func:`~tpu_parallel.models.generate.block_step`: L rows at
+    ``[start, start + L)``, their K/V rewritten at ``start``).  A slot with
+    masked positions DENOISES: :func:`sample_block` picks and weighs each
+    position, :func:`unmask_choice` fills the most confident; a filled
+    position never changes.  The step that fills a block's last position
+    EMITS the block (its generated positions in order, cut at the budget and
+    after an EOS) and ends the slot there, or leaves it one COMMIT forward:
+    a slot with nothing masked feeds its clean block once more, which makes
+    the block's K/V final, and moves to the next block, all mask ids.  So a
+    step emits 0 or up to L tokens a slot.
+
+    Returns ``(blocks [steps, n, L] (-1: nothing), counts [steps, n],
+    fsteps [steps, n, L], kinds [steps, n] (0 parked, 1 denoise, 2 commit),
+    fills [steps, n], state, cache, rows)``."""
+    cfg = model.config
+    width, mask_id = cfg.block_len, cfg.mask_token_id
+    offs = jnp.arange(width, dtype=jnp.int32)[None, :]
+
+    def body(carry, step_rng):
+        blk, msk, fstep, start, ptail, nstep, live, budget, cache = carry
+        denoise = live & msk.any(axis=1)
+        commit = live & ~denoise
+        hidden, cache, rows = block_step(
+            model, params, cache, blk, start, live, block_table=table,
+            with_rows=True,
+        )
+        logits = _full_logits(cfg, params, hidden)
+        with jax.named_scope("diffusion.unmask"):
+            x0, conf = sample_block(logits, step_rng, temp, rows=denoise)
+            broken = denoise & ~jnp.where(
+                msk, jnp.isfinite(conf), True
+            ).all(axis=1)
+            fill = unmask_choice(conf, msk, nstep, dsteps, thr)
+            fill = fill & denoise[:, None]
+        blk = jnp.where(fill, x0, blk)
+        msk = msk & ~fill
+        fstep = jnp.where(fill, nstep[:, None], fstep)
+        nstep = nstep + denoise.astype(nstep.dtype)
+        complete = denoise & ~msk.any(axis=1) & ~broken
+        # the block's generated positions, in order, up to the budget and
+        # to its first EOS (delivered with it)
+        room = jnp.minimum(width - ptail, budget)
+        inside = (offs >= ptail[:, None]) & (offs < (ptail + room)[:, None])
+        is_eos = inside & (blk == eos[:, None])
+        ended = is_eos.any(axis=1)
+        cut = jnp.where(
+            ended, jnp.argmax(is_eos, axis=1).astype(jnp.int32) + 1,
+            ptail + room,
+        )
+        emitted = jnp.where(
+            complete[:, None] & inside & (offs < cut[:, None]), blk, -1
+        )
+        # non-finite logits: the sentinel alone, and the slot stops
+        emitted = jnp.where(
+            broken[:, None], jnp.where(offs == 0, NON_FINITE_TOKEN, -1),
+            emitted,
+        )
+        count = jnp.where(complete, cut - ptail, 0) + broken.astype(jnp.int32)
+        budget = budget - jnp.where(complete, cut - ptail, 0)
+        done = (complete & (ended | (budget <= 0))) | broken
+        out = (
+            emitted, count, jnp.where(emitted >= 0, fstep, -1),
+            denoise.astype(jnp.int32) + 2 * commit.astype(jnp.int32),
+            fill.sum(axis=1, dtype=jnp.int32), rows,
+        )
+        live = live & ~done
+        # the commit forward made the block's K/V final: the next block
+        move = commit[:, None]
+        blk = jnp.where(move, mask_id, blk)
+        msk = jnp.where(move, True, msk)
+        fstep = jnp.where(move, -1, fstep)
+        start = start + jnp.where(commit, width, 0)
+        ptail = jnp.where(commit, 0, ptail)
+        nstep = jnp.where(commit, 0, nstep)
+        return (blk, msk, fstep, start, ptail, nstep, live, budget, cache), out
+
+    carry, (blocks, counts, fsteps, kinds, fills, rows) = lax.scan(
+        body, (blk, msk, fstep, start, ptail, nstep, live, budget, cache),
+        jax.random.split(rng, steps),
+    )
+    return blocks, counts, fsteps, kinds, fills, carry[:-1], carry[-1], rows
+
+
+@functools.lru_cache(maxsize=16)
+def _block_engine_fns(model, steps: int):
+    """A block model's two jitted programs ``(prefill, tick)``, cached per
+    (model, steps): the prefill of a prompt's whole blocks (no logits) and
+    the tick of ``steps`` block forwards (:func:`_block_decode_core`).  As
+    in :func:`_fused_engine_fn` the slot state (argnum 1) and the cache pool
+    (argnum 3) of the tick are donated and the block table is the last
+    operand (None: this family runs on the fixed-slot pool)."""
+    prefill = jax.jit(
+        lambda params, prompt, positions: _block_prefill_core(
+            model, params, prompt, positions
+        )
+    )
+    tick = jax.jit(
+        lambda params, state, knobs, cache, rng, table=None: (
+            _block_decode_core(
+                model, params, steps, *state, *knobs, cache, rng, table
+            )
+        ),
+        donate_argnums=(1, 3),
+    )
+    return prefill, tick
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _seat_block_rows(
+    state, knobs, slots, blk, ptail, start, budget, eos, temp, dsteps, thr
+):
+    """Write admitted requests' rows into a block model's device-resident
+    slot state (:func:`_seat_rows`' twin): ``slots`` [nb] (a dummy row
+    carries ``n_slots`` and is dropped), ``blk`` [nb, L] the first block
+    (the prompt's tail, then mask ids), ``ptail`` the tail's length,
+    ``start`` the block's first position.  Every seated row goes live."""
+
+    def put(rows, values):
+        return rows.at[slots].set(values.astype(rows.dtype), mode="drop")
+
+    width = blk.shape[1]
+    masked = jnp.arange(width)[None, :] >= ptail[:, None]
+    one = jnp.ones_like(ptail)
+    values = (
+        blk, masked, jnp.full_like(blk, -1), start, ptail, 0 * one,
+        one.astype(bool), budget,
+    )
+    state = tuple(put(rows, v) for rows, v in zip(state, values))
+    knobs = tuple(
+        put(rows, v) for rows, v in zip(knobs, (eos, temp, dsteps, thr))
+    )
+    return state, knobs
+
+
+@jax.jit
+def _block_state_alive(state, alive):
+    """A block model's slot state with the slots the host removed (a
+    cancel, an integrity trip) dead: nothing is in flight when this runs,
+    so the device's state is whole and only lacks the removal."""
+    return state[:6] + (state[6] & alive,) + state[7:]
+
+
 @functools.lru_cache(maxsize=16)
 def _engine_fns(model):
     """Jitted per-step engine programs ``(prefill, extend, decode, verify,
@@ -1264,6 +1500,56 @@ class ServingEngine:
                         f"{why} - serve it on the fixed-slot pool with "
                         "bucketed or chunked prefill"
                     )
+        self._block_len = int(cfg.block_len)
+        if self._block_len:
+            # generation by diffusion over blocks: a step feeds a slot's
+            # whole block and rewrites its keys until the block is final
+            if cfg.mask_token_id is None or cfg.seq_len % self._block_len:
+                raise ValueError(
+                    f"a block model (block_len={self._block_len}) states its "
+                    f"mask_token_id (got {cfg.mask_token_id}) and a seq_len "
+                    f"of whole blocks (got {cfg.seq_len})"
+                )
+            refused = {
+                "draft_tokens > 0": (
+                    draft_tokens > 0,
+                    "the verify tick keeps a rejected draft's column "
+                    "invisible by kp <= qp, and a block's query sees past "
+                    "itself to its block's end; a draft has no confidence "
+                    "to be chosen by either",
+                ),
+                "prefill_chunk_tokens": (
+                    prefill_chunk_tokens is not None,
+                    "the unified tick's chunk phase samples a first token "
+                    "from the chunk's last position, and a chunk that ends "
+                    "inside a block would leave half a block's keys final",
+                ),
+                "prefix_cache_size > 0": (
+                    prefix_cache_size > 0,
+                    "a stored prefix is cut at a bucket, not at a block "
+                    "boundary that the hit's first block could start from",
+                ),
+                "kv_block_tokens": (
+                    kv_block_tokens not in (None, 0),
+                    "the block step rewrites L columns a forward through "
+                    "write_index; the paged pool's copy-on-write window is "
+                    "sized for one column a step",
+                ),
+                "kv_radix_cache / kv_host_blocks / kv_disk_dir": (
+                    bool(kv_radix_cache) or kv_host_blocks > 0
+                    or kv_disk_dir is not None,
+                    "the radix tree and the host and disk tiers live on the "
+                    "paged pool (and with them K/V export and import)",
+                ),
+            }
+            for option, (asked, why) in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"the serving engine does not run a block-diffusion "
+                        f"model (block_len={self._block_len}) under "
+                        f"{option}: {why} - serve it on the fixed-slot pool "
+                        "with whole-prompt bucketed prefill"
+                    )
         self.model = model
         self.params = params
         # the served weight set's identity — rebind_params() updates it;
@@ -1324,6 +1610,15 @@ class ServingEngine:
             self._buckets = bs
         else:
             self._buckets = None
+        if self._block_len:
+            if self._buckets is None or any(
+                b % self._block_len for b in self._buckets
+            ):
+                raise ValueError(
+                    f"a block model prefills its prompt's whole blocks: "
+                    f"prefill_buckets={self._buckets} have to be multiples "
+                    f"of block_len={self._block_len}"
+                )
         # block-paged KV cache: kv_block_tokens > 0 (or "auto") swaps the
         # fixed n_slots x seq_len pool for a flat pool of kv_pool_blocks
         # blocks addressed through per-slot block tables — slot count
@@ -1526,6 +1821,8 @@ class ServingEngine:
          self._verify_fn, self._sample_fn) = _engine_fns(model)
         self._fused_fn = None
         self._unified_fn = None
+        self._block_fn = None
+        self._block_prefill_fn = None
         self._spec_fused_fn = None
         self._spec_unified_fn = None
         if self._spec_fused:
@@ -1543,6 +1840,12 @@ class ServingEngine:
                 self._spec_unified_fn = _fused_spec_engine_fn(
                     model, fused, chunkw, *spec_sig
                 )
+        elif self._block_len:
+            # ONE tick program whatever decode_steps_per_tick is (1 runs the
+            # same scan at one step), and a prefill without a head
+            self._block_prefill_fn, self._block_fn = _block_engine_fns(
+                model, fused
+            )
         elif fused > 1:
             self._fused_fn = _fused_engine_fn(model, fused)
             if chunkw > 0:
@@ -1560,7 +1863,9 @@ class ServingEngine:
         self._dev_state = None
         self._dev_knobs = None
         self._state_dirty = True
-        self._chains = fused > 1 and not self._spec_fused and not self._paged
+        self._chains = (
+            fused > 1 or bool(self._block_len)
+        ) and not self._spec_fused and not self._paged
         # device copy of the paged block-table mirror, re-uploaded only
         # when the allocator bumped table_version
         self._dev_table = None
@@ -1602,7 +1907,7 @@ class ServingEngine:
         # keep the device arrays until the tick's collect reads them
         self._expert_rows: list = []
         for name in ("_prefill_fn", "_extend_fn", "_decode_fn", "_fused_fn",
-                     "_unified_fn"):
+                     "_unified_fn", "_block_fn", "_block_prefill_fn"):
             fn = getattr(self, name)
             if fn is not None:
                 setattr(self, name, _WithoutExpertRows(fn, self._expert_rows))
@@ -1610,6 +1915,7 @@ class ServingEngine:
         self.moe_plan = self._plan_experts(n_slots)
         self.ssm_plan = self._plan_state(n_slots)
         self.sampler_plan = self._plan_sampler(n_slots)
+        self.block_plan = self._plan_blocks(n_slots)
 
         n = n_slots
         self._tok = np.zeros(n, np.int32)
@@ -1641,7 +1947,7 @@ class ServingEngine:
         )
         if spec is None:
             return None
-        shapes = {"decode": n_slots}
+        shapes = {"decode": n_slots * max(1, self._block_len)}
         if self._chunk_tokens and self._unified:
             shapes["chunk"] = n_slots * self._chunk_tokens
         for b in self._buckets or ():
@@ -1673,6 +1979,26 @@ class ServingEngine:
         logging.getLogger(__name__).info("sampler_plan %s", json.dumps(plan))
         if self.tracer.enabled:
             self.tracer.instant("sampler_plan", track="scheduler", **plan)
+        return plan
+
+    def _plan_blocks(self, n_slots: int) -> Optional[Dict[str, object]]:
+        """What a tick does for a block-diffusion model: the block's length
+        and mask id, the rows a forward feeds (slots x L), the forwards a
+        tick, and where the fill is chosen; logged and put on the tracer
+        once at build; None for any other model."""
+        if not self._block_len:
+            return None
+        cfg = self.model.config
+        plan = {
+            "block_len": self._block_len, "mask_token_id": cfg.mask_token_id,
+            "rows_per_step": n_slots * self._block_len,
+            "steps_per_tick": self._fused_steps,
+            "commit": "one_forward_a_block",
+            "chosen": "top_k_over_block_on_device",
+        }
+        logging.getLogger(__name__).info("block_plan %s", json.dumps(plan))
+        if self.tracer.enabled:
+            self.tracer.instant("block_plan", track="scheduler", **plan)
         return plan
 
     def _plan_state(self, n_slots: int) -> Optional[Dict[str, object]]:
@@ -1742,6 +2068,13 @@ class ServingEngine:
                 arrival_time if arrival_time is not None else self.clock()
             ),
         )
+        unsupported = self.unsupported(request)
+        if unsupported is not None:
+            out.status = REJECTED
+            out.finish_reason = REJECT_UNSUPPORTED
+            out.detail = unsupported
+            self.metrics.record_rejected()
+            return out
         total = len(request.prompt) + request.max_new_tokens
         if total > self.model.config.seq_len:
             out.status = REJECTED
@@ -1788,6 +2121,37 @@ class ServingEngine:
                 "queue", track="scheduler", async_id=rid, request_id=rid
             )
         return out
+
+    def unsupported(self, request: Request) -> Optional[str]:
+        """What ``request`` asks for that the served model's decoding rule
+        does not do (the typed ``unsupported`` rejection), or None."""
+        size = self._block_len
+        sp = request.sampling
+        if not size:
+            if request.denoising_steps is not None or (
+                request.confidence_threshold > 0.0
+            ):
+                return (
+                    "denoising_steps / confidence_threshold on a model that "
+                    "does not generate by diffusion over blocks"
+                )
+            return None
+        if request.denoising_steps is not None and not (
+            1 <= request.denoising_steps <= size
+        ):
+            return (
+                f"denoising_steps={request.denoising_steps} outside 1.."
+                f"block_len={size}"
+            )
+        if sp.top_k > 0 or 0.0 < sp.top_p < 1.0:
+            return (
+                "top_k / top_p on a block-diffusion model: a position's pick "
+                "and its confidence come from one pass over the logits, no "
+                "sort over the vocabulary"
+            )
+        if request.draft_tokens:
+            return "draft_tokens on a block-diffusion model"
+        return None
 
     # -- lifecycle control (cancellation / drain) --------------------------
 
@@ -1937,7 +2301,8 @@ class ServingEngine:
             return self._flush_cause
         if self._chunking or (self.scheduler.depth and self.pool.n_free):
             return None
-        reach = self._fused_steps + 1  # the tick in flight, a first token
+        # the tick in flight and a first token; a block step may emit L
+        reach = self._fused_steps * max(1, self._block_len) + 1
         for slot in np.nonzero(self._active)[0]:
             out = self._slot_out[slot]
             if out.request.max_new_tokens - len(out.tokens) > reach:
@@ -2062,7 +2427,9 @@ class ServingEngine:
         if not self._active.any() and not unified_chunks:
             return
         p.entering = tuple(int(s) for s in np.nonzero(self._active)[0])
-        if self._spec_fused:
+        if self._block_len:
+            self._launch_block(p)
+        elif self._spec_fused:
             self._launch_spec_fused(p, unified_chunks)
         elif self._spec_width > 0:
             self._launch_spec_step(p)
@@ -2090,7 +2457,9 @@ class ServingEngine:
             with self._phase(p, "deliver"):
                 for tokens, rows in p.firsts:
                     events.extend(self._deliver_firsts(tokens, rows))
-                if p.kind == "fused":
+                if p.kind == "block":
+                    events.extend(self._collect_block(p))
+                elif p.kind == "fused":
                     events.extend(self._collect_fused(p))
                 elif p.kind == "unified":
                     events.extend(self._collect_unified(p))
@@ -2559,6 +2928,10 @@ class ServingEngine:
         request regardless, so splitting by length would only serialize
         admissions across ticks."""
         length = len(out.request.prompt)
+        if self._block_len:
+            # the prefill covers the prompt's whole blocks (none: no call)
+            whole = length // self._block_len * self._block_len
+            return ("bucket", self._bucket_for(whole) if whole else 0)
         if self._chunk_tokens is not None and length > self._chunk_tokens:
             if self._unified and self._fused_steps > 1:
                 # unified tick: chunk starts BATCH — every one admitted
@@ -2586,6 +2959,9 @@ class ServingEngine:
                     span.finish()
         if self._paged:
             return self._admit_batch_paged(admitted)
+        if self._block_len:
+            self._admit_blocks(admitted)
+            return events
         for out in admitted:
             length = len(out.request.prompt)
             if self._chunk_tokens is not None and length > self._chunk_tokens:
@@ -3244,6 +3620,9 @@ class ServingEngine:
         slot's adaptive draft length and its token HISTORY row (prompt +
         delivered tokens — the in-scan drafter's context)."""
         n = self.pool.n_slots
+        if self._block_len:
+            self._upload_block_state()
+            return
         budget = np.zeros(n, np.int32)
         eos = np.full(n, -1, np.int32)
         for slot in np.nonzero(self._active)[0]:
@@ -3795,6 +4174,179 @@ class ServingEngine:
             self.metrics.record_unified_tick(p.chunk_tokens + delivered)
         return events
 
+    # -- generation by diffusion over blocks --------------------------------
+
+    def _upload_block_state(self) -> None:
+        """A block model's device-resident slot state: made once, all
+        slots dead; after a host-side removal (cancel, an integrity trip,
+        nothing in flight) the device's own state with the removed slots
+        dead.  The block in progress exists only on the device, so there
+        is no rebuilding it from host mirrors, and no need."""
+        n, width = self.pool.n_slots, self._block_len
+        if self._dev_state is None:
+            ints = np.zeros(n, np.int32)
+            self._dev_state, self._dev_knobs = _own_arrays((
+                (
+                    np.zeros((n, width), np.int32),
+                    np.zeros((n, width), bool),
+                    np.full((n, width), -1, np.int32),
+                    ints, ints, ints, np.zeros(n, bool), ints,
+                ),
+                (
+                    np.full(n, -1, np.int32), np.zeros(n, np.float32),
+                    np.full(n, width, np.int32), np.zeros(n, np.float32),
+                ),
+            ))
+        else:
+            self._dev_state = _block_state_alive(
+                self._dev_state, jnp.asarray(self._active)
+            )
+        self._state_dirty, self._flush_cause = False, None
+
+    def _admit_blocks(self, outs: List[RequestOutput]) -> None:
+        """Seat one tick's admissions of a block model (one bucket's group,
+        as the scheduler grouped them): ONE padded prefill call a
+        ``prefill_batch`` rows over each prompt's WHOLE blocks (none for a
+        prompt shorter than a block: its slot needs no clearing, see
+        :func:`~tpu_parallel.models.generate.block_step`), no logits and no
+        first token, then the rows into the device's slot state with the
+        prompt's tail seated in the first block."""
+        size, nb = self._block_len, self._prefill_batch
+        for i in range(0, len(outs), nb):
+            group = outs[i:i + nb]
+            t0 = self.tracer.now()
+            whole = [len(o.request.prompt) // size * size for o in group]
+            slots = np.full(nb, self.pool.n_slots, np.int32)  # dummies drop
+            for j in range(len(group)):
+                slot = self.pool.acquire()
+                assert slot is not None, "scheduler admitted beyond free slots"
+                slots[j] = slot
+            width = self._bucket_for(max(whole)) if max(whole) else 0
+            if width:
+                tokens = np.zeros((nb, width), np.int32)
+                lengths = np.full(nb, size, np.int32)  # dummy rows: a block
+                for j, out in enumerate(group):
+                    tokens[j, : whole[j]] = out.request.prompt[: whole[j]]
+                    lengths[j] = whole[j]
+                positions, _ = padded_prefill_inputs(lengths, width)
+                fresh = self._block_prefill_fn(
+                    self.params, jnp.asarray(tokens), positions
+                )[0]
+                self._prefill_shapes.add(("prefill", nb, width))
+                self.metrics.record_prefill_call(
+                    real=sum(whole), padded=nb * width - sum(whole)
+                )
+                self.pool.scatter(fresh, slots)
+            rows = [
+                (j, int(slots[j]), out) for j, out in enumerate(group)
+            ]
+            self._seat_blocks(rows, whole)
+            if self.tracer.enabled:
+                t1 = self.tracer.now()
+                for j, slot, out in rows:
+                    self.tracer.record(
+                        "prefill", f"slot {slot}", t0, t1,
+                        request_id=out.request.request_id, slot=slot,
+                        bucket=width, cache_hit=False,
+                    )
+
+    def _seat_blocks(self, rows, whole) -> None:
+        """One :func:`_seat_block_rows` call for ``(i, slot, out)`` rows
+        whose prefill covered ``whole[i]`` positions."""
+        size, nb, n = self._block_len, self._prefill_batch, self.pool.n_slots
+        where = np.full(nb, n, np.int32)
+        blk = np.full((nb, size), self.model.config.mask_token_id, np.int32)
+        ints = np.zeros((5, nb), np.int32)  # tail, start, budget, EOS, steps
+        ints[4] = size
+        reals = np.zeros((2, nb), np.float32)  # temperature, threshold
+        for i, slot, out in rows:
+            req = out.request
+            tail = list(req.prompt[whole[i]:])
+            where[i] = slot
+            blk[i, : len(tail)] = tail
+            eos = -1 if req.eos_token_id is None else req.eos_token_id
+            steps = size if req.denoising_steps is None else req.denoising_steps
+            ints[:, i] = len(tail), whole[i], req.max_new_tokens, eos, steps
+            reals[:, i] = req.sampling.temperature, req.confidence_threshold
+            self._occupy(slot, out)
+        self._dev_state, self._dev_knobs = _seat_block_rows(
+            self._dev_state, self._dev_knobs, jnp.asarray(where),
+            jnp.asarray(blk), jnp.asarray(ints[0]), jnp.asarray(ints[1]),
+            jnp.asarray(ints[2]), jnp.asarray(ints[3]), jnp.asarray(reals[0]),
+            jnp.asarray(ints[4]), jnp.asarray(reals[1]),
+        )
+
+    def _launch_block(self, p: _PendingTick) -> None:
+        """Dispatch one tick of a block model: ``_fused_steps`` forwards of
+        every slot's current block in one jitted scan
+        (:func:`_block_decode_core`), cache and slot state donated."""
+        if self._state_dirty or self._dev_state is None:
+            self._upload_slot_state()
+        out = self._block_fn(
+            self.params, self._dev_state, self._dev_knobs, self.pool.cache,
+            self._next_rng(), self._device_table(),
+        )
+        *payload, self._dev_state, self.pool.cache = out
+        p.kind = "block"
+        p.payload = tuple(payload)
+
+    def _collect_block(self, p: _PendingTick) -> List[StreamEvent]:
+        """Collect one tick of a block model: a block's tokens go to the
+        stream in order at the collect of the tick that completed it, one
+        event a token, and arrive TOGETHER (one timestamp a block).  A slot
+        may have filled positions all tick and completed no block: no
+        progress guard reads token counts here."""
+        blocks, counts, fsteps, kinds, fills = p.payload
+        events: List[StreamEvent] = []
+        trace = self.tracer.enabled
+        mine = np.array([
+            self._active[s] and self._slot_out[s] is p.owners[s]
+            for s in range(self.pool.n_slots)
+        ])
+        # the window's work by what the device did, for the slots whose
+        # owner launched this tick (a later tenant has nothing in it)
+        self.metrics.record_block_steps(
+            forwards=int((kinds[:, mine] > 0).sum()),
+            commits=int((kinds[:, mine] == 2).sum()),
+            filled=int(fills[:, mine].sum()),
+            completed=int((counts[:, mine] > 0).sum()),
+        )
+        if trace:
+            self.tracer.record(
+                "decode_tick", "scheduler", p.t0, p.t1,
+                steps=self._fused_steps, tokens=int(counts.sum()),
+            )
+        for slot in np.nonzero(mine)[0]:
+            slot = int(slot)
+            for t in np.nonzero(counts[:, slot])[0]:
+                if not self._active[slot]:
+                    break  # finished, or a stream callback cancelled it
+                keep = blocks[t, slot] != -1
+                tokens = blocks[t, slot][keep]
+                steps = fsteps[t, slot][keep]
+                out = self._slot_out[slot]
+                if trace:
+                    self.tracer.record(
+                        "decode", f"slot {slot}", p.t0, p.t1,
+                        request_id=out.request.request_id, slot=slot,
+                        token_index=len(out.tokens), tokens=len(tokens),
+                    )
+                now = self.clock()
+                out.token_groups.append(len(out.tokens))
+                if out.first_token_time is None:
+                    out.first_token_time = now
+                self._pos[slot] += len(tokens)
+                self._widx[slot] = self._pos[slot]
+                for token, step in zip(tokens, steps):
+                    if token != NON_FINITE_TOKEN:
+                        out.fill_steps.append(int(step))
+                    event = self._deliver(slot, int(token), now=now)
+                    events.append(event)
+                    if not self._active[slot]:
+                        break
+        self.metrics.record_dispatch(tokens=len(events))
+        return events
+
     def _fail_integrity(self, slot: int) -> StreamEvent:
         """The device sampled the NaN/Inf sentinel for this slot: fail
         the request TYPED (``FAIL_INTEGRITY``) and release the slot —
@@ -3831,16 +4383,19 @@ class ServingEngine:
             req.on_token(event)
         return event
 
-    def _deliver(self, slot: int, token: int) -> StreamEvent:
+    def _deliver(
+        self, slot: int, token: int, now: Optional[float] = None
+    ) -> StreamEvent:
         """Record one generated token for the request in ``slot``; retire
         the slot when the token finishes the request (EOS or length).
         The device-side sentinel (``NON_FINITE_TOKEN``) never counts as
-        a token: it reroutes to the typed integrity failure."""
+        a token: it reroutes to the typed integrity failure.  ``now``: the
+        arrival time of a group of tokens delivered together (a block)."""
         if token == NON_FINITE_TOKEN:
             return self._fail_integrity(slot)
         out = self._slot_out[slot]
         req = out.request
-        now = self.clock()
+        now = self.clock() if now is None else now
         out.tokens.append(token)
         out.token_times.append(now)
         finish_reason = None

@@ -268,6 +268,13 @@ class ServingMetrics:
         # dropless expert layers (models/moe.py RoutedExperts): what the
         # tick and prefill programs return beside their tokens.  A call is
         # one layer's pass over one program step's rows.
+        # generation by diffusion over blocks (engine._block_decode_core):
+        # live slot-steps, those that were a commit pass, positions filled,
+        # blocks completed
+        self._block_forwards = r.counter("serving_block_forwards_total")
+        self._block_commits = r.counter("serving_block_commit_forwards_total")
+        self._block_filled = r.counter("serving_block_tokens_filled_total")
+        self._blocks_completed = r.counter("serving_blocks_completed_total")
         self._moe_calls = r.counter("serving_moe_calls_total")
         self._moe_assignments = r.counter("serving_moe_assignments_total")
         self._moe_held = r.counter("serving_moe_assignments_held_total")
@@ -521,6 +528,18 @@ class ServingMetrics:
         consumed plus tokens generated by its ONE dispatch (chunk
         counting goes through :meth:`record_chunks`)."""
         self._unified_tick_tokens.observe(tokens)
+
+    def record_block_steps(
+        self, forwards: int, commits: int, filled: int, completed: int
+    ) -> None:
+        """One tick of a block-diffusion model: the live slot-steps it ran
+        (``forwards``, a slot's block fed once), how many of them filled
+        nothing because they were a block's commit pass, the positions
+        filled, and the blocks completed."""
+        self._block_forwards.inc(forwards)
+        self._block_commits.inc(commits)
+        self._block_filled.inc(filled)
+        self._blocks_completed.inc(completed)
 
     def record_expert_rows(self, rows: np.ndarray) -> None:
         """``rows`` ``[calls, layers, held + 1]`` from one program: the rows
@@ -800,6 +819,21 @@ class ServingMetrics:
                 None if qd_max is None else int(qd_max)
             ),
         }
+        # block rows only appear once a block-diffusion model has ticked
+        forwards = int(self._block_forwards.value)
+        if forwards:
+            commits = int(self._block_commits.value)
+            filled = int(self._block_filled.value)
+            out.update(
+                {
+                    "block_forwards": forwards,
+                    "block_commit_forwards": commits,
+                    "block_tokens_filled": filled,
+                    "blocks_completed": int(self._blocks_completed.value),
+                    "tokens_per_forward": round(filled / forwards, 4),
+                    "commit_forward_share": round(commits / forwards, 4),
+                }
+            )
         # expert rows only appear once a dropless layer has reported
         calls = int(self._moe_calls.value)
         if calls:

@@ -66,6 +66,10 @@ REJECT_SHED = "shed"
 # bad hardware).  The request FAILS typed instead of streaming garbage
 # tokens, and the replica escalates to DEGRADED health.
 FAIL_INTEGRITY = "integrity"
+# the request asks for something the served model's decoding rule does not
+# do (a block-diffusion model: drafts, top-k / top-p, denoising steps
+# outside 1..block_len; any other model: denoising knobs at all)
+REJECT_UNSUPPORTED = "unsupported"
 
 
 @dataclasses.dataclass
@@ -90,6 +94,15 @@ class Request:
     # exact either way — the knob trades wasted verify positions against
     # multi-token ticks per request.
     draft_tokens: Optional[int] = None
+    # generation by diffusion over blocks (a model with ``block_len`` L > 0;
+    # docs/10_serving_engine.md): ``denoising_steps`` T is the forwards a
+    # block of L masked positions is filled in, ``L // T`` positions a
+    # forward, the most confident first (None = L: one position a forward);
+    # with ``confidence_threshold`` > 0 a forward fills EVERY masked
+    # position whose confidence passes it, where those are at least that
+    # many.  Any other model refuses a request that sets either.
+    denoising_steps: Optional[int] = None
+    confidence_threshold: float = 0.0
     # cluster-frontend fields (tpu_parallel/cluster/ — the engine itself
     # ignores all three): per-client concurrency caps key off client_id;
     # priority reorders frontend admission (higher first, aged so lower
@@ -117,6 +130,13 @@ class Request:
             raise ValueError(f"max_new_tokens={self.max_new_tokens} < 1")
         if self.draft_tokens is not None and self.draft_tokens < 0:
             raise ValueError(f"draft_tokens={self.draft_tokens} < 0")
+        if self.denoising_steps is not None and self.denoising_steps < 1:
+            raise ValueError(f"denoising_steps={self.denoising_steps} < 1")
+        if not 0.0 <= self.confidence_threshold < 1.0:
+            raise ValueError(
+                f"confidence_threshold={self.confidence_threshold} outside "
+                "[0, 1)"
+            )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,6 +175,11 @@ class RequestOutput:
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     token_times: List[float] = dataclasses.field(default_factory=list)
+    # a block model's: the denoising step of its block at which each token
+    # was filled (parallel to ``tokens``), and the index of the first token
+    # of each group that arrived together (a block is delivered whole)
+    fill_steps: List[int] = dataclasses.field(default_factory=list)
+    token_groups: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def done(self) -> bool:
@@ -168,7 +193,11 @@ class RequestOutput:
         return self.first_token_time - self.arrival_time
 
     def inter_token_latencies(self) -> List[float]:
-        """Gaps between consecutive token deliveries (seconds)."""
-        return [
-            b - a for a, b in zip(self.token_times, self.token_times[1:])
-        ]
+        """Gaps between consecutive token deliveries (seconds).  Tokens
+        that arrived together (``token_groups``: a block model delivers a
+        block whole) are ONE delivery: the gaps lie between groups, not
+        ``L - 1`` gaps of zero inside each."""
+        times = self.token_times
+        if self.token_groups:
+            times = [times[i] for i in self.token_groups]
+        return [b - a for a, b in zip(times, times[1:])]
