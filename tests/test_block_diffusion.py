@@ -50,6 +50,11 @@ from tpu_parallel.ops.flash_attention import (  # noqa: E402
     flash_attention,
     flash_plan,
 )
+from tpu_parallel.serving.engine import (  # noqa: E402
+    BLOCK_CARRIED as CARRIED,
+    BLOCK_DENOISED as DENOISED,
+    BLOCK_WAITED as WAITED,
+)
 from tpu_parallel.serving import (  # noqa: E402
     REJECT_UNSUPPORTED,
     REJECTED,
@@ -263,6 +268,11 @@ def test_engine_equals_the_reference_token_for_token(env):
     summary = eng.metrics.summary()
     assert summary["tokens_out"] == sum(r.max_new_tokens for r in requests)
     assert summary["block_forwards"] > summary["block_commit_forwards"] > 0
+    # a live slot-step carried a commit, or waited for a wide step, or neither
+    assert (
+        summary["block_commit_forwards"] + summary["block_commit_waits"]
+        < summary["block_forwards"]
+    )
     assert summary["blocks_completed"] >= len(requests)
     assert summary["tokens_per_forward"] == round(
         summary["block_tokens_filled"] / summary["block_forwards"], 4
@@ -366,12 +376,19 @@ def test_block_plan_is_logged_and_traced(env, caplog):
                             tracer=tracer)
     plan = eng.block_plan
     assert plan["block_len"] == cfg.block_len
-    assert plan["rows_per_step"] == 2 * cfg.block_len
+    # a slot feeds its block behind the completed one before it: 2L rows
+    assert plan["rows_per_step"] == 2 * 2 * cfg.block_len
+    assert plan["commit"] == "rides_next_block_first_step"
+    # the even steps of a tick are wide, the odd ones feed the block alone
+    assert plan["rows_per_narrow_step"] == 2 * cfg.block_len
+    assert plan["wide_steps_per_tick"] == 4
+    assert eng.moe_plan["decode_narrow"]["tokens"] == 2 * cfg.block_len
     assert plan["steps_per_tick"] == 8 and plan["mask_token_id"] == 255
     assert any("block_plan" in r.getMessage() for r in caplog.records)
     (instant,) = [i for i in tracer.instants if i["name"] == "block_plan"]
     assert instant["attrs"]["rows_per_step"] == plan["rows_per_step"]
-    assert eng.moe_plan["decode"]["tokens"] == 2 * cfg.block_len
+    assert instant["attrs"]["commit"] == plan["commit"]
+    assert eng.moe_plan["decode"]["tokens"] == plan["rows_per_step"]
     plain = GPTLM(tiny_test(dtype=jnp.float32))
     weights = plain.init(
         {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32),
@@ -392,6 +409,253 @@ def test_scopes_and_expert_rows_come_out_of_the_block_core(env):
     summary = eng.metrics.summary()
     assert summary["moe_calls"] > 0
     assert 0 < summary["moe_experts_touched_mean"] <= 8
+
+
+# -- a completed block's commit rides the next block's first forward -----------
+
+
+def columns(cache, seq_len, lo=None, hi=None):
+    """Every leaf of a cache that has positions, cut to columns [lo, hi)."""
+    out = []
+    for leaf in jax.tree.leaves(cache):
+        if seq_len in leaf.shape:
+            axis = leaf.shape.index(seq_len)
+            out.append(np.asarray(leaf).take(range(lo or 0, hi or seq_len), axis))
+    assert len(out) >= 3  # keys, values, stored positions
+    return out
+
+
+def test_a_folded_commit_writes_what_a_forward_of_its_own_writes(env):
+    """The completed block's K/V after the step that carried it beside the
+    next block equal those a stand-alone clean forward of that block writes
+    over the same prefix, and the next block's rows see the same thing."""
+    from tpu_parallel.models.generate import block_step, prefill_step
+
+    cfg, model, params, _, _ = env
+    size, seq = cfg.block_len, cfg.seq_len
+    prompt = jnp.asarray(prompt_of(2 * size, 40))[None]
+    clean = jnp.asarray(prompt_of(size, 41))[None]
+    masks = jnp.full((1, size), cfg.mask_token_id, jnp.int32)
+    half = jnp.where(jnp.arange(size) % 2 == 0, clean, masks)
+    start, yes = jnp.array([2 * size]), jnp.array([True])
+    with jax.default_matmul_precision("highest"):
+        _, cache = prefill_step(
+            model, params, prompt, jnp.arange(2 * size)[None]
+        )
+        # the block in progress leaves half-filled keys in its columns
+        _, dirty = block_step(model, params, cache, masks, half, start, yes, ~yes)
+        _, alone = block_step(model, params, dirty, masks, clean, start, yes, ~yes)
+        after, _ = block_step(
+            model, params, alone, clean, masks, start + size, yes, ~yes
+        )
+        both, folded = block_step(
+            model, params, dirty, clean, masks, start + size, yes, yes
+        )
+    lo, hi = 2 * size, 3 * size
+    moved = False
+    for was, want, got in zip(
+        columns(dirty, seq, lo, hi), columns(alone, seq, lo, hi),
+        columns(folded, seq, lo, hi),
+    ):
+        np.testing.assert_allclose(got, want, atol=2e-5)
+        moved |= bool(np.abs(want - was).max() > 1e-3)
+    assert moved  # the half-filled keys were another thing
+    for want, got in zip(columns(dirty, seq, 0, lo), columns(folded, seq, 0, lo)):
+        np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(both[:, size:], after[:, size:], atol=2e-5)
+
+
+def watched(env, request, n_slots=2, steps=1):
+    """One request through ticks of ``steps`` forwards (one: every step is a
+    wide one), with every call of the tick program recorded: the slot state
+    and the pool before it, the pool after it, and what the steps were
+    (``kinds``)."""
+    cfg, model, params, _, _ = env
+    calls = []
+    with jax.default_matmul_precision("highest"):
+        eng = ServingEngine(
+            model, params, n_slots=n_slots, prefill_buckets=(16, 32),
+            decode_steps_per_tick=steps,
+        )
+        inner = eng._block_fn
+
+        def spy(params, state, knobs, cache, rng, table):
+            before = jax.device_get((state, cache))
+            out = inner(params, state, knobs, cache, rng, table)
+            calls.append((*before, jax.device_get(out[6]), np.asarray(out[3])))
+            return out
+
+        eng._block_fn = spy
+        out = eng.add_request(request)
+        eng.run()
+    return eng, out, calls
+
+
+@pytest.mark.parametrize("prompt,steps", [
+    ("a_block", None), ("a_block_and_a_tail", 2), ("shorter_than_a_block", 2),
+    ("a_block", 1),
+])
+def test_a_final_column_is_written_once(env, prompt, steps):
+    """Step by step, a slot writes its block's L columns, and the L before
+    them only in the step that carries the completed block: with nothing
+    pending (the first block after a prefill, with or without a tail; a
+    prompt shorter than a block, which has no prefill; every step inside a
+    block) the columns before its block stay bit for bit, as does the rest
+    of the pool."""
+    cfg = env[0]
+    size, seq = cfg.block_len, cfg.seq_len
+    length = {"a_block": size, "a_block_and_a_tail": size + 2,
+              "shorter_than_a_block": 3}[prompt]
+    request = Request(prompt=prompt_of(length, 50), max_new_tokens=2 * size + 3,
+                      denoising_steps=steps)
+    eng, out, calls = watched(env, request)
+    assert out.tokens == reference(env, request)[0]
+    slot = next(i for i in range(2) if calls[0][0][6][i])
+    carried = 0
+    for state, before, after, kinds in calls:
+        start, live, pend = int(state[3][slot]), state[6][slot], state[9][slot]
+        assert live and kinds[0, slot] == (CARRIED if pend else DENOISED)
+        assert not (pend and start < size)
+        lo = start - size if pend else start
+        for was, now in zip(columns(before, seq), columns(after, seq)):
+            axis = was.shape.index(seq)
+            rows = [slice(None)] * was.ndim
+            rows[axis - 1] = slot
+            mine_was, mine_now = was[tuple(rows)], now[tuple(rows)]
+            keep = np.ones(seq, bool)
+            keep[lo:start + size] = False
+            np.testing.assert_array_equal(
+                mine_now.compress(keep, axis - 1), mine_was.compress(keep, axis - 1)
+            )
+            rows[axis - 1] = 1 - slot  # the idle slot: nothing at all
+            np.testing.assert_array_equal(now[tuple(rows)], was[tuple(rows)])
+        carried += bool(pend)
+    first = calls[0][0]
+    assert not first[9][slot] and int(first[3][slot]) == length // size * size
+    blocks = -(-(length % size + request.max_new_tokens) // size)
+    assert carried == blocks - 1  # one commit a completed block that goes on
+    summary = eng.metrics.summary()
+    assert summary["block_commit_forwards"] == carried
+    assert summary["block_commit_waits"] == 0  # a tick of one step is wide
+
+
+def test_a_request_that_ends_with_its_block_makes_no_commit(env):
+    size = env[0].block_len
+    request = Request(prompt=prompt_of(size, 60), max_new_tokens=size)
+    eng, (out,) = serve(env, [request])
+    assert out.tokens == reference(env, request)[0]
+    summary = eng.metrics.summary()
+    assert summary["block_forwards"] == size and summary["blocks_completed"] == 1
+    assert summary["block_commit_forwards"] == 0
+    assert summary["block_commit_waits"] == 0
+    assert summary["commit_forward_share"] == 0.0
+
+
+def test_a_one_step_request_carries_a_commit_in_every_step_after_its_first(env):
+    size = env[0].block_len
+    more = 40 // size  # blocks after the one that holds the prompt's tail
+    request = Request(prompt=prompt_of(size + 1, 61), max_new_tokens=more * size,
+                      denoising_steps=1)
+    eng, out, calls = watched(env, request)
+    tokens, fill_steps = reference(env, request)
+    assert out.tokens == tokens and out.fill_steps == fill_steps
+    slot = next(i for i in range(2) if calls[0][0][6][i])
+    assert [int(k[0, slot]) for *_, k in calls] == [DENOISED] + [CARRIED] * more
+    summary = eng.metrics.summary()
+    assert summary["block_forwards"] == more + 1
+    assert summary["block_commit_forwards"] == more
+    assert summary["commit_forward_share"] == round(more / (more + 1), 4)
+
+
+@pytest.mark.parametrize("tail,steps", [
+    (0, 2), (2, 2), (0, None), (1, None), (0, 1), (0, 3),
+])
+def test_a_commit_waits_for_a_wide_step_and_the_slot_stays_on_them(
+    env, tail, steps
+):
+    """The steps of a tick alternate, wide and narrow, and only a wide one
+    carries a commit.  A block completed by a wide step leaves its slot
+    waiting through the narrow one, which moves the slot: from then on a
+    request of an even number of steps a block completes its blocks in
+    narrow steps, so it waits once at most.  A request of one step a block
+    completes one in every step it is fed and waits in every narrow one; one
+    of three steps a block waits once a block."""
+    size = env[0].block_len
+    request = Request(prompt=prompt_of(size + tail, 65),
+                      max_new_tokens=40 - tail, denoising_steps=steps)
+    eng, out, calls = watched(env, request, steps=4)
+    tokens, fill_steps = reference(env, request)
+    assert out.tokens == tokens and out.fill_steps == fill_steps
+    slot = next(i for i in range(2) if calls[0][0][6][i])
+    kinds = np.concatenate([k[:, slot] for *_, k in calls])
+    kinds = kinds[: np.nonzero(kinds)[0][-1] + 1]
+    # no commit rides a narrow step
+    assert set(kinds[1::2]) <= {DENOISED, WAITED}
+    summary = eng.metrics.summary()
+    assert summary["block_commit_waits"] == (kinds == WAITED).sum()
+    # a slot that waits is live: its step counts, and fills nothing
+    assert summary["block_forwards"] == len(kinds)
+    blocks = -(-(tail + len(tokens)) // size)
+    assert summary["block_commit_forwards"] == blocks - 1
+    if steps == 1:
+        every = [DENOISED, WAITED] + [CARRIED, WAITED] * len(kinds)
+        assert list(kinds) == every[: len(kinds)]
+    elif steps == 3:
+        # an odd number of steps a block: every block is completed by a wide
+        # step and waits through the narrow one, so the request gets the
+        # slot-steps it got when a commit had a forward of its own
+        assert (kinds == WAITED).sum() == blocks - 1
+        assert len(kinds) == (steps + 1) * blocks - 1
+    else:
+        a_step = size // (steps or size)  # positions a step, the static rule
+        first = -(-(size - tail) // a_step)  # forwards of the first block
+        assert (kinds == WAITED).sum() == first % 2  # completed by a wide step
+        assert (kinds == CARRIED).sum() == blocks - 1
+
+
+def test_a_lone_request_of_many_blocks_fills_a_position_a_forward(env):
+    """One step a position: a block of L took L + 1 forwards (0.8 tokens a
+    forward at L 4) and takes L."""
+    size = env[0].block_len
+    blocks = 40 // size
+    request = Request(prompt=prompt_of(size, 62), max_new_tokens=blocks * size,
+                      denoising_steps=size)
+    eng, (out,) = serve(env, [request], n_slots=1)
+    assert out.tokens == reference(env, request)[0]
+    summary = eng.metrics.summary()
+    assert summary["block_forwards"] == blocks * size
+    assert abs(summary["tokens_per_forward"] - 1.0) < 0.02
+    assert summary["block_commit_forwards"] == blocks - 1
+    assert summary["block_commit_waits"] == 0
+
+
+def test_a_slot_seated_after_a_cancel_with_a_commit_pending(env):
+    """One slot.  The first occupant is cancelled while a completed block
+    awaits its final K/V; the newcomer (a prompt shorter than a block: no
+    prefill, nothing clears anything) is seated with nothing pending, writes
+    nothing before its block and reads nothing stale."""
+    size = env[0].block_len
+    first = Request(prompt=prompt_of(size, 63), max_new_tokens=3 * size,
+                    denoising_steps=1)
+    short = Request(prompt=prompt_of(3, 64), max_new_tokens=2 * size + 1,
+                    denoising_steps=2)
+    cfg, model, params, _, _ = env
+    with jax.default_matmul_precision("highest"):
+        eng = ServingEngine(
+            model, params, n_slots=1, prefill_buckets=(16, 32),
+            decode_steps_per_tick=2,
+        )
+        gone = eng.add_request(first)
+        eng.collect(eng.launch())
+        assert bool(jax.device_get(eng._dev_state[9])[0])  # a commit pending
+        assert eng.cancel(first.request_id)
+        out = eng.add_request(short)
+        eng.run()
+    # a tick of a wide and a narrow step: the first completes a block, the
+    # second is no step for a slot whose commit is pending
+    assert gone.status == "cancelled" and len(gone.tokens) == size
+    assert out.tokens == reference(env, short)[0]
+    assert eng.metrics.summary()["prefill_calls"] == 1
 
 
 # -- the knob: HTTP, the journal, a recovery -----------------------------------
@@ -477,7 +741,7 @@ def test_the_knob_through_http_the_journal_and_a_recovery(tmp_path):
                    if r["record"] == "submit"]
         assert [s["denoising_steps"] for s in submits] == [2]
         assert submits[0]["confidence_threshold"] == 0.0
-        for _ in range(4):
+        for _ in range(3):  # a tick of 2 forwards completes a block
             d1.tick()
         partial = len(d1.result(rid)["tokens"])
         assert 0 < partial < 14  # the kill lands mid-stream, at a block's end

@@ -337,7 +337,9 @@ def test_blockgen_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
     """The serving cell of the block-diffusion expert decoder at its
     published widths, one pipeline stage whole, built from the benchmark's
     own configuration and cell files: the tick of 8 block forwards over 64
-    slots (256 rows a forward, 16 an expert, the streamed kernel) and the
+    slots (a wide step and a narrow one by turns: 512 rows, a slot's block and
+    the completed one before it, 32 an expert, then 256 rows, 16 an expert;
+    the streamed kernel in both) and the
     largest whole-prompt prefill (the flash kernels under the block rule at
     heads of 128, GQA 8:1; no head, no logits) fit one v5e."""
     import json
@@ -392,7 +394,7 @@ def test_blockgen_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
         assert kv == n * 4096 * 12288  # 3.22 GB
         flags, size = shaped(jnp.bool_, n), cfg.block_len
         state = (ints(n, size), shaped(jnp.bool_, n, size), ints(n, size),
-                 ints(n), ints(n), ints(n), flags, ints(n))
+                 ints(n), ints(n), ints(n), flags, ints(n), ints(n, size), flags)
         knobs = (ints(n), shaped(jnp.float32, n), ints(n),
                  shaped(jnp.float32, n))
         lowered = tick.lower(
@@ -405,8 +407,9 @@ def test_blockgen_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
         kernels = re.findall(r"%ragged-dot-streamed[.\d]* = .*", text)
         # two calls a layer in each of the layer's two buffers (2048 rows
         # reach the size at which a quarter-size buffer is compiled beside
-        # the worst case; with every expert held it never runs)
-        assert len(kernels) == 4 * cfg.n_layers
+        # the worst case; with every expert held it never runs), in each of
+        # the scan body's two forwards (the wide step and the narrow one)
+        assert len(kernels) == 2 * 4 * cfg.n_layers
         assert "ragged-dot-none" not in text
         # the choice among a block's positions is counted, not sorted; what
         # sorts are left are the experts' (by expert id), none over the

@@ -284,41 +284,58 @@ def verify_step(model: GPTLM, params, cache, tokens: jax.Array,
     )
 
 
-def block_step(model: GPTLM, params, cache, tokens: jax.Array,
-               start: jax.Array, live: jax.Array,
-               block_table: Optional[jax.Array] = None,
+def block_step(model: GPTLM, params, cache, prev: Optional[jax.Array],
+               tokens: jax.Array, start: jax.Array, live: jax.Array,
+               pend: jax.Array, block_table: Optional[jax.Array] = None,
                with_rows: bool = False):
     """One forward of a block model's CURRENT blocks: ``tokens`` [b, L] are
     row ``i``'s block as it stands (filled ids and mask ids), at positions
-    ``start[i] + [0..L)``; its K/V are written at columns ``start[i] + [0..L)``
-    EVERY call, so a block's keys are rewritten until the block is final.
-    Rows that are not ``live`` ride as pads (positions -1, writes parked at
-    ``seq_len``).  Returns ``(hidden [b, L, d_model], cache)``.
+    ``start[i] + [0..L)``; its K/V are written at those columns EVERY call, so
+    a block's keys are rewritten until the block is final.  Rows that are not
+    ``live`` ride as pads (positions -1, writes parked at ``seq_len``).
 
-    Why no stale column can be seen.  Under the block rule
+    NARROW (``prev`` None): those L rows alone; returns ``(hidden [b, L,
+    d_model], cache)``.  WIDE (``prev`` [b, L]): 2L rows, the completed block
+    before the current one riding along where it still awaits its final K/V
+    (``pend[i]``): ``prev[i]`` clean at ``start[i] - L + [0..L)``, written by
+    this one call and final from then on; pads elsewhere.  A FINAL COLUMN IS
+    WRITTEN ONCE: every token has a column of its own (``write_index`` [b,
+    2L]) and a pad's is parked at ``seq_len``, so a row with nothing pending
+    leaves the columns before its block as they are.  Returns ``(hidden [b,
+    2L, d_model], cache)``: the current block is ``[:, L:]``.
+
+    Why the two halves are one forward.  Under the block rule
     (``TransformerConfig.block_len``) a query of the block that starts at
-    ``s`` sees the stored positions ``<= s + L - 1`` and no other.  The layer
-    writes the call's K/V before it reads, so the block's own columns hold
-    THIS call's rows.  Every column before ``s`` was written by this same
-    occupant: by the prefill of its prompt's whole blocks, or by the last
-    forward of an earlier block, which fed the block clean (the engine's
-    commit pass) - a block is always written whole before it is read.  Every
-    column from ``s + L`` on holds -1 (a prefill's padding) or, in the aligned
-    layout (column == stored position), a position ``>= s + L`` left by a
-    longer earlier occupant of the slot or by this occupant's own prefill
-    padding: past the block's end, invisible.  So a slot needs no clearing
-    between occupants, and the half-filled keys of a block in progress are
+    ``s`` sees the stored positions ``<= s + L - 1`` and no other, and a layer
+    writes the call's K/V before it reads.  So the completed block's queries
+    see their own clean rows and nothing of the block behind them, which is
+    what a forward of their own would show them, and the current block's
+    queries read the completed block's FINAL keys, written by this call.
+
+    Why no stale column can be seen.  The block's own columns hold THIS
+    call's rows.  Every column before ``s`` was written by this same occupant:
+    by the prefill of its prompt's whole blocks, or by the call that carried
+    an earlier block clean, at the latest this one - a block is always
+    written whole before a later block reads it (the engine feeds no block
+    whose predecessor is still pending through a narrow call).  Every column
+    from ``s + L`` on holds -1 (a prefill's padding) or, in the aligned layout
+    (column == stored position), a position ``>= s + L`` left by a longer
+    earlier occupant of the slot or by this occupant's own prefill padding:
+    past the block's end, invisible.  So a slot needs no clearing between
+    occupants, and the half-filled keys of a block in progress are
     overwritten before any later block can see them."""
     width = tokens.shape[1]
-    offs = jnp.arange(width, dtype=jnp.int32)[None, :]
-    positions = jnp.where(live[:, None], start[:, None] + offs, -1)
+    first = 0 if prev is None else -width
+    offs = jnp.arange(first, width, dtype=jnp.int32)[None, :]
+    real = live[:, None] & (pend[:, None] | (offs >= 0))
+    columns = start[:, None] + offs
     return _cached_apply(
         model,
         {"params": params, "cache": cache},
-        tokens,
+        tokens if prev is None else jnp.concatenate([prev, tokens], axis=1),
         with_rows,
-        positions=positions,
-        write_index=jnp.where(live, start, model.config.seq_len),
+        positions=jnp.where(real, columns, -1),
+        write_index=jnp.where(real, columns, model.config.seq_len),
         block_table=block_table,
     )
 

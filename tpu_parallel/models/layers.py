@@ -978,10 +978,10 @@ class Attention(nn.Module):
                         "write_index under lazy beam search (beam_src slot "
                         "bookkeeping assumes the shared scalar cache_index)"
                     )
-                wi = (
-                    write_index.astype(jnp.int32)[:, None]
-                    + jnp.arange(x.shape[1])[None, :]
-                )
+                # [b, T] (a block step) gives every token a column of its own
+                wi = write_index.astype(jnp.int32)
+                if wi.ndim == 1:
+                    wi = wi[:, None] + jnp.arange(x.shape[1])[None, :]
                 if paged:
                     # logical column -> (physical block, offset) through the
                     # row's block table: table[row, col // bt] * bt +

@@ -837,9 +837,15 @@ def _block_prefill_core(model, params, prompt, positions):
     return cache, _calls(rows)
 
 
+# what a slot did in one step of a block tick (``kinds``; 0: parked): it
+# denoised its block, it denoised and carried the commit of the block before,
+# or it was live and not fed, its commit pending at a narrow step
+BLOCK_DENOISED, BLOCK_CARRIED, BLOCK_WAITED = 1, 2, 3
+
+
 def _block_decode_core(
     model, params, steps, blk, msk, fstep, start, ptail, nstep, live,
-    budget, eos, temp, dsteps, thr, cache, rng, table=None,
+    budget, prev, pend, eos, temp, dsteps, thr, cache, rng, table=None,
 ):
     """``steps`` forwards of every slot's CURRENT block in ONE jitted
     ``lax.scan``: generation by diffusion over blocks (a model with
@@ -847,50 +853,66 @@ def _block_decode_core(
     ids, ``msk`` which are still masked, ``fstep`` the step at which each
     was filled, -1 for a prompt's tail), the block's ``start``, the prompt
     tail's length ``ptail`` (the first block only), the denoising steps the
-    block has had (``nstep``), and ``live`` / ``budget`` as the fused tick
-    has them; ``dsteps`` and ``thr`` are the request's steps a block and
-    confidence threshold.
+    block has had (``nstep``), ``live`` / ``budget`` as the fused tick has
+    them, and the completed block that still awaits its final K/V (``prev``
+    [n, L] ids, ``pend`` [n] whether there is one); ``dsteps`` and ``thr``
+    are the request's steps a block and confidence threshold.
 
-    One step feeds each live slot's block as it stands
-    (:func:`~tpu_parallel.models.generate.block_step`: L rows at
-    ``[start, start + L)``, their K/V rewritten at ``start``).  A slot with
-    masked positions DENOISES: :func:`sample_block` picks and weighs each
-    position, :func:`unmask_choice` fills the most confident; a filled
-    position never changes.  The step that fills a block's last position
-    EMITS the block (its generated positions in order, cut at the budget and
-    after an EOS) and ends the slot there, or leaves it one COMMIT forward:
-    a slot with nothing masked feeds its clean block once more, which makes
-    the block's K/V final, and moves to the next block, all mask ids.  So a
-    step emits 0 or up to L tokens a slot.
+    A step feeds each slot its block as it stands
+    (:func:`~tpu_parallel.models.generate.block_step`) and every slot it
+    feeds DENOISES (a live slot always has masked positions):
+    :func:`sample_block` picks and weighs each position of the block,
+    :func:`unmask_choice` fills the most confident; a filled position never
+    changes.  The step that fills a block's last position EMITS the block
+    (its generated positions in order, cut at the budget and after an EOS)
+    and ends the slot there, or moves it on at once: the completed block
+    becomes the pending one and the next block is all mask ids.  No forward
+    is spent on a COMMIT alone: the pending block rides, clean, the next
+    block's first denoising forward, which makes its K/V final.
+
+    Rows are not free (the decode attention's scores grow with them: on the
+    chip a step of 2L rows a slot costs 1.45 steps of L), so the steps of a
+    tick alternate: the even ones are WIDE (2L rows a slot, the half before
+    the block pads where nothing is pending) and carry the commits, the odd
+    ones NARROW (the block's L rows).  A slot whose commit is pending at a
+    narrow step WAITS that step (live, not fed, it fills nothing); waiting
+    moves it onto the wide steps, where a request of an EVEN number of steps
+    a block then stays: one wait a request.  A request of an odd number (or
+    under the threshold rule, whose blocks end where they end) waits once a
+    block, so it gets the forwards it got when a commit had one of its own,
+    in a tick whose steps cost more: the gain needs even steps a block.  So
+    a step emits 0 or up to L tokens a slot, and every block that a later one
+    reads gets exactly one clean forward.
 
     Returns ``(blocks [steps, n, L] (-1: nothing), counts [steps, n],
-    fsteps [steps, n, L], kinds [steps, n] (0 parked, 1 denoise, 2 commit),
-    fills [steps, n], state, cache, rows)``."""
+    fsteps [steps, n, L], kinds [steps, n] (0 parked, else ``BLOCK_DENOISED``
+    / ``BLOCK_CARRIED`` / ``BLOCK_WAITED``), fills [steps, n], state, cache,
+    rows)``."""
     cfg = model.config
     width, mask_id = cfg.block_len, cfg.mask_token_id
     offs = jnp.arange(width, dtype=jnp.int32)[None, :]
 
-    def body(carry, step_rng):
-        blk, msk, fstep, start, ptail, nstep, live, budget, cache = carry
-        denoise = live & msk.any(axis=1)
-        commit = live & ~denoise
+    def step(carry, step_rng, wide):
+        blk, msk, fstep, start, ptail, nstep, live, budget = carry[:8]
+        prev, pend, cache = carry[8:]
+        fed = live if wide else live & ~pend
         hidden, cache, rows = block_step(
-            model, params, cache, blk, start, live, block_table=table,
-            with_rows=True,
+            model, params, cache, prev if wide else None, blk, start, fed,
+            pend, block_table=table, with_rows=True,
         )
-        logits = _full_logits(cfg, params, hidden)
+        logits = _full_logits(cfg, params, hidden[:, -width:])
         with jax.named_scope("diffusion.unmask"):
-            x0, conf = sample_block(logits, step_rng, temp, rows=denoise)
-            broken = denoise & ~jnp.where(
+            x0, conf = sample_block(logits, step_rng, temp, rows=fed)
+            broken = fed & ~jnp.where(
                 msk, jnp.isfinite(conf), True
             ).all(axis=1)
             fill = unmask_choice(conf, msk, nstep, dsteps, thr)
-            fill = fill & denoise[:, None]
+            fill = fill & fed[:, None]
         blk = jnp.where(fill, x0, blk)
         msk = msk & ~fill
         fstep = jnp.where(fill, nstep[:, None], fstep)
-        nstep = nstep + denoise.astype(nstep.dtype)
-        complete = denoise & ~msk.any(axis=1) & ~broken
+        nstep = nstep + fed.astype(nstep.dtype)
+        complete = fed & ~msk.any(axis=1) & ~broken
         # the block's generated positions, in order, up to the budget and
         # to its first EOS (delivered with it)
         room = jnp.minimum(width - ptail, budget)
@@ -912,25 +934,52 @@ def _block_decode_core(
         count = jnp.where(complete, cut - ptail, 0) + broken.astype(jnp.int32)
         budget = budget - jnp.where(complete, cut - ptail, 0)
         done = (complete & (ended | (budget <= 0))) | broken
+        kind = jnp.where(
+            fed, jnp.where(pend, BLOCK_CARRIED, BLOCK_DENOISED),
+            jnp.where(live, BLOCK_WAITED, 0),
+        )
         out = (
             emitted, count, jnp.where(emitted >= 0, fstep, -1),
-            denoise.astype(jnp.int32) + 2 * commit.astype(jnp.int32),
-            fill.sum(axis=1, dtype=jnp.int32), rows,
+            kind, fill.sum(axis=1, dtype=jnp.int32), rows,
         )
         live = live & ~done
-        # the commit forward made the block's K/V final: the next block
-        move = commit[:, None]
-        blk = jnp.where(move, mask_id, blk)
-        msk = jnp.where(move, True, msk)
-        fstep = jnp.where(move, -1, fstep)
-        start = start + jnp.where(commit, width, 0)
-        ptail = jnp.where(commit, 0, ptail)
-        nstep = jnp.where(commit, 0, nstep)
-        return (blk, msk, fstep, start, ptail, nstep, live, budget, cache), out
+        # a completed block whose request goes on awaits its final K/V, which
+        # the next wide forward writes; the next block starts at once
+        move = complete & ~done
+        prev = jnp.where(move[:, None], blk, prev)
+        pend = jnp.where(fed, move, pend)
+        blk = jnp.where(move[:, None], mask_id, blk)
+        msk = msk | move[:, None]
+        fstep = jnp.where(move[:, None], -1, fstep)
+        start = start + jnp.where(move, width, 0)
+        ptail = jnp.where(move, 0, ptail)
+        nstep = jnp.where(move, 0, nstep)
+        carry = (
+            blk, msk, fstep, start, ptail, nstep, live, budget, prev, pend,
+            cache,
+        )
+        return carry, out
 
-    carry, (blocks, counts, fsteps, kinds, fills, rows) = lax.scan(
-        body, (blk, msk, fstep, start, ptail, nstep, live, budget, cache),
-        jax.random.split(rng, steps),
+    def pair(carry, rngs):
+        carry, wide = step(carry, rngs[0], True)
+        carry, narrow = step(carry, rngs[1], False)
+        return carry, jax.tree.map(lambda a, b: jnp.stack([a, b]), wide, narrow)
+
+    carry = (blk, msk, fstep, start, ptail, nstep, live, budget, prev, pend, cache)
+    rngs = jax.random.split(rng, steps)
+    pairs, outs = steps // 2, []
+    if pairs:
+        carry, both = lax.scan(
+            pair, carry, rngs[: 2 * pairs].reshape(pairs, 2, *rngs.shape[1:])
+        )
+        outs.append(jax.tree.map(
+            lambda x: x.reshape(2 * pairs, *x.shape[2:]), both
+        ))
+    if steps % 2:
+        carry, last = step(carry, rngs[-1], True)
+        outs.append(jax.tree.map(lambda x: x[None], last))
+    blocks, counts, fsteps, kinds, fills, rows = jax.tree.map(
+        lambda *parts: jnp.concatenate(parts), *outs
     )
     return blocks, counts, fsteps, kinds, fills, carry[:-1], carry[-1], rows
 
@@ -967,7 +1016,8 @@ def _seat_block_rows(
     slot state (:func:`_seat_rows`' twin): ``slots`` [nb] (a dummy row
     carries ``n_slots`` and is dropped), ``blk`` [nb, L] the first block
     (the prompt's tail, then mask ids), ``ptail`` the tail's length,
-    ``start`` the block's first position.  Every seated row goes live."""
+    ``start`` the block's first position.  Every seated row goes live, with
+    no block pending: the columns before its block are the prefill's."""
 
     def put(rows, values):
         return rows.at[slots].set(values.astype(rows.dtype), mode="drop")
@@ -977,7 +1027,7 @@ def _seat_block_rows(
     one = jnp.ones_like(ptail)
     values = (
         blk, masked, jnp.full_like(blk, -1), start, ptail, 0 * one,
-        one.astype(bool), budget,
+        one.astype(bool), budget, jnp.zeros_like(blk), one < 0,
     )
     state = tuple(put(rows, v) for rows, v in zip(state, values))
     knobs = tuple(
@@ -1947,7 +1997,9 @@ class ServingEngine:
         )
         if spec is None:
             return None
-        shapes = {"decode": n_slots * max(1, self._block_len)}
+        shapes = {"decode": n_slots * max(1, 2 * self._block_len)}
+        if self._block_len and self._fused_steps > 1:
+            shapes["decode_narrow"] = n_slots * self._block_len
         if self._chunk_tokens and self._unified:
             shapes["chunk"] = n_slots * self._chunk_tokens
         for b in self._buckets or ():
@@ -1983,17 +2035,21 @@ class ServingEngine:
 
     def _plan_blocks(self, n_slots: int) -> Optional[Dict[str, object]]:
         """What a tick does for a block-diffusion model: the block's length
-        and mask id, the rows a forward feeds (slots x L), the forwards a
-        tick, and where the fill is chosen; logged and put on the tracer
-        once at build; None for any other model."""
+        and mask id, the rows a forward feeds (a wide step slots x 2L: the
+        block and the completed one before it; a narrow step slots x L), the
+        forwards a tick and which of them are wide, what makes a completed
+        block's K/V final, and where the fill is chosen; logged and put on the
+        tracer once at build; None for any other model."""
         if not self._block_len:
             return None
         cfg = self.model.config
         plan = {
             "block_len": self._block_len, "mask_token_id": cfg.mask_token_id,
-            "rows_per_step": n_slots * self._block_len,
+            "rows_per_step": n_slots * 2 * self._block_len,
+            "rows_per_narrow_step": n_slots * self._block_len,
             "steps_per_tick": self._fused_steps,
-            "commit": "one_forward_a_block",
+            "wide_steps_per_tick": (self._fused_steps + 1) // 2,
+            "commit": "rides_next_block_first_step",
             "chosen": "top_k_over_block_on_device",
         }
         logging.getLogger(__name__).info("block_plan %s", json.dumps(plan))
@@ -4191,6 +4247,7 @@ class ServingEngine:
                     np.zeros((n, width), bool),
                     np.full((n, width), -1, np.int32),
                     ints, ints, ints, np.zeros(n, bool), ints,
+                    np.zeros((n, width), np.int32), np.zeros(n, bool),
                 ),
                 (
                     np.full(n, -1, np.int32), np.zeros(n, np.float32),
@@ -4304,10 +4361,12 @@ class ServingEngine:
             for s in range(self.pool.n_slots)
         ])
         # the window's work by what the device did, for the slots whose
-        # owner launched this tick (a later tenant has nothing in it)
+        # owner launched this tick (a later tenant has nothing in it); a
+        # slot that waited was live, and its pad rows rode the forward
         self.metrics.record_block_steps(
             forwards=int((kinds[:, mine] > 0).sum()),
-            commits=int((kinds[:, mine] == 2).sum()),
+            commits=int((kinds[:, mine] == BLOCK_CARRIED).sum()),
+            waits=int((kinds[:, mine] == BLOCK_WAITED).sum()),
             filled=int(fills[:, mine].sum()),
             completed=int((counts[:, mine] > 0).sum()),
         )

@@ -269,10 +269,12 @@ class ServingMetrics:
         # tick and prefill programs return beside their tokens.  A call is
         # one layer's pass over one program step's rows.
         # generation by diffusion over blocks (engine._block_decode_core):
-        # live slot-steps, those that were a commit pass, positions filled,
+        # live slot-steps, those that made a completed block's K/V final (a
+        # commit), those that waited for a wide step, positions filled,
         # blocks completed
         self._block_forwards = r.counter("serving_block_forwards_total")
         self._block_commits = r.counter("serving_block_commit_forwards_total")
+        self._block_waits = r.counter("serving_block_commit_waits_total")
         self._block_filled = r.counter("serving_block_tokens_filled_total")
         self._blocks_completed = r.counter("serving_blocks_completed_total")
         self._moe_calls = r.counter("serving_moe_calls_total")
@@ -530,14 +532,19 @@ class ServingMetrics:
         self._unified_tick_tokens.observe(tokens)
 
     def record_block_steps(
-        self, forwards: int, commits: int, filled: int, completed: int
+        self, forwards: int, commits: int, waits: int, filled: int,
+        completed: int,
     ) -> None:
         """One tick of a block-diffusion model: the live slot-steps it ran
-        (``forwards``, a slot's block fed once), how many of them filled
-        nothing because they were a block's commit pass, the positions
-        filled, and the blocks completed."""
+        (``forwards``: a slot live through one forward of the tick), how many
+        of them carried a commit (the completed block before the slot's own
+        fed clean beside it, its K/V made final; a commit has no forward of
+        its own), how many filled nothing because the slot was not fed, its
+        commit pending at a narrow step (``waits``), the positions filled,
+        and the blocks completed."""
         self._block_forwards.inc(forwards)
         self._block_commits.inc(commits)
+        self._block_waits.inc(waits)
         self._block_filled.inc(filled)
         self._blocks_completed.inc(completed)
 
@@ -828,6 +835,7 @@ class ServingMetrics:
                 {
                     "block_forwards": forwards,
                     "block_commit_forwards": commits,
+                    "block_commit_waits": int(self._block_waits.value),
                     "block_tokens_filled": filled,
                     "blocks_completed": int(self._blocks_completed.value),
                     "tokens_per_forward": round(filled / forwards, 4),
