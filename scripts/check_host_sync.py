@@ -56,7 +56,9 @@ SYNC_ATTRS = frozenset({"asarray", "array"})
 SYNC_MODULES = frozenset({"np", "numpy"})
 FENCE_ATTRS = frozenset({"block_until_ready", "device_get"})
 
-DEFAULT_PATHS = ("tpu_parallel/serving", "tpu_parallel/fleet")
+# obs/ holds the completion clock (obs/device_clock.py): its wait for a
+# program's output is the one sanctioned read off the pump thread
+DEFAULT_PATHS = ("tpu_parallel/serving", "tpu_parallel/fleet", "tpu_parallel/obs")
 
 WHITELIST_MARK = "# host-sync:"
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """What the serving engine's device programs lower to, as one line a program.
 
-For the engines of the benchmark's two serving cells (built from the cell and
+For the engines of the benchmark's four serving cells (built from the cell and
 configuration files under ``benchmarks/``) every jitted program the traffic
 reaches is lowered for a DESCRIBED v5e chip from abstract operands (nothing
 runs, no weights are made) and printed as::
@@ -21,7 +21,7 @@ host side shows that it changed no program by::
       python scripts/lowered_programs.py --tree /tmp/tree > /tmp/$rev.txt
     done; diff /tmp/<parent>.txt /tmp/HEAD.txt
 
-About 20 s a tree at full depth (``--layers N`` lowers ``gpt2_xl`` at N
+About a minute a tree at full depth (``--layers N`` lowers ``gpt2_xl`` at N
 layers); ``--dump DIR`` keeps the texts for a ``diff`` when a line differs.
 Each program is lowered with the operands the engine passes: a tree whose
 programs take a trailing block table gets ``None`` there, as the fixed-slot
@@ -136,6 +136,39 @@ def main() -> None:
                 ints(rows), ints(rows), fresh, key,
             ))
 
+    def block_programs(cell):
+        """The block-diffusion cell's two programs: the whole-prompt prefill
+        at its first bucket and the tick of 8 block forwards."""
+        from drivers.serve_blockgen import model_config as block_config
+
+        eng = cell["engine"]
+        cfg = block_config(read("configs", "sdar_30b_a3b_depth6.json"), eng)
+        model, n, size = GPTLM(cfg), eng["n_slots"], cfg.block_len
+        params = jax.tree.map(
+            lambda x: on_chip(x, getattr(jnp, eng["served_parameters"])),
+            jax.eval_shape(lambda: model.init(
+                {"params": jax.random.PRNGKey(0)},
+                jnp.zeros((1, 16), jnp.int32), train=False,
+            ))["params"],
+        )
+        prefill, tick = engine._block_engine_fns(model, 8)
+        width = eng["prefill_buckets"][0]
+        report(cell["name"], f"block_prefill_1x{width}", prefill.lower(
+            params, ints(1, width), ints(1, width)
+        ))
+        pool = jax.tree.map(on_chip, jax.eval_shape(
+            lambda p: engine._block_prefill_core(
+                model, p, jnp.zeros((n, 16), jnp.int32),
+                jnp.zeros((n, 16), jnp.int32),
+            )[0], params,
+        ))
+        state = (ints(n, size), bools(n, size), ints(n, size), ints(n),
+                 ints(n), ints(n), bools(n), ints(n), ints(n, size), bools(n))
+        knobs = (ints(n), floats(n), ints(n), floats(n))
+        report(cell["name"], "block_tick_8", with_table(
+            tick, params, state, knobs, pool, key
+        ))
+
     xl_cell = read("workloads", "serve-gpt2_xl-batch.json")["engine"]
     xl_file = read("configs", "gpt2_xl.json")
     xl = MODEL_REGISTRY[xl_file["registry"]](**weights.model_overrides(
@@ -152,6 +185,16 @@ def main() -> None:
                      moe_cell["engine"]),
         0, [(1, moe_cell["engine"]["prefill_buckets"][0])],
     )
+    from drivers.serve_hybrid import model_config as hybrid_config
+
+    hybrid_cell = read("workloads", "serve-granite_4_0_h_micro-shortchat.json")
+    programs(
+        hybrid_cell["name"],
+        hybrid_config(read("configs", "granite_4_0_h_micro.json"),
+                      hybrid_cell["engine"]),
+        0, [(1, hybrid_cell["engine"]["prefill_buckets"][0])],
+    )
+    block_programs(read("workloads", "serve-sdar_30b_a3b_depth6-blockgen.json"))
 
 
 if __name__ == "__main__":

@@ -659,6 +659,108 @@ def test_phase_writes_three_ways_from_one_pair_of_reads(monkeypatch):
     assert len(entered) == 2 and len(tr.spans) == 1
 
 
+def test_device_clock_writes_three_ways_from_one_read(monkeypatch):
+    """The completion clock, as `phase` beside it: ONE read of the owner's
+    clock a program; the histogram, the span and the annotation carry the
+    interval that read closes, and tracing off leaves the histogram."""
+    import threading
+    import time
+
+    from tpu_parallel import obs
+    from tpu_parallel.obs import DeviceClock, device_clock
+    from tpu_parallel.serving import ServingMetrics
+
+    assert "DeviceClock" in obs.__all__
+    entered = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("exit", self.name))
+
+    monkeypatch.setattr(device_clock, "TraceAnnotation", Recorder)
+    clock = FakeClock()  # a second more at every read
+    tr = Tracer(clock=clock)
+
+    class Owner:  # the two methods the clock holds of an engine, weakly
+        metrics = ServingMetrics()
+
+        def ran(self, *interval):
+            self.metrics.record_device(*interval)
+
+        def lost(self, dropped):
+            raise AssertionError("nothing is dropped or fails here")
+
+    owner = Owner()
+    ready = threading.Event()
+    dc = DeviceClock(
+        clock, tr, owner.ran, owner.lost, wait=lambda leaf: leaf.wait(10)
+    )
+
+    def programs():
+        return owner.metrics.summary()["device_programs"]
+
+    def settle(n):
+        deadline = time.monotonic() + 10
+        while programs() < n:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+
+    dc.watch("prefill", "16x2", 0.25, ready)
+    ready.set()
+    settle(1)
+    assert clock.t == 1.0  # one read, no more
+    (span,) = tr.spans
+    assert (span.name, span.track, span.start, span.end) == (
+        "device.prefill", "device", 0.25, 1.0
+    )
+    assert span.attrs == {"shape": "16x2"}
+    assert entered == [("enter", "device.run.prefill"),
+                       ("exit", "device.run.prefill")]
+    (hist,) = [
+        h for h in owner.metrics.registry.snapshot()["histograms"]
+        if h["name"] == "serving_device_seconds"
+    ]
+    assert hist["labels"] == {"program": "prefill", "shape": "16x2"}
+    assert hist["sum"] == 0.75 and hist["count"] == 1
+    tr.enabled = False
+    dc.watch("prefill", "16x2", 0.5, ready)
+    settle(2)
+    assert clock.t == 2.0 and len(tr.spans) == 1 and len(entered) == 4
+    assert owner.metrics.summary()["device_prefill_ms_mean"] == 875.0
+
+
+def test_registry_hands_racing_threads_one_instrument():
+    """The completion clock's thread makes its histograms at a shape's
+    first completion, beside the pump thread's own registrations: two
+    threads asking for a new (name, labels) pair hold ONE object."""
+    import threading
+
+    r = MetricRegistry()
+    barrier = threading.Barrier(8)
+    got = []
+
+    def ask(i):
+        barrier.wait()
+        for shape in range(200):
+            got.append(r.histogram("serving_device_seconds",
+                                   program="tick", shape=shape))
+
+    threads = [threading.Thread(target=ask, args=(i,)) for i in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(got) == 1600 and len({id(h) for h in got}) == 200
+    rows = [h for h in r.snapshot()["histograms"]]
+    assert len(rows) == 200
+
+
 def test_spool_drain_keeps_the_tracer_small_over_10k_ticks(tmp_path):
     """A tracer drained by a spool holds a tick's worth, not the 10 k
     ticks' spans, and the log has every span exactly once."""

@@ -1839,7 +1839,8 @@ def test_engine_trace_complete_span_chain_per_request(rng):
     eng.run()
     assert all(out.status == FINISHED for out in outs)
 
-    assert tracer.tracks() == ["scheduler", "slot 0", "slot 1"]
+    # the completion clock's spans (device.<kind>) have a track of their own
+    assert tracer.tracks() == ["scheduler", "device", "slot 0", "slot 1"]
     for out in outs:
         rid = out.request.request_id
         chain = [
@@ -2107,18 +2108,36 @@ PREFILL_SUMMARY_KEYS = {
 # the busy ticks whose sampler could draw, and the share that could not
 # (PR 33): a counter, and a share that is None until a tick was busy
 SAMPLER_SUMMARY_KEYS = {"sampler_draw_ticks", "sampler_skip_share"}
+# the completion clock (PR 41): device time by program, from inside; two
+# counts that start at 0 and the split by shape that starts empty, the
+# rest None until a program has completed
+DEVICE_SUMMARY_KEYS = {
+    "device_tick_ms_mean", "device_tick_chunk_ms_mean",
+    "device_prefill_ms_mean", "device_prefill_share",
+    "device_prefill_ms_per_ktok", "device_idle_share", "device_programs",
+    "device_clock_dropped", "device_by_shape",
+}
 
 
 class _SetClock:
     """A clock that moves only when told to (so all of a tick's time falls
-    inside the code the test makes slow) and counts its reads."""
+    inside the code the test makes slow) and counts its reads: the thread's
+    that made it (the pump's), and apart from them every other thread's
+    (the completion clock's, one a program, whenever it gets to them)."""
 
     def __init__(self):
+        import threading
+
         self.t = 0.0
         self.reads = 0
+        self.reads_elsewhere = 0
+        self._thread, self._me = threading.current_thread, threading.get_ident()
 
     def __call__(self):
-        self.reads += 1
+        if self._thread().ident == self._me:
+            self.reads += 1
+        else:
+            self.reads_elsewhere += 1
         return self.t
 
 
@@ -2156,7 +2175,8 @@ def test_phases_partition_busy_ticks_and_skip_idle_ones(rng, kind):
     flight the periods tile the run, gaps and all, and what ran beside
     device work is not host-exposed.  Idle ticks and the sleep before a
     burst enter no histogram; a step reads the clock at most 12 times
-    beside its per-token stamps."""
+    beside its per-token stamps and once a program it dispatched (the
+    completion clock's second read a program is on its own thread)."""
     cfg, model, prompt, params = _build(rng, n_rows=2, prompt_len=7)
     clock = _SetClock()
     knobs = dict(
@@ -2186,9 +2206,10 @@ def test_phases_partition_busy_ticks_and_skip_idle_ones(rng, kind):
         ticks += 1
         tokens += len(events)
         if events and all(ev.index > 0 for ev in events):  # steady
-            assert clock.reads - before - len(events) <= 12
+            assert clock.reads - before - len(events) <= 12 + 1
         clock.t += 0.5  # whatever the engine's owner does between ticks
     assert ticks >= 3
+    assert clock.reads_elsewhere <= eng.metrics.host_dispatches
     if kind == "unified":
         assert eng.metrics.summary()["unified_tick_tokens_mean"] is not None
 
@@ -2255,8 +2276,15 @@ def test_summary_keeps_every_old_key_and_has_the_phase_clock(rng):
     eng = ServingEngine(model, params, n_slots=1)
     empty = eng.metrics.summary()
     every = (OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS | PREFILL_SUMMARY_KEYS
-             | SAMPLER_SUMMARY_KEYS)
+             | SAMPLER_SUMMARY_KEYS | DEVICE_SUMMARY_KEYS)
     assert set(empty) == every
+    counts = {"device_programs", "device_clock_dropped"}
+    assert all(empty[k] == 0 for k in counts)
+    assert empty["device_by_shape"] == {}
+    assert all(
+        empty[k] is None
+        for k in DEVICE_SUMMARY_KEYS - counts - {"device_by_shape"}
+    )
     assert all(empty[k] == 0 for k in PREFILL_SUMMARY_KEYS)
     assert empty["sampler_draw_ticks"] == 0
     assert empty["sampler_skip_share"] is None

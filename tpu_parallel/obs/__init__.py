@@ -1,6 +1,6 @@
 """Unified telemetry: labeled metric registry, request-lifecycle span
-tracer, the pump loops' phase clock, and pluggable exporters (Chrome
-trace / Prometheus text).
+tracer, the pump loops' phase clock, the device's completion clock, and
+pluggable exporters (Chrome trace / Prometheus text).
 
 Shared by the serving engine and the trainer (docs/11_observability.md):
 ``MetricRegistry`` is the one store every counter/gauge/histogram lives
@@ -14,6 +14,7 @@ appends each process's finished spans to a bounded JSONL span log, and
 single Perfetto timeline with flow arrows across the wire crossings.
 """
 
+from tpu_parallel.obs.device_clock import DeviceClock
 from tpu_parallel.obs.exporters import (
     chrome_trace_events,
     parse_prometheus_text,
@@ -77,4 +78,5 @@ __all__ = [
     "parse_prometheus_text",
     "write_prometheus",
     "phase",
+    "DeviceClock",
 ]

@@ -266,7 +266,8 @@ class MetricRegistry:
         key = _label_key(labels)
         inst = by_label.get(key)
         if inst is None:
-            inst = by_label[key] = factory()
+            # setdefault: two threads asking for a new pair get ONE object
+            inst = by_label.setdefault(key, factory())
         return inst
 
     def counter(self, name: str, **labels) -> Counter:

@@ -138,6 +138,7 @@ from tpu_parallel.models.generate import (
     prefill_step,
     verify_step,
 )
+from tpu_parallel.obs.device_clock import DeviceClock
 from tpu_parallel.obs.phases import ENGINE_PREFIX, SPAN_PREFIX, phase
 from tpu_parallel.obs.registry import MetricRegistry
 from tpu_parallel.obs.tracer import NULL_TRACER, Tracer
@@ -192,6 +193,7 @@ from tpu_parallel.serving.spec_decode import (
     ngram_draft_tokens,
     verify_tokens,
 )
+from tpu_parallel.utils.stack_room import stack_room
 
 
 def validate_same_shapes(old, new) -> None:
@@ -1456,6 +1458,16 @@ class ServingEngine:
       ``serving_tick_phase_seconds``, to a ``tick.<phase>`` span when
       tracing, and to an ``engine.tick.<phase>`` profiler annotation —
       on ``clock``, which a tracer should share.
+    - the completion clock, always on: every device program is launched
+      through :meth:`_run`, and one thread an engine
+      (:class:`~tpu_parallel.obs.device_clock.DeviceClock`, started at
+      a dispatch, ended when the engine drains) stamps each as it
+      completes:
+      ``serving_device_seconds{program, shape}`` (``tick`` /
+      ``tick_chunk`` / ``prefill`` / ``extend``),
+      ``serving_device_idle_seconds_total``, a ``device.<program>`` span
+      on the ``device`` track when tracing, a ``device.run.<program>``
+      profiler annotation; ``summary()["device_*"]``.
     """
 
     def __init__(
@@ -1643,6 +1655,11 @@ class ServingEngine:
         self._newest_tick: Optional[_PendingTick] = None
         self._collect_end = float("-inf")
         self._busy_end: Optional[float] = None
+        # the completion clock: what _run dispatched, stamped as it
+        # completes; its thread starts with a dispatch and ends at a drain
+        self._device_clock = DeviceClock(
+            self.clock, self.tracer, self._device_ran, self._device_lost
+        )
         self._rng = rng if rng is not None else jax.random.PRNGKey(0)
 
         if prefill_buckets == "auto":
@@ -2440,6 +2457,47 @@ class ServingEngine:
             annotation=ENGINE_PREFIX + name,
         )
 
+    @stack_room
+    def _run(self, kind: Optional[str], shape: str, fn, *args):
+        """THE dispatch point: every jitted program of the engine is
+        launched here and nowhere else.  Calls ``fn(*args)`` and returns
+        what it returned; for a watched ``kind`` (``tick``: a decode tick
+        that carried no prompt tokens, ``tick_chunk``, ``prefill``,
+        ``extend``) it hands the completion clock
+        (:mod:`tpu_parallel.obs.device_clock`) ``shape`` (the compiled
+        shape in words), the engine's clock read now that the call has
+        returned, and the smallest array of the program's FIRST output:
+        a tick's token block, a prefill's logits, a block prefill's fresh
+        rows - what no later program donates, so the clock's thread may
+        wait for it.  ``kind=None`` (the first-token sampler) is not
+        watched: its device time falls to the watched program that
+        follows, as that of the row scatters, the seat programs and the
+        uploads, which have no output that survives.
+
+        A program's first call traces it, millions of Python calls deep
+        in this frame: :func:`stack_room` keeps them clear of the edges
+        of the interpreter's stack chunks, where this frame, one more
+        than a direct call had, had pushed them (the block-diffusion
+        cell's prefill programs traced 2.6 times as long)."""
+        out = fn(*args)
+        if kind is not None:
+            leaf = min(
+                jax.tree_util.tree_leaves(out[0]), key=lambda x: x.size
+            )
+            self._device_clock.watch(kind, shape, self.clock(), leaf)
+        return out
+
+    def _device_ran(self, *interval) -> None:
+        """The completion clock's sink, on its thread: the record that is
+        current when the program completes takes it."""
+        self.metrics.record_device(*interval)
+
+    def _device_lost(self, dropped: bool) -> None:
+        if dropped:
+            self.metrics.record_device_dropped()
+        else:
+            self.metrics.record_device_fault()
+
     def _schedule(self, now: float) -> List[RequestOutput]:
         bucket_key = (
             self._admission_key
@@ -2614,6 +2672,10 @@ class ServingEngine:
                 decoded=decoded,
                 **{f"{k}_ms": 1e3 * v for k, v in p.phases.items()},
             )
+        if not self.has_work():
+            # drained: every program has completed, and the completion
+            # clock lets go of the device (its thread ends)
+            self._device_clock.rest()
         return events
 
     def _sync_payload(self, p: _PendingTick) -> None:
@@ -2668,6 +2730,9 @@ class ServingEngine:
             metrics = ServingMetrics(
                 logger=self.metrics.logger, log_every=self.metrics.log_every
             )
+        # what the device finishes from here on is the new record's, from
+        # here on: a program in flight now is clipped to this instant
+        metrics.open_device_window(self.clock())
         self.metrics = metrics
         self.registry = self.metrics.registry
         self.scheduler.registry = self.registry
@@ -3054,7 +3119,8 @@ class ServingEngine:
         positions = jnp.broadcast_to(
             jnp.arange(length, dtype=jnp.int32), (1, length)
         )
-        logits, fresh = self._prefill_fn(
+        logits, fresh = self._run(
+            "prefill", f"{length}x1", self._prefill_fn,
             self.params, prompt, positions,
             jnp.asarray([length - 1], jnp.int32), self._next_rng(),
         )
@@ -3098,7 +3164,8 @@ class ServingEngine:
             assert slot is not None, "scheduler admitted beyond free slots"
             slots[i] = slot
         positions, last_idx = padded_prefill_inputs(lengths, width)
-        logits, fresh = self._prefill_fn(
+        logits, fresh = self._run(
+            "prefill", f"{width}x{nb}", self._prefill_fn,
             self.params, jnp.asarray(tokens), positions, last_idx,
             self._next_rng(),
         )
@@ -3156,7 +3223,8 @@ class ServingEngine:
             slots[i] = slot
         base, last_idx = padded_prefill_inputs(rems, width)
         positions = jnp.where(base >= 0, base + prefix_len, -1)
-        logits, ext = self._extend_fn(
+        logits, ext = self._run(
+            "extend", f"{width}x{nb}", self._extend_fn,
             self.params, jnp.asarray(tokens), positions, last_idx,
             jnp.full((nb,), prefix_len, jnp.int32), stacked,
             self._next_rng(),
@@ -3282,7 +3350,8 @@ class ServingEngine:
             table[i] = self.pool.block_table[slot]
         base, last_idx = padded_prefill_inputs(rems, width)
         positions = jnp.where(base >= 0, base + plen, -1)
-        logits, self.pool.cache = self._extend_fn(
+        logits, self.pool.cache = self._run(
+            "extend", f"{width}x{nb}", self._extend_fn,
             self.params, jnp.asarray(tokens), positions, last_idx,
             jnp.full((nb,), plen, jnp.int32), self.pool.cache,
             self._next_rng(), jnp.asarray(table),
@@ -3331,7 +3400,8 @@ class ServingEngine:
             table = jnp.asarray(self.pool.block_table[slot : slot + 1])
         else:
             rows, table = self.pool.extract(slot), None
-        logits, rows = self._extend_fn(
+        logits, rows = self._run(
+            "extend", f"{width}x1", self._extend_fn,
             self.params, jnp.asarray(tokens), positions, last_idx,
             jnp.asarray([offset], jnp.int32), rows, self._next_rng(), table,
         )
@@ -3500,7 +3570,8 @@ class ServingEngine:
         for i, out in enumerate(outs):
             sp = out.request.sampling
             temp[i], topk[i], topp[i] = sp.temperature, sp.top_k, sp.top_p
-        return self._sample_fn(
+        return self._run(
+            None, "", self._sample_fn,
             logits,
             self._next_rng(),
             jnp.asarray(temp),
@@ -3616,7 +3687,8 @@ class ServingEngine:
                 w = int(self._widx[slot])
                 if w < seq_len:
                     self.pool.ensure_writable(slot, w, w + 1)
-        nxt, self.pool.cache = self._decode_fn(
+        nxt, self.pool.cache = self._run(
+            "tick", "1", self._decode_fn,
             self.params,
             jnp.asarray(self._tok),
             jnp.asarray(self._pos),
@@ -3761,7 +3833,8 @@ class ServingEngine:
         if self._state_dirty or self._dev_state is None:
             self._upload_slot_state()
         self._ensure_decode_writable(p, self._fused_steps)
-        block, counts, self._dev_state, self.pool.cache = self._fused_fn(
+        block, counts, self._dev_state, self.pool.cache = self._run(
+            "tick", str(self._fused_steps), self._fused_fn,
             self.params, self._dev_state, self._dev_knobs, self.pool.cache,
             self._next_rng(), self._device_table(),
         )
@@ -3827,7 +3900,9 @@ class ServingEngine:
             self._upload_slot_state()
         chunk_ops = self._build_chunk_block(p)
         self._ensure_decode_writable(p, self._fused_steps)
-        out = self._unified_fn(
+        out = self._run(
+            "tick_chunk", f"{self._fused_steps}+{self._chunk_tokens}",
+            self._unified_fn,
             self.params, self._dev_state, self._dev_knobs, chunk_ops,
             self.pool.cache, self._next_rng(), self._device_table(),
         )
@@ -4041,7 +4116,8 @@ class ServingEngine:
                     w,
                     min(w + int(dlen[slot]) + 1, cfg.seq_len),
                 )
-        block, accepted, self.pool.cache = self._verify_fn(
+        block, accepted, self.pool.cache = self._run(
+            "tick", f"1x{k + 1}", self._verify_fn,
             self.params,
             jnp.asarray(self._tok),
             jnp.asarray(drafts),
@@ -4137,11 +4213,15 @@ class ServingEngine:
         )
         # the unified program takes the chunk operands after the knobs
         # and returns the activation row first; else they are one call
+        steps = f"{self._fused_steps}x{self._spec_width + 1}"
         if chunk_ops is None:
             fn, chunk, first = self._spec_fused_fn, (), (None,)
+            kind = "tick"
         else:
             fn, chunk, first = self._spec_unified_fn, (chunk_ops,), ()
-        *out, self._dev_state, self.pool.cache = fn(
+            kind, steps = "tick_chunk", f"{steps}+{self._chunk_tokens}"
+        *out, self._dev_state, self.pool.cache = self._run(
+            kind, steps, fn,
             self.params, self._dev_state, self._dev_knobs, *chunk,
             self.pool.cache, self._next_rng(), self._device_table(),
         )
@@ -4286,8 +4366,9 @@ class ServingEngine:
                     tokens[j, : whole[j]] = out.request.prompt[: whole[j]]
                     lengths[j] = whole[j]
                 positions, _ = padded_prefill_inputs(lengths, width)
-                fresh = self._block_prefill_fn(
-                    self.params, jnp.asarray(tokens), positions
+                fresh = self._run(
+                    "prefill", f"{width}x{nb}", self._block_prefill_fn,
+                    self.params, jnp.asarray(tokens), positions,
                 )[0]
                 self._prefill_shapes.add(("prefill", nb, width))
                 self.metrics.record_prefill_call(
@@ -4339,7 +4420,8 @@ class ServingEngine:
         (:func:`_block_decode_core`), cache and slot state donated."""
         if self._state_dirty or self._dev_state is None:
             self._upload_slot_state()
-        out = self._block_fn(
+        out = self._run(
+            "tick", str(self._fused_steps), self._block_fn,
             self.params, self._dev_state, self._dev_knobs, self.pool.cache,
             self._next_rng(), self._device_table(),
         )

@@ -22,7 +22,7 @@ unchanged — attributes read through to the registry instruments.
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -48,6 +48,12 @@ TICK_PHASES = (
     "between",
 )
 HOST_EXPOSED_PHASES = ("schedule", "deliver", "record", "between")
+
+# what the completion clock (obs/device_clock.py) says the device ran
+# (serving_device_seconds{program=...}): a decode tick that carried no
+# prompt tokens, the unified tick with a chunk, a whole-prompt prefill, a
+# remainder or chunk extend
+DEVICE_PROGRAMS = ("tick", "tick_chunk", "prefill", "extend")
 
 
 def percentile(values: Sequence[float], p: float) -> Optional[float]:
@@ -284,6 +290,22 @@ class ServingMetrics:
         # rows by (layer, held expert), summed over calls: its max over its
         # mean is the imbalance the grouped matmuls saw
         self._moe_rows: Optional[np.ndarray] = None
+        # the completion clock (obs/device_clock.py; written by its thread,
+        # read by summary()): device seconds by (program, shape), made at a
+        # shape's first completion; the seconds no program ran; entries a
+        # full queue dropped and waits that raised.  Beside them, on the
+        # engine's clock: when this record was opened (reset_metrics; None
+        # for an engine's first record, which nothing precedes), where its
+        # first interval began and its last ended, and the prompt tokens of
+        # the prefill CALLS (a chunk that rides a tick is in the tick)
+        self._device: Dict[Tuple[str, str], object] = {}
+        self._device_idle = r.counter("serving_device_idle_seconds_total")
+        self._device_dropped = r.counter("serving_device_clock_dropped_total")
+        self._device_faults = r.counter("serving_device_clock_faults_total")
+        self._device_opened: Optional[float] = None
+        self._device_first: Optional[float] = None
+        self._device_last: Optional[float] = None
+        self._prefill_call_real = 0
         self._t_start: Optional[float] = None
         self._t_last: Optional[float] = None
 
@@ -496,6 +518,7 @@ class ServingMetrics:
         self._prefill_calls.inc()
         self._prefill_chunks.inc(chunks)
         self._host_dispatches.inc()
+        self._prefill_call_real += real
         self.record_prefill_tokens(real, padded)
 
     def record_prefill_tokens(self, real: int, padded: int) -> None:
@@ -504,6 +527,48 @@ class ServingMetrics:
         block), which a recurrent layer pays for at full cost."""
         self._prefill_real.inc(real)
         self._prefill_padded.inc(padded)
+
+    def open_device_window(self, now: float) -> None:
+        """This record replaces another on a running engine at ``now``
+        (the engine's clock): what the device did before belongs to the
+        record before."""
+        self._device_opened = now
+
+    def record_device(
+        self, kind: str, shape: str, idle_from: Optional[float],
+        start: float, done: float,
+    ) -> None:
+        """One watched program, from the completion clock's thread: it ran
+        ``[start, done)`` on the engine's clock and the device had nothing
+        to run in ``[idle_from, start)`` (None: no gap).  What lies before
+        this record was opened is clipped off."""
+        opened = self._device_opened
+        if opened is not None:
+            start = max(start, opened)
+            done = max(done, start)
+        if idle_from is None:
+            idle_from = start
+        elif opened is not None:
+            idle_from = min(max(idle_from, opened), start)
+        hist = self._device.get((kind, shape))
+        if hist is None:
+            hist = self._device[kind, shape] = self.registry.histogram(
+                "serving_device_seconds", program=kind, shape=shape
+            )
+        hist.observe(done - start)
+        self._device_idle.inc(start - idle_from)
+        if self._device_first is None:
+            self._device_first = idle_from if opened is None else opened
+        self._device_last = done
+
+    def record_device_dropped(self) -> None:
+        """The completion clock's queue was full: one program unwatched."""
+        self._device_dropped.inc()
+
+    def record_device_fault(self) -> None:
+        """A wait for a program raised (a deleted array, a failed
+        program): no completion was stamped."""
+        self._device_faults.inc()
 
     def set_state_bytes_per_slot(self, nbytes: int) -> None:
         self._state_bytes.set(nbytes)
@@ -684,6 +749,29 @@ class ServingMetrics:
         busy_ticks = decode_only.count + with_prefill.count
         phase_s = {n: h.sum for n, h in self._tick_phase.items()}
         all_phases_s = sum(phase_s.values())
+        # the completion clock: seconds and programs by kind; the record's
+        # elapsed time runs from its opening (its first interval, for an
+        # engine's first record) to its last completion, so that watched
+        # seconds + idle seconds = elapsed
+        device_s = dict.fromkeys(DEVICE_PROGRAMS, 0.0)
+        device_n = dict.fromkeys(DEVICE_PROGRAMS, 0)
+        by_shape = {}
+        for (kind, shape), h in list(self._device.items()):
+            device_s[kind] += h.sum
+            device_n[kind] += h.count
+            by_shape[f"{kind} {shape}"] = [h.count, round(h.sum, 6)]
+        elapsed = (
+            self._device_last - self._device_first
+            if self._device_last is not None
+            else 0.0
+        )
+        prefill_s = device_s["prefill"] + device_s["extend"]
+
+        def device_ms(kind):
+            return per_tick_ms(device_s[kind], device_n[kind])
+
+        def of_elapsed(seconds):
+            return round(seconds / elapsed, 6) if elapsed > 0 else None
         out = {
             "ticks": self.ticks,
             "decode_ticks": self.decode_ticks,
@@ -811,6 +899,25 @@ class ServingMetrics:
                 if all_phases_s > 0
                 else None
             ),
+            # the device, by program (the completion clock): a decode
+            # tick alone, a tick with a chunk, a whole-prompt prefill;
+            # prefill + extend seconds over the record's elapsed time and
+            # over the prompt tokens those calls computed; the seconds no
+            # program ran, over elapsed
+            "device_tick_ms_mean": device_ms("tick"),
+            "device_tick_chunk_ms_mean": device_ms("tick_chunk"),
+            "device_prefill_ms_mean": device_ms("prefill"),
+            "device_prefill_share": of_elapsed(prefill_s),
+            "device_prefill_ms_per_ktok": (
+                round(1e6 * prefill_s / self._prefill_call_real, 4)
+                if self._prefill_call_real and prefill_s > 0
+                else None
+            ),
+            "device_idle_share": of_elapsed(float(self._device_idle.value)),
+            "device_programs": sum(device_n.values()),
+            "device_clock_dropped": int(self._device_dropped.value),
+            # "<program> <shape>" -> [calls, seconds]: the split by bucket
+            "device_by_shape": by_shape,
             "tokens_per_sec": (
                 round(self.throughput(), 1)
                 if self.throughput() is not None
