@@ -42,6 +42,14 @@ where the budget cuts it, is left out: what the program held in its
 undelivered positions is not known to anyone but the program.  ``--control
 1`` also reads the control, the reference with float8 operands: what IT would
 fill and with what, read in the fp32 logits and confidences.
+
+The comparison's device memory is bounded and read (PR 43): the replay holds
+one layer at a time with nothing in flight when weights are made
+(``reference/sdar_moe_ref.py``), :class:`MemoryWatch` samples ``bytes_in_use``
+at each draw and layer boundary, and ``run.facts["comparison_memory"]``
+carries the sampled peak beside ``replay_bytes_bound`` of the mix's worst
+sample; ``lib/serve_window.py`` logs both.  ``benchmarks/compare_blockgen.py``
+runs this comparison alone, on streams made from a seed.
 """
 
 import os
@@ -118,6 +126,49 @@ def reference_shape(config: dict) -> dict:
     }
 
 
+def replay_bound(config: dict, mix: dict, streams: int) -> int:
+    """``replay_bytes_bound`` of the worst sample a mix allows: ``streams``
+    streams of the longest prompt with the longest answer, at the most
+    denoising steps a block; sizes from the configuration's published keys."""
+    sizes = {
+        "d_model": config["hidden_size"],
+        "n_heads": config["num_attention_heads"],
+        "n_kv_heads": config["num_key_value_heads"],
+        "head_dim": config["head_dim"],
+        "experts": config["num_experts"],
+        "expert_width": config["moe_intermediate_size"],
+        "vocab_size": config["vocab_size"],
+        "block_len": config["model"]["block_len"],
+    }
+    generated = mix["output_tokens"]["max"]
+    return sdar_moe_ref.replay_bytes_bound(
+        sizes, mix["prompt_tokens"]["max"] + generated, generated, streams,
+        max(mix["request_knobs"]["denoising_steps"]["values"]),
+        pad=REFERENCE_PAD, passes=2,
+    )
+
+
+class MemoryWatch:
+    """``bytes_in_use`` of the first device at the points it is called at.
+    The replay calls it after each draw of weights and at each layer's end,
+    with nothing in flight, so the largest sample is the comparison's peak
+    up to one call's temporaries; the runtime's own ``peak_bytes_in_use`` is
+    a process's lifetime peak, and in a cell's run the engine's stands in
+    it."""
+
+    def __init__(self):
+        self.device = jax.local_devices()[0]
+        self.samples = []  # (where, bytes in use)
+
+    def __call__(self, where: str) -> None:
+        stats = self.device.memory_stats() or {}  # None off the chip
+        self.samples.append((where, stats.get("bytes_in_use", 0)))
+
+    @property
+    def peak(self) -> int:
+        return max((held for _, held in self.samples), default=0)
+
+
 class KnobTraffic:
     """``lib/traffic.py`` as ``lib/serve_window.py`` uses it, with the mix's
     ``request_knobs`` drawn: each knob's values in equal shares over the
@@ -190,6 +241,26 @@ class StreamProbe:
         return ([self.longest] if self.longest else []) + self.rest
 
 
+def describe(run):
+    """The cell's model and the shape of its parameters, no weights made:
+    what ``compare`` needs of ``built``."""
+    from tpu_parallel.models import GPTLM
+
+    cfg = model_config(run.config, run.cell["engine"])
+    model = GPTLM(cfg)
+    abstract = jax.eval_shape(
+        lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 16), jnp.int32),
+            train=False,
+        )
+    )["params"]
+    return types.SimpleNamespace(
+        model=model, cfg=cfg, abstract=abstract,
+        served=getattr(jnp, run.cell["engine"]["served_parameters"]),
+        vocab=run.traffic["token_ids"]["below"],
+    )
+
+
 class BlockGen:
     """What ``lib/serve_window.py`` asks of a family of model."""
 
@@ -206,22 +277,11 @@ class BlockGen:
     )
 
     def build(self, run):
-        from tpu_parallel.models import GPTLM
-
-        cfg = model_config(run.config, run.cell["engine"])
-        model = GPTLM(cfg)
-        abstract = jax.eval_shape(
-            lambda: model.init(
-                {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 16), jnp.int32),
-                train=False,
-            )
-        )["params"]
-        served = getattr(jnp, run.cell["engine"]["served_parameters"])
-        return types.SimpleNamespace(
-            model=model, cfg=cfg, abstract=abstract, served=served,
-            vocab=run.traffic["token_ids"]["below"],
-            params=sdar_weights.make_params(run.seed, abstract, dtype=served),
+        built = describe(run)
+        built.params = sdar_weights.make_params(
+            run.seed, built.abstract, dtype=built.served
         )
+        return built
 
     def engine_built(self, run, engine):
         run.log(f"block_plan: {engine.block_plan}")
@@ -354,17 +414,20 @@ def compare(run, held, ended, requests, built) -> None:
         for s in sample
     ]
 
-    def reference(precision):
-        weights = sdar_weights.to_reference(
-            run.seed, built.abstract, built.cfg.n_heads, built.cfg.n_kv_heads,
-            dtype=built.served,
-        )
-        return weights, sdar_moe_ref.replay(
-            weights, streams, shape, mask_id, precision, pad=REFERENCE_PAD
+    watch = MemoryWatch()
+    made = (run.seed, built.abstract, built.cfg.n_heads, built.cfg.n_kv_heads,
+            built.served)
+
+    def replay(weights, precision):
+        return sdar_moe_ref.replay(
+            weights, streams, shape, mask_id, precision, pad=REFERENCE_PAD,
+            watch=watch,
         )
 
     t0 = time.perf_counter()
-    weights, replayed = reference("float32")
+    weights = sdar_weights.to_reference(*made)
+    watch("top-level weights")
+    replayed = replay(weights, "float32")
     served = [{t: r["tokens"] for t in r["hidden"]} for r in replayed]
     # the program's choices are its fill steps: judge them by a confidence
     # that ranks the filled positions first
@@ -384,7 +447,8 @@ def compare(run, held, ended, requests, built) -> None:
         run.check(name, got[name], limits[name])
     if run.control:
         precision = run.cell["control_precision"]
-        _, low = reference(precision)
+        # a second pass over the layers, under the same top-level weights
+        low = replay(dict(weights, layers=sdar_weights.layers(*made)), precision)
         picks, confidences = [], []
         for r in low:
             reads = {
@@ -399,3 +463,14 @@ def compare(run, held, ended, requests, built) -> None:
                 + " ".join(f"{k}={v:.6g}" for k, v in ctl.items())
                 + f" (over its limit: {', '.join(over) or 'none'})")
         run.facts["control"] = {precision: dict(ctl, over=over)}
+    watch("numbers read")
+    run.facts["comparison_memory"] = {
+        "sampled_peak_bytes": watch.peak,
+        "bound_bytes": replay_bound(
+            run.config, run.traffic, run.cell["reference_streams"]
+        ),
+    }
+    if watch.peak:  # the CPU's runtime reports none
+        run.log("comparison memory, GB in use: " + ", ".join(
+            f"{where} {held / 1e9:.2f}" for where, held in watch.samples
+        ))

@@ -41,6 +41,7 @@ import shutil
 import tempfile
 import threading
 import time
+import weakref
 
 import jax
 
@@ -273,8 +274,56 @@ def run(run, family) -> None:
     # -- the reference, once the engine and its weights are freed -----------
     engines.clear()
     built.params = None
+    gone = weakref.ref(engine)
     del engine, daemon, server, frontend_factory
-    gc.collect()
+    device = jax.local_devices()[0]
+    wait_freed(run, gone, device)
+    before = device.memory_stats() or {}
     t_ref = time.perf_counter()
     family.compare(run, ended, requests, built)
     run.log(f"reference and comparison: {time.perf_counter() - t_ref:.1f}s")
+    # what the comparison held: the runtime's peak is a process's lifetime
+    # peak and the engine's stands in it, so a family that samples its own
+    # (and states a bound) leaves both under run.facts["comparison_memory"]
+    after = device.memory_stats() or {}
+    memory = run.facts.setdefault("comparison_memory", {})
+    memory.update(
+        bytes_limit=after.get("bytes_limit", 0),
+        in_use_before=before.get("bytes_in_use", 0),
+        lifetime_peak_before=before.get("peak_bytes_in_use", 0),
+        lifetime_peak_after=after.get("peak_bytes_in_use", 0),
+    )
+    gb = lambda key: (f"{memory[key] / 1e9:.2f} GB" if memory.get(key)
+                      else "not read")
+    run.log(f"comparison memory: sampled peak {gb('sampled_peak_bytes')}, "
+            f"bound {gb('bound_bytes')}, limit {gb('bytes_limit')}; "
+            f"{gb('in_use_before')} in use when it began; the process's "
+            f"peak {gb('lifetime_peak_before')} before it and "
+            f"{gb('lifetime_peak_after')} after")
+
+
+def wait_freed(run, gone, device, patience_s: float = 60.0) -> None:
+    """Collect until the engine (``gone``, a weak reference) is no more, and
+    say what the device still holds.  One collection is not always enough: a
+    handler thread of the HTTP server that is still parked on its stream's
+    queue (a keep-alive period, 2 s) holds the daemon and with it the engine,
+    weights and pool, and the comparison then starts on a chip that is three
+    quarters full and runs out of memory (PR 43)."""
+    t0 = time.perf_counter()
+    rounds, holders = 0, []
+    while True:
+        gc.collect()
+        rounds += 1
+        if gone() is None or time.perf_counter() - t0 > patience_s:
+            break
+        if not holders:
+            holders = sorted(
+                t.name for t in threading.enumerate()
+                if t is not threading.current_thread()
+            )
+        time.sleep(0.25)
+    stats = device.memory_stats() or {}
+    run.log(f"engine {'freed' if gone() is None else 'STILL REFERENCED'} after "
+            f"{rounds} collection(s) in {time.perf_counter() - t0:.2f}s: "
+            f"{stats.get('bytes_in_use', 0) / 1e9:.2f} GB in use"
+            + (f"; threads alive after the first: {holders}" if holders else ""))

@@ -73,6 +73,14 @@ sequence once (keeping each layer's keys and values) and then, for each step
 index, the generated blocks as they stood at that step (a block sees the
 clean blocks before it and itself), so the reference reads the logits the
 program's forward of that step should have produced.
+
+**What it holds on the device.**  :func:`replay` and :func:`forward` wait for
+every call before they make the next (nothing is in flight when weights are
+drawn) and drop a layer's weights before they ask ``weights["layers"]`` for
+the next, so whatever the streams and however fast the host: the top-level
+weights, ONE layer, the streams' own rows, and either a layer in the making
+or one call's outputs and temporaries.  :func:`replay_bytes_bound` is that
+sum as a function of the shapes.
 """
 
 import functools
@@ -252,8 +260,14 @@ def forward(weights, tokens, shape, precision="float32", rows=None):
     frozen = _frozen(shape)
     with jax.default_matmul_precision("highest"):
         x = weights["embed"][jnp.asarray(tokens)]
-        for lw in weights["layers"]:
-            x, _, _ = _layer_jit(x, lw, shape=frozen, precision=precision)
+        layers = iter(weights["layers"])
+        lw = next(layers, None)
+        while lw is not None:
+            x, _, _ = jax.block_until_ready(
+                _layer_jit(x, lw, shape=frozen, precision=precision)
+            )
+            del lw  # gone before the next is made
+            lw = next(layers, None)
         if rows is not None:
             x = x[rows]
         return _head_jit(
@@ -329,7 +343,8 @@ def generate(weights, prompt, shape, mask_id: int, max_new_tokens: int,
     return out, out_steps
 
 
-def replay(weights, streams, shape, mask_id: int, precision="float32", pad=256):
+def replay(weights, streams, shape, mask_id: int, precision="float32", pad=256,
+           watch=None):
     """The final hidden rows of what the program's forwards should have seen.
 
     ``streams``: ``[{"prompt": [...], "tokens": [...], "fill_steps": [...]}]``
@@ -340,7 +355,10 @@ def replay(weights, streams, shape, mask_id: int, precision="float32", pad=256):
     stood at step ``t`` (a position filled at a step ``< t``, or a prompt's
     tail, holds its token, every other the mask id) go through them seeing
     the clean keys before their block and themselves.  With the layers
-    outermost: ``weights["layers"]`` is walked once.
+    outermost: ``weights["layers"]`` is walked once, one layer held at a
+    time, every call waited for (the module's docstring); ``watch(where)``,
+    if given, is called after each draw of a layer and at each layer's end,
+    with nothing in flight.
 
     Returns, a stream, ``{"positions": [R] (the rows' positions), "tokens":
     [R] served ids, "fill_steps": [R] (-1: a prompt's tail), "hidden": {t:
@@ -370,21 +388,90 @@ def replay(weights, streams, shape, mask_id: int, precision="float32", pad=256):
         xs = [weights["embed"][w["clean"]] for w in work]
         hs = [{t: weights["embed"][ids] for t, ids in w["noisy"].items()}
               for w in work]
-        for lw in weights["layers"]:
+        watch = watch or (lambda where: None)
+        layers = iter(weights["layers"])
+        lw, depth = next(layers, None), 0
+        while lw is not None:
+            watch(f"layer {depth} drawn")
             for i, w in enumerate(work):
-                xs[i], k, v = _layer_jit(
+                xs[i], k, v = jax.block_until_ready(_layer_jit(
                     xs[i], lw, shape=frozen, precision=precision
-                )
+                ))
                 for t in hs[i]:
-                    hs[i][t] = _layer_over_jit(
+                    hs[i][t] = jax.block_until_ready(_layer_over_jit(
                         hs[i][t], w["positions"], k, v, lw, shape=frozen,
                         precision=precision,
-                    )
+                    ))
+            watch(f"layer {depth} done")
+            del lw  # gone before the next is made
+            lw, depth = next(layers, None), depth + 1
     return [
         {"positions": np.asarray(w["positions"]), "tokens": w["tokens"],
          "fill_steps": w["fill_steps"], "hidden": hs[i]}
         for i, w in enumerate(work)
     ]
+
+
+def replay_bytes_bound(sizes, longest: int, generated: int, streams: int,
+                       steps: int, pad: int = 256, served_bytes: int = 2,
+                       passes: int = 1) -> int:
+    """The most device memory :func:`replay` holds at once, in bytes, from
+    shapes alone: ``streams`` streams of at most ``longest`` positions of
+    which at most ``generated`` were generated, ``steps`` step indices a
+    block, layers made in a type of ``served_bytes`` a value.  Not a
+    function of the number of layers: one is held at a time.
+
+    ``sizes``: ``d_model``, ``n_heads``, ``n_kv_heads``, ``head_dim``,
+    ``experts``, ``expert_width``, ``vocab_size``, ``block_len`` (the
+    configuration file's ``model`` group).  Everything float32 but a layer in
+    the making:
+
+    - the top-level weights: embedding, head, final norm;
+    - one layer;
+    - the streams' rows: a stream's clean residual ``[T, d]`` (``T`` is
+      ``longest`` padded) and ``steps`` noisy ones ``[R, d]`` (``R``: the
+      generated positions and a prompt's tail, under a block), one stream's
+      clean keys and values, and with ``passes`` > 1 (the control reads a
+      second pass against the first) the earlier passes' noisy rows;
+    - and the larger of a layer IN THE MAKING (the tree as it was made, let
+      go leaf by leaf while the float32 copy, counted above, grows) and ONE
+      CALL's outputs and temporaries: scores and probabilities of one query
+      block over all keys, the keys and values repeated to every query head
+      with their rounded copies, eight arrays of the rows' size.  A
+      described v5e's compiler gives the calls of the cell's worst sample
+      (``layer`` over 4096 rows, ``layer_over`` of 512 over 4608 keys) 0.82
+      and 0.31 GB in float32 and 1.34 GB as the float8 control (sandbox
+      AOT, PR 43); this counts 1.93 and 0.98.
+
+    The reads that follow (:func:`read`: ``[R, V]`` logits, a rounded copy of
+    the head in the control) hold no layer and stay under the last term.
+    """
+    d, heads, kv, hd = (sizes[k] for k in
+                        ("d_model", "n_heads", "n_kv_heads", "head_dim"))
+    experts, width, vocab = (sizes[k] for k in
+                             ("experts", "expert_width", "vocab_size"))
+    f32 = 4
+    top = f32 * (2 * vocab * d + d)
+    layer_values = (2 * d * heads * hd + 2 * d * kv * hd + 2 * hd + 2 * d
+                    + d * experts + 3 * experts * d * width)
+    padded = -(-longest // pad) * pad
+    rows = generated + sizes["block_len"]
+    held = f32 * (
+        streams * d * (padded + steps * rows) + 2 * padded * kv * hd
+        + (passes - 1) * streams * steps * rows * d
+    )
+
+    def call(rows, keys):
+        return f32 * (
+            rows * d + 2 * rows * kv * hd
+            + 2 * heads * min(rows, QUERY_BLOCK) * keys
+            + 4 * keys * heads * hd + 8 * rows * max(d, heads * hd)
+        )
+
+    return top + f32 * layer_values + held + max(
+        served_bytes * layer_values, call(padded, padded),
+        call(rows, padded + rows),
+    )
 
 
 def read(weights, hidden, pick, shape, precision="float32"):

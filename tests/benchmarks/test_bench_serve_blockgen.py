@@ -11,6 +11,7 @@ import json
 import os
 import sys
 import threading
+import time
 import types
 
 import numpy as np
@@ -315,3 +316,243 @@ def test_the_readers_on_hand_made_facts():
     assert share.read(run) == pytest.approx(5.0)
     run.facts = {}
     assert reader.read(run) is None and share.read(run) is None
+
+
+# -- the comparison's device memory (PR 43) ------------------------------------
+
+
+@pytest.fixture(scope="module")
+def toy_reference():
+    """The toy cell's model as the comparison sees it: what ``to_reference``
+    takes (weights made in bfloat16, as served), the reference's ``shape``
+    and two streams of different padded shapes, at 2 and 4 steps a block."""
+    import compare_blockgen
+
+    run = types.SimpleNamespace(
+        config=bench_tiny_blockgen.CONFIG, cell=bench_tiny_blockgen.SERVE_CELL,
+        traffic=bench_tiny_blockgen.TRAFFIC,
+    )
+    built = serve_blockgen.describe(run)
+    made = (SEED, built.abstract, built.cfg.n_heads, built.cfg.n_kv_heads,
+            built.served)
+    streams = []
+    for positions, generated, steps in ((27, 17, 2), (44, 24, 4)):
+        s = compare_blockgen.make_streams(
+            SEED + positions, 1, positions, generated, steps, 4, 250
+        )[0]
+        streams.append({"prompt": s.prompt, "tokens": s.tokens,
+                        "fill_steps": s.fill_steps})
+    return types.SimpleNamespace(
+        made=made, streams=streams, mask_id=built.cfg.mask_token_id,
+        shape=serve_blockgen.reference_shape(run.config),
+    )
+
+
+def test_replay_gives_the_rows_of_the_plain_walk(toy_reference):
+    """``replay`` (layers outermost, one held at a time, every call jitted
+    and waited for) against the plain way: every layer's weights held at
+    once in a list, one stream at a time through the un-jitted ``layer`` /
+    ``layer_over``, stream by stream and step by step."""
+    import jax
+    import jax.numpy as jnp
+
+    from lib import sdar_weights
+    from reference import sdar_moe_ref as ref
+
+    toy, pad, size = toy_reference, 16, 4
+    weights = sdar_weights.to_reference(*toy.made)
+    replayed = ref.replay(weights, toy.streams, toy.shape, toy.mask_id, pad=pad)
+    every_layer = list(sdar_weights.layers(*toy.made))
+    assert len(every_layer) == 3
+    assert [len(r["hidden"]) for r in replayed] == [2, 4]
+    assert len({-(-(len(s["prompt"]) + len(s["tokens"])) // size * size // pad)
+                for s in toy.streams}) == 2  # two padded shapes
+    with jax.default_matmul_precision("highest"):
+        for stream, got in zip(toy.streams, replayed):
+            seq = list(stream["prompt"]) + list(stream["tokens"])
+            first = len(stream["prompt"]) // size * size
+            seq = seq[:len(seq) // size * size]
+            steps = np.array(
+                ([-1] * (len(stream["prompt"]) - first)
+                 + list(stream["fill_steps"]))[:len(seq) - first]
+            )
+            padded = seq + [0] * (-len(seq) % pad)
+            x = weights["embed"][jnp.asarray(padded)]
+            noisy = {
+                t: weights["embed"][jnp.asarray(
+                    np.where(steps < t, np.array(seq[first:]), toy.mask_id)
+                )] for t in range(int(steps.max()) + 1)
+            }
+            positions = jnp.arange(first, len(seq))
+            for lw in every_layer:
+                x, k, v = ref.layer(x, lw, toy.shape)
+                noisy = {t: ref.layer_over(h, positions, k, v, lw, toy.shape)
+                         for t, h in noisy.items()}
+            assert set(got["hidden"]) == set(noisy)
+            for t, rows in noisy.items():
+                assert float(jnp.std(rows)) > 0.1  # the rows carry weight
+                np.testing.assert_allclose(got["hidden"][t], rows, atol=1e-5)
+
+
+def test_no_earlier_layer_is_alive_when_a_layer_is_drawn(toy_reference, monkeypatch):
+    """Every draw of weights records what is alive on the device
+    (``jax.live_arrays()``): when layer 1, 2, ... is drawn, nothing of an
+    earlier layer is, neither its float32 weights (the loop drops its layer
+    before it asks for the next) nor the tree it was made as (let go leaf by
+    leaf as its float32 copy is ready): the draws after the first read what
+    the first read, the top-level weights and the streams' rows.  On the
+    files as they were before PR 43 this FAILS (read there: 1.5 layers'
+    worth over the first draw at every later one, the previous layer's
+    float32 weights bound in ``replay``'s loop and its bfloat16 tree, half
+    as large, bound in the generator's frame; 0.04 on PR 43's)."""
+    import jax
+
+    from lib import sdar_weights
+    from reference import sdar_moe_ref as ref
+
+    toy = toy_reference
+    alive = []
+    real = sdar_weights.make_params
+
+    def make_params(*args, **kw):
+        alive.append(sum(a.nbytes for a in jax.live_arrays()))
+        return real(*args, **kw)
+
+    weights = sdar_weights.to_reference(*toy.made)
+    monkeypatch.setattr(sdar_weights, "make_params", make_params)
+    replayed = ref.replay(weights, toy.streams, toy.shape, toy.mask_id, pad=16)
+    assert len(alive) == 3 and len(replayed) == 2
+    one_layer = sum(
+        a.nbytes for a in jax.tree.leaves(
+            sdar_weights.layer_weights(*toy.made[:2], 0, *toy.made[2:])
+        )
+    )
+    over = [(held - alive[0]) / one_layer for held in alive[1:]]
+    assert all(abs(x) < 0.25 for x in over), over
+    # and forward(), which generate() and tests/test_block_diffusion.py use
+    del alive[:]
+    weights = dict(weights, layers=sdar_weights.layers(*toy.made))
+    ref.forward(weights, list(toy.streams[0]["prompt"]), toy.shape)
+    over = [(held - alive[0]) / one_layer for held in alive[1:]]
+    assert len(alive) == 3 and all(abs(x) < 0.25 for x in over), over
+
+
+def test_the_replays_bound_at_the_cells_shapes():
+    """``replay_bytes_bound`` of the cell's published sizes and the worst
+    sample of its mix (eight streams of 3072 + 512 positions, four steps a
+    block) leaves a quarter of the chip free, with and without the control's
+    second pass; it grows with the longest stream and not with the layers."""
+    from reference import sdar_moe_ref as ref
+
+    config = json.load(open(os.path.join(
+        REPO, "benchmarks", "configs", "sdar_30b_a3b_depth6.json"
+    )))
+    mix = json.load(open(os.path.join(
+        REPO, "benchmarks", "traffic", "blockgen.json"
+    )))
+    cell = json.load(open(os.path.join(
+        REPO, "benchmarks", "workloads", f"{CELL}.json"
+    )))
+    limit = 16_909_336_064  # the runtime's bytes_limit of one v5e chip
+    sizes = dict(config["model"], n_layers=None)
+    worst = dict(longest=3072 + 512, generated=512, streams=8, steps=4,
+                 pad=serve_blockgen.REFERENCE_PAD)
+    one = ref.replay_bytes_bound(sizes, **worst)
+    two = ref.replay_bytes_bound(sizes, passes=2, **worst)
+    assert 6.0e9 < one < two < 0.75 * limit
+    assert two == serve_blockgen.replay_bound(config, mix, cell["reference_streams"])
+    deeper = dict(config, num_hidden_layers=48)
+    assert serve_blockgen.replay_bound(deeper, mix, 8) == two
+    assert ref.replay_bytes_bound(sizes, **dict(worst, longest=2048)) < one
+    assert ref.replay_bytes_bound(sizes, **dict(worst, streams=16)) > one
+    # the terms a reader can check by hand: top-level weights and one layer
+    top = 4 * (2 * 151936 * 2048 + 2048)
+    layer = 4 * config["model"]["parameters_per_layer"]
+    assert one > top + layer + 2 * config["model"]["parameters_per_layer"]
+
+
+def test_the_comparison_alone_repeats_its_numbers(root, capsys):
+    """``benchmarks/compare_blockgen.py`` on the toy cell: streams from the
+    seed in the shape the probe hands over, the cell's own ``compare``, the
+    same four numbers in every repeat, the bound beside them."""
+    import compare_blockgen
+
+    held = compare_blockgen.make_streams(9, 2, 30, 20, 2, 4, 250)
+    assert [len(s.prompt) for s in held] == [10, 10]
+    # the first block holds the prompt's tail of 2: its two masked positions
+    # are what step 0 fills
+    assert held[0].fill_steps[:2] == [0, 0] and len(held[0].tokens) == 18
+    assert all(sorted(held[1].fill_steps[i:i + 4]) == [0, 0, 1, 1]
+               for i in range(2, 18, 4))
+    records = compare_blockgen.alone(
+        SEED, streams=3, positions=40, steps=4, repeats=2,
+        workload=bench_tiny_blockgen.CELL, root=root,
+        bench_dir=os.path.join(root, "benchmarks"),
+    )
+    assert len(records) == 2
+    assert records[0]["numbers"] == records[1]["numbers"]
+    assert set(records[0]["numbers"]) == set(
+        bench_tiny_blockgen.SERVE_CELL["limits"]
+    )
+    assert records[0]["bound_bytes"] > 0
+    assert "comparison alone: serve-tiny_blockgen" in capsys.readouterr().out
+
+
+def test_a_run_logs_what_its_comparison_held(root, capsys):
+    """``lib/serve_window.py`` says, after the comparison, what it held: the
+    family's sampled peak and bound (the CPU's runtime reports no bytes in
+    use: "not read") beside the process's own counters; nothing is added to
+    what decides ``correct``."""
+    out = drive(root, seconds=1.0)
+    text = capsys.readouterr().out
+    line = next(l for l in text.splitlines()
+                if l.startswith("comparison memory: "))
+    assert "sampled peak not read, bound 0.0" in line and "limit not read" in line
+    assert text.index("reference and comparison:") < text.index(line)
+    assert out["correct"] is True
+    assert not any("memory" in name for name in checks(text))
+
+
+def test_the_comparison_waits_until_the_engine_is_gone():
+    """What PR 43 found on the chip: a handler thread of the HTTP server,
+    still parked on its stream's queue when the window's code lets the
+    engine go, holds the daemon and with it the engine (weights and pool,
+    11.95 GB), so ONE collection frees nothing and the comparison starts on
+    a full chip.  ``wait_freed`` collects until the engine is no more and
+    names the threads that were alive after the first collection."""
+    import weakref
+
+    from lib import serve_window
+
+    class Engine:
+        pass
+
+    engine = Engine()
+    engine.release_slot = lambda: engine  # a cycle, as the probe's wrapper makes
+    parked = threading.Thread(
+        target=lambda held: time.sleep(0.6), args=(engine,), name="parked-handler"
+    )
+    parked.start()
+    gone = weakref.ref(engine)
+    del engine
+    said = []
+    run = types.SimpleNamespace(log=said.append)
+    device = types.SimpleNamespace(memory_stats=lambda: None)
+    t0 = time.perf_counter()
+    serve_window.wait_freed(run, gone, device)
+    assert gone() is None and 0.4 < time.perf_counter() - t0 < 5
+    assert said[0].startswith("engine freed after ") and len(said) == 1
+    assert int(said[0].split("after ")[1].split()[0]) >= 2
+    assert "threads alive after the first: ['parked-handler']" in said[0]
+    parked.join(timeout=5)
+    assert not parked.is_alive()
+    # nothing holds it: one collection, no thread named
+    engine = Engine()
+    gone = weakref.ref(engine)
+    del engine
+    serve_window.wait_freed(run, gone, device)
+    assert "after 1 collection(s)" in said[1] and "threads" not in said[1]
+    # never let go: it says so after its patience and goes on
+    engine = Engine()
+    serve_window.wait_freed(run, weakref.ref(engine), device, patience_s=0.3)
+    assert said[2].startswith("engine STILL REFERENCED after ")
