@@ -1,4 +1,5 @@
-"""Time the flash-attention kernels alone, on the chip, forward and backward apart.
+"""Time the attention kernels alone, on the chip: the flash kernels forward and
+backward apart, and (``--decode``) the decode kernel over the stored stripes.
 
 The tool of the tile sweep behind ``ops.flash_attention.flash_plan`` (PERF.md
 section 6, PR 27).  For each tile it runs the forward kernel and the backward
@@ -10,12 +11,26 @@ share of the least time the chip could take: ``benchmarks/lib/flops.py``'s
 count for causal attention (2 of its 7 matmuls forward, 5 backward) over
 ``benchmarks/lib/peaks.py``'s peak for the device.
 
+``--decode`` times ``ops.decode_attention.decode_stripes`` beside XLA's
+program of ``models.layers.decode_attention_xla`` at the decode shapes of the
+two serving cells whose heads are 128 wide (``DECODE_SHAPES``: the
+block-diffusion cell's step at 4 and 8 rows a slot, the expert cell's one row
+under its window and without), at tiles 128 / 512 / 1024, with stream lengths
+drawn as the cell's traffic draws them and with every stripe full.  A line a
+variant: milliseconds a layer-call on the device (all that ran there under
+the variant's own trace: the kernel with the few XLA ops around it), the share of the least
+time the walked tiles' bytes allow (K and V tiles inside the slots' ranges,
+the query and output rows, over the chip's HBM peak), the share of a stripe's
+tiles walked, and the largest difference from XLA's output over the rows
+that see a key.  It times nothing a cell runs.
+
 It refuses to run off the TPU: a CPU time says nothing about a kernel.
 
 Usage:
     python scripts/attn_microbench.py                     # the train cell's shape, derived tiles
     python scripts/attn_microbench.py --sweep             # + every tile 128-1024 each way
     python scripts/attn_microbench.py --seq 2048 --heads 6 --head-dim 128 --sweep
+    python scripts/attn_microbench.py --decode            # the decode kernel beside XLA's program
 """
 
 import argparse
@@ -39,6 +54,146 @@ SWEEP_TILES = (128, 256, 512, 1024)
 REPEATS = 5
 INTERPRET = False  # a rehearsal off the chip flips this; a measurement never
 
+DECODE_TILES = (128, 512, 1024)
+# name: slots, rows a slot, heads, K/V heads, stored positions, block rule,
+# windows, and the cell's stream lengths (a lognormal prompt, clipped, plus a
+# uniform share of the answer: ``benchmarks/traffic/{blockgen,longshort}.json``)
+DECODE_SHAPES = {
+    "blockgen_q4": dict(slots=64, new_len=4, heads=32, kv_heads=4,
+                        positions=4096, block_len=4, windows=(0,),
+                        prompt=(768, 0.8, 64, 3072), answer=512),
+    "blockgen_q8": dict(slots=64, new_len=8, heads=32, kv_heads=4,
+                        positions=4096, block_len=4, windows=(0,),
+                        prompt=(768, 0.8, 64, 3072), answer=512),
+    "longshort_q1": dict(slots=32, new_len=1, heads=16, kv_heads=1,
+                         positions=8192, block_len=0, windows=(0, 4096),
+                         prompt=(1536, 0.9, 128, 7680), answer=512),
+}
+
+
+def decode_main():
+    """The decode kernel beside XLA's program, a line a variant."""
+    import numpy as np
+
+    from lib.peaks import peaks
+    from tpu_parallel.runtime import require_tpu
+
+    require_tpu()
+    hbm = peaks(jax.devices()[0].device_kind)["hbm_bytes_per_s"]
+    rng = np.random.default_rng(0)
+    for name, shape in DECODE_SHAPES.items():
+        b, nq, h, h_kv = (shape[k] for k in ("slots", "new_len", "heads", "kv_heads"))
+        s, size, d = shape["positions"], shape["block_len"], 128
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        q = jax.random.normal(keys[0], (b, nq, h, d), jnp.bfloat16)
+        k = jax.random.normal(keys[1], (b, s, h_kv, d), jnp.bfloat16)
+        v = jax.random.normal(keys[2], (b, s, h_kv, d), jnp.bfloat16)
+        median, sigma, low, high = shape["prompt"]
+        drawn = np.clip(rng.lognormal(np.log(median), sigma, b), low, high)
+        drawn = drawn + rng.uniform(0, shape["answer"], b)
+        for lengths_name, lengths in (
+            ("traffic", np.minimum(drawn.astype(np.int64), s - nq)),
+            ("full", np.full(b, s - nq)),
+        ):
+            if size:  # a block step's rows start on a block
+                lengths = lengths // size * size
+            # the aligned table: column j holds position j up to the rows
+            # the step has just written
+            cols = np.arange(s)[None, :]
+            k_pos = jnp.asarray(
+                np.where(cols < (lengths + nq)[:, None], cols, -1), jnp.int32
+            )
+            pos = jnp.asarray(lengths[:, None] + np.arange(nq)[None, :], jnp.int32)
+            for window in shape["windows"]:
+                run_decode_variants(
+                    name, lengths_name, window, (q, k, v, pos, k_pos),
+                    size, lengths, hbm,
+                )
+
+
+def run_decode_variants(name, lengths_name, window, operands, size, lengths,
+                        hbm):
+    """XLA's program and the kernel at every tile over ``operands``, each
+    under a trace of its own."""
+    import numpy as np
+
+    from lib import xplane, xplane_scopes
+    from tpu_parallel.models.layers import _score_scale, decode_attention_xla
+    from tpu_parallel.ops import decode_attention as da
+
+    q, k, v, pos, k_pos = operands
+    (b, nq, h, d), (s, h_kv) = q.shape, k.shape[1:3]
+
+    def scoped(tag, fn):
+        def run(*a):
+            with jax.named_scope(tag):
+                return fn(*a)
+        return jax.jit(run)
+
+    def xla(q, k, v, pos, k_pos):
+        return decode_attention_xla(
+            q, k, v, pos, window=window, k_positions=k_pos, block_len=size
+        )
+
+    def kernel(q, k, v, pos, k_pos, tile):
+        lo, hi = da.visible_bounds(pos, window, size)
+        return da.decode_stripes(
+            q * _score_scale(None, d, q.dtype), k, v, lo, hi, k_pos, tile=tile,
+            interpret=INTERPRET,
+        )
+
+    jobs = [("mb_dec_xla", None, scoped("mb_dec_xla", xla))]
+    jobs += [
+        (f"mb_dec_kernel_{tile}", tile,
+         scoped(f"mb_dec_kernel_{tile}", functools.partial(kernel, tile=tile)))
+        for tile in DECODE_TILES
+    ]
+    outs = {}
+    for tag, _, fn in jobs:
+        outs[tag] = np.asarray(
+            jax.block_until_ready(fn(*operands)).astype(jnp.float32)
+        )  # compile + warm up
+    # a trace a job, and ALL the device's busy time in it: some of XLA's ops
+    # (the expert cell's shape: half its program) carry no scope to find
+    # them by
+    seconds = {}
+    for tag, _, fn in jobs:
+        logdir = tempfile.mkdtemp(prefix="attn_microbench_")
+        jax.profiler.start_trace(logdir)
+        for _ in range(REPEATS):
+            res = fn(*operands)
+        jax.block_until_ready(res)
+        jax.profiler.stop_trace()
+        seconds[tag] = xplane_scopes.by_pattern(
+            xplane.find_trace(logdir), [tag]
+        )["busy_s"]
+        shutil.rmtree(logdir, ignore_errors=True)
+    hi = lengths + nq - 1  # the last position a row of the slot sees
+    lo = np.maximum(lengths - window + 1, 0) if window else np.zeros_like(hi)
+    rows_bytes = 2 * q.size * q.dtype.itemsize  # the rows in, the rows out
+    for tag, tile, _ in jobs:
+        ms = seconds[tag] / REPEATS * 1e3
+        line = {
+            "shape": name, "lengths": lengths_name, "window": window,
+            "mean_length": round(float(lengths.mean()), 1),
+            "path": "kernel" if tile else "xla", "tile": tile,
+            "ms_per_layer_call": round(ms, 4),
+        }
+        if tile:
+            walked = int((hi // tile - lo // tile + 1).sum())
+            least = (
+                walked * tile * h_kv * d * k.dtype.itemsize * 2 + rows_bytes
+            ) / hbm * 1e3
+            line.update(
+                tiles_walked_share=round(walked / (b * (s // tile)), 4),
+                floor_ms=round(least, 4),
+                floor_pct=round(100 * least / ms, 2) if ms else None,
+                max_abs_diff_vs_xla=float(
+                    np.abs(outs[tag] - outs["mb_dec_xla"]).max()
+                ),
+            )
+        print(json.dumps(line), flush=True)
+
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -49,7 +204,12 @@ def main():
     ap.add_argument("--head-dim", type=int, default=64)
     ap.add_argument("--sweep", action="store_true",
                     help="also every tile of 128-1024 each way that divides seq")
+    ap.add_argument("--decode", action="store_true",
+                    help="the decode kernel over the stored stripes beside "
+                         "XLA's program, at the serving cells' decode shapes")
     args = ap.parse_args()
+    if args.decode:
+        return decode_main()
 
     from lib import flops, xplane
     from lib.peaks import peaks

@@ -422,6 +422,56 @@ def test_blockgen_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
     assert low < held < high, memory
 
 
+# name -> (slots, rows a slot, heads, K/V heads, stored positions, window,
+# block rule): the decode steps of the two serving cells with heads of 128
+DECODE_CASES = {
+    "blockgen_wide": (64, 8, 32, 4, 4096, 0, 4),
+    "blockgen_narrow": (64, 4, 32, 4, 4096, 0, 4),
+    "longshort_window": (32, 1, 16, 1, 8192, 4096, 0),
+    "longshort_full": (32, 1, 16, 1, 8192, 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_decode_kernel_compiles_for_v5e(case, v5e_chip, monkeypatch):
+    """``layers.decode_attention`` at a cell's decode shape, behind the
+    scatter that writes the step's K/V into the donated pool as the model
+    does: ONE kernel (``attn.decode_stripes``) that reads the pool as it is
+    stored.  The compiled step holds no copy of a stripe: its temporaries
+    stay under a tenth of one (the ``[slots, positions, kv_heads *
+    head_dim]`` view of 4 K/V heads re-tiled both stripes, 540 MB)."""
+    from tpu_parallel.models.layers import decode_attention
+
+    n, new, heads, kv, positions, window, block_len = DECODE_CASES[case]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    shaped = lambda dtype, *shape: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=v5e_chip
+    )
+
+    def step(q, k_all, v_all, k_pos, k, v, pos):
+        rows = jnp.arange(n)[:, None]
+        k_all, v_all = k_all.at[rows, pos].set(k), v_all.at[rows, pos].set(v)
+        k_pos = k_pos.at[rows, pos].set(pos)
+        out = decode_attention(
+            q, k_all, v_all, pos, window=window, k_positions=k_pos,
+            block_len=block_len,
+        )
+        return out, k_all, v_all, k_pos
+
+    bf16 = jnp.bfloat16
+    lowered = jax.jit(step, donate_argnums=(1, 2, 3)).lower(
+        shaped(bf16, n, new, heads, 128), shaped(bf16, n, positions, kv, 128),
+        shaped(bf16, n, positions, kv, 128), shaped(jnp.int32, n, positions),
+        shaped(bf16, n, new, kv, 128), shaped(bf16, n, new, kv, 128),
+        shaped(jnp.int32, n, new),
+    )
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    assert len(re.findall(r"%attn\.decode_stripes[.\d]* = ", compiled.as_text())) == 1
+    stripe = n * positions * kv * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < stripe // 10
+
+
 def test_compile_cache_honours_the_environment(monkeypatch, tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set, JAX already uses it and the code
     sets no directory of its own."""

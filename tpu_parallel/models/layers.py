@@ -459,7 +459,7 @@ def causal_attention(
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def decode_attention(
+def decode_attention_xla(
     q: jax.Array, k_all: jax.Array, v_all: jax.Array, positions: jax.Array,
     window: int = 0, bias: Optional[jax.Array] = None,
     k_positions: Optional[jax.Array] = None,
@@ -1110,10 +1110,10 @@ class Attention(nn.Module):
                     # positions (-1 never attends)
                     mapped = jnp.repeat(block_table >= 0, bt, axis=1)
                     k_pos = jnp.where(mapped, pages(new_p), -1)
-                # decode_attention contracts grouped queries against the
-                # kv-width cache directly — no K/V expansion
+                # grouped queries contract against the kv-width cache directly
+                attend = decode_attention_xla if paged else decode_attention
                 with self._scope():
-                    out = decode_attention(
+                    out = attend(
                         q, k_all, v_all, positions, window=self.window,
                         bias=attn_bias, k_positions=k_pos,
                         k_scale=k_scale, v_scale=v_scale,
@@ -1693,3 +1693,52 @@ class Embedding(nn.Module):
             # BERT's embeddings.LayerNorm over the summed embedding
             emb = make_norm(cfg, "norm")(emb).astype(cfg.dtype)
         return emb
+
+
+def decode_attention(
+    q: jax.Array, k_all: jax.Array, v_all: jax.Array, positions: jax.Array,
+    window: int = 0, bias: Optional[jax.Array] = None,
+    k_positions: Optional[jax.Array] = None,
+    k_scale: Optional[jax.Array] = None,
+    v_scale: Optional[jax.Array] = None,
+    scale: Optional[float] = None,
+    block_len: int = 0,
+) -> jax.Array:
+    """Attention of new queries against a full KV cache: the operands and the
+    result of :func:`decode_attention_xla`, which runs every shape but those
+    that ``ops.decode_attention.decode_attention_plan`` sends to the Pallas
+    kernel over the stored stripes (bfloat16 heads of a multiple of 128, no
+    bias, no int8 scales, at least two tiles of stored positions).  The rule
+    sees shapes and operand kinds only; both paths apply one mask
+    (``ops.decode_attention.visible_bounds``), the kernel to float32 scores
+    that never leave the chip.  A row that sees no key (a pad at -1, a parked
+    slot) gets zeros from the kernel and the mean of V from the ``jax.numpy``
+    body: it is discarded downstream either way.  The paged gather has copied
+    the stripe already and calls the ``jax.numpy`` body itself.
+
+    (Down here, below every frame that reaches a ``pallas_call``: a kernel's
+    bytes carry the line numbers of those frames, and a function added above
+    them would recompile every kernel program of every cell once a machine.)
+    """
+    from tpu_parallel.ops import decode_attention as stripes
+
+    plan = stripes.decode_attention_plan(
+        q.shape, k_all.shape, q.dtype, k_all.dtype,
+        bias=bias is not None, scales=k_scale is not None,
+    )
+    if plan is None:
+        return decode_attention_xla(
+            q, k_all, v_all, positions, window=window, bias=bias,
+            k_positions=k_positions, k_scale=k_scale, v_scale=v_scale,
+            scale=scale, block_len=block_len,
+        )
+    if k_positions is None:  # the aligned layout: slot j holds position j
+        k_positions = jnp.broadcast_to(
+            jnp.arange(k_all.shape[1]), k_all.shape[:2]
+        )
+    lo, hi = stripes.visible_bounds(positions, window, block_len)
+    return stripes.decode_stripes(
+        q * _score_scale(scale, q.shape[-1], q.dtype), k_all, v_all, lo, hi,
+        k_positions, tile=plan["tile"],
+        vmem_limit_bytes=plan["vmem_limit_bytes"],
+    )

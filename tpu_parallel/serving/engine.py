@@ -1279,7 +1279,7 @@ class _PendingTick:
         "kind", "start", "t0", "t1", "tick_span", "events", "admitted",
         "chunks_advanced", "chunk_tokens", "chunk_spans", "active_tokens",
         "entering", "finals", "payload", "overlapped", "phases", "between",
-        "expert_rows", "firsts", "owners",
+        "expert_rows", "firsts", "owners", "tiles",
     )
 
     def __init__(self):
@@ -1315,6 +1315,9 @@ class _PendingTick:
         # tick's collect (None: there was none, or it was not busy)
         self.phases: Dict[str, float] = {}
         self.between: Optional[float] = None
+        # (walked, held) stripe tiles of the decode kernel over the slots
+        # this tick entered with (None: no program uses the kernel)
+        self.tiles: Optional[Tuple[int, int]] = None
 
     def add_phase(self, name: str, seconds: float) -> None:
         self.phases[name] = self.phases.get(name, 0.0) + seconds
@@ -1983,6 +1986,7 @@ class ServingEngine:
         self.ssm_plan = self._plan_state(n_slots)
         self.sampler_plan = self._plan_sampler(n_slots)
         self.block_plan = self._plan_blocks(n_slots)
+        self.attn_plan = self._plan_attention(n_slots)
 
         n = n_slots
         self._tok = np.zeros(n, np.int32)
@@ -2033,6 +2037,77 @@ class ServingEngine:
                    for k, v in p.items()},
             )
         return plan
+
+    def _plan_attention(self, n_slots: int) -> Dict[str, dict]:
+        """What runs the attention of new rows against the stored stripes in
+        each decode program shape (``ops.decode_attention.
+        decode_attention_plan``, the rule ``layers.decode_attention`` applies
+        to the same shapes): ``kernel`` with its tile, tiles a stripe and rows
+        a call, or ``xla``; logged and put on the tracer once at build.  The
+        choice is static a shape; what moves at run time is how much of a
+        stripe is walked, which :meth:`_walked_tiles` counts a tick."""
+        from tpu_parallel.ops.decode_attention import decode_attention_plan
+
+        cfg = self.model.config
+        kv_heads = cfg.n_kv_heads or cfg.n_heads
+        shapes = {"decode": max(1, 2 * self._block_len)}
+        if self._block_len and self._fused_steps > 1:
+            shapes["decode_narrow"] = self._block_len
+        if self._chunk_tokens and self._unified:
+            shapes["chunk"] = self._chunk_tokens
+        dtype = jnp.dtype(cfg.dtype)
+        plan = {}
+        for name, new_len in shapes.items():
+            found = decode_attention_plan(
+                (n_slots, new_len, cfg.n_heads, cfg.head_dim),
+                (n_slots, cfg.seq_len, kv_heads, cfg.head_dim),
+                dtype, jnp.int8 if cfg.kv_cache_dtype == "int8" else dtype,
+                scales=cfg.kv_cache_dtype == "int8", paged=self._paged,
+                bias=cfg.positional == "relative",
+            )
+            plan[name] = {"path": "xla"} if found is None else {
+                "path": "kernel", "tile": found["tile"],
+                "tiles": found["tiles"], "rows": found["rows"],
+            }
+        # the windows of the attention layers, by how many layers have each
+        windows: Dict[int, int] = {}
+        for spec in cfg.layer_specs:
+            if spec.mixer == "attention":
+                window = spec.window if spec.attn == "window" else 0
+                windows[window] = windows.get(window, 0) + 1
+        self._attn_walk = None
+        if plan["decode"]["path"] == "kernel":
+            self._attn_walk = (
+                plan["decode"]["tile"], plan["decode"]["tiles"],
+                max(1, self._block_len), sorted(windows.items()),
+            )
+        logging.getLogger(__name__).info("attn_plan %s", json.dumps(plan))
+        if self.tracer.enabled:
+            self.tracer.instant(
+                "attn_plan", track="scheduler",
+                **{f"{name}_{k}": v for name, p in plan.items()
+                   for k, v in p.items()},
+            )
+        return plan
+
+    def _walked_tiles(self, slots) -> Optional[Tuple[int, int]]:
+        """``(walked, held)`` for one tick over the live ``slots``: the
+        tiles the decode kernel walks a layer-call, summed over the
+        attention layers, and ``live slots x tiles a stripe x layers``.
+        From the host's mirrors at launch (a slot's rows go in at
+        ``_pos``; a window layer starts at the first tile a row can see):
+        no device read.  None where no decode program uses the kernel."""
+        if self._attn_walk is None or not len(slots):
+            return None
+        tile, tiles, new_len, windows = self._attn_walk
+        pos = self._pos[list(slots)].astype(np.int64)
+        last = np.minimum((pos + new_len - 1) // tile, tiles - 1)
+        walked = held = 0
+        for window, layers in windows:
+            first = np.maximum(pos - window + 1, 0) // tile if window else 0
+            walked += layers * int((last - first + 1).sum())
+            held += layers * len(pos) * tiles
+        return walked, held
 
     def _plan_sampler(self, n_slots: int) -> Dict[str, object]:
         """What :func:`sample_tokens` is compiled for: rows x vocabulary
@@ -2434,6 +2509,7 @@ class ServingEngine:
             p.chunks_advanced = len(self._chunking)
         with self._phase(p, "dispatch") as dispatch:
             self._launch_decode(p)
+            p.tiles = self._walked_tiles(p.entering)
             # active tokens RESIDENT during this tick's decode = slots'
             # written depths + chunked prefills' post-advance offsets,
             # captured BEFORE delivery retires finished slots — the
@@ -2657,6 +2733,7 @@ class ServingEngine:
                     and out.request.sampling.temperature > 0.0
                     for out in p.owners
                 ),
+                tiles=p.tiles,
             )
             self._busy_end = end
             if p.between is not None and self.tracer.enabled:

@@ -256,6 +256,11 @@ class ServingMetrics:
         # the sampler (engine.sample_tokens) sorts and draws only where a
         # live row is sampled: the busy ticks launched with such an owner
         self._sampler_ticks = r.counter("serving_sampler_draw_ticks_total")
+        # the decode kernel over the stored stripes (ops/decode_attention)
+        # walks the tiles a slot holds: walked and held a busy tick, by the
+        # host's mirrors at launch (both stay 0 where no program uses it)
+        self._tiles_walked = r.counter("serving_decode_tiles_walked_total")
+        self._tiles_held = r.counter("serving_decode_tiles_held_total")
         # per-tick stall attribution, pre-registered so every cause shows
         # a (possibly zero) series in exports
         self._stall = {
@@ -452,6 +457,7 @@ class ServingMetrics:
         ahead: bool = False,
         hidden=(),
         sampled: bool = False,
+        tiles: Optional[tuple] = None,
     ) -> None:
         """One BUSY tick (it dispatched decode work): its period
         ``seconds`` — from its launch's entry to its collect's exit, or
@@ -462,7 +468,10 @@ class ServingMetrics:
         names the phases that ran beside a tick in flight: they count in
         their series, and not towards ``host_exposed_share``.  ``sampled``
         says that some slot's owner at the launch had a temperature, so
-        the tick's sampler could have drawn (the device decides).  Idle ticks
+        the tick's sampler could have drawn (the device decides).  ``tiles``
+        is ``(walked, held)``, the stripe tiles the decode kernel walked a
+        layer-call over the live slots and those the slots hold in all (None
+        where no program uses the kernel).  Idle ticks
         are left out (they would pull every mean toward the cost of doing
         nothing)."""
         self._busy_tick["1" if prefill else "0"].observe(seconds)
@@ -476,6 +485,9 @@ class ServingMetrics:
             self._overlapped.inc()
         if sampled:
             self._sampler_ticks.inc()
+        if tiles is not None:
+            self._tiles_walked.inc(tiles[0])
+            self._tiles_held.inc(tiles[1])
 
     def record_flush(self, cause: str) -> None:
         """A busy tick was collected before its successor was launched:
@@ -858,6 +870,13 @@ class ServingMetrics:
             "sampler_skip_share": (
                 round(1.0 - int(self._sampler_ticks.value) / busy_ticks, 4)
                 if busy_ticks
+                else None
+            ),
+            # the share of the live slots' stripe tiles the decode kernel
+            # walked, over the busy ticks (None: no program uses the kernel)
+            "decode_tiles_walked_share": (
+                round(self._tiles_walked.value / self._tiles_held.value, 4)
+                if self._tiles_held.value
                 else None
             ),
             "host_ms_per_tick_p50": (
