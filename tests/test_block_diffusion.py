@@ -847,7 +847,9 @@ def test_any_other_model_refuses_the_denoising_knobs(fields):
 # sha256 of ``Lowered.as_text()`` (no source locations) of the serving
 # programs of three toy models that stand for cells 2, 3 and 4 and of one
 # train step's forward and backward, taken on the commit BEFORE the block
-# fields existed (PR 33, 8df3bbc): with the new fields at their defaults
+# fields existed (PR 33, 8df3bbc), and of the block-diffusion toy that stands
+# for cell 5, taken on the commit before the one-sublayer and latent-expert
+# fields existed (PR 44, 868692f): with the new fields at their defaults
 # every one of them lowers to the same text.
 GOLDEN = json.load(open(os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "golden_lowered_programs.json"
@@ -901,6 +903,31 @@ def lowered_programs():
         note(f"{family}.extend", eng._extend_fn.lower(
             params, ints(2, 16), ints(2, 16), ints(2), ints(2), fresh, key
         ))
+    # the block-diffusion toy's own two programs (added by the PR after the
+    # one that brought them: hashes taken on ITS parent, 868692f)
+    cfg = tiny_block_diffusion()
+    model = GPTLM(cfg)
+    params = model.init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32),
+        train=False,
+    )["params"]
+    ints = lambda *s: jnp.zeros(s, jnp.int32)
+    flags = lambda *s: jnp.zeros(s, bool)
+    floats = lambda *s: jnp.zeros(s, jnp.float32)
+    block_prefill, block_tick = engine_mod._block_engine_fns(model, 8)
+    note("blockgen.prefill", block_prefill.lower(params, ints(1, 16), ints(1, 16)))
+    pool = jax.eval_shape(
+        lambda p: engine_mod._block_prefill_core(
+            model, p, ints(2, 16), ints(2, 16)
+        )[0], params,
+    )
+    size = cfg.block_len
+    state = (ints(2, size), flags(2, size), ints(2, size), ints(2), ints(2),
+             ints(2), flags(2), ints(2), ints(2, size), flags(2))
+    knobs = (ints(2), floats(2), ints(2), floats(2))
+    note("blockgen.tick", block_tick.lower(
+        params, state, knobs, pool, jax.random.PRNGKey(0), None
+    ))
     cfg = tiny_test(dtype=jnp.float32)
     model = GPTLM(cfg)
     params = jax.eval_shape(lambda: model.init(

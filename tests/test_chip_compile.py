@@ -422,6 +422,93 @@ def test_blockgen_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
     assert low < held < high, memory
 
 
+@pytest.mark.parametrize("program", ["decode_tick", "prefill_2048"])
+def test_latent_moe_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
+    """The serving cell of the one-sublayer hybrid decoder at its published
+    widths, one chip's share of one stage, built from the benchmark's own
+    configuration and cell files: the fused decode tick over 128 slots (5
+    float32 recurrent states of 128 x 64 x 128 a slot, ONE K/V stripe of 4096
+    positions, nothing for the 5 expert layers; 128 two-matrix experts a
+    layer in a latent of 1024 through the streamed kernel's one-weight
+    ``relu^2`` call, the decode attention through the kernel over the stored
+    stripes) and the largest whole-prompt prefill fit one v5e."""
+    import json
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from drivers.serve_latent_moe import model_config
+
+    from tpu_parallel.models import GPTLM
+    from tpu_parallel.serving import engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    read = lambda *rel: json.load(open(os.path.join(REPO, "benchmarks", *rel)))
+    cell = read("workloads", "serve-nemotron_3_super_120b_share4-reasoning.json")
+    cfg = model_config(
+        read("configs", "nemotron_3_super_120b_share4.json"), cell["engine"]
+    )
+    model, n = GPTLM(cfg), cell["engine"]["n_slots"]
+    on_chip = lambda x, dtype=None: jax.ShapeDtypeStruct(
+        x.shape, dtype or x.dtype, sharding=v5e_chip
+    )
+    params = jax.tree.map(
+        lambda x: on_chip(x, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 16), jnp.int32),
+            train=False,
+        ))["params"],
+    )
+    assert sum(x.size for x in jax.tree.leaves(params)) == 4_648_163_712
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_chip)
+    floats = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=v5e_chip
+    )
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip)
+    if program == "prefill_2048":
+        width = max(cell["engine"]["prefill_buckets"])
+        lowered = jax.jit(
+            lambda p, toks, pos, last, rng: engine._prefill_core(
+                model, p, toks, pos, last, rng
+            )
+        ).lower(params, ints(1, width), ints(1, width), ints(1), key)
+        # the one attention layer attends through the flash kernel; 45056
+        # rows of 22 assignments a token go through lax.ragged_dot
+        assert lowered.as_text().count("tpu_custom_call") == 1
+        low, high = 9.3e9, 11.5e9
+    else:
+        pool = jax.tree.map(on_chip, jax.eval_shape(
+            lambda p: engine._prefill_core(
+                model, p, jnp.zeros((n, 16), jnp.int32),
+                jnp.zeros((n, 16), jnp.int32), jnp.zeros((n,), jnp.int32), None,
+            )[1], params,
+        ))
+        by_name = {}
+        for p, x in jax.tree_util.tree_flatten_with_path(pool)[0]:
+            by_name.setdefault(p[-1].key, []).append(x.size * x.dtype.itemsize)
+        assert len(by_name["ssm_state"]) == len(by_name["conv_state"]) == 5
+        assert len(by_name["cached_key"]) == 1
+        assert sum(by_name["ssm_state"]) == n * 5 * 128 * 64 * 128 * 4  # 2.68 GB
+        assert sum(by_name["cached_key"]) * 2 == n * 4096 * 1024  # 0.54 GB
+        live = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=v5e_chip)
+        state = (ints(n), ints(n), ints(n), live, ints(n))
+        knobs = (ints(n), floats(n), ints(n), floats(n))
+        lowered = engine._fused_engine_fn(model, 8).lower(
+            params, state, knobs, pool, key
+        )
+        low, high = 12.5e9, 15.75e9  # 9.30 GB of weights + 3.26 GB of pool
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert low < held < high, memory
+    if program == "decode_tick":
+        text = compiled.as_text()
+        # two calls a layer in each of the layer's two buffers (2816 rows
+        # reach the size at which a quarter-size buffer is compiled beside
+        # the worst case), 5 expert layers; no lax.ragged_dot in the tick
+        assert len(re.findall(r"%ragged-dot-streamed[.\d]* = ", text)) == 2 * 2 * 5
+        assert "ragged-dot-none" not in text
+        assert len(re.findall(r"%attn\.decode_stripes[.\d]* = ", text)) == 1
+
+
 # name -> (slots, rows a slot, heads, K/V heads, stored positions, window,
 # block rule): the decode steps of the two serving cells with heads of 128
 DECODE_CASES = {

@@ -8,6 +8,7 @@ import json
 import os
 import sys
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -1187,6 +1188,67 @@ def test_pump_tick_observes_its_phases_and_its_own_lock_wait(env, tmp_path):
         )["count"] == 5, name
     waited = _hist(d, "daemon_tick_phase_seconds", phase="lock_wait")
     assert waited["sum"] == 2.0  # the one contended tick, nothing else
+
+
+def test_callers_that_wait_go_before_the_pumps_next_tick(
+    env, tmp_path, monkeypatch
+):
+    """`threading.RLock` is not fair, and the pump asks for it again
+    microseconds after a tick's end: the callers that waited through a
+    tick take the lock before the next tick does (`_callers_turn`)."""
+    from tpu_parallel.daemon import daemon as daemon_mod
+
+    # the bound is not what is timed here (a loaded machine wakes threads late)
+    monkeypatch.setattr(daemon_mod, "_CALLERS_TURN_SECONDS", 30.0)
+    _, _, _, prompts, _ = env
+    d = _daemon(env, tmp_path / "j.jsonl")
+    d.submit(Request(prompt=prompts[0], max_new_tokens=4, request_id="r0"))
+    step, mid_tick, go = d.frontend.step, threading.Event(), threading.Event()
+
+    def held_step():  # the pump, mid-tick, until every caller waits
+        mid_tick.set()
+        assert go.wait(30)
+        return step()
+
+    d.frontend.step = held_step
+    seen = []
+
+    def caller():
+        d.result("r0")
+        seen.append(d.ticks)
+
+    pump = threading.Thread(target=lambda: [d.tick() for _ in range(3)])
+    pump.start()
+    assert mid_tick.wait(30)
+    callers = [threading.Thread(target=caller) for _ in range(16)]
+    for t in callers:
+        t.start()
+    deadline = time.monotonic() + 30
+    while d._callers_waiting < len(callers) and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert d._callers_waiting == len(callers)
+    go.set()
+    for t in callers + [pump]:
+        t.join(timeout=30)
+        assert not t.is_alive()
+    # every one of them ran between the first tick and the second
+    assert seen == [1] * len(callers) and d.ticks == 3
+    assert d._callers_waiting == 0
+
+
+def test_a_caller_that_never_comes_does_not_hold_the_pump_off(
+    env, tmp_path, monkeypatch
+):
+    from tpu_parallel.daemon import daemon as daemon_mod
+
+    monkeypatch.setattr(daemon_mod, "_CALLERS_TURN_SECONDS", 0.05)
+    d = _daemon(env, tmp_path / "j.jsonl")
+    d._callers_waiting = 1  # counted, and never takes the lock
+    t0 = time.monotonic()
+    d.tick()
+    assert 0.05 <= time.monotonic() - t0 < 20 and d.ticks == 1
+    # the wait is the pump's `lock_wait` phase; the fake clock stood still
+    assert _hist(d, "daemon_tick_phase_seconds", phase="lock_wait")["sum"] == 0.0
 
 
 def test_phase_annotations_are_leaves_on_the_pump_thread(

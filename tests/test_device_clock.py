@@ -871,7 +871,10 @@ def test_a_reader_reads_the_number_or_nothing_and_never_raises(name, held):
 def test_a_reader_is_the_entry_the_manifest_appends(name):
     manifest = json.load(open(os.path.join(REPO_ROOT, "BENCHMARK.json")))
     names = [m["name"] for m in manifest["per_layer"]]
-    assert set(names[-5:]) == set(READERS)  # appended, nothing between
+    # found by name: later PRs append their own entries after these five,
+    # which stay together, appended, nothing between
+    first = min(names.index(n) for n in READERS)
+    assert set(names[first:first + 5]) == set(READERS)
     entry = manifest["per_layer"][names.index(name)]
     assert {k: entry[k] for k in ("name", "layer", "unit", "source",
                                   "moves")} == _reader(name).META

@@ -78,6 +78,9 @@ TICK_PHASES = ("lock_wait", "step", "journal", "fsync", "housekeeping")
 # the public calls whose wait for / hold of the daemon's lock is
 # observed (daemon_call_seconds{call=..., phase=lock_wait|held})
 LOCKED_CALLS = ("submit", "subscribe", "result", "cancel")
+# the most the pump waits, a tick, for the callers that already wait for
+# the lock to take their turn first (`ServingDaemon._callers_turn`)
+_CALLERS_TURN_SECONDS = 0.05
 
 # exit codes (the signal contract; docs/13_daemon.md)
 EXIT_CLEAN = 0  # drained: every accepted request terminal, journal clean
@@ -222,6 +225,11 @@ class ServingDaemon:
         self.span_spool = span_spool
         self._spool_lock = threading.Lock()
         self._lock = threading.RLock()
+        # handler threads waiting in `_locked` for the lock, whom the pump
+        # lets go first (`_callers_turn`); counted under a condition of
+        # its own, which tells the pump when the last of them has it
+        self._callers_waiting = 0
+        self._callers = threading.Condition()
         self._requests: Dict[str, _DaemonRequest] = {}
         self._dedupe: Dict[str, str] = {}
         # request ids with staged journal work, in first-dirty order
@@ -539,7 +547,15 @@ class ServingDaemon:
         ``daemon.tick.*`` annotation (those are the pump thread's)."""
         waited, held = self._m_call[call]
         t0 = self.clock()
-        self._lock.acquire()
+        with self._callers:
+            self._callers_waiting += 1
+        try:
+            self._lock.acquire()
+        finally:
+            with self._callers:
+                self._callers_waiting -= 1
+                if not self._callers_waiting:
+                    self._callers.notify_all()
         t1 = self.clock()
         try:
             yield t0, t1
@@ -892,6 +908,7 @@ class ServingDaemon:
         """One daemon tick: a frontend step, then the tick's journal
         batch (tokens + terminals) and ONE batched fsync window."""
         with self._tick_phase("lock_wait"):
+            self._callers_turn()
             self._lock.acquire()
         try:
             with self._tick_phase("step", leaf=False):
@@ -1073,6 +1090,27 @@ class ServingDaemon:
                 open=open_req,
             )
         return EXIT_CLEAN if clean else EXIT_FORCED
+
+    def _callers_turn(self) -> None:
+        """Before the pump takes the lock for a tick, the callers that
+        already wait for it take their turn.  ``threading.RLock`` is not
+        fair: the pump releases it at a tick's end and asks for it again
+        microseconds later, before a woken handler thread has run, so a
+        caller could lose that race tick after tick.  With 192 clients
+        submitting at once behind a 220 ms tick their first streams
+        attached over 4 to 21 s and a closed loop's pool was a sixth to
+        wholly full 20 s in (PERF.md section 6, PR 45).  A caller holds
+        the lock for about a millisecond, which stood on the pump's path
+        before as it does now: only who goes first changes.  Bounded, so
+        that callers who keep coming cannot hold the pump off: a bound on
+        a wait for threads, not a reading of time, so it asks no clock
+        (``scripts/check_clock.py``)."""
+        if self._callers_waiting:
+            with self._callers:
+                self._callers.wait_for(
+                    lambda: not self._callers_waiting,
+                    timeout=_CALLERS_TURN_SECONDS,
+                )
 
     def run(self, max_ticks: Optional[int] = None) -> int:
         """The pump: tick until shut down.  Returns the process exit

@@ -993,3 +993,65 @@ def tiny_test(**overrides) -> GPTConfig:
             **overrides,
         }
     )
+
+
+def one_sublayer_decoder(
+    *, pattern: str, ssm: "SSMSpec", experts: ExpertsSpec, **overrides
+) -> GPTConfig:
+    """A pre-norm decoder whose layers are ONE sublayer each behind ONE
+    RMSNorm: ``pattern`` is one period, a letter a layer, of ``"M"`` (the
+    recurrent Mamba-2 mixer of ``models/ssm.py``, sized by ``ssm``), ``"*"``
+    (causal grouped-query attention with no positional encoding at all) and
+    ``"E"`` (the dropless ``experts`` layer); no bias, the head untied.
+    Sizes come as ``overrides``."""
+    kinds = {
+        "M": LayerSpec(positions="none", mlp="none", mixer="ssm", ssm=ssm),
+        "*": LayerSpec(positions="none", mlp="none"),
+        "E": LayerSpec(mlp="experts", experts=experts, mixer="none"),
+    }
+    return GPTConfig(
+        **{
+            **dict(
+                positional="rope",  # no learned table; every layer says "none"
+                norm="rmsnorm",
+                dense_bias=False,
+                scan_layers=False,
+                layer_pattern=tuple(kinds[k] for k in pattern),
+            ),
+            **overrides,
+        }
+    )
+
+
+def tiny_one_sublayer(**overrides) -> GPTConfig:
+    """``one_sublayer_decoder`` at CPU-test size: one period ``MEMEMEM*EME``,
+    8 recurrent heads of 16 in 2 groups with a state of 16, 4 query heads of
+    16 on 2 K/V heads, 16 sigmoid-routed relu2 experts of width 24 in a latent
+    of 32, top-4 with a selection bias and a scale of 2.5, of which 8 are
+    held, one shared expert of width 48."""
+    from tpu_parallel.models.layers import SSMSpec
+
+    pattern = overrides.pop("pattern", "MEMEMEM*EME")
+    return one_sublayer_decoder(
+        pattern=pattern,
+        ssm=overrides.pop(
+            "ssm",
+            SSMSpec(n_heads=8, head_dim=16, d_state=16, n_groups=2, chunk=8),
+        ),
+        experts=overrides.pop(
+            "experts",
+            ExpertsSpec(
+                n_experts=16, top_k=4, width=24, score="sigmoid", shared=1,
+                held=(0, 8), latent=32, ffn="relu2", shared_width=48,
+                shared_sum=True, select_bias=True, route_scale=2.5,
+            ),
+        ),
+        **{
+            **dict(
+                vocab_size=256, d_model=64, n_layers=len(pattern), n_heads=4,
+                n_kv_heads=2, head_dim=16, seq_len=48, dtype=jnp.float32,
+                remat=False,
+            ),
+            **overrides,
+        },
+    )

@@ -41,10 +41,22 @@ class ExpertsSpec:
 
     n_experts: int
     top_k: int
-    width: int  # hidden width of one expert, routed or shared
+    width: int  # hidden width of one routed expert (a shared one: shared_width)
     score: str = "softmax"  # "softmax" | "sigmoid" over the router's logits
-    shared: int = 0  # shared experts beside the routed ones, their mean added
+    shared: int = 0  # shared experts beside the routed ones
     held: Optional[Tuple[int, int]] = None
+    # > 0: the routed experts live in a latent of this width, between ONE
+    # down-projection and ONE up-projection a layer around the routed sum
+    # (router and shared experts read the full-width input)
+    latent: int = 0
+    # an expert: "swiglu" W_down(silu(W_gate x) * (W_up x)), three matrices;
+    # "relu2" W_2 relu(W_1 x)^2, two
+    ffn: str = "swiglu"
+    shared_width: int = 0  # a shared expert's own hidden width (0: ``width``)
+    shared_sum: bool = False  # shared outputs added (True) or averaged
+    # a learned bias added to the scores in the CHOICE of the top-k only
+    select_bias: bool = False
+    route_scale: float = 1.0  # times the renormalised weights
 
     @property
     def held_range(self) -> Tuple[int, int]:
@@ -67,15 +79,16 @@ class SSMSpec:
 class LayerSpec:
     """One block's kind.  ``TransformerConfig.layer_pattern`` holds one period
     of these, repeated over the depth; a model whose blocks are all alike is
-    the one-entry case (:meth:`TransformerConfig.layer_specs` derives it)."""
+    the one-entry case (:meth:`TransformerConfig.layer_specs` derives it).
+    ``mixer="none"`` or ``mlp="none"``: ONE sublayer behind ONE norm."""
 
     attn: str = "full"  # "full" | "window"
     window: int = 0  # keys a query sees, itself included ("window" only)
     positions: str = "model"  # "model" (config.positional) | "rope" | "none"
-    mlp: str = "dense"  # "dense" | "experts"
+    mlp: str = "dense"  # "dense" | "experts" | "none" (the mixer alone)
     # None with mlp="experts": the config's capacity-routed moe_* experts
     experts: Optional[ExpertsSpec] = None
-    mixer: str = "attention"  # "attention" | "ssm" (models/ssm.py)
+    mixer: str = "attention"  # "attention" | "ssm" | "none" (the MLP alone)
     ssm: Optional[SSMSpec] = None  # the recurrent mixer's sizes
 
 
@@ -152,28 +165,15 @@ class TransformerConfig:
     # only viable at short sequence or small batch)
     remat_policy: str = "full"
     scan_layers: bool = True
-    # layers per unrolled step of the layer scan (nn.scan's ``unroll``).
-    # The scan's cost persists unchanged under plain remat (it is no
-    # remat-policy interaction) AND in-scan unrolling makes it worse:
-    # the cost is the per-tick carry
-    # round-trips, which unrolling the loop body does not remove (on the
-    # chip: not measured).  Deep
-    # configs should keep scan_unroll=1 and accept the scan tax, or go
-    # fully unrolled (scan_layers=False) where compile budget allows; the
-    # knob stays for measurement on other shapes/hardware.
+    # layers per unrolled step of the layer scan (nn.scan's ``unroll``): the
+    # scan's cost is the per-tick carry round-trips, which unrolling the
+    # body does not remove (on the chip: not measured); the knob stays
     scan_unroll: int = 1
     # blocks per scanned BODY (scan length becomes n_layers / scan_group):
-    # the residual-stream carry is materialized at tick boundaries only, so
-    # grouping divides the scan's per-tick HBM round-trips by the group size
-    # — unlike scan_unroll, which unrolls the loop but keeps one carry
-    # round-trip per block.  Param layout changes to [n_layers/g] stacks of
-    # g named blocks ("block0".."block{g-1}"); g=1 keeps the historical
-    # layout.  Must divide n_layers and hold whole periods of layer kinds.
-    # An earlier round found throughput FLAT in g at 125M (g=1..6) — which
-    # falsified the carry-round-trip
-    # theory of the scan tax; a bisect then located it in the backward.
-    # Not measured on the current machine (PERF.md); the knob stays for
-    # other depths/hardware.
+    # the residual carry is materialized at tick boundaries only.  Param
+    # layout changes to [n_layers/g] stacks of g named blocks ("block0"..);
+    # g=1 keeps the historical layout.  Must divide n_layers and hold whole
+    # periods of layer kinds.  What was measured: docs/05, "The scan tax"
     scan_group: int = 1
     # lax.scan's _split_transpose: lowers the layer scan's BACKWARD as two
     # loops (residual regeneration + gradient accumulation) that XLA can
@@ -1340,9 +1340,9 @@ def _scaled(fn, scale: float, *args, **kwargs):
 
 def make_mixer(config: TransformerConfig, spec: Optional[LayerSpec]):
     """A block's token mixer by the layer's kind: :class:`Attention`
-    (``"attn"``) or the recurrent :class:`~tpu_parallel.models.ssm.SSMMixer`
-    (``"ssm"``); where the config states a residual multiplier, its output
-    times that.  Called inside the block's compact method."""
+    (``"attn"``), the recurrent :class:`~tpu_parallel.models.ssm.SSMMixer`
+    (``"ssm"``) or None (``"none"``), its output times the residual
+    multiplier the config states.  Called inside the block's compact method."""
     kind = spec or config.layer_specs[0]
     if kind.mixer == "ssm":
         from tpu_parallel.models.ssm import SSMMixer
@@ -1351,7 +1351,7 @@ def make_mixer(config: TransformerConfig, spec: Optional[LayerSpec]):
     elif kind.mixer == "attention":
         mixer = Attention(config, spec=spec, name="attn")
     else:
-        raise ValueError(f"LayerSpec.mixer={kind.mixer!r} (attention | ssm)")
+        return _no_mixer(kind)
     if config.residual_scale == 1.0:
         return mixer
     return functools.partial(_scaled, mixer, config.residual_scale)
@@ -1360,7 +1360,7 @@ def make_mixer(config: TransformerConfig, spec: Optional[LayerSpec]):
 class Block(nn.Module):
     """Pre-norm transformer block: x + attn(norm(x)); x + mlp(norm(x)).
     ``config.parallel_block``: x + attn(h) + mlp(h), both from h = norm(x).
-    ``spec`` is this layer's kind (None: the uniform model's one kind)."""
+    ``spec``: this layer's kind; one without a mixer or MLP: x + f(norm(x))."""
 
     config: TransformerConfig
     spec: Optional[LayerSpec] = None
@@ -1409,15 +1409,15 @@ class Block(nn.Module):
         if cfg.residual_scale != 1.0:
             mlp_fn = functools.partial(_scaled, mlp_fn, cfg.residual_scale)
         attn_kwargs = dict(
-            positions=positions,
-            segment_ids=segment_ids,
-            train=train,
-            decode=decode,
-            cache_valid=cache_valid,
-            attn_bias=attn_bias,
-            write_index=write_index,
-            block_table=block_table,
+            positions=positions, segment_ids=segment_ids, train=train,
+            decode=decode, cache_valid=cache_valid, attn_bias=attn_bias,
+            write_index=write_index, block_table=block_table,
         )
+        if "none" in (spec.mixer, spec.mlp):
+            # ONE sublayer behind ONE norm; the absent half makes nothing
+            return one_sublayer(
+                cfg, spec, x, attn, attn_kwargs, mlp_fn
+            )
         if cfg.parallel_block:
             if not cfg.prenorm:
                 raise ValueError("parallel_block is a pre-norm block")
@@ -1742,3 +1742,45 @@ def decode_attention(
         k_positions, tile=plan["tile"],
         vmem_limit_bytes=plan["vmem_limit_bytes"],
     )
+
+
+def _no_mixer(kind: LayerSpec) -> None:
+    """``make_mixer`` for a layer that says it has none."""
+    if kind.mixer != "none":
+        raise ValueError(
+            f"LayerSpec.mixer={kind.mixer!r} (attention | ssm | none)"
+        )
+    return None
+
+
+def one_sublayer(
+    config: TransformerConfig, spec: LayerSpec, x, mixer, mixer_kwargs, mlp_fn
+):
+    """A block that is ONE sublayer behind ONE norm, ``x + f(norm(x))`` with
+    ``f`` the layer's mixer (``spec.mlp == "none"``) or its feed-forward part
+    (``spec.mixer == "none"``).  The absent half creates no parameter and no
+    cache leaf.  Called inside :class:`Block`'s compact method (down here for
+    the reason :func:`decode_attention` gives)."""
+    if spec.mixer == "none" and spec.mlp == "none":
+        raise ValueError("a layer with neither a mixer nor a feed-forward part")
+    if config.parallel_block or not config.prenorm:
+        raise ValueError(
+            "a one-sublayer block is pre-norm and has nothing to run in "
+            "parallel with"
+        )
+    h = make_norm(config, "norm")(x).astype(config.dtype)
+    if spec.mixer == "none":
+        return x + mlp_fn(h)
+    return x + mixer(h, **mixer_kwargs)
+
+
+def layer_kinds(config: TransformerConfig) -> dict:
+    """Layers by kind over the whole depth: ``{"ssm", "attention", "experts",
+    "dense"}`` count sublayers (a two-sublayer block counts in two of them),
+    ``"layers"`` the depth."""
+    period, out = config.layer_specs, {"layers": config.n_layers}
+    for s in period:
+        for kind in (s.mixer, s.mlp):
+            if kind != "none":
+                out[kind] = out.get(kind, 0) + config.n_layers // len(period)
+    return out

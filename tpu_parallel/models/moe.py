@@ -284,8 +284,6 @@ class MoEMLP(nn.Module):
                 x_send, cfg.model_axis, split_axis=0, concat_axis=1, tiled=True
             )  # [E_local, ep*C_s, d]
 
-        import functools
-
         expert_stack = nn.vmap(
             ExpertFFN,
             in_axes=0,
@@ -378,8 +376,6 @@ class MoEMLP(nn.Module):
             split_rngs={"params": True},
         )
         if ep_size > 1:
-            import functools
-
             y_exp = ModuleShard(
                 functools.partial(expert_stack, cfg),
                 axis_name=cfg.model_axis,
@@ -407,24 +403,24 @@ class MoEMLP(nn.Module):
 # collection the dropless layer sows its per-call row counts into
 MOE_STATS = "moe_stats"
 # below this many assignments one buffer serves; above it, a quarter-size
-# buffer runs whenever the rows routed here fit it (twice the 1/8 a share of
-# an eighth expects), and the worst-case buffer otherwise
+# buffer runs whenever the rows routed here fit it, the worst case otherwise
 SMALL_BUFFER_MIN_ROWS = 2048
+# an expert's matrices by ``ExpertsSpec.ffn``
+FFN_MATRICES = {"swiglu": 3, "relu2": 2}
 
 
 def moe_plan(spec, tokens: int, d_model: int, dtype, ep_size: int = 1) -> dict:
     """What a dropless layer does with ``tokens`` rows: experts held of how
-    many, top-k, the worst-case buffer (every token on ``min(top_k, held)``
-    held experts), the small buffer that runs whenever the rows routed here
-    fit it, and what runs the grouped matmuls of each (``grouped``,
-    ``small_grouped``): ``"streamed"`` where the buffer's rows an expert are
-    few (:func:`~tpu_parallel.ops.grouped_ffn.grouped_ffn_plan` has the
-    rule; a decode step) with the kernel's window, slots, contraction blocks
-    and VMEM limit beside it, ``"ragged_dot"``, whose tiles are the
-    compiler's, where they are many (a prefill).  :class:`RoutedExperts`
-    sizes its buffers from this and :func:`_grouped_ffn` follows the same
-    rule; the serving engine logs it at build for each of its program
-    shapes, as ``flash_plan`` is for the attention kernels."""
+    many, top-k, the width the experts live in (``latent``, or the model's),
+    an expert's matrices and activation, the worst-case buffer (every token
+    on ``min(top_k, held)`` held experts), the small buffer that runs whenever
+    the rows routed here fit it, and what runs the grouped matmuls of each
+    (``grouped``, ``small_grouped``): ``"streamed"`` where the buffer's rows
+    an expert are few (``ops.grouped_ffn.grouped_ffn_plan`` has the rule; a
+    decode step) with the kernel's window, slots, contraction blocks and VMEM
+    limit beside it, ``"ragged_dot"`` where they are many (a prefill).
+    :class:`RoutedExperts` sizes its buffers from this, :func:`_grouped_ffn`
+    follows the same rule, and the serving engine logs it at build."""
     held = spec.held_range[1] // ep_size
     worst = tokens * min(spec.top_k, held)
     small = worst
@@ -434,10 +430,14 @@ def moe_plan(spec, tokens: int, d_model: int, dtype, ep_size: int = 1) -> dict:
         experts=spec.n_experts, held=held, top_k=spec.top_k,
         shared=spec.shared, width=spec.width, score=spec.score,
         tokens=tokens, buffer_rows=worst, small_buffer_rows=small,
+        latent=spec.latent, matrices=FFN_MATRICES[spec.ffn], ffn=spec.ffn,
     )
     buffers = {"": worst} if small == worst else {"": worst, "small_": small}
     for prefix, rows in buffers.items():
-        streamed = grouped_ffn_plan(rows, held, d_model, spec.width, dtype)
+        streamed = grouped_ffn_plan(
+            rows, held, spec.latent or d_model, spec.width, dtype,
+            plan["matrices"],
+        )
         plan[prefix + "grouped"] = "streamed" if streamed else "ragged_dot"
         plan.update({
             prefix + k: v for k, v in (streamed or {}).items()
@@ -464,9 +464,8 @@ _EXPERT_INIT = nn.initializers.variance_scaling(
 
 
 class _Stacked(nn.Module):
-    """``[experts, in, out]`` weights as a ``kernel`` of their own, so that
-    whatever reads a parameter tree by name (an initialiser by fan-in, a
-    re-layout) sees a matrix where there is one."""
+    """``[experts, in, out]`` weights as a ``kernel`` of their own: whatever
+    reads a tree by name (an initialiser by fan-in) sees a matrix there."""
 
     shape: tuple
 
@@ -476,8 +475,9 @@ class _Stacked(nn.Module):
 
 
 class _HeldExperts(nn.Module):
-    """The held experts' weights ``(gate, up, down)``, each expert
-    ``W_down(silu(W_gate x) * (W_up x))``; a module of its own so that
+    """The held experts' weights: ``(gate, up, down)``, each expert
+    ``W_down(silu(W_gate x) * (W_up x))``, or with ``matrices=2`` ``(up,
+    down)``, each ``W_down relu(W_up x)^2``; a module of its own so that
     :class:`~tpu_parallel.parallel.tp.ModuleShard` can give every rank of
     an expert axis its part."""
 
@@ -485,6 +485,7 @@ class _HeldExperts(nn.Module):
     d_model: int
     width: int
     dtype: "jnp.dtype"
+    matrices: int = 3
 
     @nn.compact
     def __call__(self):
@@ -494,16 +495,15 @@ class _HeldExperts(nn.Module):
             for name, shape in (
                 ("gate", shape_in), ("up", shape_in),
                 ("down", (self.n_local, self.width, self.d_model)),
-            )
+            )[-self.matrices:]
         )
 
 
 def _grouped_ffn(rows, weights, group_sizes):
-    """Every expert's FFN over its own run of ``rows`` (sorted by expert);
-    rows past the groups are not computed and hold nothing.  Where the
-    buffer's rows an expert are few (``grouped_ffn_plan``'s rule) the
-    streamed kernel reads each touched expert's matrices once
-    (``ops/grouped_ffn.py``); else three ``lax.ragged_dot``."""
+    """Every expert's FFN over its own run of ``rows`` (sorted by expert):
+    the streamed kernel where ``grouped_ffn_plan`` has a plan, else ragged_dot."""
+    if len(weights) == 2:  # (up, down): W_down relu(W_up x)^2
+        return _relu2_ffn(rows, weights, group_sizes)
     w_gate, w_up, w_down = weights
     n_local, d_model, width = w_gate.shape
     if grouped_ffn_plan(
@@ -525,18 +525,18 @@ class RoutedExperts(nn.Module):
     added is left out: on one chip the layer runs without its exchange.  Under
     a bound model axis each rank holds an equal part of the held range and the
     routed sum closes with the ``psum`` the capacity layer has; router and
-    shared experts are replicated and counted once.
+    shared experts are replicated and counted once.  A spec may state more
+    (defaults: the above): a selection bias and a scale in the router, experts
+    of two matrices (``relu2``), a ``latent`` the routed experts live in
+    (``y = W_up sum w_e E_e(W_dn x) + sum_i S_i(x)``, one pair of projections
+    a layer), shared experts of their own width that are summed.
 
     Assignments are sorted by expert with those of absent experts behind the
     held ones; the grouped matmuls do work for the rows routed here, while
     the buffer holds the worst case - every token on ``min(top_k, held)``
     held experts - so that no imbalance drops a token.  What runs them follows
-    the shape (:func:`moe_plan`): a decode step, where an expert receives a
-    row or two and the cost is reading its matrices, goes through the
-    streamed kernel of ``ops/grouped_ffn.py`` (each touched expert's matrices
-    once, nothing for the others); a prefill, where an expert receives
-    hundreds of rows, through ``lax.ragged_dot`` (on the TPU a kernel that
-    walks only the tiles its groups fill).
+    the shape (:func:`moe_plan`): few rows an expert (a decode step) go through
+    the streamed kernel of ``ops/grouped_ffn.py``, many through ``ragged_dot``.
     """
 
     config: "TransformerConfig"  # noqa: F821
@@ -551,10 +551,7 @@ class RoutedExperts(nn.Module):
         out of the row counts)."""
         cfg, es = self.config, self.spec
         first, count = es.held_range
-        if not (0 <= first and first + count <= es.n_experts and count > 0):
-            raise ValueError(f"held={es.held} outside 0..{es.n_experts}")
-        if not 1 <= es.top_k <= es.n_experts:
-            raise ValueError(f"top_k={es.top_k} of {es.n_experts} experts")
+        _check_spec(es)
         ep_size = axis_size_or_none(cfg.model_axis) or 1
         if count % ep_size:
             raise ValueError(
@@ -571,15 +568,18 @@ class RoutedExperts(nn.Module):
             logits = nn.Dense(
                 es.n_experts, use_bias=False, dtype=jnp.float32, name="router"
             )(xf.astype(jnp.float32))
-            if es.score == "sigmoid":
-                scores = jax.nn.sigmoid(logits)
-            elif es.score == "softmax":
-                scores = jax.nn.softmax(logits, axis=-1)
-            else:
-                raise ValueError(f"score={es.score!r} (softmax | sigmoid)")
-            top_s, top_e = lax.top_k(scores, k)  # [T, k]
-            weights = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
-
+            bias = None
+            if es.select_bias:
+                bias = self.param(
+                    "select_bias", nn.initializers.zeros, (es.n_experts,)
+                )
+            weights, top_e = _route(es, logits, bias)
+        xin, d_in = xf, es.latent or d
+        if es.latent:  # the routed experts' input, projected down ONCE a layer
+            with jax.named_scope("moe.latent_down"):
+                xin = nn.Dense(
+                    d_in, use_bias=False, dtype=cfg.dtype, name="latent_down"
+                )(xf.astype(cfg.dtype))
         with jax.named_scope("moe.experts"):
             local = top_e.reshape(-1) - first  # [T * k]
             here = (local >= 0) & (local < n_local)
@@ -605,10 +605,10 @@ class RoutedExperts(nn.Module):
             group_sizes, routed = sizes[:n_local], tokens * k - sizes[n_local]
             expert_weights = ModuleShard(
                 functools.partial(
-                    _HeldExperts, n_local, d, es.width, cfg.dtype
+                    _HeldExperts, n_local, d_in, es.width, cfg.dtype,
+                    FFN_MATRICES[es.ffn],
                 ),
-                axis_name=cfg.model_axis,
-                name="experts",
+                axis_name=cfg.model_axis, name="experts",
             )()
             mix = jnp.where(here, weights.reshape(-1), 0.0)
 
@@ -616,12 +616,12 @@ class RoutedExperts(nn.Module):
                 """The routed sum through a buffer of ``cap`` rows (the held
                 assignments are its first ``routed``).  Gathers only: no
                 scatter-add, whose order of summation is not fixed."""
-                rows = xf[order[:cap] // k].astype(cfg.dtype)
+                rows = xin[order[:cap] // k].astype(cfg.dtype)
                 out = _grouped_ffn(rows, expert_weights, group_sizes)
-                back = out[jnp.minimum(place, cap - 1)]  # [T * k, d]
+                back = out[jnp.minimum(place, cap - 1)]  # [T * k, d_in]
                 back = jnp.where(here[:, None], back.astype(jnp.float32), 0.0)
                 return jnp.sum(
-                    back.reshape(tokens, k, d) * mix.reshape(tokens, k, 1),
+                    back.reshape(tokens, k, d_in) * mix.reshape(tokens, k, 1),
                     axis=1,
                 )
 
@@ -637,22 +637,94 @@ class RoutedExperts(nn.Module):
                 with jax.named_scope("moe_combine_psum"):
                     y = lax.psum(y, cfg.model_axis)
 
+        if es.latent:  # the routed sum, projected up ONCE a layer
+            with jax.named_scope("moe.latent_up"):
+                y = nn.Dense(
+                    d, use_bias=False, dtype=cfg.dtype, name="latent_up"
+                )(y.astype(cfg.dtype)).astype(jnp.float32)
         if es.shared:
             with jax.named_scope("moe.shared"):
-                w_gate, w_up, w_down = (
-                    _Stacked(shape, name=name)().astype(cfg.dtype)
-                    for name, shape in (
-                        ("shared_gate", (es.shared, d, es.width)),
-                        ("shared_up", (es.shared, d, es.width)),
-                        ("shared_down", (es.shared, es.width, d)),
-                    )
-                )
-                h = xf.astype(cfg.dtype)
-                mid = nn.silu(jnp.einsum("td,edw->tew", h, w_gate)) * (
-                    jnp.einsum("td,edw->tew", h, w_up)
-                )
-                y = y + jnp.einsum(
-                    "tew,ewd->td", mid, w_down,
-                    preferred_element_type=jnp.float32,
-                ) / es.shared
+                y = y + _shared_experts(es, cfg.dtype, xf, d)
         return y.astype(cfg.dtype).reshape(b, s, d)
+
+
+def _check_spec(es) -> None:
+    """Refuse an :class:`ExpertsSpec` the layer does not build."""
+    first, count = es.held_range
+    if not (0 <= first and first + count <= es.n_experts and count > 0):
+        raise ValueError(f"held={es.held} outside 0..{es.n_experts}")
+    if not 1 <= es.top_k <= es.n_experts:
+        raise ValueError(f"top_k={es.top_k} of {es.n_experts} experts")
+    if es.ffn not in FFN_MATRICES:
+        raise ValueError(f"ffn={es.ffn!r} ({' | '.join(FFN_MATRICES)})")
+
+
+def _route(es, logits, bias):
+    """``(weights [T, k], experts [T, k])`` from the router's float32
+    ``logits`` over ALL experts: scores by softmax or sigmoid, the top-k
+    chosen by score (plus ``bias`` where the spec has a selection bias: it
+    moves the CHOICE and no weight), the chosen scores renormalised over the
+    token's own top-k and times ``route_scale``."""
+    k = es.top_k
+    if es.score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif es.score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"score={es.score!r} (softmax | sigmoid)")
+    if bias is None:
+        top_s, top_e = lax.top_k(scores, k)  # [T, k]
+    else:
+        _, top_e = lax.top_k(scores + bias, k)
+        top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+    weights = top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    if es.route_scale != 1.0:
+        weights = weights * es.route_scale
+    return weights, top_e
+
+
+def _relu2_ffn(rows, weights, group_sizes):
+    """:func:`_grouped_ffn` for two-matrix experts ``W_down relu(W_up x)^2``:
+    the same rule, the streamed kernel's one-weight first call (float32 until
+    after the square) or two ``lax.ragged_dot``."""
+    w_up, w_down = weights
+    n_local, d_in, width = w_up.shape
+    if grouped_ffn_plan(
+        rows.shape[0], n_local, d_in, width, rows.dtype, 2
+    ) is not None:
+        return grouped_ffn(rows, weights, group_sizes)
+    up = lax.ragged_dot(
+        rows, w_up, group_sizes, preferred_element_type=jnp.float32
+    )
+    mid = jnp.square(nn.relu(up)).astype(rows.dtype)
+    return lax.ragged_dot(mid, w_down, group_sizes)
+
+
+def _shared_experts(es, dtype, xf, d: int):
+    """What the ``es.shared`` shared experts add, float32 ``[T, d]``: each of
+    the routed experts' form (``es.ffn``) at its own width over the full-width
+    input, their outputs averaged or (``shared_sum``) added.  Called inside
+    :class:`RoutedExperts`' compact method."""
+    width = es.shared_width or es.width
+    shape_in = (es.shared, d, width)
+    ws = [
+        _Stacked(shape, name=name)().astype(dtype)
+        for name, shape in (
+            ("shared_gate", shape_in), ("shared_up", shape_in),
+            ("shared_down", (es.shared, width, d)),
+        )[-FFN_MATRICES[es.ffn]:]
+    ]
+    h = xf.astype(dtype)
+    if es.ffn == "relu2":
+        up = jnp.einsum(
+            "td,edw->tew", h, ws[0], preferred_element_type=jnp.float32
+        )
+        mid = jnp.square(nn.relu(up)).astype(dtype)
+    else:
+        mid = nn.silu(jnp.einsum("td,edw->tew", h, ws[0])) * (
+            jnp.einsum("td,edw->tew", h, ws[1])
+        )
+    out = jnp.einsum(
+        "tew,ewd->td", mid, ws[-1], preferred_element_type=jnp.float32,
+    )
+    return out if es.shared_sum else out / es.shared

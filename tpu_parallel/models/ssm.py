@@ -9,7 +9,8 @@ For one layer, with ``u`` the normed residual ``[batch, T, d_model]`` and
     [x | B | C] = xBC                             # [heads, head_dim] | [G, N] | [G, N]
     dt_t = softplus(dt_t + dt_bias);  A = -exp(A_log)
     S_t = exp(dt_t A) S_{t-1} + dt_t * x_t (outer) B_t;  y_t = S_t C_t + D x_t
-    y = rmsnorm(y * silu(z)) * g                  # the gate BEFORE the norm, one group
+    y = rmsnorm_G(y * silu(z)) * g                # the gate BEFORE the norm; the mean
+                                                  # square over each of G groups apart
     out = W_out y
 
 The recurrence runs in :mod:`tpu_parallel.ops.ssd_scan`: the chunked scan for
@@ -86,6 +87,28 @@ def last_inputs(window: jax.Array, inputs: jax.Array, valid: jax.Array):
     idx = jnp.where(j < keep, j, start[:, None] + j)
     both = jnp.concatenate([window, inputs.astype(window.dtype)], axis=1)
     return jnp.take_along_axis(both, idx[:, :, None], axis=1)
+
+
+class GroupRMSNorm(nn.Module):
+    """RMSNorm whose mean square is taken over each of ``groups`` equal runs
+    of the last axis apart (Mamba-2's gate norm with ``n_groups > 1``: a
+    group's channels are normalised among themselves), one ``scale`` over
+    the whole axis; float32."""
+
+    groups: int
+    epsilon: float
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        width = x.shape[-1]
+        scale = self.param("scale", nn.initializers.ones, (width,))
+        xg = x.astype(jnp.float32).reshape(
+            *x.shape[:-1], self.groups, width // self.groups
+        )
+        xg = xg * jax.lax.rsqrt(
+            jnp.mean(jnp.square(xg), axis=-1, keepdims=True) + self.epsilon
+        )
+        return xg.reshape(x.shape) * scale.astype(jnp.float32)
 
 
 class SSMMixer(nn.Module):
@@ -195,9 +218,13 @@ class SSMMixer(nn.Module):
             gated = y.reshape(b, t, d_inner).astype(jnp.float32) * nn.silu(
                 z.astype(jnp.float32)
             )
-            y = nn.RMSNorm(
-                epsilon=cfg.norm_eps, dtype=jnp.float32, name="gate_norm"
-            )(gated).astype(cfg.dtype)
+            if s.n_groups == 1:
+                norm = nn.RMSNorm(
+                    epsilon=cfg.norm_eps, dtype=jnp.float32, name="gate_norm"
+                )
+            else:
+                norm = GroupRMSNorm(s.n_groups, cfg.norm_eps, name="gate_norm")
+            y = norm(gated).astype(cfg.dtype)
         with jax.named_scope("ssm.out_proj"):
             return nn.Dense(
                 cfg.d_model, use_bias=False, dtype=cfg.dtype, name="out_proj"

@@ -11,11 +11,11 @@ Where an expert can receive many rows the work is a matmul again and
 ``models.moe.moe_plan`` says which runs for a program shape).
 
 One kernel, called twice a layer: ``h = silu(x @ W_gate[e]) * (x @ W_up[e])``
-reads gate and up in ONE pass over the rows, ``y = h @ W_down[e]`` is the same
-kernel with one weight.  Operands stay in the dtype they come in (bf16 when
-served), every product accumulates in fp32, and gate / up stay fp32 until
-after the ``silu`` product: nothing is rounded lower than the three
-``ragged_dot`` calls round it.
+reads gate and up in ONE pass over the rows (two-matrix experts: ``h = relu(x
+@ W_up[e])^2``, one weight), ``y = h @ W_down[e]`` is the same kernel with one
+weight.  Operands stay in the dtype they come in (bf16 when served), every
+product accumulates in fp32 and stays fp32 until after the ``silu`` product
+or the square: nothing is rounded lower than ``ragged_dot`` rounds it.
 
 Grid ``(slots, contraction blocks)``, both sequential.  A slot is one window
 of rows under one expert's matrices; the windows of the experts that got a
@@ -87,12 +87,13 @@ def _block_k(contract: int, out: int, itemsize: int) -> Optional[int]:
 
 
 def grouped_ffn_plan(
-    rows: int, n_experts: int, d_model: int, width: int, dtype=jnp.bfloat16
+    rows: int, n_experts: int, d_model: int, width: int, dtype=jnp.bfloat16,
+    matrices: int = 3,
 ) -> Optional[dict]:
     """What the streamed kernel does with a buffer of ``rows`` rows over
-    ``n_experts`` experts: the window, the grid's slots, the contraction
-    block of each call, the VMEM limit it asks for.  None where
-    ``lax.ragged_dot`` runs instead.
+    ``n_experts`` experts of ``matrices`` matrices (3: gate, up, down; 2: up,
+    down): the window, the grid's slots, the contraction block of each call,
+    the VMEM limit it asks for.  None where ``lax.ragged_dot`` runs instead.
 
     The rule: ``rows / n_experts``, the rows an expert receives when the
     buffer is full (and so the layer's FLOPs a byte of bf16 weights), is at
@@ -101,14 +102,14 @@ def grouped_ffn_plan(
     v5e's ridge of 240, and a window costs the matrix unit no more than the
     load of the tile it is multiplied by.  Past it the work is a matmul
     again.  It holds for any number of experts, and it sees only shapes.
-    Besides, the call has to fit ``VMEM_BUDGET_BYTES`` and a block has to
-    divide each width.
+    The call has to fit ``VMEM_BUDGET_BYTES``, a block to divide each width.
 
     The VMEM limit is what the larger call holds (the weight blocks and the
     resident rows and output double-buffered, the fp32 accumulators and as
     much again for the products on their way into them) and a quarter more,
     never under 32 MiB: the 16 MiB default does not hold two 4 MiB blocks of
-    each of two matrices beside the rows.
+    each of two matrices beside the rows.  ``d_model`` is the width the
+    experts read and write (a latent's, where they live in one).
     """
     if rows > WINDOW_ROWS * n_experts:
         return None
@@ -117,11 +118,10 @@ def grouped_ffn_plan(
     block_mid = _block_k(width, d_model, itemsize)
     if block_in is None or block_mid is None:
         return None
-    padded = _round_up(rows, ROW_ALIGN)
+    padded, need = _round_up(rows, ROW_ALIGN), 0
     window = min(WINDOW_ROWS, padded)
-    need = 0
     for n_w, block, contract, out in (
-        (2, block_in, d_model, width), (1, block_mid, width, d_model),
+        (matrices - 1, block_in, d_model, width), (1, block_mid, width, d_model),
     ):
         need = max(need, itemsize * (
             2 * n_w * block * out + 2 * padded * contract + 2 * padded * out
@@ -168,7 +168,7 @@ def _schedule(group_sizes: jax.Array, slots: int, window: int, buffer_rows: int)
 
 
 def _kernel(ids_ref, start_ref, lo_ref, hi_ref, x_ref, *refs, n_weights: int,
-            window: int, block_k: int, n_k: int):
+            window: int, block_k: int, n_k: int, square: bool = False):
     """One grid step ``(slot, contraction block)``: the slot's window of rows
     against one block of each of its expert's weights, into fp32
     accumulators; a slot's last block stores the expert's own rows."""
@@ -205,7 +205,7 @@ def _kernel(ids_ref, start_ref, lo_ref, hi_ref, x_ref, *refs, n_weights: int,
 
         @pl.when(k == n_k - 1)
         def _():
-            y = accs[0][...]
+            y = accs[0][...] if not square else _relu2(accs[0][...])
             if n_weights == 2:
                 y = jax.nn.silu(y) * accs[1][...]
             row = start + lax.broadcasted_iota(jnp.int32, (window, 1), 0)
@@ -214,9 +214,9 @@ def _kernel(ids_ref, start_ref, lo_ref, hi_ref, x_ref, *refs, n_weights: int,
 
 
 def _streamed(x, weights, schedule, *, window, block_k, vmem_limit_bytes,
-              interpret):
-    """``f(x[rows of e] @ w[e] for w in weights)`` for every expert of the
-    schedule: ``silu(a) * b`` of two weights, the product of one."""
+              interpret, square=False):
+    """``f(x[rows of e] @ w[e] for w in weights)`` for every expert: ``silu(a)
+    * b`` of two weights, the product of one (``square``: its ``relu(a)^2``)."""
     rows, contract = x.shape
     out = weights[0].shape[-1]
     n_k = contract // block_k
@@ -237,7 +237,7 @@ def _streamed(x, weights, schedule, *, window, block_k, vmem_limit_bytes,
     return pl.pallas_call(
         functools.partial(
             _kernel, n_weights=len(weights), window=window, block_k=block_k,
-            n_k=n_k,
+            n_k=n_k, square=square,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -265,7 +265,7 @@ def _planned(rows, weights, group_sizes, *, window, buffer_rows, slots,
              block_in, block_mid, vmem_limit_bytes, interpret):
     """Both calls of one layer under a plan.  Jitted so that the layers of a
     model (and the calls of a test that runs eagerly) share one trace."""
-    w_gate, w_up, w_down = weights
+    w_in, w_down, s = tuple(weights[:-1]), weights[-1], len(weights) == 2
     n_rows = rows.shape[0]
     if buffer_rows != n_rows:
         rows = jnp.pad(rows, ((0, buffer_rows - n_rows), (0, 0)))
@@ -276,7 +276,7 @@ def _planned(rows, weights, group_sizes, *, window, buffer_rows, slots,
     # the scope holds the kernels alone: the roofline's reader sums what
     # matches ``ragged-dot`` and the gathers around them are not its work
     with jax.named_scope("ragged-dot"):
-        mid = call(rows, (w_gate, w_up), block_k=block_in)
+        mid = call(rows, w_in, block_k=block_in, square=s)
         out = call(mid, (w_down,), block_k=block_mid)
     return out[:n_rows]
 
@@ -287,13 +287,13 @@ def grouped_ffn(
     group_sizes: jax.Array,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Every expert's ``W_down(silu(W_gate x) * (W_up x))`` over its own run
-    of ``rows`` (``[buffer, d_model]``, sorted by expert; ``group_sizes``
-    ``[experts]`` int32, of any sizes that fit the buffer), through the
-    streamed kernel.  Rows past the groups come back zero.  Raises
-    ``ValueError`` for a shape ``grouped_ffn_plan`` has no plan for."""
+    """Every expert's ``W_down(silu(W_gate x) * (W_up x))`` (three weights;
+    two: ``W_down relu(W_up x)^2``) over its own run of ``rows`` (``[buffer,
+    d_model]``, sorted by expert; ``group_sizes`` ``[experts]`` int32, of any
+    sizes that fit the buffer), through the streamed kernel.  Rows past the
+    groups come back zero.  ``ValueError`` where ``grouped_ffn_plan`` has none."""
     (n_rows, d_model), (n_experts, _, width) = rows.shape, weights[0].shape
-    plan = grouped_ffn_plan(n_rows, n_experts, d_model, width, rows.dtype)
+    plan = grouped_ffn_plan(n_rows, n_experts, d_model, width, rows.dtype, len(weights))
     if plan is None:
         raise ValueError(
             f"no streamed plan for {n_rows} rows over {n_experts} experts of "
@@ -302,3 +302,9 @@ def grouped_ffn(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     return _planned(rows, weights, group_sizes, interpret=interpret, **plan)
+
+
+def _relu2(y):
+    """``relu(y)^2`` of a first call's float32 accumulator (down here: the
+    kernel's bytes carry the line numbers above)."""
+    return jnp.square(jnp.maximum(y, 0.0))

@@ -1982,6 +1982,12 @@ class ServingEngine:
             if fn is not None:
                 setattr(self, name, _WithoutExpertRows(fn, self._expert_rows))
 
+        from tpu_parallel.models.layers import layer_kinds
+
+        # sublayers by kind over the depth ("ssm", "attention", "experts",
+        # "dense"; "layers" the depth): a layer may be ONE of them alone, so
+        # each plan's log line and tracer instant says of how many
+        self.layer_kinds = layer_kinds(cfg)
         self.moe_plan = self._plan_experts(n_slots)
         self.ssm_plan = self._plan_state(n_slots)
         self.sampler_plan = self._plan_sampler(n_slots)
@@ -2029,10 +2035,14 @@ class ServingEngine:
             name: moe_plan(spec, t, cfg.d_model, cfg.dtype)
             for name, t in shapes.items()
         }
-        logging.getLogger(__name__).info("moe_plan %s", json.dumps(plan))
+        logging.getLogger(__name__).info(
+            "moe_plan %s layers %s", json.dumps(plan),
+            json.dumps(self.layer_kinds),
+        )
         if self.tracer.enabled:
             self.tracer.instant(
                 "moe_plan", track="scheduler", layers=cfg.routed_layers,
+                of_layers=cfg.n_layers,
                 **{f"{name}_{k}": v for name, p in plan.items()
                    for k, v in p.items()},
             )
@@ -2081,10 +2091,15 @@ class ServingEngine:
                 plan["decode"]["tile"], plan["decode"]["tiles"],
                 max(1, self._block_len), sorted(windows.items()),
             )
-        logging.getLogger(__name__).info("attn_plan %s", json.dumps(plan))
+        logging.getLogger(__name__).info(
+            "attn_plan %s layers %s", json.dumps(plan),
+            json.dumps(self.layer_kinds),
+        )
         if self.tracer.enabled:
             self.tracer.instant(
                 "attn_plan", track="scheduler",
+                layers=self.layer_kinds.get("attention", 0),
+                of_layers=cfg.n_layers,
                 **{f"{name}_{k}": v for name, p in plan.items()
                    for k, v in p.items()},
             )
@@ -2177,7 +2192,8 @@ class ServingEngine:
         spec = next(s.ssm for s in cfg.layer_specs if s.mixer == "ssm")
         plan = {
             "ssm_layers": cfg.recurrent_layers,
-            "attention_layers": cfg.n_layers - cfg.recurrent_layers,
+            "attention_layers": self.layer_kinds.get("attention", 0),
+            "expert_layers": cfg.routed_layers, "layers": cfg.n_layers,
             "heads": spec.n_heads, "head_dim": spec.head_dim,
             "d_state": spec.d_state, "groups": spec.n_groups,
             "conv_width": spec.d_conv, "chunk": spec.chunk,
