@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from tpu_parallel.core.losses import token_cross_entropy
-from tpu_parallel.core.metrics import Metrics
+from tpu_parallel.core.losses import token_ce_and_argmax
+from tpu_parallel.core.metrics import Metrics, pvary_missing, vma_of
 from tpu_parallel.core.rng import fold_rng_over_axis
 from tpu_parallel.models.layers import (
     Attention,
@@ -342,6 +342,38 @@ class GPTLM(nn.Module):
         return _make_lm_head(cfg)(x)
 
 
+def ce_form(model_axis_size: Optional[int]) -> str:
+    """Which cross-entropy a step runs, from what it can observe: the size
+    of the bound model axis (None outside a mesh).  ``"fused"`` where the
+    vocabulary is whole on the chip, ``"vocab_parallel"`` where the head's
+    columns are sharded and the row statistics cross chips."""
+    return "vocab_parallel" if (model_axis_size or 1) > 1 else "fused"
+
+
+def loss_plan(config, rows: int, tokens: int, model_axis_size: Optional[int]) -> dict:
+    """What the head and the loss of one pass over ``rows`` sequences of
+    ``tokens`` positions will do, said once (static for a compiled step, as
+    ``flash_plan`` is): the form, the logits' shape and type, and the bytes
+    of ``[rows, vocab]``-sized residuals the loss keeps for its backward —
+    the logits as the head wrote them plus a float32 log-sum-exp a row for
+    ``fused``; the float32 exponentials of the shard, which autodiff saves,
+    for ``vocab_parallel``.  Under ``loss_chunk`` the backward holds one
+    chunk's at a time."""
+    form = ce_form(model_axis_size)
+    vocab = config.vocab_size // (model_axis_size or 1)
+    dtype = jnp.dtype(config.dtype)
+    live = rows * (config.loss_chunk or tokens)
+    per_logit = dtype.itemsize if form == "fused" else 4
+    return {
+        "form": form,
+        "rows_per_pass": rows,
+        "vocab": vocab,
+        "logits_dtype": dtype.name,
+        "residual_bytes_per_pass": live * (vocab * per_logit + 4),
+        "chunk": config.loss_chunk,
+    }
+
+
 def make_ce_fn(config: GPTConfig):
     """``(lm_params, hidden, targets, mask) -> (loss_sum, correct_sum)``:
     the shared CE machinery of every token-prediction objective (causal LM,
@@ -357,24 +389,37 @@ def make_ce_fn(config: GPTConfig):
 
     def ce_block(lm_params, h, targets, mask):
         """lm_head + CE + accuracy on one block of hidden states; returns
-        (loss_sum, correct_sum).  Vocab-parallel when the model axis is
-        bound (mesh path), plain CE on full logits otherwise."""
+        (loss_sum, correct_sum).  Vocab-parallel when the vocabulary is
+        sharded over the model axis, the one-pass unit of
+        ``core.losses.token_ce_and_argmax`` where it is whole on the chip
+        (no mesh, or a model axis of one)."""
         from tpu_parallel.parallel.tp import axis_size_or_none
 
+        tp = axis_size_or_none(config.model_axis)
         # stable names for the step's second bottleneck: this head is
         # unnamed (applied outside the model), so it gets no module scope
         with jax.named_scope("lm_head"):
             logits = _apply_lm_head(config, lm_params, h)
-        with jax.named_scope("cross_entropy"):
-            if axis_size_or_none(config.model_axis) is not None:
+        if ce_form(tp) == "vocab_parallel":
+            with jax.named_scope("cross_entropy"):
                 ce, pred = vocab_parallel_cross_entropy(
                     logits, targets, config.model_axis
                 )
-            else:
-                ce = token_cross_entropy(logits, targets)
-                pred = logits.argmax(-1)
+        else:
+            ce, pred = token_ce_and_argmax(logits, targets)
+        with jax.named_scope("cross_entropy"):
             loss_sum = (ce * mask).sum()
             correct = ((pred == targets) * mask).sum()
+            if tp == 1:
+                # the head's shard is typed as varying over the model axis
+                # even when that axis is one chip: the sum over it (no
+                # collective once compiled) closes the type, as the
+                # vocab-parallel psums do
+                loss_sum, correct = (
+                    lax.psum(x, config.model_axis)
+                    if config.model_axis in vma_of(x) else x
+                    for x in (loss_sum, correct)
+                )
         return loss_sum, correct
 
     def chunked_ce(lm_params, h, targets, mask):
@@ -396,8 +441,6 @@ def make_ce_fn(config: GPTConfig):
         # hidden states' axes plus the model axis, which the CE's psums over
         # the sharded vocab introduce) so the scan type-checks under
         # shard_map's replication checker
-        from tpu_parallel.core.metrics import pvary_missing, vma_of
-
         vma = vma_of(h)
         if vma and config.model_axis not in vma:
             vma = vma + (config.model_axis,)

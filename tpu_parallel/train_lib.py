@@ -24,6 +24,7 @@ from tpu_parallel.core import compute as compute_metrics
 from tpu_parallel.core.state import TextBatch, TrainState, get_num_params
 from tpu_parallel.data import lm_batch, seq2seq_batch
 from tpu_parallel.models import GPTLM, GPTConfig, make_gpt_loss, make_mlm_loss
+from tpu_parallel.models.gpt import loss_plan
 from tpu_parallel.models import (
     EncoderDecoder,
     Seq2SeqConfig,
@@ -338,6 +339,19 @@ class Trainer:
                 "flash_plan %s", json.dumps(self.flash_plan)
             )
 
+        # what the head and the loss of a pass will do, said once: the form
+        # (the one-pass unit, or vocab-parallel under a model axis), the
+        # logits' shape and type, the residual bytes the loss keeps
+        self.loss_plan = loss_plan(
+            self.model_config,
+            config.global_batch_size // mesh_sizes["data"] // config.num_minibatches,
+            self.model_config.seq_len // mesh_sizes.get("seq", 1),
+            mesh_sizes.get("model", 1),
+        )
+        logging.getLogger(__name__).info(
+            "loss_plan %s", json.dumps(self.loss_plan)
+        )
+
     def _flash_plan(self) -> Optional[dict]:
         cfg = self.model_config
         if cfg.attn_impl != "flash":
@@ -433,6 +447,8 @@ class Trainer:
                 **{f"{p}_{k}": v for p in ("fwd", "bwd")
                    for k, v in plan[p].items()},
             )
+        if tr.enabled:
+            tr.instant("loss_plan", track="trainer", **self.loss_plan)
         for step in range(1, steps + 1):
             if tr.enabled:
                 with tr.span("data_wait", track="trainer", step=step):
