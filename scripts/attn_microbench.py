@@ -31,6 +31,16 @@ Usage:
     python scripts/attn_microbench.py --sweep             # + every tile 128-1024 each way
     python scripts/attn_microbench.py --seq 2048 --heads 6 --head-dim 128 --sweep
     python scripts/attn_microbench.py --decode            # the decode kernel beside XLA's program
+    python scripts/attn_microbench.py --latent            # the forward kernel at latent attention's widths
+
+``--latent`` times the FORWARD kernel at latent attention's shape (one prompt,
+128 heads, scores at 192 and values at 128; ``LATENT_SEQS``) two ways: the
+widths as they are (192 is a block's full last axis) and both padded with
+zero columns to 256 (the layout the one-width kernels would need), at the
+call's own tile and variant, at other tiles, and through the streamed
+kernel (``LATENT_VARIANTS``).  The share is of the least time of the
+PUBLISHED widths, the causal half of ``2 x 128 x (192 + 128) x S^2`` FLOPs
+(``benchmarks/lib/mla_cost.py``), so padding reads as lost share.
 """
 
 import argparse
@@ -195,6 +205,82 @@ def run_decode_variants(name, lengths_name, window, operands, size, lengths,
         print(json.dumps(line), flush=True)
 
 
+LATENT_SEQS = (4096, 8192)
+# (label, tile, stream): the call's own choice (the largest of 512 / 256 / 128
+# that divides the row, resident where a row fits), other tiles, and the
+# streamed kernel that flash_plan would derive past 4096
+LATENT_VARIANTS = (("own", None, None), ("t256", 256, None),
+                   ("t1024", 1024, None), ("streamed512", 512, True))
+
+
+def latent_main():
+    from lib import flops, mla_cost, xplane
+    from lib.peaks import peaks
+    from tpu_parallel.runtime import require_tpu
+
+    fa = importlib.import_module("tpu_parallel.ops.flash_attention")
+    require_tpu()
+    heads, qk, dv = 128, 192, 128
+    mla = {"heads": heads, "qk": qk, "v": dv, "bytes_per_value": 2}
+    jobs = []
+    for s in LATENT_SEQS:
+        keys = jax.random.split(jax.random.PRNGKey(s), 3)
+        q, k = (jax.random.normal(kk, (1, heads, s, qk), jnp.bfloat16) for kk in keys[:2])
+        v = jax.random.normal(keys[2], (1, heads, s, dv), jnp.bfloat16)
+        pad = lambda x: jnp.pad(x, ((0, 0),) * 3 + ((0, 256 - x.shape[-1]),))
+        # zero key columns add nothing to a score; the kernel scales by
+        # width ** -0.5, so the padded queries carry the rest of the factor
+        padded = (pad(q) * jnp.asarray((256 / qk) ** 0.5, q.dtype), pad(k), pad(v))
+        for label, tile, stream in LATENT_VARIANTS:
+            for widths, operands in (("192_128", (q, k, v)), ("256_256", padded)):
+                tag = f"mb_latent_{s}_{widths}_{label}"
+
+                def run(q, k, v, tag=tag, tile=tile, stream=stream):
+                    with jax.named_scope(tag):
+                        return fa.flash_attention_fwd_bhsd(
+                            q, k, v, block_q=tile, block_k=tile,
+                            stream=stream, interpret=INTERPRET,
+                        )
+
+                fn = jax.jit(run)
+                try:
+                    out = jax.block_until_ready(fn(*operands))
+                except Exception as exc:  # noqa: BLE001 - a tile the chip refuses
+                    print(json.dumps({"tag": tag, "error": repr(exc)[:160]}), flush=True)
+                    continue
+                jobs.append((tag, s, widths, label, fn, operands, out))
+    logdir = tempfile.mkdtemp(prefix="attn_microbench_")
+    jax.profiler.start_trace(logdir)
+    for tag, *_, fn, operands, _ in jobs:
+        for _ in range(REPEATS):
+            res = fn(*operands)
+        jax.block_until_ready(res)
+    jax.profiler.stop_trace()
+    trace = xplane.load(xplane.find_trace(logdir))
+    shutil.rmtree(logdir, ignore_errors=True)
+    seconds = collections.Counter()
+    for name, start, end in trace["devices"][min(trace["devices"])]["ops"]:
+        for tag, *_ in jobs:
+            if name == tag or name.startswith(tag + "."):
+                seconds[tag] += end - start
+    first = {}
+    for tag, s, widths, label, _, _, out in jobs:
+        least, bound = flops.roofline_seconds(
+            mla_cost.prefill_attention_cost(float(s) ** 2, mla),
+            peaks(jax.devices()[0].device_kind),
+        )
+        ms = seconds[tag] / REPEATS * 1e3
+        same = first.setdefault(s, out)[..., :dv].astype(jnp.float32)
+        print(json.dumps({
+            "seq": s, "widths": widths, "variant": label,
+            "kernel_ms": round(ms, 4), "least_ms": round(least * 1e3, 4),
+            "bound": bound,
+            "roofline_pct": round(100 * least * 1e3 / ms, 2) if ms else None,
+            "max_diff_from_first": float(jnp.max(jnp.abs(
+                out[..., :dv].astype(jnp.float32) - same))),
+        }), flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--batch", type=int, default=16)
@@ -207,9 +293,14 @@ def main():
     ap.add_argument("--decode", action="store_true",
                     help="the decode kernel over the stored stripes beside "
                          "XLA's program, at the serving cells' decode shapes")
+    ap.add_argument("--latent", action="store_true",
+                    help="the forward kernel at latent attention's widths "
+                         "(192 / 128) beside both padded to 256")
     args = ap.parse_args()
     if args.decode:
         return decode_main()
+    if args.latent:
+        return latent_main()
 
     from lib import flops, xplane
     from lib.peaks import peaks

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """What the serving engine's device programs lower to, as one line a program.
 
-For the engines of the benchmark's four serving cells (built from the cell and
+For the engines of the benchmark's serving cells (built from the cell and
 configuration files under ``benchmarks/``) every jitted program the traffic
 reaches is lowered for a DESCRIBED v5e chip from abstract operands (nothing
 runs, no weights are made) and printed as::
@@ -195,6 +195,26 @@ def main() -> None:
         0, [(1, hybrid_cell["engine"]["prefill_buckets"][0])],
     )
     block_programs(read("workloads", "serve-sdar_30b_a3b_depth6-blockgen.json"))
+    # cells 6 and 7 (a tree that has no such driver is an older one: skipped)
+    for driver, config_file, cell_file in (
+        ("serve_latent_moe", "nemotron_3_super_120b_share4.json",
+         "serve-nemotron_3_super_120b_share4-reasoning.json"),
+        ("serve_latent_attn", "openpangu_ultra_moe_718b_share16.json",
+         "serve-openpangu_ultra_moe_718b_share16-longdoc.json"),
+    ):
+        if not os.path.exists(
+            os.path.join(tree, "benchmarks", "drivers", driver + ".py")
+        ):
+            continue
+        import importlib
+
+        family = importlib.import_module("drivers." + driver)
+        cell = read("workloads", cell_file)
+        programs(
+            cell["name"], family.model_config(read("configs", config_file),
+                                              cell["engine"]),
+            0, [(1, cell["engine"]["prefill_buckets"][0])],
+        )
 
 
 if __name__ == "__main__":

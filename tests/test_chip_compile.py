@@ -509,6 +509,113 @@ def test_latent_moe_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
         assert len(re.findall(r"%attn\.decode_stripes[.\d]* = ", text)) == 1
 
 
+@pytest.mark.parametrize("seq", [4096, 8192])
+def test_latent_flash_forward_compiles_for_v5e(seq, v5e_chip, monkeypatch):
+    """The forward flash kernels at latent attention's real widths: 128 heads
+    that score at 192 (no multiple of the 128 lanes: a block's full last axis)
+    and sum values at 128, one prompt of 4096 and of 8192, as the prefill calls
+    them: at ``flash_plan``'s tiles, as every caller of the kernels."""
+    from tpu_parallel.ops.flash_attention import flash_attention_fwd_bhsd, flash_plan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the derived plan: resident at a tile of 256 up to 4096 positions, the
+    # streamed kernel at 512 past them
+    plan = flash_plan(seq, 192)["fwd"]
+    assert (plan["variant"], plan["block_q"]) == (
+        ("resident", 256) if seq == 4096 else ("streamed", 512)
+    )
+    spec = lambda width: jax.ShapeDtypeStruct(
+        (1, 128, seq, width), jnp.bfloat16, sharding=v5e_chip
+    )
+    lowered = jax.jit(
+        functools.partial(flash_attention_fwd_bhsd, interpret=False)
+    ).lower(spec(192), spec(192), spec(128))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert lowered.out_info.shape == (1, 128, seq, 128)
+    lowered.compile()  # raises what the chip's compiler would raise
+
+
+@pytest.mark.parametrize("program", ["decode_tick", "prefill_8192"])
+def test_latent_attn_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
+    """The serving cell of the latent-attention expert decoder at its
+    published widths, one chip's share of the first stage, built from the
+    benchmark's own configuration and cell files: the fused decode tick over
+    the cell's slots (ONE row of 576 a position and layer, no K/V heads; the
+    absorbed form through XLA; 16 held experts a layer through the streamed
+    kernel) and the largest whole-prompt prefill (five flash kernels at 192 /
+    128; the routed sum in blocks of tokens, ``moe._combine``) fit one v5e
+    BESIDE the pool."""
+    import json
+
+    sys.path.insert(0, os.path.join(REPO, "benchmarks"))
+    from drivers.serve_latent_attn import model_config
+
+    from tpu_parallel.models import GPTLM
+    from tpu_parallel.serving import cache_pool, engine
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    read = lambda *rel: json.load(open(os.path.join(REPO, "benchmarks", *rel)))
+    cell = read("workloads", "serve-openpangu_ultra_moe_718b_share16-longdoc.json")
+    cfg = model_config(
+        read("configs", "openpangu_ultra_moe_718b_share16.json"), cell["engine"]
+    )
+    model, n = GPTLM(cfg), cell["engine"]["n_slots"]
+    on_chip = lambda x, dtype=None: jax.ShapeDtypeStruct(
+        x.shape, dtype or x.dtype, sharding=v5e_chip
+    )
+    params = jax.tree.map(
+        lambda x: on_chip(x, jnp.bfloat16),
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 16), jnp.int32),
+            train=False,
+        ))["params"],
+    )
+    assert sum(x.size for x in jax.tree.leaves(params)) == 4_919_139_840
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=v5e_chip)
+    floats = lambda *shape: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=v5e_chip
+    )
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip)
+    pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda p: cache_pool._pool_cache_shapes(model, p, n), params
+    ))
+    by_name = {}
+    for p, x in jax.tree_util.tree_flatten_with_path(pool)[0]:
+        by_name.setdefault(p[-1].key, []).append(x.size * x.dtype.itemsize)
+    assert set(by_name) == {"cached_latent", "cached_pos", "cache_index"}
+    assert sum(by_name["cached_latent"]) == n * 8192 * 5 * 576 * 2
+    pool_bytes = sum(sum(v) for v in by_name.values())
+    if program == "prefill_8192":
+        lowered = jax.jit(
+            lambda p, toks, pos, last, rng: engine._prefill_core(
+                model, p, toks, pos, last, rng
+            )
+        ).lower(params, ints(1, 8192), ints(1, 8192), ints(1), key)
+        # one flash kernel a layer; the prompt's 65536 assignments go through
+        # lax.ragged_dot
+        assert lowered.as_text().count("tpu_custom_call") == 5
+        beside = pool_bytes  # the pool stays on the chip while a prompt runs
+    else:
+        live = jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=v5e_chip)
+        state = (ints(n), ints(n), ints(n), live, ints(n))
+        knobs = (ints(n), floats(n), ints(n), floats(n))
+        lowered = engine._fused_engine_fn(model, 8).lower(
+            params, state, knobs, pool, key, None
+        )
+        beside = 0  # the pool is an argument
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes + beside
+    print(program, memory.argument_size_in_bytes / 1e9,
+          memory.temp_size_in_bytes / 1e9, beside / 1e9)
+    # 9.84 GB of weights and the pool, and half a GB of the chip's 15.75 left
+    assert 9.84e9 + pool_bytes < held < 15.25e9, memory
+    if program == "decode_tick":
+        text = compiled.as_text()
+        assert len(re.findall(r"%ragged-dot-streamed[.\d]* = ", text)) >= 4
+        assert "attn.decode_stripes" not in text  # attn_plan says xla
+
+
 # name -> (slots, rows a slot, heads, K/V heads, stored positions, window,
 # block rule): the decode steps of the two serving cells with heads of 128
 DECODE_CASES = {

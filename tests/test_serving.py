@@ -2111,6 +2111,9 @@ SAMPLER_SUMMARY_KEYS = {"sampler_draw_ticks", "sampler_skip_share"}
 # the decode kernel over the stored stripes (PR 44): None where no program
 # uses it (tests/test_ops_decode_attention.py has the engines that do)
 ATTENTION_SUMMARY_KEYS = {"decode_tiles_walked_share"}
+# the latent attention layers' rows a decode step reads and bytes a position
+# (both 0 for a model without one)
+LATENT_SUMMARY_KEYS = {"latent_positions_read", "latent_bytes_per_position"}
 # the completion clock (PR 41): device time by program, from inside; two
 # counts that start at 0 and the split by shape that starts empty, the
 # rest None until a program has completed
@@ -2280,9 +2283,10 @@ def test_summary_keeps_every_old_key_and_has_the_phase_clock(rng):
     empty = eng.metrics.summary()
     every = (OLD_SUMMARY_KEYS | NEW_SUMMARY_KEYS | PREFILL_SUMMARY_KEYS
              | SAMPLER_SUMMARY_KEYS | DEVICE_SUMMARY_KEYS
-             | ATTENTION_SUMMARY_KEYS)
+             | ATTENTION_SUMMARY_KEYS | LATENT_SUMMARY_KEYS)
     assert set(empty) == every
     assert empty["decode_tiles_walked_share"] is None
+    assert all(empty[k] == 0 for k in LATENT_SUMMARY_KEYS)
     counts = {"device_programs", "device_clock_dropped"}
     assert all(empty[k] == 0 for k in counts)
     assert empty["device_by_shape"] == {}

@@ -645,7 +645,7 @@ def beam_cache_batch_axis(path, x):
         ("cached_key", "cached_value", "cross_key", "cross_value", "ssm_state")
     ):
         return x.ndim - 4
-    if name.startswith("conv_state"):
+    if name.startswith(("conv_state", "cached_latent")):  # [.., b, rows, width]
         return x.ndim - 3
     if name.startswith(("cached_pos", "cross_mask")):
         return x.ndim - 2

@@ -1098,3 +1098,66 @@ def tiny_one_sublayer(**overrides) -> GPTConfig:
             **overrides,
         },
     )
+
+
+def latent_experts_decoder(
+    *, latent: "LatentSpec", experts: ExpertsSpec, dense_layers: int = 1,
+    **overrides,
+) -> GPTConfig:
+    """A pre-norm decoder of latent attention layers (``models/
+    latent_attention.py``, sized by ``latent``) with sandwich norms (four
+    RMSNorms a layer): ``dense_layers`` leading layers with a dense SwiGLU MLP
+    of ``mlp_dim``, then layers of the dropless ``experts``; rotary positions
+    on the latent layer's rotary columns in rotate-half pairing, no bias, the
+    head untied.  Sizes come as ``overrides`` (``n_layers`` counts the leading
+    layers too)."""
+    attn = dict(attn="latent", latent=latent)
+    return GPTConfig(
+        **{
+            **dict(
+                positional="rope",
+                rope_pairing="half",
+                norm="rmsnorm",
+                mlp="swiglu",
+                dense_bias=False,
+                sandwich_norm=True,
+                scan_layers=False,
+                layer_head=(LayerSpec(**attn),) * dense_layers,
+                layer_pattern=(
+                    LayerSpec(mlp="experts", experts=experts, **attn),
+                ),
+            ),
+            **overrides,
+        }
+    )
+
+
+def tiny_latent_experts(**overrides) -> GPTConfig:
+    """``latent_experts_decoder`` at CPU-test size: one leading dense layer of
+    width 96 and three expert layers; 4 heads that score at 16 + 8 and sum
+    values at 16 over a latent of 32 (queries through 48), a cache row of 40;
+    16 sigmoid-routed experts of width 24, top-4 with a scale of 2.5, of which
+    4 are held, one shared expert."""
+    from tpu_parallel.models.layers import LatentSpec
+
+    return latent_experts_decoder(
+        latent=overrides.pop(
+            "latent",
+            LatentSpec(q_rank=48, kv_rank=32, nope_dim=16, rope_dim=8, v_dim=16),
+        ),
+        experts=overrides.pop(
+            "experts",
+            ExpertsSpec(
+                n_experts=16, top_k=4, width=24, score="sigmoid", shared=1,
+                held=(0, 4), shared_sum=True, route_scale=2.5,
+            ),
+        ),
+        **{
+            **dict(
+                vocab_size=256, d_model=64, n_layers=4, n_heads=4,
+                mlp_dim=96, seq_len=48, rope_theta=25600.0,
+                dtype=jnp.float32, remat=False,
+            ),
+            **overrides,
+        },
+    )

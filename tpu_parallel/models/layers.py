@@ -82,7 +82,7 @@ class LayerSpec:
     the one-entry case (:meth:`TransformerConfig.layer_specs` derives it).
     ``mixer="none"`` or ``mlp="none"``: ONE sublayer behind ONE norm."""
 
-    attn: str = "full"  # "full" | "window"
+    attn: str = "full"  # "full" | "window" | "latent" (sizes in ``latent``)
     window: int = 0  # keys a query sees, itself included ("window" only)
     positions: str = "model"  # "model" (config.positional) | "rope" | "none"
     mlp: str = "dense"  # "dense" | "experts" | "none" (the mixer alone)
@@ -90,6 +90,7 @@ class LayerSpec:
     experts: Optional[ExpertsSpec] = None
     mixer: str = "attention"  # "attention" | "ssm" | "none" (the MLP alone)
     ssm: Optional[SSMSpec] = None  # the recurrent mixer's sizes
+    latent: Optional[LatentSpec] = None  # attn="latent": its five sizes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -294,32 +295,31 @@ class TransformerConfig:
     # the id a position of a block holds until the denoising fills it (the
     # serving engine's block step feeds it; no layer reads this field)
     mask_token_id: Optional[int] = None
+    # leading layers BEFORE the repeated period (a dense layer ahead of the
+    # expert layers): n_layers = len(layer_head) + whole periods of
+    # layer_pattern (None: of the one kind every other block has); unrolled
+    # stacks only (a scanned body is one period)
+    layer_head: Tuple[LayerSpec, ...] = ()
+    # sandwich norms: a norm on the OUTPUT of each sublayer too, before it is
+    # added to the residual (four norms a layer: norm_attn, norm_post_attn,
+    # norm_mlp, norm_post_mlp); a pre-norm block with both sublayers
+    sandwich_norm: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if self.mlp_dim is None:
             object.__setattr__(self, "mlp_dim", self.mlp_ratio * self.d_model)
-        if self.layer_pattern is not None:
-            if not self.layer_pattern or self.n_layers % len(self.layer_pattern):
-                raise ValueError(
-                    f"n_layers={self.n_layers} is not a whole number of "
-                    f"periods of {len(self.layer_pattern or ())} layers"
-                )
         if self.rope_pairing not in ("interleaved", "half"):
             raise ValueError(
                 f"rope_pairing={self.rope_pairing!r} (interleaved | half)"
             )
-        if self.block_len < 0 or (self.block_len and (
-            self.bidirectional or self.recurrent_layers
-            or any(s.attn == "window" for s in self.layer_specs)
-        )):
-            raise ValueError(
-                f"block_len={self.block_len}: the block rule is causal across "
-                "blocks and full inside one; it does not combine with a "
-                "window, a bidirectional stack or a recurrent layer (whose "
-                "state cannot take a block back)"
-            )
+        # the depth is leading layers and whole periods; what a block rule,
+        # a latent layer, a head of layers or sandwich norms do not combine
+        # with is refused here, at construction (down at the end of the file,
+        # where lines may be added: a kernel's bytes carry the line numbers
+        # of the frames that reach it, BlockStack's and Block's among them)
+        check_layer_kinds(self)
 
     @property
     def layer_specs(self) -> Tuple[LayerSpec, ...]:
@@ -336,23 +336,23 @@ class TransformerConfig:
 
     @property
     def routed_layers(self) -> int:
-        """Layers whose MLP is a dropless :class:`ExpertsSpec` layer."""
-        period = self.layer_specs
-        per = sum(1 for s in period if s.experts is not None)
-        return per * (self.n_layers // len(period))
+        """Layers whose MLP is a dropless :class:`ExpertsSpec` layer (over the
+        whole depth, leading layers included: :func:`depth_specs`)."""
+        return sum(1 for s in depth_specs(self) if s.experts is not None)
+
 
     @property
     def recurrent_layers(self) -> int:
         """Layers whose mixer carries a recurrent state (``mixer="ssm"``)."""
-        per = sum(1 for s in self.layer_specs if s.mixer == "ssm")
-        return per * (self.n_layers // len(self.layer_specs))
+        # over the whole depth (a leading layer may be one)
+        return sum(1 for s in depth_specs(self) if s.mixer == "ssm")
 
     @property
     def drops_tokens(self) -> bool:
         """Whether some layer routes with a capacity (a token's output then
         depends on its batch-mates: no serving comparison can accept it)."""
         return any(
-            s.mlp == "experts" and s.experts is None for s in self.layer_specs
+            s.mlp == "experts" and s.experts is None for s in depth_specs(self)
         )
 
 
@@ -1349,7 +1349,7 @@ def make_mixer(config: TransformerConfig, spec: Optional[LayerSpec]):
 
         mixer = SSMMixer(config, kind.ssm, name="ssm")
     elif kind.mixer == "attention":
-        mixer = Attention(config, spec=spec, name="attn")
+        mixer = attention_module(config, spec, kind)
     else:
         return _no_mixer(kind)
     if config.residual_scale == 1.0:
@@ -1411,10 +1411,10 @@ class Block(nn.Module):
         attn_kwargs = dict(
             positions=positions, segment_ids=segment_ids, train=train,
             decode=decode, cache_valid=cache_valid, attn_bias=attn_bias,
-            write_index=write_index, block_table=block_table,
-        )
-        if "none" in (spec.mixer, spec.mlp):
-            # ONE sublayer behind ONE norm; the absent half makes nothing
+            write_index=write_index, block_table=block_table)
+        if cfg.sandwich_norm:  # a norm on each sublayer's output too
+            return sandwich_block(cfg, x, attn, attn_kwargs, mlp_fn)
+        if "none" in (spec.mixer, spec.mlp):  # ONE sublayer behind ONE norm
             return one_sublayer(
                 cfg, spec, x, attn, attn_kwargs, mlp_fn
             )
@@ -1542,7 +1542,7 @@ class BlockStack(nn.Module):
     ) -> jax.Array:
         cfg = self.config
         period = cfg.layer_specs
-        if self.n_layers % len(period) != 0:
+        if (self.n_layers - len(cfg.layer_head)) % len(period) != 0:
             raise ValueError(
                 f"a stack of {self.n_layers} layers is not a whole number "
                 f"of periods of {len(period)}"
@@ -1622,7 +1622,7 @@ class BlockStack(nn.Module):
                 else base_block
             )
             for i in range(self.n_layers):
-                spec = period[i % len(period)] if len(period) > 1 else None
+                spec = spec_at(cfg, i)  # None: the uniform model's one kind
                 x = block_cls(cfg, spec, name=f"layer_{i}")(
                     x, positions, segment_ids, train, decode, aux_scale,
                     cache_valid, attn_bias, write_index, block_table,
@@ -1778,9 +1778,136 @@ def layer_kinds(config: TransformerConfig) -> dict:
     """Layers by kind over the whole depth: ``{"ssm", "attention", "experts",
     "dense"}`` count sublayers (a two-sublayer block counts in two of them),
     ``"layers"`` the depth."""
-    period, out = config.layer_specs, {"layers": config.n_layers}
-    for s in period:
+    out = {"layers": config.n_layers}
+    for s in depth_specs(config):
         for kind in (s.mixer, s.mlp):
             if kind != "none":
-                out[kind] = out.get(kind, 0) + config.n_layers // len(period)
+                out[kind] = out.get(kind, 0) + 1
     return out
+
+
+# --- leading layers, sandwich norms, latent attention --------------------------
+# (every line below was added under the last frame that reaches a kernel)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """A latent attention layer's five sizes (``LayerSpec.attn="latent"``,
+    ``models/latent_attention.py``): queries go through a latent of
+    ``q_rank``, keys and values through ONE of ``kv_rank`` a position that all
+    heads share; a head scores at ``nope_dim + rope_dim`` (the rotary part is
+    ``rope_dim`` columns and ONE key for all heads) and sums values at
+    ``v_dim``.  The cache row is ``kv_rank + rope_dim`` wide."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+    @property
+    def row(self) -> int:
+        """Numbers a position stores: the normed latent and the rotary key."""
+        return self.kv_rank + self.rope_dim
+
+
+def depth_specs(config: TransformerConfig) -> Tuple[LayerSpec, ...]:
+    """Every layer's kind over the whole depth: the leading layers, then the
+    period repeated."""
+    period = config.layer_specs
+    repeats = (config.n_layers - len(config.layer_head)) // len(period)
+    return tuple(config.layer_head) + tuple(period) * repeats
+
+
+def spec_at(config: TransformerConfig, i: int) -> Optional[LayerSpec]:
+    """Layer ``i``'s kind for its :class:`Block`; None for a uniform model
+    (one kind, no leading layers), whose ops keep the names they had."""
+    if not config.layer_head and len(config.layer_specs) == 1:
+        return None
+    return depth_specs(config)[i]
+
+
+def check_layer_kinds(config: TransformerConfig) -> None:
+    """``TransformerConfig.__post_init__``'s checks of what the layer kinds
+    combine with: raises ``ValueError`` for what is not written."""
+    head, pattern = config.layer_head, config.layer_pattern
+    if pattern is not None or head:
+        body = config.n_layers - len(head)
+        if pattern is not None and not pattern or body < 0 or body % len(
+            config.layer_specs
+        ):
+            raise ValueError(
+                f"n_layers={config.n_layers} is not {len(head)} leading "
+                f"layer(s) and a whole number of periods of "
+                f"{len(pattern or (None,))} layers"
+            )
+    if head and config.scan_layers:
+        raise ValueError(
+            "layer_head (leading layers before the period) runs on unrolled "
+            "stacks: set scan_layers=False (a scanned body is one period)"
+        )
+    specs = depth_specs(config)
+    if config.block_len < 0 or (config.block_len and (
+        config.bidirectional or config.recurrent_layers
+        or any(s.attn != "full" for s in specs)
+    )):
+        raise ValueError(
+            f"block_len={config.block_len}: the block rule is causal across "
+            "blocks and full inside one; it does not combine with a "
+            "window, a bidirectional stack, a latent layer or a recurrent "
+            "layer (whose state cannot take a block back)"
+        )
+    if config.sandwich_norm and (
+        config.parallel_block or not config.prenorm
+        or config.residual_scale != 1.0
+        or any("none" in (s.mixer, s.mlp) for s in specs)
+    ):
+        raise ValueError(
+            "sandwich_norm is a pre-norm block of two sublayers, each between "
+            "two norms: no parallel_block, post-norm, residual multiplier or "
+            "one-sublayer layer beside it"
+        )
+    for s in specs:
+        if s.attn != "latent" or s.mixer != "attention":
+            continue
+        refused = {
+            "no LatentSpec (LayerSpec.latent)": s.latent is None,
+            "a window": bool(s.window or config.attn_window),
+            "int8 K/V (kv_cache_dtype)": config.kv_cache_dtype != "bf16",
+            "the paged pool (kv_block_tokens)": config.kv_block_tokens > 0,
+            "a bidirectional stack": config.bidirectional,
+            "lazy beam search (beam_width)": config.beam_width > 1,
+            "a relative score bias": config.positional == "relative",
+            "q/k norms over a head (its latents have their own)": config.qk_norm,
+            "sequence parallelism": config.attn_impl in ("ring", "ulysses"),
+            "positions other than rotary": not (
+                s.positions == "rope"
+                or s.positions == "model" and config.positional == "rope"
+            ),
+        }
+        for what, asked in refused.items():
+            if asked:
+                raise ValueError(
+                    f"a latent attention layer does not run with {what}: its "
+                    "cache is one row a position that all heads share, read "
+                    "by the absorbed form (models/latent_attention.py)"
+                )
+
+
+def attention_module(config: TransformerConfig, spec, kind: LayerSpec):
+    """``make_mixer``'s attention by the layer's kind: :class:`Attention`, or
+    the latent sibling behind the same call."""
+    if kind.attn == "latent":
+        from tpu_parallel.models.latent_attention import LatentAttention
+
+        return LatentAttention(config, kind.latent, name="attn")
+    return Attention(config, spec=spec, name="attn")
+
+
+def sandwich_block(config: TransformerConfig, x, mixer, mixer_kwargs, mlp_fn):
+    """A block with sandwich norms: ``a = x + N(mixer(N(x)))``, ``y = a +
+    N(mlp(N(a)))``, four norms a layer.  Called inside :class:`Block`'s
+    compact method (its combinations are checked at construction)."""
+    norm = lambda name, y: make_norm(config, name)(y).astype(config.dtype)
+    x = x + norm("norm_post_attn", mixer(norm("norm_attn", x), **mixer_kwargs))
+    return x + norm("norm_post_mlp", mlp_fn(norm("norm_mlp", x)))

@@ -618,12 +618,12 @@ class RoutedExperts(nn.Module):
                 scatter-add, whose order of summation is not fixed."""
                 rows = xin[order[:cap] // k].astype(cfg.dtype)
                 out = _grouped_ffn(rows, expert_weights, group_sizes)
-                back = out[jnp.minimum(place, cap - 1)]  # [T * k, d_in]
-                back = jnp.where(here[:, None], back.astype(jnp.float32), 0.0)
-                return jnp.sum(
-                    back.reshape(tokens, k, d_in) * mix.reshape(tokens, k, 1),
-                    axis=1,
-                )
+                # each assignment's output back at its token, weighed and
+                # summed over the token's k: :func:`_combine`, at the end of
+                # the file (in blocks of tokens where one float32 copy of
+                # every assignment's row is over a GiB: a prompt of 8192 at
+                # a width of 7680)
+                return _combine(out, place, here, mix, cap, k)
 
             plan = moe_plan(es, tokens, d, cfg.dtype, ep_size)
             worst, small = plan["buffer_rows"], plan["small_buffer_rows"]
@@ -728,3 +728,42 @@ def _shared_experts(es, dtype, xf, d: int):
         "tew,ewd->td", mid, ws[-1], preferred_element_type=jnp.float32,
     )
     return out if es.shared_sum else out / es.shared
+
+
+# what ONE temporary of the routed sum may take: a sixteenth of the memory of
+# the smallest chip served (a TPU v5e's 16 GiB).  The sum's temporary is one
+# float32 copy of every assignment's output row, [tokens * k, width]: up to
+# this many bytes it is taken in one piece, above it in blocks of tokens of a
+# quarter of it (a prompt of 8192 tokens, top-8, at a width of 7680 would be
+# 2 GiB beside 9.8 GB of weights)
+COMBINE_BYTES = (16 << 30) // 16
+
+
+def _combine(out, place, here, mix, cap: int, k: int) -> jax.Array:
+    """:class:`RoutedExperts`' routed sum ``[tokens, width]`` in float32 from
+    the buffer's outputs ``out`` ``[cap, width]``: assignment ``i`` (token
+    ``i // k``) reads row ``place[i]``, counts where it is ``here`` (held, and
+    inside the buffer), is weighed by ``mix[i]`` and summed over its token's
+    ``k``.  Gathers only.  Above ``COMBINE_BYTES`` the same sums are taken a
+    block of tokens at a time (``lax.map``), so that the float32 copy is a
+    block's and not the prompt's; a token's sum is the same either way."""
+    width = out.shape[-1]
+    tokens = place.shape[0] // k
+
+    def some(place, here, mix):
+        back = out[jnp.minimum(place, cap - 1)]  # [n * k, width]
+        back = jnp.where(here[:, None], back.astype(jnp.float32), 0.0)
+        n = place.shape[0] // k
+        return jnp.sum(
+            back.reshape(n, k, width) * mix.reshape(n, k, 1),
+            axis=1,
+        )
+
+    total, blocks = tokens * k * width * 4, 1
+    if total <= COMBINE_BYTES:
+        return some(place, here, mix)
+    while total > COMBINE_BYTES // 4 * blocks and tokens % (2 * blocks) == 0:
+        blocks *= 2
+    cut = lambda a: a.reshape(blocks, -1)
+    parts = lax.map(lambda a: some(*a), (cut(place), cut(here), cut(mix)))
+    return parts.reshape(tokens, width)

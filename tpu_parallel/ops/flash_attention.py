@@ -479,7 +479,7 @@ def _fwd_kernel(
         seg_q_ref, seg_k_ref, o_ref, lse_ref = rest
     else:
         o_ref, lse_ref = rest
-    d = q_ref.shape[-1]
+    d = v_ref.shape[-1]  # the value width (the keys' may differ)
     for qi in range(q_ref.shape[1] // block_q):
         rows = slice(qi * block_q, (qi + 1) * block_q)
         # keep MXU operands in the input dtype (bf16 on TPU: full MXU rate)
@@ -601,13 +601,13 @@ def _flash_fwd(
     stream: Optional[bool] = None,
     q_offset: int = 0,
 ) -> Tuple[jax.Array, jax.Array]:
-    b, h, s, d = q.shape
+    (b, h, s, d), e = q.shape, v.shape[-1]  # e: the width of a value
     h_kv, s_kv = k.shape[1], k.shape[2]
     scale = 1.0 / (d**0.5)
     bh = b * h
     qf = q.reshape(bh, s, d)
     kf = k.reshape(b * h_kv, s_kv, d)
-    vf = v.reshape(b * h_kv, s_kv, d)
+    vf = v.reshape(b * h_kv, s_kv, e)
     kv_row = _kv_row_map(h, h_kv)
     kernel_kwargs = dict(
         block_q=block_q,
@@ -631,7 +631,7 @@ def _flash_fwd(
         in_specs = [
             pl.BlockSpec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)),
             pl.BlockSpec((1, block_k, d), kv_map),
-            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, e), kv_map),
         ]
         args = [qf, kf, vf]
         if seg_q is not None:
@@ -653,32 +653,32 @@ def _flash_fwd(
             grid=(bh, s // block_q, num_ki),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda bh_, qi, ki: (bh_, qi, 0)),
+                pl.BlockSpec((1, block_q, e), lambda bh_, qi, ki: (bh_, qi, 0)),
                 pl.BlockSpec((1, block_q, 1), lambda bh_, qi, ki: (bh_, qi, 0)),
             ],
             out_shape=[
-                _sds((bh, s, d), q.dtype, qf),
+                _sds((bh, s, e), q.dtype, qf),
                 _sds((bh, s, 1), jnp.float32, qf),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, e), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
             interpret=interpret,
         )(*args)
-        return out.reshape(b, h, s, d), lse.reshape(b, h, s)
+        return out.reshape(b, h, s, e), lse.reshape(b, h, s)
 
     # resident: one grid step per (batch, head) row; consecutive heads of a
     # GQA group map to the same K/V block, which Pallas does not fetch twice
     in_specs = [
         pl.BlockSpec((1, s, d), lambda bh_: (bh_, 0, 0)),
         pl.BlockSpec((1, s_kv, d), lambda bh_: (kv_row(bh_), 0, 0)),
-        pl.BlockSpec((1, s_kv, d), lambda bh_: (kv_row(bh_), 0, 0)),
+        pl.BlockSpec((1, s_kv, e), lambda bh_: (kv_row(bh_), 0, 0)),
     ]
     args = [qf, kf, vf]
-    block_bytes = 2 * _padded_bytes(s, d, q.dtype) + 2 * _padded_bytes(
-        s_kv, d, q.dtype
+    block_bytes = 2 * _padded_bytes(s, max(d, e), q.dtype) + 2 * _padded_bytes(
+        s_kv, max(d, e), q.dtype
     )
     if seg_q is not None:
         # all H heads of batch row b read the same ids: the q side as
@@ -697,17 +697,17 @@ def _flash_fwd(
         grid=(bh,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, s, d), lambda bh_: (bh_, 0, 0)),
+            pl.BlockSpec((1, s, e), lambda bh_: (bh_, 0, 0)),
             pl.BlockSpec((1, n_q, 1, block_q), lambda bh_: (bh_, 0, 0, 0)),
         ],
         out_shape=[
-            _sds((bh, s, d), q.dtype, qf),
+            _sds((bh, s, e), q.dtype, qf),
             _sds((bh, n_q, 1, block_q), jnp.float32, qf),
         ],
         interpret=interpret,
         **_resident_params(interpret, block_bytes, 0, block_q, block_k),
     )(*args)
-    return out.reshape(b, h, s, d), lse.reshape(b, h, s)
+    return out.reshape(b, h, s, e), lse.reshape(b, h, s)
 
 
 # --- backward kernels ---------------------------------------------------------
@@ -1456,3 +1456,49 @@ def flash_attention(
         qt, kt, vt, seg, fwd_tile, bwd_tile, interpret, window, stream,
     )
     return out.transpose(0, 2, 1, 3)
+
+
+def flash_attention_fwd_bhsd(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
+    stream: Optional[bool] = None,
+) -> jax.Array:
+    """Causal flash attention, FORWARD ONLY, in the kernels' own layout, for a
+    query-key width that differs from the value width (latent attention
+    scores at 192 and sums values at 128): ``q`` ``[batch, heads, seq, dk]``,
+    ``k`` ``[batch, kv_heads, seq, dk]``, ``v`` ``[batch, kv_heads, seq, dv]``
+    give ``[batch, heads, seq, dv]``; scores are scaled by ``dk ** -0.5``.
+
+    The forward kernels of :func:`flash_attention` at the tiles and in the
+    variant :func:`flash_plan` gives the row and the query-key width, as every
+    caller's: a ``dk`` that is no multiple of the 128 lanes (192) is a
+    block's FULL last axis, which the chip's compiler lays out itself
+    (``tests/test_chip_compile.py`` compiles it).  No backward: the dq / dkv
+    kernels take one width, so this is the serving prefill's call and
+    differentiating it raises.  (At the end of the file: a kernel's bytes
+    carry the line numbers of the frames above it.)"""
+    (b, h, s, d), h_kv = q.shape, k.shape[1]
+    if h % h_kv != 0:
+        raise ValueError(f"q heads {h} not a multiple of k/v heads {h_kv}")
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    plan = flash_plan(
+        s, d, h // h_kv, q.dtype, block_q=block_q, block_k=block_k,
+        stream=stream,
+    )
+    if plan is None or plan["fwd"]["block_q"] % plan["fwd"]["block_k"]:
+        raise ValueError(
+            f"no tile of the flash kernels divides a row of {s} positions "
+            f"(block_q={block_q}, block_k={block_k}): pad the row to a "
+            "multiple of 128"
+        )
+    out, _ = _flash_fwd(
+        q, k, v, None, None, block_q=plan["fwd"]["block_q"],
+        block_k=plan["fwd"]["block_k"], interpret=interpret, stream=stream,
+    )
+    return out

@@ -1279,7 +1279,7 @@ class _PendingTick:
         "kind", "start", "t0", "t1", "tick_span", "events", "admitted",
         "chunks_advanced", "chunk_tokens", "chunk_spans", "active_tokens",
         "entering", "finals", "payload", "overlapped", "phases", "between",
-        "expert_rows", "firsts", "owners", "tiles",
+        "expert_rows", "firsts", "owners", "tiles", "latent_rows",
     )
 
     def __init__(self):
@@ -1318,6 +1318,9 @@ class _PendingTick:
         # (walked, held) stripe tiles of the decode kernel over the slots
         # this tick entered with (None: no program uses the kernel)
         self.tiles: Optional[Tuple[int, int]] = None
+        # stored rows the tick's decode steps read, over the latent layers
+        # (None: the model has none)
+        self.latent_rows: Optional[int] = None
 
     def add_phase(self, name: str, seconds: float) -> None:
         self.phases[name] = self.phases.get(name, 0.0) + seconds
@@ -1564,6 +1567,60 @@ class ServingEngine:
                         f"recurrent (state-space) layers under {option}: "
                         f"{why} - serve it on the fixed-slot pool with "
                         "bucketed or chunked prefill"
+                    )
+        from tpu_parallel.models.layers import depth_specs
+
+        self._latent = [
+            s.latent for s in depth_specs(cfg)
+            if s.mixer == "attention" and s.attn == "latent"
+        ]
+        if self._latent:
+            # a latent layer stores one row a position that all heads share
+            # and reads it through the absorbed form: what extends, shares,
+            # pages, exports or verifies K/V rows is not written for it
+            refused = {
+                "prefill_chunk_tokens": (
+                    prefill_chunk_tokens is not None,
+                    "chunk extension (and the unified tick's chunk phase) "
+                    "attends a chunk's queries against up-projected stored "
+                    "latents, which models/generate.py::prefill_extend_step "
+                    "and ops/flash_attention.py::flash_chunk_attention do "
+                    "not do",
+                ),
+                "prefix_cache_size > 0": (
+                    prefix_cache_size > 0,
+                    "a prefix hit lands stored rows and EXTENDS them with "
+                    "the rest of the prompt (serving/prefix_cache.py): chunk "
+                    "extension over a latent cache",
+                ),
+                "kv_block_tokens": (
+                    kv_block_tokens not in (None, 0),
+                    "the block-paged pool (serving/cache_pool.py) gathers "
+                    "K/V heads through a block table; the latent leaf has "
+                    "no paged layout",
+                ),
+                "kv_radix_cache / kv_host_blocks / kv_disk_dir": (
+                    bool(kv_radix_cache) or kv_host_blocks > 0
+                    or kv_disk_dir is not None,
+                    "the radix tree and the host and disk tiers live on the "
+                    "paged pool (and with them K/V export and import, "
+                    "serving/kv_wire.py)",
+                ),
+                "draft_tokens > 0": (
+                    draft_tokens > 0,
+                    "the verify tick scores draft_tokens + 1 rows a slot "
+                    "(serving/spec_decode.py); the absorbed form and its "
+                    "row count are written for a step of one (multi-token "
+                    "prediction needs the same tick)",
+                ),
+            }
+            for option, (asked, why) in refused.items():
+                if asked:
+                    raise NotImplementedError(
+                        f"the serving engine does not run a model with "
+                        f"latent attention layers under {option}: {why} - "
+                        "serve it on the fixed-slot pool with whole-prompt "
+                        "bucketed prefill"
                     )
         self._block_len = int(cfg.block_len)
         if self._block_len:
@@ -1993,6 +2050,7 @@ class ServingEngine:
         self.sampler_plan = self._plan_sampler(n_slots)
         self.block_plan = self._plan_blocks(n_slots)
         self.attn_plan = self._plan_attention(n_slots)
+        self.latent_plan = self._plan_latent(n_slots)
 
         n = n_slots
         self._tok = np.zeros(n, np.int32)
@@ -2059,7 +2117,10 @@ class ServingEngine:
         from tpu_parallel.ops.decode_attention import decode_attention_plan
 
         cfg = self.model.config
-        kv_heads = cfg.n_kv_heads or cfg.n_heads
+        kv_heads, head_dim = cfg.n_kv_heads or cfg.n_heads, cfg.head_dim
+        if self._latent:
+            # the absorbed form: every head against ONE stored head of a row
+            kv_heads, head_dim = 1, self._latent[0].row
         shapes = {"decode": max(1, 2 * self._block_len)}
         if self._block_len and self._fused_steps > 1:
             shapes["decode_narrow"] = self._block_len
@@ -2069,8 +2130,8 @@ class ServingEngine:
         plan = {}
         for name, new_len in shapes.items():
             found = decode_attention_plan(
-                (n_slots, new_len, cfg.n_heads, cfg.head_dim),
-                (n_slots, cfg.seq_len, kv_heads, cfg.head_dim),
+                (n_slots, new_len, cfg.n_heads, head_dim),
+                (n_slots, cfg.seq_len, kv_heads, head_dim),
                 dtype, jnp.int8 if cfg.kv_cache_dtype == "int8" else dtype,
                 scales=cfg.kv_cache_dtype == "int8", paged=self._paged,
                 bias=cfg.positional == "relative",
@@ -2082,7 +2143,7 @@ class ServingEngine:
         # the windows of the attention layers, by how many layers have each
         windows: Dict[int, int] = {}
         for spec in cfg.layer_specs:
-            if spec.mixer == "attention":
+            if spec.mixer == "attention" and spec.attn != "latent":
                 window = spec.window if spec.attn == "window" else 0
                 windows[window] = windows.get(window, 0) + 1
         self._attn_walk = None
@@ -2104,6 +2165,55 @@ class ServingEngine:
                    for k, v in p.items()},
             )
         return plan
+
+    def _plan_latent(self, n_slots: int) -> Optional[Dict[str, object]]:
+        """What a slot holds and which form runs for a model with latent
+        attention layers (``models/latent_attention.py``): the heads and the
+        five sizes, the bytes a position stores a layer and over the latent
+        layers, and the form of each program shape (``absorbed``: the stored
+        rows are read as they lie; ``expanded``: the call's own latents are
+        up-projected once and go through the flash kernels); logged and put
+        on the tracer once at build; None without such a layer.
+        ``latent_bytes_per_position`` goes to the metrics either way."""
+        cfg = self.model.config
+        if not self._latent:
+            self.metrics.set_latent_bytes_per_position(0)
+            return None
+        spec = self._latent[0]
+        row_bytes = spec.row * jnp.dtype(cfg.dtype).itemsize
+        plan = {
+            "layers": len(self._latent), "of_layers": cfg.n_layers,
+            "heads": cfg.n_heads, "q_rank": spec.q_rank,
+            "kv_rank": spec.kv_rank, "nope_dim": spec.nope_dim,
+            "rope_dim": spec.rope_dim, "v_dim": spec.v_dim, "row": spec.row,
+            "bytes_per_position_per_layer": row_bytes,
+            "bytes_per_position": row_bytes * len(self._latent),
+            "pool_bytes": row_bytes * len(self._latent) * n_slots * cfg.seq_len,
+            "decode": "absorbed",
+        }
+        prefill = "expanded" if cfg.prefill_flash else "absorbed"
+        for b in self._buckets or ():
+            plan[f"prefill_{b}"] = prefill
+        if self._buckets is None:
+            plan["prefill"] = prefill
+        self.metrics.set_latent_bytes_per_position(plan["bytes_per_position"])
+        logging.getLogger(__name__).info("latent_plan %s", json.dumps(plan))
+        if self.tracer.enabled:
+            self.tracer.instant("latent_plan", track="scheduler", **plan)
+        return plan
+
+    def _latent_rows_read(self, slots) -> Optional[int]:
+        """Stored rows the tick's decode steps read over the live ``slots``,
+        summed over the latent layers: a step reads what the slot holds once
+        its own row is in, ``_pos + j + 1`` at step ``j``.  From the host's
+        mirrors at launch, as :meth:`_walked_tiles` (a slot that ends inside
+        the tick is counted to the tick's end); None without a latent layer."""
+        if not self._latent or not len(slots):
+            return None
+        pos = self._pos[list(slots)].astype(np.int64)
+        steps = np.arange(1, self._fused_steps + 1)
+        rows = np.minimum(pos[:, None] + steps[None, :], self.model.config.seq_len)
+        return len(self._latent) * int(rows.sum())
 
     def _walked_tiles(self, slots) -> Optional[Tuple[int, int]]:
         """``(walked, held)`` for one tick over the live ``slots``: the
@@ -2526,6 +2636,7 @@ class ServingEngine:
         with self._phase(p, "dispatch") as dispatch:
             self._launch_decode(p)
             p.tiles = self._walked_tiles(p.entering)
+            p.latent_rows = self._latent_rows_read(p.entering)
             # active tokens RESIDENT during this tick's decode = slots'
             # written depths + chunked prefills' post-advance offsets,
             # captured BEFORE delivery retires finished slots — the
@@ -2749,7 +2860,7 @@ class ServingEngine:
                     and out.request.sampling.temperature > 0.0
                     for out in p.owners
                 ),
-                tiles=p.tiles,
+                tiles=p.tiles, latent_rows=p.latent_rows,
             )
             self._busy_end = end
             if p.between is not None and self.tracer.enabled:
@@ -2834,6 +2945,9 @@ class ServingEngine:
             # so the fresh record's delta-synced counters start at zero
             self.metrics.seed_block_pool(self.pool)
         self.metrics.set_state_bytes_per_slot(self._state_bytes_per_slot)
+        self.metrics.set_latent_bytes_per_position(
+            (self.latent_plan or {}).get("bytes_per_position", 0)
+        )
         return self.metrics
 
     def rebind_params(self, params, version: Optional[str] = None) -> None:

@@ -261,6 +261,11 @@ class ServingMetrics:
         # host's mirrors at launch (both stay 0 where no program uses it)
         self._tiles_walked = r.counter("serving_decode_tiles_walked_total")
         self._tiles_held = r.counter("serving_decode_tiles_held_total")
+        # stored rows the decode steps of latent attention layers read
+        # (models/latent_attention.py), over the layers and the busy ticks,
+        # by the host's mirrors at launch (0 for a model without one)
+        self._latent_rows = r.counter("serving_latent_positions_read_total")
+        self._latent_bytes = r.gauge("serving_latent_bytes_per_position")
         # per-tick stall attribution, pre-registered so every cause shows
         # a (possibly zero) series in exports
         self._stall = {
@@ -458,6 +463,7 @@ class ServingMetrics:
         hidden=(),
         sampled: bool = False,
         tiles: Optional[tuple] = None,
+        latent_rows: Optional[int] = None,
     ) -> None:
         """One BUSY tick (it dispatched decode work): its period
         ``seconds`` — from its launch's entry to its collect's exit, or
@@ -471,7 +477,9 @@ class ServingMetrics:
         the tick's sampler could have drawn (the device decides).  ``tiles``
         is ``(walked, held)``, the stripe tiles the decode kernel walked a
         layer-call over the live slots and those the slots hold in all (None
-        where no program uses the kernel).  Idle ticks
+        where no program uses the kernel); ``latent_rows`` the stored rows
+        the tick's decode steps read over the latent attention layers (None
+        for a model without one).  Idle ticks
         are left out (they would pull every mean toward the cost of doing
         nothing)."""
         self._busy_tick["1" if prefill else "0"].observe(seconds)
@@ -488,6 +496,8 @@ class ServingMetrics:
         if tiles is not None:
             self._tiles_walked.inc(tiles[0])
             self._tiles_held.inc(tiles[1])
+        if latent_rows is not None:
+            self._latent_rows.inc(latent_rows)
 
     def record_flush(self, cause: str) -> None:
         """A busy tick was collected before its successor was launched:
@@ -581,6 +591,11 @@ class ServingMetrics:
         """A wait for a program raised (a deleted array, a failed
         program): no completion was stamped."""
         self._device_faults.inc()
+
+    def set_latent_bytes_per_position(self, nbytes: int) -> None:
+        """What a position stores over the latent attention layers (the
+        engine's ``latent_plan``; 0 for a model without one)."""
+        self._latent_bytes.set(nbytes)
 
     def set_state_bytes_per_slot(self, nbytes: int) -> None:
         self._state_bytes.set(nbytes)
@@ -879,6 +894,11 @@ class ServingMetrics:
                 if self._tiles_held.value
                 else None
             ),
+            # stored rows the latent attention layers' decode steps read,
+            # over layers and busy ticks, and what a position stores over
+            # those layers (both 0 for a model without one)
+            "latent_positions_read": int(self._latent_rows.value),
+            "latent_bytes_per_position": int(self._latent_bytes.value),
             "host_ms_per_tick_p50": (
                 None
                 if self._host_ms_per_tick.percentile(50) is None
