@@ -32,6 +32,18 @@ Usage:
     python scripts/attn_microbench.py --seq 2048 --heads 6 --head-dim 128 --sweep
     python scripts/attn_microbench.py --decode            # the decode kernel beside XLA's program
     python scripts/attn_microbench.py --latent            # the forward kernel at latent attention's widths
+    python scripts/attn_microbench.py --fwd-sweep         # the forward kernel over the serving cells' prefill rows
+
+``--fwd-sweep`` is the sweep behind ``flash_plan``'s FORWARD rule (PERF.md
+section 6, PR 48): the forward kernel alone at square tiles of 128 / 256 /
+512 / 1024, resident and streamed, over the rows that the serving cells with
+heads of 128 and more prefill (``FWD_SWEEP_SHAPES``: one prompt a call, each
+cell's heads, K/V heads, widths, rule and windows, its buckets and its
+slot's last rung).  A line a variant: milliseconds a layer-call, the share of
+the least time of the pairs the rule lets a query see, the tiles computed
+and masked, and ``derived`` where the variant is the one ``flash_plan``
+gives that shape today; the lines also go to
+``chiprun_out/attn_fwd_sweep_<unix time>.jsonl``.  ``--cells a,b`` keeps some of the table's rows.
 
 ``--latent`` times the FORWARD kernel at latent attention's shape (one prompt,
 128 heads, scores at 192 and values at 128; ``LATENT_SEQS``) two ways: the
@@ -52,6 +64,7 @@ import os
 import shutil
 import sys
 import tempfile
+import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -206,15 +219,14 @@ def run_decode_variants(name, lengths_name, window, operands, size, lengths,
 
 
 LATENT_SEQS = (4096, 8192)
-# (label, tile, stream): the call's own choice (the largest of 512 / 256 / 128
-# that divides the row, resident where a row fits), other tiles, and the
-# streamed kernel that flash_plan would derive past 4096
+# (label, tile, stream): the call's own choice (what flash_plan derives for
+# the row, as for every caller), other tiles, and the streamed kernel
 LATENT_VARIANTS = (("own", None, None), ("t256", 256, None),
                    ("t1024", 1024, None), ("streamed512", 512, True))
 
 
 def latent_main():
-    from lib import flops, mla_cost, xplane
+    from lib import flops, mla_cost
     from lib.peaks import peaks
     from tpu_parallel.runtime import require_tpu
 
@@ -231,7 +243,16 @@ def latent_main():
         # zero key columns add nothing to a score; the kernel scales by
         # width ** -0.5, so the padded queries carry the rest of the factor
         padded = (pad(q) * jnp.asarray((256 / qk) ** 0.5, q.dtype), pad(k), pad(v))
+        ran = set()
         for label, tile, stream in LATENT_VARIANTS:
+            # not one program under two labels: programs that differ in their
+            # op names alone share one compile-cache entry, and the trace then
+            # shows both under the first one's name (PR 47 read its ``own``
+            # rows twice over that way, 16.47 and 83.19 ms for 8.23 and 41.60)
+            plan = fa.flash_plan(s, qk, block_q=tile, block_k=tile, stream=stream)["fwd"]
+            if (plan["block_q"], plan["variant"]) in ran:
+                continue
+            ran.add((plan["block_q"], plan["variant"]))
             for widths, operands in (("192_128", (q, k, v)), ("256_256", padded)):
                 tag = f"mb_latent_{s}_{widths}_{label}"
 
@@ -256,13 +277,7 @@ def latent_main():
             res = fn(*operands)
         jax.block_until_ready(res)
     jax.profiler.stop_trace()
-    trace = xplane.load(xplane.find_trace(logdir))
-    shutil.rmtree(logdir, ignore_errors=True)
-    seconds = collections.Counter()
-    for name, start, end in trace["devices"][min(trace["devices"])]["ops"]:
-        for tag, *_ in jobs:
-            if name == tag or name.startswith(tag + "."):
-                seconds[tag] += end - start
+    seconds = device_seconds_by_tag(logdir, {tag for tag, *_ in jobs})
     first = {}
     for tag, s, widths, label, _, _, out in jobs:
         least, bound = flops.roofline_seconds(
@@ -281,6 +296,164 @@ def latent_main():
         }), flush=True)
 
 
+# the forward sweep's rows (PERF.md section 6, PR 48): one prompt a call,
+# name: heads, K/V heads, score and value widths, the rule as the kernels
+# take it (1 causal, L > 1 blocks of L), windows, the cell's prefill rows
+FWD_SWEEP_SHAPES = {
+    "longdoc": dict(heads=128, kv_heads=128, qk=192, v=128, rule=1,
+                    windows=(0,), rows=(1024, 2048, 3072, 4096, 6144, 8192)),
+    "longshort": dict(heads=16, kv_heads=1, qk=128, v=128, rule=1,
+                      windows=(0, 4096),
+                      rows=(512, 1024, 1536, 2048, 3072, 4096, 6144, 8192)),
+    "blockgen": dict(heads=32, kv_heads=4, qk=128, v=128, rule=4,
+                     windows=(0,), rows=(256, 512, 1024, 2048, 3072)),
+    "reasoning": dict(heads=32, kv_heads=2, qk=128, v=128, rule=1,
+                      windows=(0,), rows=(128, 256, 512, 1024, 2048)),
+    # heads of 64 past the 4096 rows no cell prefills (configs/gpt2_125m_long)
+    "long64": dict(heads=12, kv_heads=12, qk=64, v=64, rule=1,
+                   windows=(0,), rows=(4096, 8192)),
+    # no cell's shapes: what tells the head's width from the group as the
+    # key of the tile (every head its own K/V at 128; one K/V head at 192;
+    # a width of two full lane tiles)
+    "mha128": dict(heads=32, kv_heads=32, qk=128, v=128, rule=1,
+                   windows=(0,), rows=(1024, 2048, 4096)),
+    "gqa192": dict(heads=16, kv_heads=1, qk=192, v=128, rule=1,
+                   windows=(0,), rows=(2048, 4096)),
+    "mha256": dict(heads=16, kv_heads=16, qk=256, v=256, rule=1,
+                   windows=(0,), rows=(2048, 4096)),
+}
+# a resident variant unrolls its walk: the sweep compiles none past this
+FWD_SWEEP_MAX_BODIES = 320
+
+
+def visible_pairs(rows: int, rule: int, window: int) -> int:
+    """(query, key) pairs the rule lets see each other in a row of ``rows``:
+    what the two matmuls of the forward have to compute."""
+    if window:
+        return sum(min(q + 1, window) for q in range(rows))
+    if rule > 1:  # a query sees to the end of its block of ``rule``
+        return sum((q // rule + 1) * rule for q in range(rows))
+    return rows * (rows + 1) // 2
+
+
+def fwd_sweep_jobs(fa, cells):
+    """``(tag, line, cost, fn, operands)`` for every variant of the table's
+    rows: the line holds what is known before anything runs, ``cost`` the
+    FLOPs and bytes of the pairs the rule lets see each other."""
+    for cell in cells:
+        shape = FWD_SWEEP_SHAPES[cell]
+        h, h_kv, qk, dv, rule = (
+            shape[k] for k in ("heads", "kv_heads", "qk", "v", "rule")
+        )
+        for s in shape["rows"]:
+            keys = jax.random.split(jax.random.PRNGKey(s), 3)
+            q = jax.random.normal(keys[0], (1, h, s, qk), jnp.bfloat16)
+            k = jax.random.normal(keys[1], (1, h_kv, s, qk), jnp.bfloat16)
+            v = jax.random.normal(keys[2], (1, h_kv, s, dv), jnp.bfloat16)
+            for window in shape["windows"]:
+                if window >= s:
+                    continue  # the window binds nothing: the causal program
+                own = fa.flash_plan(
+                    s, max(qk, dv), h // h_kv, causal=rule, window=window
+                )["fwd"]
+                for tile in SWEEP_TILES:
+                    if s % tile or tile % rule:
+                        continue
+                    for stream in (False, True):
+                        plan = fa.flash_plan(
+                            s, max(qk, dv), h // h_kv, causal=rule,
+                            window=window, block_q=tile, block_k=tile,
+                            stream=stream,
+                        )["fwd"]
+                        if not stream and plan["tiles_computed"] > FWD_SWEEP_MAX_BODIES:
+                            continue
+                        tag = (f"mb_fs_{cell}_{s}_w{window}_"
+                               f"{'s' if stream else 'r'}{tile}")
+
+                        def run(q, k, v, tag=tag, tile=tile, stream=stream,
+                                window=window):
+                            with jax.named_scope(tag):
+                                return fa._flash_fwd(
+                                    q, k, v, None, None, block_q=tile,
+                                    block_k=tile, interpret=INTERPRET,
+                                    causal=rule, window=window, stream=stream,
+                                )[0]
+
+                        line = {
+                            "cell": cell, "rows": s, "window": window,
+                            "tile": tile, "variant": plan["variant"],
+                            "tiles_computed": plan["tiles_computed"],
+                            "tiles_masked": plan["tiles_masked"],
+                            "derived": plan == own,
+                        }
+                        cost = {
+                            "flops": 2 * h * (qk + dv) * visible_pairs(s, rule, window),
+                            "bytes": 2 * s * (h + h_kv) * (qk + dv),
+                        }
+                        yield tag, line, cost, jax.jit(run), (q, k, v)
+
+
+def device_seconds_by_tag(logdir, tags):
+    """Seconds of the device's ops named by each tag in the trace under
+    ``logdir``, which is removed (an op carries the innermost
+    ``jax.named_scope``'s name, ``<tag>`` or ``<tag>.<n>``)."""
+    from lib import xplane
+
+    trace = xplane.load(xplane.find_trace(logdir))
+    shutil.rmtree(logdir, ignore_errors=True)
+    seconds = collections.Counter()
+    for name, start, end in trace["devices"][min(trace["devices"])]["ops"]:
+        tag = name.split(".", 1)[0]
+        if tag in tags:
+            seconds[tag] += end - start
+    return seconds
+
+
+def fwd_sweep_main(cells):
+    from lib import flops
+    from lib.peaks import peaks
+    from tpu_parallel.runtime import require_tpu
+
+    fa = importlib.import_module("tpu_parallel.ops.flash_attention")
+    require_tpu()
+    peak = peaks(jax.devices()[0].device_kind)
+    os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
+    # a name of its own a call: the chip tool merges a call's files over
+    # what an earlier call brought back
+    out_path = os.path.join(
+        REPO, "chiprun_out", f"attn_fwd_sweep_{int(time.time())}.jsonl"
+    )
+    for cell in cells:  # a trace a cell: its operands go when it is read
+        jobs = []
+        for job in fwd_sweep_jobs(fa, [cell]):
+            tag, line, _, fn, operands = job
+            try:
+                jax.block_until_ready(fn(*operands))  # compile + warm up
+            except Exception as exc:  # noqa: BLE001 - a tile the chip refuses
+                print(json.dumps({**line, "error": repr(exc)[:160]}), flush=True)
+                continue
+            jobs.append(job)
+        logdir = tempfile.mkdtemp(prefix="attn_microbench_")
+        jax.profiler.start_trace(logdir)
+        for *_, fn, operands in jobs:
+            for _ in range(REPEATS):
+                res = fn(*operands)
+            jax.block_until_ready(res)
+        jax.profiler.stop_trace()
+        seconds = device_seconds_by_tag(logdir, {tag for tag, *_ in jobs})
+        with open(out_path, "a") as out:
+            for tag, line, cost, *_ in jobs:
+                least, bound = flops.roofline_seconds(cost, peak)
+                ms = seconds[tag] / REPEATS * 1e3
+                line.update(
+                    kernel_ms=round(ms, 4), least_ms=round(least * 1e3, 4),
+                    bound=bound,
+                    roofline_pct=round(100 * least * 1e3 / ms, 2) if ms else None,
+                )
+                print(json.dumps(line), flush=True)
+                out.write(json.dumps(line) + "\n")
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--batch", type=int, default=16)
@@ -296,13 +469,21 @@ def main():
     ap.add_argument("--latent", action="store_true",
                     help="the forward kernel at latent attention's widths "
                          "(192 / 128) beside both padded to 256")
+    ap.add_argument("--fwd-sweep", action="store_true",
+                    help="the forward kernel at tiles 128-1024, resident and "
+                         "streamed, over the serving cells' prefill rows "
+                         "(FWD_SWEEP_SHAPES)")
+    ap.add_argument("--cells", default=",".join(FWD_SWEEP_SHAPES),
+                    help="--fwd-sweep: the table's rows to run, by name")
     args = ap.parse_args()
     if args.decode:
         return decode_main()
+    if args.fwd_sweep:
+        return fwd_sweep_main(args.cells.split(","))
     if args.latent:
         return latent_main()
 
-    from lib import flops, xplane
+    from lib import flops
     from lib.peaks import peaks
     from tpu_parallel.runtime import require_tpu
 
@@ -364,13 +545,7 @@ def main():
             res = fn(*fn_args)
         jax.block_until_ready(res)
     jax.profiler.stop_trace()
-    trace = xplane.load(xplane.find_trace(logdir))
-    shutil.rmtree(logdir, ignore_errors=True)
-    seconds = collections.Counter()
-    for name, start, end in trace["devices"][min(trace["devices"])]["ops"]:
-        for tag, *_ in jobs:
-            if name == tag or name.startswith(tag + "."):
-                seconds[tag] += end - start
+    seconds = device_seconds_by_tag(logdir, {tag for tag, *_ in jobs})
 
     model = {"seq_len": s, "d_model": h * d}
     least_all, bound = flops.roofline_seconds(

@@ -18,7 +18,8 @@ line, one JSON line ``device_clock_check``:
 - ``clock_against_device``: the clock's own (start, done) of the traced
   programs against the device's: by kind, the count and the MEDIAN of the
   clock's milliseconds beside the median of the device's, over the matched
-  stamps only;
+  stamps only, and each matched program's pair in the clock's order (a
+  prefill's bucket shows in its milliseconds);
 - ``split``: ``summary()["device_by_shape"]`` over the whole window with the
   mean milliseconds a call, the seconds by kind, the idle seconds, and tick +
   prefill + idle seconds a busy tick beside ``busy_tick_ms_mean``;
@@ -124,6 +125,7 @@ def clock_against_device(stamps, matched, seen, ops):
     spread, i0, offset = best
     clock_idle, prev_clock_done = 0.0, None
     by_kind = {}  # kind: ([the clock's ms], [the device's ms])
+    each = []  # [kind, the clock's ms, the device's ms] in the clock's order
     for j, program in enumerate(matched):
         kind, start, done = seen[i0 + j]
         if prev_clock_done is not None:
@@ -134,6 +136,7 @@ def clock_against_device(stamps, matched, seen, ops):
         mine, theirs = by_kind.setdefault(kind, ([], []))
         mine.append(1e3 * (done - start))
         theirs.append(1e3 * (program[1] - program[0]))
+        each.append([kind, round(mine[-1], 3), round(theirs[-1], 3)])
     # the device's own idle time over the same span, by its ops
     from lib import xplane
 
@@ -152,6 +155,8 @@ def clock_against_device(stamps, matched, seen, ops):
                    round(statistics.median(theirs), 3)]
             for kind, (mine, theirs) in by_kind.items()
         },
+        # a prefill call reads by its bucket: one line a matched program
+        "ms_each_clock_vs_device": each,
     }
 
 

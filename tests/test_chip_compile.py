@@ -65,9 +65,10 @@ def _bidirectional(q, k, v):
 # name -> (fn(q, k, v, *extra), q shape, kv shape, extra int32 operand
 # shapes[, custom calls]).  Shapes are [batch, seq, heads, head_dim], bf16.
 # Every case compiles forward AND backward: two custom calls on the resident
-# path (the forward and the one-pass backward), three on the streamed one
-# (fwd, dq, dkv) — about a second a case here; the first pays libtpu's
-# start-up.
+# path (the forward and the one-pass backward), three where the backward is
+# the streamed pair (fwd, dq, dkv) — about a second a case here, half a
+# minute and more where a resident walk unrolls over a hundred tile bodies;
+# the first pays libtpu's start-up.
 KERNEL_CASES = {
     # the benchmark's train cell: tiles derived from the shape
     "gpt2_125m_derived": (
@@ -110,11 +111,20 @@ KERNEL_CASES = {
         ),
         (16, 1024, 12, 64), (16, 1024, 12, 64), (),
     ),
+    # the streamed kernels, all three (``stream=True``: by itself the
+    # forward of a row whose blocks fit VMEM is resident)
     "gqa_16q_4kv_seq8192_streamed": (
         functools.partial(
-            flash_attention, block_q=512, block_k=512, interpret=False
+            flash_attention, block_q=512, block_k=512, stream=True,
+            interpret=False,
         ),
         (1, 8192, 16, 128), (1, 8192, 4, 128), (), 3,
+    ),
+    # configs/gpt2_125m_long.py's attention as derived: the forward resident
+    # at a tile of 512 (136 tile bodies), the backward the streamed pair
+    "gpt2_125m_long_seq8192_derived": (
+        functools.partial(flash_attention, interpret=False),
+        (2, 8192, 12, 64), (2, 8192, 12, 64), (), 3,
     ),
     "packed_segment_ids": (
         lambda q, k, v, seg: flash_attention(
@@ -509,20 +519,20 @@ def test_latent_moe_cell_compiles_for_v5e(program, v5e_chip, monkeypatch):
         assert len(re.findall(r"%attn\.decode_stripes[.\d]* = ", text)) == 1
 
 
-@pytest.mark.parametrize("seq", [4096, 8192])
+@pytest.mark.parametrize("seq", [4096, 6144, 8192])
 def test_latent_flash_forward_compiles_for_v5e(seq, v5e_chip, monkeypatch):
     """The forward flash kernels at latent attention's real widths: 128 heads
     that score at 192 (no multiple of the 128 lanes: a block's full last axis)
-    and sum values at 128, one prompt of 4096 and of 8192, as the prefill calls
-    them: at ``flash_plan``'s tiles, as every caller of the kernels."""
+    and sum values at 128, one prompt of 4096, 6144 and 8192, as the prefill
+    calls them: at ``flash_plan``'s tiles, as every caller of the kernels."""
     from tpu_parallel.ops.flash_attention import flash_attention_fwd_bhsd, flash_plan
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    # the derived plan: resident at a tile of 256 up to 4096 positions, the
-    # streamed kernel at 512 past them
+    # the derived plan: the resident kernel at a tile of 512 while the row's
+    # blocks fit VMEM, which the compile below holds (PERF.md section 6, PR 48)
     plan = flash_plan(seq, 192)["fwd"]
-    assert (plan["variant"], plan["block_q"]) == (
-        ("resident", 256) if seq == 4096 else ("streamed", 512)
+    assert (plan["variant"], plan["block_q"], plan["block_k"]) == (
+        "resident", 512, 512
     )
     spec = lambda width: jax.ShapeDtypeStruct(
         (1, 128, seq, width), jnp.bfloat16, sharding=v5e_chip
@@ -533,6 +543,32 @@ def test_latent_flash_forward_compiles_for_v5e(seq, v5e_chip, monkeypatch):
     assert lowered.as_text().count("tpu_custom_call") == 1
     assert lowered.out_info.shape == (1, 128, seq, 128)
     lowered.compile()  # raises what the chip's compiler would raise
+
+
+@pytest.mark.parametrize("window", [0, 4096])
+def test_longest_grouped_forward_compiles_for_v5e(window, v5e_chip, monkeypatch):
+    """The expert cell's last rung: one prompt of 8192, 16 query heads on ONE
+    K/V head of 128, causal and under the 4096 window of three layers in four,
+    forward only as the serving prefill calls it: resident at a tile of 512,
+    the window's tiles classified at trace time (the streamed kernel it took
+    before masked every one of its 108)."""
+    from tpu_parallel.ops.flash_attention import flash_plan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    plan = flash_plan(8192, 128, 16, window=window)["fwd"]
+    assert plan == {
+        "block_q": 512, "block_k": 512, "variant": "resident",
+        "tiles_computed": 108 if window else 136,
+        "tiles_masked": 16 + 8 if window else 16,
+    }
+    spec = lambda heads: jax.ShapeDtypeStruct(
+        (1, 8192, heads, 128), jnp.bfloat16, sharding=v5e_chip
+    )
+    lowered = jax.jit(
+        functools.partial(flash_attention, window=window, interpret=False)
+    ).lower(spec(16), spec(1), spec(1))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    lowered.compile()
 
 
 @pytest.mark.parametrize("program", ["decode_tick", "prefill_8192"])

@@ -482,17 +482,19 @@ def test_stream_chunk_attention_combines(rng):
 
 
 def test_stream_auto_dispatch_long_seq(rng):
-    """seq 8192 > STREAM_SEQ_THRESHOLD auto-selects the streamed kernels and
-    fwd+bwd stay correct (spot-checked against the dense reference on a
-    slice-able size is impractical at 8k; instead check self-consistency of
-    the online softmax: output rows equal a direct jnp computation on a few
-    sampled query positions)."""
+    """The streamed kernels at seq 8192 stay correct (``stream=True``: since
+    the forward's variant follows the bytes of a row, an 8192 x 64 row is
+    resident by itself; a dense reference is impractical at 8k, so the check
+    is the online softmax's self-consistency: output rows equal a direct jnp
+    computation on a few sampled query positions)."""
     b, s, h, d = 1, 8192, 1, 64
     ks = jax.random.split(rng, 3)
     q, k, v = (
         jax.random.normal(kk, (b, s, h, d), jnp.float32) * 0.1 for kk in ks
     )
-    out = flash_attention(q, k, v, block_q=512, block_k=512, interpret=True)
+    out = flash_attention(
+        q, k, v, block_q=512, block_k=512, interpret=True, stream=True
+    )
 
     # dense ground truth at a handful of query positions
     for pos in (0, 511, 4096, 8191):
@@ -516,8 +518,9 @@ def test_stream_long_seq_backward_runs(rng):
 
     def loss(q, k, v):
         return (
-            flash_attention(q, k, v, block_q=512, block_k=512, interpret=True)
-            ** 2
+            flash_attention(
+                q, k, v, block_q=512, block_k=512, interpret=True, stream=True
+            ) ** 2
         ).sum()
 
     gq, gk, gv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
@@ -820,27 +823,176 @@ _PLAN_SHAPES = [
 ]
 
 
-@pytest.mark.parametrize("seq,head_dim,group", _PLAN_SHAPES)
-def test_derived_tiles_divide_the_row(seq, head_dim, group):
-    """Every shape of the table gets tiles that divide it, a walk short
-    enough to unroll or the streamed kernels, and an explicit tile wins."""
+def _holds_the_variant_rule(plan, seq, head_dim, group):
+    """A resident pass fits what its rule allows: the forward the VMEM budget
+    by the bytes of the row's blocks and its live score tiles, the backward
+    the row count; both a walk short enough to unroll."""
     from tpu_parallel.ops.flash_attention import (
-        MAX_STATIC_TILES, STREAM_SEQ_THRESHOLD, flash_plan,
+        MAX_STATIC_TILES, RESIDENT_VMEM_BUDGET, STREAM_SEQ_THRESHOLD,
+        _fwd_row_bytes, _resident_need,
     )
 
-    plan = flash_plan(seq, head_dim, group)
-    for name, bodies, rows in (("fwd", 1, seq), ("bwd", group, group * seq)):
+    for name, bodies in (("fwd", 1), ("bwd", group)):
         p = plan[name]
         assert seq % p["block_q"] == 0 and seq % p["block_k"] == 0
         assert p["block_q"] % p["block_k"] == 0
-        if p["variant"] == "resident":
-            assert rows <= STREAM_SEQ_THRESHOLD
-            assert bodies * p["tiles_computed"] <= MAX_STATIC_TILES
         assert 0 < p["tiles_masked"] <= p["tiles_computed"]
+        if p["variant"] != "resident":
+            continue
+        assert bodies * p["tiles_computed"] <= MAX_STATIC_TILES
+        if name == "fwd":
+            need = _resident_need(
+                _fwd_row_bytes(seq, seq, head_dim, jnp.bfloat16), 0,
+                p["block_q"], p["block_k"],
+            )
+            assert need <= RESIDENT_VMEM_BUDGET
+        else:
+            assert group * seq <= STREAM_SEQ_THRESHOLD
     assert plan["fused_bwd"] == (plan["bwd"]["variant"] == "resident")
+
+
+@pytest.mark.parametrize("seq,head_dim,group", _PLAN_SHAPES)
+def test_derived_tiles_divide_the_row(seq, head_dim, group):
+    """Every shape of the table gets tiles that divide it, a resident pass
+    only where its rule allows one (the forward by bytes, the backward by
+    rows) or the streamed kernels, and an explicit tile wins."""
+    from tpu_parallel.ops.flash_attention import flash_plan
+
+    plan = flash_plan(seq, head_dim, group)
+    _holds_the_variant_rule(plan, seq, head_dim, group)
+    # the forward of a row that fits is resident whatever its length: a
+    # causal 8192 row at a tile of 512 is 136 bodies; 32768 rows do not fit
+    assert plan["fwd"]["variant"] == ("resident" if seq <= 8192 else "streamed")
     explicit = flash_plan(seq, head_dim, group, block_q=64, block_k=32)
     for name in ("fwd", "bwd"):
         assert (explicit[name]["block_q"], explicit[name]["block_k"]) == (64, 32)
+
+
+# the backward's plan of every shape above as PR 47 left it (tile, variant,
+# tiles computed, tiles masked): PR 48 changed the forward's rule alone
+_BACKWARD_PLANS = {
+    64: (64, "resident", 1, 1), 128: (128, "resident", 1, 1),
+    256: (256, "resident", 1, 1), 512: (256, "resident", 3, 2),
+    1024: (256, "resident", 10, 4), 2048: (256, "resident", 36, 8),
+    4096: (256, "resident", 136, 16), 8192: (512, "streamed", 136, 136),
+    32768: (2048, "streamed", 136, 136),
+}
+_GROUPED_BACKWARD_PLANS = {  # a group of 4: streamed past 4096 / 4 rows
+    **_BACKWARD_PLANS, 2048: (512, "streamed", 10, 10),
+    4096: (512, "streamed", 36, 36),
+}
+
+
+@pytest.mark.parametrize("seq,head_dim,group", _PLAN_SHAPES)
+def test_the_backwards_plan_is_the_one_it_had(seq, head_dim, group):
+    from tpu_parallel.ops.flash_attention import flash_plan
+
+    tile, variant, computed, masked = (
+        _GROUPED_BACKWARD_PLANS if group == 4 else _BACKWARD_PLANS
+    )[seq]
+    assert flash_plan(seq, head_dim, group)["bwd"] == {
+        "block_q": tile, "block_k": tile, "variant": variant,
+        "tiles_computed": computed, "tiles_masked": masked,
+    }
+    if head_dim < 128 and seq <= 4096:  # the forward too: tiles of 512
+        fwd = flash_plan(seq, head_dim, group)["fwd"]
+        assert (fwd["block_q"], fwd["variant"]) == (min(seq, 512), "resident")
+
+
+# the prefill shapes of the serving cells with heads of 128 and more (PERF.md
+# section 6, PR 48): head width, group, rule, window, and each row with the
+# forward tile that flash_plan derives (512 at 192 columns; at 128 columns
+# one tile to 512 rows, then 128 grown to at most 16 tiles a side); all
+# resident, cell 3's 6144 and 8192 rows under its window too
+_CELL_FORWARD_PLANS = [
+    (cell, seq, width, group, rule, window, tile)
+    for cell, width, group, rule, windows, rows in (
+        ("longdoc", 192, 1, True, (0,), {
+            1024: 512, 2048: 512, 3072: 512, 4096: 512, 6144: 512, 8192: 512}),
+        ("longshort", 128, 16, True, (0, 4096), {
+            512: 512, 1024: 128, 1536: 128, 2048: 128, 3072: 256, 4096: 256,
+            6144: 512, 8192: 512}),
+        ("blockgen", 128, 8, 4, (0,), {
+            256: 256, 512: 512, 1024: 128, 2048: 128, 3072: 256}),
+        ("reasoning", 128, 16, True, (0,), {
+            128: 128, 256: 256, 512: 512, 1024: 128, 2048: 128}),
+    )
+    for window in windows
+    for seq, tile in rows.items()
+]
+
+
+@pytest.mark.parametrize(
+    "cell,seq,width,group,rule,window,tile", _CELL_FORWARD_PLANS
+)
+def test_forward_plan_of_the_serving_cells(cell, seq, width, group, rule,
+                                           window, tile):
+    """One rule for every caller, keyed on shape alone (the head's width and
+    the row): the forward tile the sweep found and the resident kernel while
+    the row's blocks fit VMEM."""
+    from tpu_parallel.ops.flash_attention import _count_tiles, flash_plan
+
+    plan = flash_plan(seq, width, group, causal=rule, window=window)
+    computed, masked = _count_tiles(
+        seq // tile, seq // tile, tile, tile, rule, window
+    )
+    assert plan["fwd"] == {
+        "block_q": tile, "block_k": tile, "variant": "resident",
+        "tiles_computed": computed, "tiles_masked": masked,
+    }
+    # under a window the resident walk classifies its tiles at trace time:
+    # the diagonal's and the window edge's are masked, not every one
+    assert masked < computed or computed == 1
+    _holds_the_variant_rule(plan, seq, width, group)
+
+
+@pytest.mark.parametrize("seq,head_dim,causal,tile,computed", [
+    (16384, 128, True, 1024, 136),   # the blocks of a 16k row: 112 MiB
+    (16384, 64, True, 1024, 136),    # lanes pad 64 columns to 128: the same
+    (8192, 128, False, 512, 256),    # a full walk: more bodies than unroll
+])
+def test_forward_streams_past_the_budget_or_the_unroll(seq, head_dim, causal,
+                                                       tile, computed):
+    from tpu_parallel.ops.flash_attention import flash_plan
+
+    fwd = flash_plan(seq, head_dim, causal=causal)["fwd"]
+    assert (fwd["block_q"], fwd["variant"], fwd["tiles_computed"]) == (
+        tile, "streamed", computed
+    )
+    # an explicit variant wins either way
+    assert flash_plan(seq, head_dim, causal=causal, stream=False)["fwd"][
+        "variant"] == "resident"
+    assert flash_plan(1024, head_dim, stream=True)["fwd"]["variant"] == "streamed"
+
+
+def test_resident_forward_past_4096_rows_at_two_widths(rng):
+    """The forward a latent prefill calls, on a row past the 4096 that used to
+    send it to the streamed kernel: scores at 192, values at 128, one head;
+    resident at the derived tile of 512 (45 tile bodies at 4608 rows),
+    against a direct computation at sampled positions, as
+    ``test_stream_auto_dispatch_long_seq`` checks the streamed one."""
+    from tpu_parallel.ops.flash_attention import (
+        flash_attention_fwd_bhsd, flash_plan,
+    )
+
+    s, dk, dv = 4608, 192, 128
+    plan = flash_plan(s, dk)["fwd"]
+    assert (plan["variant"], plan["block_q"], plan["tiles_computed"]) == (
+        "resident", 512, 45
+    )
+    ks = jax.random.split(rng, 3)
+    q = jax.random.normal(ks[0], (1, 1, s, dk), jnp.float32) * 0.1
+    k = jax.random.normal(ks[1], (1, 1, s, dk), jnp.float32) * 0.1
+    v = jax.random.normal(ks[2], (1, 1, s, dv), jnp.float32) * 0.1
+    out = flash_attention_fwd_bhsd(q, k, v, interpret=True)
+    assert out.shape == (1, 1, s, dv)
+    for pos in (0, 511, 512, 4095, 4096, 4607):
+        scores = jnp.einsum("d,kd->k", q[0, 0, pos], k[0, 0, : pos + 1]) / jnp.sqrt(dk)
+        ref = jax.nn.softmax(scores) @ v[0, 0, : pos + 1]
+        np.testing.assert_allclose(
+            np.asarray(out[0, 0, pos]), np.asarray(ref), rtol=2e-3, atol=2e-3,
+            err_msg=f"pos={pos}",
+        )
 
 
 def test_plan_of_the_train_cell_is_one_backward_pass_and_a_masked_diagonal():

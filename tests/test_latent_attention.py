@@ -343,9 +343,21 @@ def test_engine_serves_it_and_counts_the_rows_it_reads(engine_toy):
     assert plan["decode"] == "absorbed"
     assert plan["prefill_16"] == plan["prefill_32"] == "expanded"
     assert eng.attn_plan == {"decode": {"path": "xla"}}
+    # what the flash kernels do in each expanded shape: by itself and, for
+    # the drivers that print latent_plan, at its END (a row under 128
+    # positions is one tile; the head's width is its scores', 16 + 8)
+    one_tile = lambda n: {
+        "tile": n, "variant": "resident", "tiles_computed": 1, "tiles_masked": 1,
+    }
+    assert eng.prefill_attn_plan == {
+        "prefill_16": one_tile(16), "prefill_32": one_tile(32),
+    }
+    assert list(plan)[-1] == "prefill_attn"
+    assert plan["prefill_attn"] == eng.prefill_attn_plan
     instants = {e["name"]: e for e in tracer.instants if e["name"].endswith("_plan")}
     assert instants["latent_plan"]["attrs"]["row"] == 40
     assert "attn_plan" in instants and "moe_plan" in instants
+    assert instants["prefill_attn_plan"]["attrs"]["prefill_32_tile"] == 32
     # the pool: one latent row a position and layer, no K/V leaf
     names = {p[-1].key for p, _ in jax.tree_util.tree_flatten_with_path(eng.pool.cache)[0]}
     assert names == {"cached_latent", "cached_pos", "cache_index"}

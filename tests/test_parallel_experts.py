@@ -217,6 +217,46 @@ def test_engine_serves_the_references_best_token(name):
     assert engine.moe_plan["decode"]["buffer_rows"] == 4 * es.top_k
 
 
+@pytest.mark.parametrize("flash", [True, False])
+def test_engine_says_what_the_flash_kernels_do_in_each_prefill_shape(flash):
+    """``prefill_attn_plan``: for each bucket (the slot's length is the last
+    rung) and each window the layers have, the forward's tile and variant and
+    the tiles of a row's walk, from ``flash_plan`` at the layers' head width,
+    group and rule; logged and on the tracer once at build, ``attn_plan`` as
+    it was; None where the prefill does not attend through the kernels."""
+    from tpu_parallel.obs import Tracer
+    from tpu_parallel.ops.flash_attention import flash_plan
+
+    cfg, model, _, params = build(prefill_flash=flash)
+    tracer = Tracer()
+    engine = ServingEngine(
+        model, params, n_slots=2, prefill_buckets=(16, 32), tracer=tracer
+    )
+    instants = {e["name"]: e["attrs"] for e in tracer.instants}
+    assert engine.attn_plan == {"decode": {"path": "xla"}}
+    if not flash:
+        assert engine.prefill_attn_plan is None
+        assert "prefill_attn_plan" not in instants
+        return
+    plan = engine.prefill_attn_plan
+    assert list(plan) == [
+        f"prefill_{b}{w}" for b in (16, 32, 40) for w in ("", "_window8")
+    ]
+    for bucket in (16, 32, 40):
+        for window in (0, 8):
+            want = flash_plan(bucket, 16, 4, cfg.dtype, window=window)["fwd"]
+            name = f"prefill_{bucket}" + (f"_window{window}" if window else "")
+            assert plan[name] == {
+                "tile": want["block_q"], "variant": "resident",
+                "tiles_computed": want["tiles_computed"],
+                "tiles_masked": want["tiles_masked"],
+            }
+    attrs = instants["prefill_attn_plan"]
+    assert (attrs["layers"], attrs["of_layers"]) == (8, 8)
+    assert attrs["prefill_40_window8_tile"] == 40
+    assert attrs["prefill_32_variant"] == "resident"
+
+
 def test_engine_refuses_a_model_that_drops():
     cfg = tiny_test(moe_experts=4, moe_top_k=2)
     assert cfg.drops_tokens

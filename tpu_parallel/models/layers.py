@@ -186,7 +186,7 @@ class TransformerConfig:
     attn_impl: str = "xla"  # "xla" | "flash" | "ring" | "ulysses"
     # flash kernel tile sizes; None derives the forward's and the backward's
     # tiles from the shape (ops.flash_attention.flash_plan, whose table is
-    # the v5e sweep in PERF.md section 6, PR 27); an int is used for both
+    # the v5e sweeps in PERF.md section 6, PRs 27 and 48); an int for both
     flash_block_q: Optional[int] = None
     flash_block_k: Optional[int] = None
     # sliding-window attention: 0 = full causal; >0 = each query sees only

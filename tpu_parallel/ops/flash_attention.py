@@ -18,28 +18,35 @@ because Pallas operands are real buffers, not fusible broadcasts).
 
 Two kernel variants share the band geometry (``_band_bounds``):
 
-- **resident** (``group * seq`` and ``seq_kv`` <= ``STREAM_SEQ_THRESHOLD``):
-  one grid step holds a whole (batch, head) row in VMEM and walks the tiles
-  of the band in a loop that is static at trace time, so each tile is
-  classified before it is emitted (``_tile_kind``): tiles outside the band
-  emit nothing, tiles strictly inside it emit no iota, compare or select,
-  and only the tiles an edge of the band crosses are masked.  Score tiles
-  are computed TRANSPOSED (``[block_k, block_q]``, keys on sublanes, queries
-  on lanes): the running max / sum, ``lse`` and ``delta`` are lane-dense
-  ``[1, block_q]`` rows and the output accumulator is ``[head_dim, block_q]``.
-  The backward is ONE kernel per (batch, kv head) row: from one score tile,
-  one ``exp`` and one ``dp`` it forms ``dv``, ``dk`` and ``dq`` (5 matmuls);
-  ``dq`` accumulates in an fp32 VMEM scratch and is written once.
-- **streamed** (longer rows, or more tiles than a static walk should
-  unroll): the K/V walk is a grid dimension; VMEM holds one [block_k, d]
-  tile plus fp32 online-softmax scratch carried across grid steps, so
-  residency is O(block) and seq 8k-32k fits v5e VMEM.  Out-of-band grid
-  steps clamp their index map to the previous block — Pallas skips the DMA
-  when the mapped block is unchanged — so causal still halves the traffic,
-  not just the FLOPs.  Its backward is the dq / dkv pair.
+- **resident**: one grid step holds a whole (batch, head) row in VMEM and
+  walks the tiles of the band in a loop that is static at trace time, so
+  each tile is classified before it is emitted (``_tile_kind``): tiles
+  outside the band emit nothing, tiles strictly inside it emit no iota,
+  compare or select, and only the tiles an edge of the band crosses are
+  masked.  Score tiles are computed TRANSPOSED (``[block_k, block_q]``, keys
+  on sublanes, queries on lanes): the running max / sum, ``lse`` and
+  ``delta`` are lane-dense ``[1, block_q]`` rows and the output accumulator
+  is ``[head_dim, block_q]``.  The backward is ONE kernel per (batch, kv
+  head) row: from one score tile, one ``exp`` and one ``dp`` it forms
+  ``dv``, ``dk`` and ``dq`` (5 matmuls); ``dq`` accumulates in an fp32 VMEM
+  scratch and is written once.
+- **streamed**: the K/V walk is a grid dimension; VMEM holds one
+  [block_k, d] tile plus fp32 online-softmax scratch carried across grid
+  steps, so residency is O(block) and any row fits v5e VMEM.  Out-of-band
+  grid steps clamp their index map to the previous block — Pallas skips the
+  DMA when the mapped block is unchanged — so causal still halves the
+  traffic, not just the FLOPs; every tile it computes is masked and every
+  grid step is paid.  Its backward is the dq / dkv pair.
 
 ``flash_plan`` maps the shape to the tiles of each pass and says which
-variant runs; chip numbers for both are in PERF.md (section 6, PR 27).
+variant runs.  The FORWARD is resident while the row's blocks and its live
+score tiles fit ``RESIDENT_VMEM_BUDGET`` and its walk has at most
+``MAX_STATIC_TILES`` bodies (``_fwd_streams``: bytes, not a row count; an
+8192-long causal row at a tile of 512 is 136 bodies), the BACKWARD while
+``group * seq`` and ``seq_kv`` are at most ``STREAM_SEQ_THRESHOLD`` rows
+and its walk is as short.  Chip numbers behind both rules are in PERF.md
+(section 6: PR 27 for the backward and heads of 64, PR 48 for the forward
+at heads of 128 and more and rows to 8192).
 
 Packed sequences: ``segment_ids`` [batch, seq] adds a same-segment condition
 to every tile in all kernels (each query can always see itself, so no row
@@ -67,11 +74,15 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu  # importable on CPU too
 
-# above this row length (K/V, or the group's queries) the streamed kernels
-# take over (a resident row at 4096 x 64 x bf16 is 1MB/operand in VMEM with
-# its lanes padded — comfortable; 16k+ overflows v5e VMEM once pipelining
-# double-buffers the operands)
+# above this row length (K/V, or the group's queries) the streamed BACKWARD
+# kernels take over (no cell runs a backward past 1024 rows: PR 27's rule)
 STREAM_SEQ_THRESHOLD = 4096
+# the resident FORWARD holds a row's q, k, v and output blocks in VMEM,
+# double-buffered, beside its live fp32 score tiles (``_resident_need``):
+# past this many bytes the streamed kernel runs (an 8192-long row of 192-wide
+# heads at a tile of 512 needs 44 MiB and compiles for a v5e; a 16k row at
+# the tile of 1024 it derives needs 80 MiB at 128 columns, 112 at 192)
+RESIDENT_VMEM_BUDGET = 64 << 20
 # the resident kernels unroll their tile walk at trace time: past this many
 # tile bodies in one kernel the streamed kernels (a grid, not an unroll) run
 MAX_STATIC_TILES = 160
@@ -271,11 +282,27 @@ def _stream_q_range(ki, block_q, block_k, causal, window, num_qi, q_offset=0):
 
 
 def _use_stream(rows: int, tiles: int, stream: Optional[bool]) -> bool:
-    """Streamed kernels for a row too long to hold in VMEM, or a walk of more
-    tile bodies than a kernel should unroll; an explicit ``stream`` wins."""
+    """Streamed BACKWARD kernels for a row too long to hold in VMEM, or a
+    walk of more tile bodies than a kernel should unroll; an explicit
+    ``stream`` wins."""
     if stream is not None:
         return bool(stream)
     return rows > STREAM_SEQ_THRESHOLD or tiles > MAX_STATIC_TILES
+
+
+def _fwd_streams(seq: int, seq_kv: int, width: int, dtype, block_q: int,
+                 block_k: int, tiles: int, stream: Optional[bool]) -> bool:
+    """The FORWARD's variant, for :func:`flash_plan` and :func:`_flash_fwd`
+    alike: streamed where the row's blocks at ``width`` columns and the live
+    score tiles need more VMEM than ``RESIDENT_VMEM_BUDGET``, or the walk
+    more tile bodies than a kernel should unroll; an explicit ``stream``
+    wins."""
+    if stream is not None:
+        return bool(stream)
+    need = _resident_need(
+        _fwd_row_bytes(seq, seq_kv, width, dtype), 0, block_q, block_k
+    )
+    return need > RESIDENT_VMEM_BUDGET or tiles > MAX_STATIC_TILES
 
 
 def _rows_can_be_empty(causal: bool, q_offset: int) -> bool:
@@ -302,14 +329,25 @@ def _derive_tile(seq: int, preferred: int) -> Optional[int]:
     return tile
 
 
-def _preferred_tiles(head_dim: int) -> Tuple[int, int]:
-    """(forward, backward) square tile of the resident kernels, from the
-    sweep on a TPU v5e recorded in PERF.md (section 6, PR 27; bf16, seq 1024
-    and 2048, tiles 128-1024 each way): the forward takes 512 below head
-    width 128 (its running max and sum are per-tile work that larger tiles
-    amortise) and 128 from 128 up (the MXU is full at any tile), the
-    backward 256 at both."""
-    return (512 if head_dim < 128 else 128), 256
+def _preferred_tiles(head_dim: int, rows: int) -> Tuple[int, int]:
+    """(forward, backward) square tile of the resident kernels, from two
+    sweeps on a TPU v5e (bf16; PERF.md section 6).  The backward takes 256
+    at every width (PR 27: rows of 1024 and 2048, tiles 128-1024 each way).
+    The forward (PR 48: heads of 64, 128, 192 and 256, rows of 128 to 8192,
+    causal, windowed and under the block rule, one K/V head a group or one a
+    head) takes 128 at a head width of exactly 128 on rows past 512 and 512
+    everywhere else.  At 128 columns the score matmul is one full pass of the
+    matrix unit and the kernel is bound by it at any tile, so the tile that
+    computes least beyond the diagonal wins (1024 to 3072 rows: 128 reads
+    55-87% of the least time, 512 16-35% more milliseconds).  At every other
+    width the walk pays by the tile BODY (64 columns leave the matrix unit
+    half empty, 192 cost it a second, half-empty pass and lay no operand on
+    the 128 lanes): 512 reads 48-67% where 128 reads 29-34% (192 columns,
+    whatever the group), and the same at 64 (4096 rows: 40% against 20% at
+    256).  A row of at most 512 is ONE tile at every width (one body beats
+    three or ten of a smaller tile by 10-20%).  The group, the rule and a
+    window move nothing: the key is the width and the row."""
+    return (128 if head_dim == 128 and rows > 512 else 512), 256
 
 
 def flash_plan(
@@ -332,13 +370,14 @@ def flash_plan(
 
     Derived tiles (``block_q`` / ``block_k`` None) are
     :func:`_preferred_tiles` cut to what divides the row; an explicit tile is
-    used for both passes.  ``dtype`` is part of the key and moves nothing
-    yet: the table was measured in bf16 and fp32 operands take the same
-    tiles.
+    used for both passes.  The forward's variant follows the bytes of the
+    row's blocks at ``head_dim`` columns of ``dtype`` (:func:`_fwd_streams`;
+    a caller with two widths gives the larger), the backward's the row count
+    (:func:`_use_stream`); the tiles were measured in bf16 and fp32 operands
+    take the same.
     """
-    del dtype
     seq_kv = seq if seq_kv is None else seq_kv
-    fwd_pref, bwd_pref = _preferred_tiles(head_dim)
+    fwd_pref, bwd_pref = _preferred_tiles(head_dim, max(seq, seq_kv))
     block = int(causal) if causal > 1 else 0  # the block rule's length
     if block and (window or q_offset % block):
         raise ValueError(
@@ -348,15 +387,15 @@ def flash_plan(
     plan = {"rule": "block" if block else "causal" if causal else "full"}
     if block:
         plan["block_len"] = block
-    for name, pref, rows, bodies in (
-        ("fwd", fwd_pref, max(seq, seq_kv), 1),
-        ("bwd", bwd_pref, max(seq_kv, group * seq), group),
-    ):
-        streamed = _use_stream(rows, 0, stream)
-        if streamed:
-            # the streamed kernels mask and re-fetch per tile: the larger
-            # tile they always ran with (no cell measures them yet)
-            pref = 512
+    bwd_rows = max(seq_kv, group * seq)
+    # the streamed kernels mask every tile and pay every grid step: the
+    # larger tile they always ran with (the forward streamed for its bytes
+    # or its bodies has a tile of 512 or more already)
+    if stream:
+        fwd_pref = 512
+    if _use_stream(bwd_rows, 0, stream):
+        bwd_pref = 512
+    for name, pref in (("fwd", fwd_pref), ("bwd", bwd_pref)):
         bq = _derive_tile(seq, pref) if block_q is None else min(block_q, seq)
         bk = _derive_tile(seq_kv, pref) if block_k is None else min(block_k, seq_kv)
         if bq is None or bk is None or seq % bq or seq_kv % bk:
@@ -366,7 +405,12 @@ def flash_plan(
         computed, masked = _count_tiles(
             seq // bq, seq_kv // bk, bq, bk, causal, window, q_offset
         )
-        streamed = _use_stream(rows, bodies * computed, stream)
+        if name == "fwd":
+            streamed = _fwd_streams(
+                seq, seq_kv, head_dim, dtype, bq, bk, computed, stream
+            )
+        else:
+            streamed = _use_stream(bwd_rows, group * computed, stream)
         plan[name] = {
             "block_q": bq,
             "block_k": bk,
@@ -387,15 +431,29 @@ def _padded_bytes(rows: int, cols: int, dtype) -> int:
     return -(-rows // sub) * sub * -(-cols // 128) * 128 * itemsize
 
 
+def _fwd_row_bytes(seq: int, seq_kv: int, width: int, dtype) -> int:
+    """VMEM bytes of the resident forward's four blocks of one row: q and
+    the output, k and v, each counted at ``width`` columns."""
+    return 2 * _padded_bytes(seq, width, dtype) + 2 * _padded_bytes(
+        seq_kv, width, dtype
+    )
+
+
+def _resident_need(block_bytes: int, scratch_bytes: int, block_q: int,
+                   block_k: int) -> int:
+    """VMEM bytes a resident kernel needs: its blocks (double-buffered), its
+    scratch and a handful of live fp32 score tiles."""
+    return 2 * block_bytes + scratch_bytes + 12 * block_q * block_k * 4
+
+
 def _resident_params(interpret: bool, block_bytes: int, scratch_bytes: int,
                      block_q: int, block_k: int):
     """Compiler parameters of a resident kernel: rows are independent, and
-    the VMEM limit follows the blocks (double-buffered), the scratch and a
-    handful of live fp32 score tiles instead of the 16 MiB default that a
-    4096-long row outgrows."""
+    the VMEM limit follows :func:`_resident_need` instead of the 16 MiB
+    default that a 4096-long row outgrows."""
     if interpret:
         return {}
-    need = 2 * block_bytes + scratch_bytes + 12 * block_q * block_k * 4
+    need = _resident_need(block_bytes, scratch_bytes, block_q, block_k)
     limit = min(max(need * 5 // 4, 32 << 20), 100 << 20)
     return {
         "compiler_params": pltpu.CompilerParams(
@@ -622,7 +680,7 @@ def _flash_fwd(
     tiles, _ = _count_tiles(
         n_q, s_kv // block_k, block_q, block_k, causal, window, q_offset
     )
-    if _use_stream(max(s, s_kv), tiles, stream):
+    if _fwd_streams(s, s_kv, max(d, e), q.dtype, block_q, block_k, tiles, stream):
         num_ki = s_kv // block_k
         kv_map = _stream_kv_map(
             kv_row, block_q, block_k, causal, window, num_ki, q_offset
@@ -677,9 +735,7 @@ def _flash_fwd(
         pl.BlockSpec((1, s_kv, e), lambda bh_: (kv_row(bh_), 0, 0)),
     ]
     args = [qf, kf, vf]
-    block_bytes = 2 * _padded_bytes(s, max(d, e), q.dtype) + 2 * _padded_bytes(
-        s_kv, max(d, e), q.dtype
-    )
+    block_bytes = _fwd_row_bytes(s, s_kv, max(d, e), q.dtype)
     if seg_q is not None:
         # all H heads of batch row b read the same ids: the q side as
         # lane-dense rows, one per q tile, the k side as a [S_kv, 1] column
@@ -1280,11 +1336,12 @@ def flash_chunk_attention(
     in both outputs — the lse cotangent folds into the backward kernel's
     delta correction, which is what makes the combine's gradient exact.
 
-    Same kernels as :func:`flash_attention`: up to ``STREAM_SEQ_THRESHOLD``
-    the resident pair (static tile walk, ONE backward pass with ``dq`` in an
-    fp32 VMEM scratch), the streamed ones beyond.  ``block_q`` / ``block_k``
-    None derives each pass's tiles from the chunk lengths
-    (:func:`flash_plan`).  The ``lse <= NEG_INF / 2`` guard for rows with no
+    Same kernels as :func:`flash_attention`: the resident pair (static tile
+    walk, ONE backward pass with ``dq`` in an fp32 VMEM scratch) while the
+    forward's blocks fit VMEM and the backward's rows are at most
+    ``STREAM_SEQ_THRESHOLD``, the streamed ones beyond.  ``block_q`` /
+    ``block_k`` None derives each pass's tiles from the chunk lengths
+    (:func:`_preferred_tiles`).  The ``lse <= NEG_INF / 2`` guard for rows with no
     visible key is compiled in exactly where such rows can exist
     (``causal=False`` or ``q_offset != 0``): a static condition.
 
@@ -1328,7 +1385,7 @@ def flash_chunk_attention(
 
     s_q, s_kv, d = q.shape[1], k.shape[1], q.shape[3]
     tiles = []
-    for preferred in _preferred_tiles(d):  # forward, backward
+    for preferred in _preferred_tiles(d, max(s_q, s_kv)):  # forward, backward
         want_q = block_q or _derive_tile(s_q, preferred) or preferred
         want_k = block_k or _derive_tile(s_kv, preferred) or preferred
         bq = math.gcd(s_q, min(want_q, s_q))
@@ -1389,8 +1446,10 @@ def flash_attention(
     not be equal); an explicit value is used for both passes.
 
     ``stream`` selects the long-sequence kernels (K/V walked as a grid
-    dimension, O(block_k) VMEM residency); ``None`` auto-selects them above
-    ``STREAM_SEQ_THRESHOLD`` tokens (or ``MAX_STATIC_TILES`` tile bodies).
+    dimension, O(block_k) VMEM residency); ``None`` auto-selects them where
+    :func:`flash_plan` says so: the forward where a row's blocks outgrow
+    ``RESIDENT_VMEM_BUDGET``, the backward above ``STREAM_SEQ_THRESHOLD``
+    rows, either past ``MAX_STATIC_TILES`` tile bodies.
     Below that the resident kernels run: a tile walk that is static at trace
     time (tiles outside the band emit nothing, tiles inside it no mask), and
     a backward that is ONE pass — per (batch, kv head) row every tile is
@@ -1488,8 +1547,8 @@ def flash_attention_fwd_bhsd(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     plan = flash_plan(
-        s, d, h // h_kv, q.dtype, block_q=block_q, block_k=block_k,
-        stream=stream,
+        s, max(d, v.shape[-1]), h // h_kv, q.dtype, block_q=block_q,
+        block_k=block_k, stream=stream,
     )
     if plan is None or plan["fwd"]["block_q"] % plan["fwd"]["block_k"]:
         raise ValueError(

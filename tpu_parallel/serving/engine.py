@@ -2050,6 +2050,7 @@ class ServingEngine:
         self.sampler_plan = self._plan_sampler(n_slots)
         self.block_plan = self._plan_blocks(n_slots)
         self.attn_plan = self._plan_attention(n_slots)
+        self.prefill_attn_plan = self._plan_prefill_attention()
         self.latent_plan = self._plan_latent(n_slots)
 
         n = n_slots
@@ -2068,6 +2069,12 @@ class ServingEngine:
         # acceptance-adaptive effective draft length (<= cap)
         self._spec_max = np.zeros(n, np.int32)
         self._spec_k = np.zeros(n, np.int32)
+
+    @staticmethod
+    def _flat_plan(plan: Dict[str, dict]) -> Dict[str, object]:
+        """A plan's entries as one flat dict, ``<shape>_<key>``: a tracer
+        instant's attributes."""
+        return {f"{name}_{k}": v for name, p in plan.items() for k, v in p.items()}
 
     def _plan_experts(self, n_slots: int) -> Optional[Dict[str, dict]]:
         """What the dropless expert layers do in each program shape this
@@ -2101,8 +2108,7 @@ class ServingEngine:
             self.tracer.instant(
                 "moe_plan", track="scheduler", layers=cfg.routed_layers,
                 of_layers=cfg.n_layers,
-                **{f"{name}_{k}": v for name, p in plan.items()
-                   for k, v in p.items()},
+                **self._flat_plan(plan),
             )
         return plan
 
@@ -2161,8 +2167,67 @@ class ServingEngine:
                 "attn_plan", track="scheduler",
                 layers=self.layer_kinds.get("attention", 0),
                 of_layers=cfg.n_layers,
-                **{f"{name}_{k}": v for name, p in plan.items()
-                   for k, v in p.items()},
+                **self._flat_plan(plan),
+            )
+        return plan
+
+    def _plan_prefill_attention(self) -> Optional[Dict[str, dict]]:
+        """What the flash kernels' FORWARD does in each whole-prompt prefill
+        shape (``ops.flash_attention.flash_plan`` at the bucket's row and the
+        layers' head width, group, rule and window, as the kernels' callers
+        ask it): the tile, ``resident`` or ``streamed``, and the tiles of one
+        row's walk computed and masked; a model whose attention layers differ
+        in their window has an entry a window (``prefill_<bucket>_window<w>``).
+        Logged and put on the tracer once at build; None where no prefill
+        attends through the kernels (``prefill_flash`` off, no buckets, no
+        attention layer).  Static a shape, as ``moe_plan`` and ``attn_plan``."""
+        from tpu_parallel.models.layers import depth_specs
+        from tpu_parallel.ops.flash_attention import flash_plan
+
+        cfg = self.model.config
+        kinds = set()  # (head width, group, window) of the attention layers
+        for spec in depth_specs(cfg):
+            if spec.mixer != "attention":
+                continue
+            if spec.attn == "latent":  # every head its own key, two widths
+                width = max(
+                    spec.latent.nope_dim + spec.latent.rope_dim,
+                    spec.latent.v_dim,
+                )
+                kinds.add((width, 1, 0))
+            else:
+                kinds.add((
+                    cfg.head_dim, cfg.n_heads // (cfg.n_kv_heads or cfg.n_heads),
+                    spec.window if spec.attn == "window" else 0,
+                ))
+        if not (cfg.prefill_flash and self._buckets and kinds):
+            return None
+        plan = {}
+        for bucket in self._buckets:
+            for width, group, window in sorted(kinds):
+                found = flash_plan(
+                    bucket, width, group, cfg.dtype,
+                    causal=cfg.block_len if cfg.block_len > 1 else True,
+                    window=window, block_q=cfg.flash_block_q,
+                    block_k=cfg.flash_block_k,
+                )
+                name = f"prefill_{bucket}" + (f"_window{window}" if window else "")
+                plan[name] = {"path": "dense"} if found is None else {
+                    "tile": found["fwd"]["block_q"],
+                    "variant": found["fwd"]["variant"],
+                    "tiles_computed": found["fwd"]["tiles_computed"],
+                    "tiles_masked": found["fwd"]["tiles_masked"],
+                }
+        logging.getLogger(__name__).info(
+            "prefill_attn_plan %s layers %s", json.dumps(plan),
+            json.dumps(self.layer_kinds),
+        )
+        if self.tracer.enabled:
+            self.tracer.instant(
+                "prefill_attn_plan", track="scheduler",
+                layers=self.layer_kinds.get("attention", 0),
+                of_layers=cfg.n_layers,
+                **self._flat_plan(plan),
             )
         return plan
 
@@ -2170,10 +2235,11 @@ class ServingEngine:
         """What a slot holds and which form runs for a model with latent
         attention layers (``models/latent_attention.py``): the heads and the
         five sizes, the bytes a position stores a layer and over the latent
-        layers, and the form of each program shape (``absorbed``: the stored
+        layers, the form of each program shape (``absorbed``: the stored
         rows are read as they lie; ``expanded``: the call's own latents are
-        up-projected once and go through the flash kernels); logged and put
-        on the tracer once at build; None without such a layer.
+        up-projected once and go through the flash kernels) and, last, what
+        the kernels do in each expanded shape (``prefill_attn_plan``); logged
+        and put on the tracer once at build; None without such a layer.
         ``latent_bytes_per_position`` goes to the metrics either way."""
         cfg = self.model.config
         if not self._latent:
@@ -2196,6 +2262,8 @@ class ServingEngine:
             plan[f"prefill_{b}"] = prefill
         if self._buckets is None:
             plan["prefill"] = prefill
+        if self.prefill_attn_plan:
+            plan["prefill_attn"] = self.prefill_attn_plan
         self.metrics.set_latent_bytes_per_position(plan["bytes_per_position"])
         logging.getLogger(__name__).info("latent_plan %s", json.dumps(plan))
         if self.tracer.enabled:
